@@ -18,10 +18,11 @@ import numpy as np
 
 __all__ = ["ByteArrayData", "byte_array_from_items"]
 
-try:  # CPython extension (native/pyext.c); every caller degrades without it
-    from .. import _native_ext as _ext
-except ImportError:  # pragma: no cover
-    _ext = None
+from ..utils.native import load_ext
+
+# CPython extension (native/pyext.c), built on first use; None when no
+# compiler is available — every caller degrades without it
+_ext = load_ext()
 
 
 @dataclass

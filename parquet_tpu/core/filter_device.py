@@ -48,6 +48,7 @@ except ImportError:  # pragma: no cover - callers gate on jax availability
 from ..kernels.device_ops import (
     list_contains_mask_device,
     predicate_mask_device,
+    prefix_sum,
 )
 from .arrays import ByteArrayData
 from .filter import FilterError
@@ -176,7 +177,7 @@ def _valid_expand(valid_np, nd, ctx, path):
         return hit
     v = jnp.asarray(valid_np)
     didx = jnp.clip(
-        jnp.cumsum(v.astype(jnp.int32)) - 1, 0, max(nd - 1, 0)
+        prefix_sum(v.astype(jnp.int32)) - 1, 0, max(nd - 1, 0)
     )
     ctx[key] = (v, didx)
     return v, didx

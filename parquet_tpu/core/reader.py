@@ -232,29 +232,33 @@ def _pad_ragged_device(values, lengths, max_len: int) -> RaggedColumn:
     """Scatter a flat element vector into [rows, max_len] ON DEVICE: row
     offsets come from a cumsum of lengths, each row gathers its slice, and
     slots past the row's length zero-fill. Static shapes — one compile per
-    (rows, max_len, dtype) bucket."""
+    (rows, element-count bucket, max_len, dtype)."""
     global _pad_ragged_jit
     import jax
     import jax.numpy as jnp
 
     if _pad_ragged_jit is None:
+        from ..kernels.device_ops import prefix_sum
 
         @partial(jax.jit, static_argnames=("max_len",))
         def pad(v, ln, max_len):
             offs = jnp.concatenate(
-                [jnp.zeros(1, jnp.int32), jnp.cumsum(ln, dtype=jnp.int32)]
+                [jnp.zeros(1, jnp.int32), prefix_sum(ln.astype(jnp.int32))]
             )
             idx = offs[:-1, None] + jnp.arange(max_len, dtype=jnp.int32)[None, :]
             nv = v.shape[0]
             mask = jnp.arange(max_len, dtype=jnp.int32)[None, :] < ln[:, None]
-            safe = jnp.clip(idx, 0, max(nv - 1, 0))
-            vals = v[safe] if nv else jnp.zeros(idx.shape, v.dtype)
             zero = jnp.zeros((), v.dtype)
-            return jnp.where(mask, vals, zero)
+            return jnp.where(mask, v[jnp.clip(idx, 0, nv - 1)], zero)
 
         _pad_ragged_jit = pad
+    from ..kernels.pipeline import _pad_device
+
+    # the element count differs in every group: bucket-pad it (see
+    # _expand_nullable_device); slots past a row's length are masked
     return RaggedColumn(
-        values=_pad_ragged_jit(values, lengths, max_len), lengths=lengths
+        values=_pad_ragged_jit(_pad_device(values), lengths, max_len),
+        lengths=lengths,
     )
 
 
@@ -282,17 +286,24 @@ def _expand_nullable_device(values, mask) -> MaskedColumn:
     import jax.numpy as jnp
 
     if _expand_nullable_jit is None:
+        from ..kernels.device_ops import prefix_sum
 
         @jax.jit
         def expand(v, m):
-            idx = jnp.cumsum(m) - 1
-            idx = jnp.clip(idx, 0, jnp.maximum(v.shape[0] - 1, 0))
-            dense = v[idx] if v.shape[0] else jnp.zeros(m.shape, v.dtype)
+            idx = prefix_sum(m.astype(jnp.int32)) - 1
+            idx = jnp.clip(idx, 0, v.shape[0] - 1)
             zero = jnp.zeros((), v.dtype)
-            return jnp.where(m, dense, zero)
+            return jnp.where(m, v[idx], zero)
 
         _expand_nullable_jit = expand
-    return MaskedColumn(values=_expand_nullable_jit(values, mask), mask=mask)
+    from ..kernels.pipeline import _pad_device
+
+    # the non-null count differs in every group: pad to its bucket so the
+    # program compiles once per bucket, not once per group (seconds each on
+    # a TPU); the padding is never gathered — idx stays below the count
+    return MaskedColumn(
+        values=_expand_nullable_jit(_pad_device(values), mask), mask=mask
+    )
 
 
 # Rows materialize in windows this size: cyclic GC cost scales with LIVE

@@ -571,12 +571,19 @@ class DatasetIterator:
         if placement is None:
             yield from gen
             return
-        from ..kernels.pipeline import device_put_pipelined
+        from ..kernels.pipeline import check_double_delivery, device_put_pipelined
 
         states: deque = deque()
 
         def host_side():
             for b, s in gen:
+                doubles = [
+                    ".".join(p) for p, a in b.items() if a.dtype == np.float64
+                ]
+                if doubles:
+                    # a TPU hands float64 back an ulp off: typed refusal,
+                    # never an inexact batch (kernels.pipeline.DeviceDoubleError)
+                    check_double_delivery(doubles, placement)
                 states.append(s)  # appended before the yield: stays aligned
                 yield b
 

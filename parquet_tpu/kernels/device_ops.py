@@ -43,25 +43,26 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
-# Persistent XLA compilation cache: the decode programs compile in O(100s) on
-# a real TPU backend (one-time per shape bucket); caching them on disk makes
-# every process after the first start in seconds. Opt out with
-# PQT_JAX_COMPILE_CACHE=0; the location is PQT_JAX_COMPILE_CACHE_DIR
-# (default ~/.cache/parquet_tpu/jax). A user-set jax_compilation_cache_dir
-# always wins.
-if (
-    os.environ.get("PQT_JAX_COMPILE_CACHE", "1") != "0"
-    and getattr(jax.config, "jax_compilation_cache_dir", None) is None
-):
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.environ.get(
-            "PQT_JAX_COMPILE_CACHE_DIR",
-            os.path.join(os.path.expanduser("~"), ".cache", "parquet_tpu", "jax"),
-        ),
-    )
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+# Persistent XLA compilation cache, shared by every process of a run. Where
+# JAX_COMPILATION_CACHE_DIR is set (jax reads it into its own config) or the
+# embedder configured a directory, no directory is set here; otherwise ONE
+# fixed, git-ignored path inside the checkout — the path is part of the
+# cache key, so a directory that moves never hits. The thresholds are set
+# either way: most of these programs compile in well under a second, and
+# jax's defaults would leave them out of an externally placed cache.
+# What a cold cache costs on a v5e (libtpu 0.0.34; chip_smoke.py at 1M-row
+# groups, PERF.md PR 21): ~190 programs in ~70 s — most under a second, delta
+# decode 4-8 s per shape, and the one 1M-element sort in dict_indices_device
+# 22 s. Nothing is near the O(100 s) per shape bucket this comment once
+# warned of, as long as row-group-sized scans go through prefix_sum.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+if jax.config.jax_compilation_cache_dir is None:
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 import jax.numpy as jnp
 import numpy as np
@@ -87,9 +88,66 @@ __all__ = [
     "masked_agg_device",
 ]
 
+
+def device_facts(device=None) -> dict:
+    """What jax reports for `device` (None = the process default, jax's
+    devices()[0]): the identity every entry point that serves or measures
+    the device path prints, so a run never has to be guessed at."""
+    devs = jax.devices()
+    d = devs[0] if device is None else device
+    return {
+        "platform": d.platform,
+        "kind": d.device_kind,
+        "id": d.id,
+        "count": len(devs),
+    }
+
+
+def require_chip(device=None) -> dict:
+    """device_facts() for entry points whose point IS the accelerator
+    (`serve --device`, bench.py's device phases): a device that is not a TPU
+    is refused unless JAX_PLATFORMS names cpu outright — the way tests and
+    rehearsals ask for the CPU — so a missing chip fails loudly instead of
+    quietly timing XLA:CPU."""
+    facts = device_facts(device)
+    asked_cpu = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+    if facts["platform"] != "tpu" and not asked_cpu:
+        raise RuntimeError(
+            f"parquet_tpu: the device path needs a TPU, jax found "
+            f"{facts['platform']} ({facts['kind']}); set JAX_PLATFORMS=cpu "
+            "to run it on the CPU on purpose"
+        )
+    return facts
+
+
 # Largest bit offset representable in the int32 position math (host drivers
 # assert batches stay under this; 2^31 bits = 256 MiB of packed payload).
 MAX_DEVICE_BATCH_BITS = 1 << 31
+
+
+# Row length of the two-level prefix sum below.
+_SCAN_BLOCK = 1024
+
+
+@jax.jit
+def prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum of a 1-D integer array in its own dtype — the
+    values of jnp.cumsum, wrapping included — computed in two levels: rows of
+    _SCAN_BLOCK scan independently, the row totals scan recursively, and the
+    carries add back. The flat form is what XLA:TPU cannot compile quickly:
+    one jnp.cumsum over 2^20 elements takes 33 s (int32) to 54 s (uint64) to
+    compile on a v5e, this form 1-3 s, and both run in ~1 ms (PERF.md,
+    PR 21) — every kernel here that scans a row group goes through it."""
+    n = x.shape[0]
+    if n <= _SCAN_BLOCK:
+        return jnp.cumsum(x)
+    pad = (-n) % _SCAN_BLOCK
+    if pad:
+        x = jnp.concatenate([x, jnp.zeros(pad, x.dtype)])
+    inner = jnp.cumsum(x.reshape(-1, _SCAN_BLOCK), axis=1)
+    totals = inner[:, -1]
+    carry = prefix_sum(totals) - totals
+    return (inner + carry[:, None]).reshape(-1)[:n]
 
 
 def bytes_to_words32(data: bytes) -> np.ndarray:
@@ -215,7 +273,7 @@ def delta_packed_decode_device(
         )
         d = ((lo | hi) & mask) + mb_min[m]
         d = jnp.where(is_start, jnp.uint32(0), d)
-        c = jnp.cumsum(d, dtype=jnp.uint32)
+        c = prefix_sum(d)
         vals = page_first[p] + c - c[page_start[p]]
         return jax.lax.bitcast_convert_type(vals, jnp.int32)
     bitpos = mb_bit_start[m] + within * w.astype(jnp.int32)
@@ -231,7 +289,7 @@ def delta_packed_decode_device(
     )
     d = ((lo | hi) & mask) + mb_min[m]
     d = jnp.where(is_start, jnp.uint64(0), d)
-    c = jnp.cumsum(d, dtype=jnp.uint64)
+    c = prefix_sum(d)
     vals = page_first[p] + c - c[page_start[p]]
     return jax.lax.bitcast_convert_type(vals, jnp.int64)
 
@@ -268,7 +326,7 @@ def record_starts_device(rep: jnp.ndarray):
     prefix count of starts, minus one. Returns (row_of int32[n], n_rows
     int32 scalar) — both stay on device for downstream ragged-batch math."""
     starts = (rep == 0).astype(jnp.int32)
-    row_of = jnp.cumsum(starts) - 1
+    row_of = prefix_sum(starts) - 1
     return row_of, jnp.sum(starts)
 
 
@@ -288,7 +346,7 @@ def list_layout_device(
     An entry opens a slot iff rep <= parent_rep; it starts an element of
     this depth iff additionally-or-independently rep <= parent_rep + 1 AND
     dfl >= elem_def (below elem_def the entry is the placeholder of an
-    empty or null list). All prefix sums are jnp.cumsum; the per-slot
+    empty or null list). All prefix sums are prefix_sum; the per-slot
     element counts are one scatter-add — the shapes XLA executes well
     (SURVEY §7.2 M3).
 
@@ -302,7 +360,7 @@ def list_layout_device(
     """
     n = rep.shape[0]
     boundary = rep <= parent_rep
-    slot_of = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+    slot_of = prefix_sum(boundary.astype(jnp.int32)) - 1
     exists = dfl >= elem_def
     elem_start = (rep <= parent_rep + 1) & exists
     counts = (
@@ -311,7 +369,7 @@ def list_layout_device(
         .add(elem_start.astype(jnp.int32))
     )
     offsets = jnp.concatenate(
-        [jnp.zeros(1, dtype=jnp.int32), jnp.cumsum(counts)]
+        [jnp.zeros(1, dtype=jnp.int32), prefix_sum(counts)]
     )
     first_def = (
         jnp.zeros(n, dtype=jnp.int32)
@@ -367,7 +425,7 @@ def list_contains_mask_device(
     n = rep.shape[0]
     valid = dfl == elem_def
     didx = jnp.clip(
-        jnp.cumsum(valid.astype(jnp.int32)) - 1,
+        prefix_sum(valid.astype(jnp.int32)) - 1,
         0,
         max(dense_match.shape[0] - 1, 0),
     )
@@ -376,12 +434,14 @@ def list_contains_mask_device(
     else:
         entry_match = jnp.zeros(n, dtype=bool)
     starts = (rep == 0).astype(jnp.int32)
-    row_of = jnp.cumsum(starts) - 1
+    row_of = prefix_sum(starts) - 1
+    # scattered as int32: the same scatter over bool takes XLA:TPU 17 s to
+    # compile at a 1M-row group (PERF.md, PR 21)
     rows = (
-        jnp.zeros(n, dtype=bool)
+        jnp.zeros(n, dtype=jnp.int32)
         .at[jnp.clip(row_of, 0, max(n - 1, 0))]
-        .max(entry_match)
-    )
+        .max(entry_match.astype(jnp.int32))
+    ) > 0
     return rows, jnp.sum(starts)
 
 
@@ -394,7 +454,7 @@ def mask_take_device(values: jnp.ndarray, mask: jnp.ndarray, out_pad: int):
     (tiny) count fetch, or carry (taken, count) into downstream masked
     kernels unsliced."""
     n = values.shape[0]
-    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
+    pos = prefix_sum(mask.astype(jnp.int32)) - 1
     tgt = jnp.where(mask, pos, out_pad)
     src = (
         jnp.zeros(out_pad + 1, dtype=jnp.int32)
@@ -479,7 +539,7 @@ def rle_hybrid_encode_device(values: jnp.ndarray, width: int):
     boundary = jnp.concatenate(
         [jnp.ones(1, dtype=bool), values[1:] != values[:-1]]
     )
-    run_of = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+    run_of = prefix_sum(boundary.astype(jnp.int32)) - 1
     # per-position run extent via segment scatter of starts/ends
     run_start = (
         jnp.full(n, n, dtype=jnp.int32).at[run_of].min(jnp.where(boundary, i, n))
@@ -495,7 +555,7 @@ def rle_hybrid_encode_device(values: jnp.ndarray, width: int):
     n_bp = jnp.sum(~in_rle)
     # compact the bit-packed positions (stable order), pad tail with zeros
     # so the trailing partial group packs its zero padding
-    pos = jnp.cumsum((~in_rle).astype(jnp.int32)) - 1
+    pos = prefix_sum((~in_rle).astype(jnp.int32)) - 1
     tgt = jnp.where(~in_rle, pos, n)
     src = (
         jnp.full(n + 1, -1, dtype=jnp.int32)
@@ -534,7 +594,7 @@ def dict_indices_device(values: jnp.ndarray):
     order = jnp.argsort(values, stable=True).astype(jnp.int32)
     sv = values[order]
     newg = jnp.concatenate([jnp.ones(1, dtype=bool), sv[1:] != sv[:-1]])
-    gid_sorted = jnp.cumsum(newg.astype(jnp.int32)) - 1
+    gid_sorted = prefix_sum(newg.astype(jnp.int32)) - 1
     n_uniques = gid_sorted[-1] + 1
     # first occurrence row of each (sorted-domain) group
     first_of_group = (
@@ -597,7 +657,7 @@ def delta_block_encode_device(values: jnp.ndarray, n, nbits: int):
         amax == 0, ut(0), ut(nbits) - jax.lax.clz(amax).astype(ut)
     ).astype(jnp.int32)
     pay_start = jnp.concatenate(
-        [jnp.zeros(1, dtype=jnp.int32), jnp.cumsum(4 * widths)]
+        [jnp.zeros(1, dtype=jnp.int32), prefix_sum(4 * widths)]
     )
     m = i >> 5
     w = widths[m]
@@ -761,7 +821,7 @@ def merge_mixed_bytes_device(
     lengths = jnp.where(rows < n_rows, jnp.where(is_dict, dlen, plen), 0)
     lengths = jnp.maximum(lengths, 0)
     off = jnp.concatenate(
-        [jnp.zeros(1, dtype=jnp.int64), jnp.cumsum(lengths, dtype=jnp.int64)]
+        [jnp.zeros(1, dtype=jnp.int64), prefix_sum(lengths.astype(jnp.int64))]
     )
     pos = jnp.arange(total_bytes_pad, dtype=jnp.int64)
     row = jnp.searchsorted(off[1:], pos, side="right")

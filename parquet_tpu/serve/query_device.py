@@ -116,6 +116,7 @@ def device_unit_partial(reader, row_group: int, query, filters, device=None):
 
         from ..core.filter_device import _device_numeric_view
         from ..kernels.device_ops import masked_agg_device
+        from ..kernels.pipeline import DeviceDoubleError
     except ImportError as e:  # pragma: no cover - jax-less deployment
         raise DeviceQueryError(f"query_device: jax unavailable: {e}") from None
 
@@ -150,9 +151,14 @@ def device_unit_partial(reader, row_group: int, query, filters, device=None):
                     paths.append(e[0])
 
     n = int(reader.row_group(row_group).num_rows or 0)
-    group = reader.read_row_group_device(
-        row_group, paths or None, device=device
-    )
+    try:
+        group = reader.read_row_group_device(
+            row_group, paths or None, device=device
+        )
+    except DeviceDoubleError as e:
+        # a DOUBLE filter/count column on a device without native f64:
+        # the unit is exact on the host
+        raise DeviceQueryError(f"query_device: {e}") from None
 
     mask = None
     matched = n

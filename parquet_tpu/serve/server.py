@@ -260,6 +260,16 @@ class ScanService:
 
     def __init__(self, config: ServeConfig):
         self.config = config
+        # the device /v1/query units run on, as jax reports it: stated at
+        # start-up and in /healthz rather than left as "whatever
+        # devices()[0] happened to be" on a multi-chip host
+        self.device_info = None
+        if config.device is not None:
+            from ..kernels.device_ops import device_facts
+
+            self.device_info = device_facts(
+                None if config.device is True else config.device
+            )
         if config.block_cache is not None:
             block_cache = config.block_cache
             self._owns_cache = False
@@ -525,6 +535,8 @@ class ScanService:
             "in_flight": in_flight,
             "slo": verdict,
         }
+        if self.device_info is not None:
+            body["device"] = self.device_info
         if draining:
             # the mesh client's failover reads this to tell "drains in a
             # couple seconds, come back" from "gone" — the remaining

@@ -1,21 +1,32 @@
-"""ctypes loader for the optional native C++ helper library (native/).
+"""Builder + ctypes loader for the optional native C++ helper library (native/).
 
 The native library accelerates the host-side scalar hot spots that neither
 NumPy nor the TPU can absorb: snappy (de)compression, PLAIN byte_array offset
-scans, and hybrid/delta run-header prescans. Everything degrades gracefully to
-the pure-Python implementations when the library is not built.
+scans, and hybrid/delta run-header prescans. Both artifacts — the ctypes
+library and the `_native_ext` CPython extension — are build outputs, never
+tracked: the first use in a checkout compiles them from native/*.cc|.h|.c
+(and again whenever a source is newer than an artifact). Everything degrades
+gracefully to the pure-Python implementations when no compiler is available;
+callers that must not degrade (the chip smoke, bench.py, `serve --device`)
+go through require_native().
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import threading
 from pathlib import Path
 from typing import NamedTuple
 
-_SO_NAMES = ("libparquet_tpu_native.so",)
+_ROOT = Path(__file__).resolve().parent.parent.parent
+_NATIVE_DIR = _ROOT / "native"
+_SOURCES = ("parquet_tpu_native.cc", "parquet_tpu_native.h", "pyext.c")
+_LIB_PATH = _NATIVE_DIR / "build" / "libparquet_tpu_native.so"
 _cached = None
 _probed = False
+_probe_lock = threading.Lock()
+_build_error: str | None = None  # why the last build attempt failed
 
 # ptq_chunk_prepare err_info[0] stage codes (parquet_tpu_native.h PTQ_STAGE_*).
 PREPARE_STAGES = {
@@ -393,16 +404,9 @@ class NativeLib:
         # binds them in C. Falls back transparently when the extension is
         # absent (ctypes also drops the GIL during the foreign call, so
         # multi-thread prepare stays correct either way, just slower).
-        self._ext_chunk_prepare = None
-        self._ext_chunk_encode = None
-        if self.has_chunk_prepare or self.has_chunk_encode:
-            try:
-                from .. import _native_ext as _ext
-
-                self._ext_chunk_prepare = getattr(_ext, "chunk_prepare", None)
-                self._ext_chunk_encode = getattr(_ext, "chunk_encode", None)
-            except ImportError:
-                pass
+        _ext = load_ext()
+        self._ext_chunk_prepare = getattr(_ext, "chunk_prepare", None)
+        self._ext_chunk_encode = getattr(_ext, "chunk_encode", None)
         self.fused_gil_free = self._ext_chunk_prepare is not None
 
     def snappy_compress(self, data) -> bytes:
@@ -1187,22 +1191,114 @@ class NativeLib:
         return out
 
 
+def _ext_path() -> Path:
+    import sysconfig
+
+    return _ROOT / "parquet_tpu" / (
+        "_native_ext" + (sysconfig.get_config_var("EXT_SUFFIX") or ".so")
+    )
+
+
+def _stale(artifacts) -> bool:
+    newest = max((_NATIVE_DIR / s).stat().st_mtime_ns for s in _SOURCES)
+    return any(
+        not a.exists() or a.stat().st_mtime_ns < newest for a in artifacts
+    )
+
+
+def _ensure_built() -> None:
+    """Compile both native artifacts when one is missing or older than its
+    sources. native/Makefile is the one recipe; it runs into a private
+    directory and the results are renamed into place, so a concurrent
+    process (or a dlopen in this one) only ever sees a whole file. A flock
+    keeps racing processes from compiling the same thing twice. A failed
+    build is remembered (require_native reports it), never raised: the
+    pure-Python paths stay available."""
+    global _build_error
+    artifacts = (_LIB_PATH, _ext_path())
+    try:
+        if not _stale(artifacts):
+            return
+    except OSError:
+        return  # installed without native/ sources: nothing to build from
+    import fcntl
+    import shutil
+    import subprocess
+    import sysconfig
+
+    try:
+        _LIB_PATH.parent.mkdir(parents=True, exist_ok=True)
+        with open(_LIB_PATH.parent / ".lock", "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            if not _stale(artifacts):
+                return  # another process built while we waited
+            tmp = Path("build") / f"tmp.{os.getpid()}"
+            try:
+                subprocess.run(
+                    [
+                        "make", "-s", "-C", str(_NATIVE_DIR),
+                        f"BUILD={tmp}",
+                        f"PYEXT={tmp / artifacts[1].name}",
+                        f"PYINC=-I{sysconfig.get_paths()['include']}",
+                    ],
+                    check=True, capture_output=True, text=True,
+                )
+                for a in artifacts:
+                    os.replace(_NATIVE_DIR / tmp / a.name, a)
+                _build_error = None
+            finally:
+                shutil.rmtree(_NATIVE_DIR / tmp, ignore_errors=True)
+    except subprocess.CalledProcessError as e:
+        _build_error = f"make -C native failed: {e.stderr[-800:]}"
+    except OSError as e:  # no make/compiler, read-only checkout
+        _build_error = f"native build unavailable: {e}"
+
+
+def load_ext():
+    """The `_native_ext` CPython extension module (built on first use), or
+    None when it cannot be built — every caller degrades without it."""
+    _ensure_built()
+    try:
+        from .. import _native_ext
+    except ImportError:
+        return None
+    return _native_ext
+
+
 def get_native() -> NativeLib | None:
-    """Load the native helper library, or None if not built/loadable."""
+    """Load the native helper library (building it when missing or older
+    than its sources), or None if it cannot be built/loaded."""
     global _cached, _probed
     if _probed:
         return _cached
-    _probed = True
-    root = Path(__file__).resolve().parent.parent.parent
-    candidates = [root / "native" / "build" / name for name in _SO_NAMES]
-    env = os.environ.get("PARQUET_TPU_NATIVE")
-    if env:
-        candidates.insert(0, Path(env))
-    for cand in candidates:
-        if cand.exists():
-            try:
-                _cached = NativeLib(ctypes.CDLL(str(cand)))
-                break
-            except OSError:
-                continue
+    with _probe_lock:
+        if _probed:
+            return _cached
+        _ensure_built()
+        candidates = [_LIB_PATH]
+        env = os.environ.get("PARQUET_TPU_NATIVE")
+        if env:
+            candidates.insert(0, Path(env))
+        for cand in candidates:
+            if cand.exists():
+                try:
+                    _cached = NativeLib(ctypes.CDLL(str(cand)))
+                    break
+                except OSError:
+                    continue
+        _probed = True
     return _cached
+
+
+def require_native() -> NativeLib:
+    """get_native() for callers that measure or serve the device path: the
+    fused native walk AND its GIL-free binding must be loaded — a run that
+    quietly took the per-page Python walk is a different program."""
+    lib = get_native()
+    if lib is None or not lib.has_chunk_prepare or not lib.fused_gil_free:
+        raise RuntimeError(
+            "parquet_tpu: the native library and its _native_ext binding are "
+            "required here but "
+            + (_build_error or "could not be loaded")
+        )
+    return lib
