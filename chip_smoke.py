@@ -21,8 +21,11 @@ This process never imports jax: a chip belongs to one process at a time, so
 each leg runs in a child that owns it alone, and the children share one
 compile cache. Any failed check fails the run. The platform must be `tpu`
 unless `--platform cpu` asks for a rehearsal by name — what jax happens to
-find decides nothing. On success the last stdout line is one JSON summary
-(also written to chiprun_out/chip_smoke.json); on failure there is none.
+find decides nothing. On success the full summary is written to
+chiprun_out/chip_smoke.json and printed as a `smoke: summary {...}` line, and
+the last stdout line is the verdict the driver reads, nothing more:
+`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`.
+On failure there is neither.
 """
 
 from __future__ import annotations
@@ -736,7 +739,9 @@ def main() -> int:
     out_dir = ROOT / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
     (out_dir / "chip_smoke.json").write_text(json.dumps(summary, indent=1) + "\n")
-    print(json.dumps(summary), flush=True)
+    say("summary " + json.dumps(summary))
+    # the last line is the driver's: exactly these keys, nothing beside them
+    print(json.dumps({"ok": True, "device": summary["device"]}), flush=True)
     return 0
 
 

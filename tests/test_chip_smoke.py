@@ -43,8 +43,15 @@ class TestChipSmoke:
         )
         assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
         assert "REHEARSAL" in r.stdout
-        summary = json.loads(r.stdout.strip().splitlines()[-1])
-        assert summary["ok"] is True
+        lines = r.stdout.strip().splitlines()
+        # the last line is the driver's verdict: exactly these keys
+        verdict = json.loads(lines[-1])
+        assert set(verdict) == {"ok", "device"} and verdict["ok"] is True
+        assert set(verdict["device"]) == {"platform", "kind", "count"}
+        assert isinstance(verdict["device"]["count"], int)
+        (tagged,) = [l for l in lines if l.startswith("smoke: summary ")]
+        summary = json.loads(tagged[len("smoke: summary "):])
+        assert summary["ok"] is True and summary["device"] == verdict["device"]
         assert summary["mode"] == "rehearsal, cpu"
         # (count follows the virtual CPU mesh conftest asks XLA for)
         assert (summary["device"]["platform"], summary["device"]["kind"]) == ("cpu", "cpu")
