@@ -43,8 +43,9 @@ from .page import PageError
 from .schema import Schema
 from ..meta.thrift import ThriftError
 from ..obs.log import log_event as _log_event
+from ..obs.pool import instrumented_submit
 from ..utils import metrics as _metrics
-from ..utils.trace import bump, span, stage, timed_stage, traced_submit
+from ..utils.trace import bump, name_os_thread, span, stage, timed_stage
 
 __all__ = ["FileReader", "PARQUET_ERRORS", "resolve_column_prefixes"]
 
@@ -105,7 +106,9 @@ def _host_pool() -> ThreadPoolExecutor | None:
     with _pool_lock:
         if _pool is None:
             _pool = ThreadPoolExecutor(
-                max_workers=workers, thread_name_prefix="pqt-host"
+                max_workers=workers,
+                thread_name_prefix="pqt-host",
+                initializer=name_os_thread,
             )
         return _pool
 
@@ -126,11 +129,18 @@ def _with_device(fn, device):
         return fn()
 
 
-def _dispatch_traced(fn, device):
+def _chunk_args(group: int, path) -> dict:
+    """The identifier one chunk's chunk.prepare -> dispatch -> deliver spans
+    share across the three threads that serve it."""
+    return {"group": group, "column": ".".join(path)}
+
+
+def _dispatch_traced(fn, device, args):
     """Dispatch-thread task wrapper: device pinning plus a 'dispatch' stage
     so traces attribute transfer/launch wall time to the pqt-dispatch lane
-    (the trace itself arrives via traced_submit's context carry)."""
-    with stage("dispatch"):
+    (the trace itself arrives via instrumented_submit's context carry);
+    the plan's dispatch.upload / dispatch.launch stages nest inside it."""
+    with stage("dispatch", args=args):
         return _with_device(fn, device)
 
 
@@ -773,11 +783,18 @@ class FileReader:
         with span("row_group.device", {"group": i}):
             plans = self._plan_row_group(i, columns, device=device)
             with self._devctx(device):
-                out = {path: plan.device_column() for path, plan in plans.items()}
-            if pack and self.compact_levels:
-                for path, dc in out.items():
-                    self._pack_chunk_levels(path, dc)
-            return out
+                return {
+                    path: self._deliver(i, path, plan, pack)
+                    for path, plan in plans.items()
+                }
+
+    def _deliver(self, i: int, path, plan, pack: bool = True):
+        """The consumer thread's share of one chunk, as the 'deliver' stage:
+        the plan's last device launches (dictionary gather, concatenation)
+        and the level packing. Waiting for the plan's future stays outside."""
+        with stage("deliver", args=_chunk_args(i, path)):
+            dc = plan.device_column()
+            return self._pack_chunk_levels(path, dc) if pack else dc
 
     def read_row_groups_device(self, row_groups=None, columns=None, device=None):
         """Decode row groups into device memory with full pipelining.
@@ -802,14 +819,14 @@ class FileReader:
             ]
         staged = self._plan_row_groups_async(indices, columns, device=device)
         out = []
-        for group in staged:
+        for i, group in zip(indices, staged):
             with self._devctx(device):
-                cols = {
-                    path: fut.result().device_column() for path, fut in group
-                }
-            out.append(
-                {p: self._pack_chunk_levels(p, dc) for p, dc in cols.items()}
-            )
+                out.append(
+                    {
+                        path: self._deliver(i, path, fut.result())
+                        for path, fut in group
+                    }
+                )
         return out
 
     def _plan_row_group_async(self, i: int, columns=None, device=None):
@@ -1059,7 +1076,8 @@ class FileReader:
                     # no level packing here: _array_of consumes the levels
                     # (mask build) within this iteration, so they never rest
                     group = {
-                        path: fut.result().device_column() for path, fut in staged
+                        path: self._deliver(i, path, fut.result(), pack=False)
+                        for path, fut in staged
                     }
                 else:
                     group = self._read_row_group_device(
@@ -1131,65 +1149,70 @@ class FileReader:
         from ..utils.native import get_native
         from .chunk import ChunkWindow, chunk_byte_range
 
-        groups = [list(self._selected_chunks(i, columns)) for i in indices]
+        groups = [(i, list(self._selected_chunks(i, columns))) for i in indices]
+        # with a block cache attached the planner bills its own io.read
+        # (misses only); the direct source read is billed here
+        direct_read = self._block_cache is None
 
-        def prep(path, cc, column):
-            with span("chunk.prepare", {"column": ".".join(path)}):
+        def prep(i, path, cc, column):
+            with span("chunk.prepare", _chunk_args(i, path)):
                 offset, total = chunk_byte_range(cc)
-                win = ChunkWindow(self._fetch_chunk(offset, total), offset)
+                with stage("io.read", total) if direct_read else nullcontext():
+                    raw = self._fetch_chunk(offset, total)
                 return prepare_chunk_plan(
-                    win, cc, column, validate_crc=self.validate_crc, alloc=self.alloc
+                    ChunkWindow(raw, offset),
+                    cc,
+                    column,
+                    validate_crc=self.validate_crc,
+                    alloc=self.alloc,
                 )
 
         dev = self._effective_device(device)
         dispatcher = _dispatch_pool()
         pool = _host_pool()
-        # Both pool hops use traced_submit: an active decode_trace is a
-        # contextvar, which ThreadPoolExecutor does NOT carry into workers
+
+        def dispatch(i, path, plan):
+            return instrumented_submit(
+                dispatcher,
+                _dispatch_traced,
+                plan.dispatch_device,
+                dev,
+                _chunk_args(i, path),
+            )
+
+        # Both pool hops use instrumented_submit: an active decode_trace is
+        # a contextvar, which ThreadPoolExecutor does NOT carry into workers
         # by itself — without the explicit copy_context() carry a traced
         # device read would lose every prepare/dispatch stage to the void
         # (and two concurrent traced readers sharing the pools would have no
-        # way to attribute worker time to the right trace).
-        staged = []
-        if pool is None or sum(len(g) for g in groups) <= 1:
+        # way to attribute worker time to the right trace). It also records
+        # how long each task waited for its pool (pool.wait): for the single
+        # pqt-dispatch thread, the wait of a prepared chunk behind the queue.
+        if pool is None or sum(len(chunks) for _, chunks in groups) <= 1:
             # Single-core host: prepare serially; device dispatch (transfer
             # RPCs, which release the GIL) still overlaps the next prepare.
-            for chunks in groups:
-                out = []
-                for path, cc, column in chunks:
-                    plan = prep(path, cc, column)
-                    out.append(
-                        (
-                            path,
-                            traced_submit(
-                                dispatcher, _dispatch_traced, plan.dispatch_device, dev
-                            ),
-                        )
-                    )
-                staged.append(out)
-            return staged
+            return [
+                [
+                    (path, dispatch(i, path, prep(i, path, cc, column)))
+                    for path, cc, column in chunks
+                ]
+                for i, chunks in groups
+            ]
         get_native()  # thread-safe lazy init before fan-out
         prep_futs = [
-            [
-                (path, traced_submit(pool, prep, path, cc, column))
-                for path, cc, column in chunks
-            ]
-            for chunks in groups
+            (
+                i,
+                [
+                    (path, instrumented_submit(pool, prep, i, path, cc, column))
+                    for path, cc, column in chunks
+                ],
+            )
+            for i, chunks in groups
         ]
-        for group in prep_futs:
-            out = []
-            for path, fut in group:
-                plan = fut.result()
-                out.append(
-                    (
-                        path,
-                        traced_submit(
-                            dispatcher, _dispatch_traced, plan.dispatch_device, dev
-                        ),
-                    )
-                )
-            staged.append(out)
-        return staged
+        return [
+            [(path, dispatch(i, path, fut.result())) for path, fut in chunks]
+            for i, chunks in prep_futs
+        ]
 
     def _plan_row_group(self, i: int, columns=None, device=None):
         """Plan every selected chunk of a row group for device decode.

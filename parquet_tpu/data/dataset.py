@@ -79,7 +79,9 @@ _STATE_VERSION = 1
 # so the exposed value is the TOTAL in-flight unit count, not whichever
 # iterator wrote last (a finishing iterator must not zero a live one's
 # starvation signal).
-_inflight_lock = threading.Lock()
+# (re-entrant: _fetch_units' `finally` lands here from a garbage collection
+# that may start while this very thread holds the lock, see utils/metrics.py)
+_inflight_lock = threading.RLock()
 _inflight_units = 0
 
 

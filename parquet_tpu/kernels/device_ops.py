@@ -63,6 +63,14 @@ if jax.config.jax_compilation_cache_dir is None:
     jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+# The cache key covers op metadata (jax leaves it out by default). The named
+# scopes below ARE metadata, and the profiler reads them out of the loaded
+# executable: with the default key a program compiled before a scope was
+# added or renamed is served from the cache as it was, and the device trace
+# shows the old names (or none) until the cache is emptied. The price is a
+# recompile when a line moves in a kernel's call stack: seconds, once per
+# checkout.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 import jax.numpy as jnp
 import numpy as np
@@ -125,11 +133,22 @@ def require_chip(device=None) -> dict:
 MAX_DEVICE_BATCH_BITS = 1 << 31
 
 
+# Every jitted kernel below traces under one jax.named_scope "pqt.<kernel>"
+# (the two that hold the reader's device time also under inner scopes:
+# pqt.hybrid_expand/{find_run,unpack,select}, pqt.delta_decode/{find_block,
+# unpack,prefix_sum,rebase}). The scope path lands in each HLO op's op_name
+# metadata, which the profiler's trace carries per device op: those names
+# are what benchmark/lib/xspans.py reads, so a refactor may rename or fuse
+# the Python functions and must keep them. Scopes act while a program is
+# traced for compilation, cost nothing when it runs and change no compiled
+# code (which is why the persistent cache's key is told to cover them, above).
+
 # Row length of the two-level prefix sum below.
 _SCAN_BLOCK = 1024
 
 
 @jax.jit
+@jax.named_scope("pqt.prefix_sum")
 def prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
     """Inclusive prefix sum of a 1-D integer array in its own dtype — the
     values of jnp.cumsum, wrapping included — computed in two levels: rows of
@@ -164,6 +183,7 @@ def bytes_to_words64(data: bytes) -> np.ndarray:
 
 
 @partial(jax.jit, static_argnames=("width", "num_values", "run_pad"))
+@jax.named_scope("pqt.hybrid_expand")
 def expand_hybrid_device(
     buf: jnp.ndarray,  # uint32: [run_meta (4*run_pad) | packed words]
     width: int,
@@ -193,21 +213,29 @@ def expand_hybrid_device(
     )
     packed_words = buf[4 * run_pad :]
     i = jnp.arange(num_values, dtype=jnp.int32)
-    r = jnp.searchsorted(run_out_start, i, side="right").astype(jnp.int32) - 1
-    within = i - run_out_start[r]
+    with jax.named_scope("find_run"):
+        r = jnp.searchsorted(run_out_start, i, side="right").astype(jnp.int32) - 1
+        within = i - run_out_start[r]
     if width == 0:
         return jnp.zeros(num_values, dtype=jnp.uint32)
-    bitpos = run_bp_bit_start[r] + within * width
-    w0 = bitpos >> 5
-    s = (bitpos & 31).astype(jnp.uint32)
-    lo = packed_words[w0] >> s
-    hi = jnp.where(s == 0, jnp.uint32(0), packed_words[w0 + 1] << ((32 - s) & 31))
-    mask = jnp.uint32((1 << width) - 1) if width < 32 else jnp.uint32(0xFFFFFFFF)
-    bp_vals = (lo | hi) & mask
-    return jnp.where(run_is_rle[r], run_rle_value[r], bp_vals)
+    with jax.named_scope("unpack"):
+        bitpos = run_bp_bit_start[r] + within * width
+        w0 = bitpos >> 5
+        s = (bitpos & 31).astype(jnp.uint32)
+        lo = packed_words[w0] >> s
+        hi = jnp.where(
+            s == 0, jnp.uint32(0), packed_words[w0 + 1] << ((32 - s) & 31)
+        )
+        mask = (
+            jnp.uint32((1 << width) - 1) if width < 32 else jnp.uint32(0xFFFFFFFF)
+        )
+        bp_vals = (lo | hi) & mask
+    with jax.named_scope("select"):
+        return jnp.where(run_is_rle[r], run_rle_value[r], bp_vals)
 
 
 @partial(jax.jit, static_argnames=("nbits", "num_values", "m_pad", "p_pad"))
+@jax.named_scope("pqt.delta_decode")
 def delta_packed_decode_device(
     meta32: jnp.ndarray,  # uint32 — packed 32-bit tables (+ words when nbits=32)
     wide: jnp.ndarray,  # uint32/uint64 — packed wide tables (+ words when nbits=64)
@@ -257,44 +285,52 @@ def delta_packed_decode_device(
         page_first = wide[m_pad : m_pad + p_pad]
         words = wide[m_pad + p_pad :]
     i = jnp.arange(num_values, dtype=jnp.int32)
-    m = jnp.searchsorted(mb_out_start, i, side="right").astype(jnp.int32) - 1
-    w = mb_width[m]
-    within = i - mb_out_start[m]
-    p = jnp.searchsorted(page_start, i, side="right").astype(jnp.int32) - 1
-    is_start = i == page_start[p]
+    with jax.named_scope("find_block"):
+        m = jnp.searchsorted(mb_out_start, i, side="right").astype(jnp.int32) - 1
+        w = mb_width[m]
+        within = i - mb_out_start[m]
+        p = jnp.searchsorted(page_start, i, side="right").astype(jnp.int32) - 1
+        is_start = i == page_start[p]
     if nbits == 32:
+        with jax.named_scope("unpack"):
+            bitpos = mb_bit_start[m] + within * w.astype(jnp.int32)
+            w0 = bitpos >> 5
+            s = (bitpos & 31).astype(jnp.uint32)
+            lo = words[w0] >> s
+            hi = jnp.where(s == 0, jnp.uint32(0), words[w0 + 1] << ((32 - s) & 31))
+            mask = jnp.where(
+                w >= 32, jnp.uint32(0xFFFFFFFF), (jnp.uint32(1) << (w & 31)) - 1
+            )
+            d = ((lo | hi) & mask) + mb_min[m]
+            d = jnp.where(is_start, jnp.uint32(0), d)
+        with jax.named_scope("prefix_sum"):
+            c = prefix_sum(d)
+        with jax.named_scope("rebase"):
+            vals = page_first[p] + c - c[page_start[p]]
+        return jax.lax.bitcast_convert_type(vals, jnp.int32)
+    with jax.named_scope("unpack"):
         bitpos = mb_bit_start[m] + within * w.astype(jnp.int32)
-        w0 = bitpos >> 5
-        s = (bitpos & 31).astype(jnp.uint32)
+        w0 = bitpos >> 6
+        s = (bitpos & 63).astype(jnp.uint64)
         lo = words[w0] >> s
-        hi = jnp.where(s == 0, jnp.uint32(0), words[w0 + 1] << ((32 - s) & 31))
+        hi = jnp.where(s == 0, jnp.uint64(0), words[w0 + 1] << ((64 - s) & 63))
+        wmask = w.astype(jnp.uint64)
         mask = jnp.where(
-            w >= 32, jnp.uint32(0xFFFFFFFF), (jnp.uint32(1) << (w & 31)) - 1
+            w >= 64,
+            jnp.uint64(0xFFFFFFFFFFFFFFFF),
+            (jnp.uint64(1) << (wmask & 63)) - 1,
         )
         d = ((lo | hi) & mask) + mb_min[m]
-        d = jnp.where(is_start, jnp.uint32(0), d)
+        d = jnp.where(is_start, jnp.uint64(0), d)
+    with jax.named_scope("prefix_sum"):
         c = prefix_sum(d)
+    with jax.named_scope("rebase"):
         vals = page_first[p] + c - c[page_start[p]]
-        return jax.lax.bitcast_convert_type(vals, jnp.int32)
-    bitpos = mb_bit_start[m] + within * w.astype(jnp.int32)
-    w0 = bitpos >> 6
-    s = (bitpos & 63).astype(jnp.uint64)
-    lo = words[w0] >> s
-    hi = jnp.where(s == 0, jnp.uint64(0), words[w0 + 1] << ((64 - s) & 63))
-    wmask = w.astype(jnp.uint64)
-    mask = jnp.where(
-        w >= 64,
-        jnp.uint64(0xFFFFFFFFFFFFFFFF),
-        (jnp.uint64(1) << (wmask & 63)) - 1,
-    )
-    d = ((lo | hi) & mask) + mb_min[m]
-    d = jnp.where(is_start, jnp.uint64(0), d)
-    c = prefix_sum(d)
-    vals = page_first[p] + c - c[page_start[p]]
     return jax.lax.bitcast_convert_type(vals, jnp.int64)
 
 
 @jax.jit
+@jax.named_scope("pqt.bss_transpose")
 def _bss_transpose_padded(streams: jnp.ndarray) -> jnp.ndarray:
     m = streams.transpose()  # (n_pad, 4) uint8, one value per row
     return jax.lax.bitcast_convert_type(m, jnp.uint32)
@@ -312,12 +348,14 @@ def bss_transpose_device(streams: jnp.ndarray, num_values: int) -> jnp.ndarray:
 
 
 @jax.jit
+@jax.named_scope("pqt.dict_gather")
 def dict_gather_device(dictionary: jnp.ndarray, indices: jnp.ndarray) -> jnp.ndarray:
     """Dictionary expansion: one gather (reference: type_dict.go lookup loop)."""
     return dictionary[indices]
 
 
 @jax.jit
+@jax.named_scope("pqt.record_starts")
 def record_starts_device(rep: jnp.ndarray):
     """Record assembly scan 1: which record each level entry belongs to.
 
@@ -331,6 +369,7 @@ def record_starts_device(rep: jnp.ndarray):
 
 
 @jax.jit
+@jax.named_scope("pqt.list_layout")
 def list_layout_device(
     rep: jnp.ndarray,  # int32[n]: repetition levels of one leaf
     dfl: jnp.ndarray,  # int32[n]: definition levels of the same leaf
@@ -383,6 +422,7 @@ def list_layout_device(
 
 
 @partial(jax.jit, static_argnames=("op", "exact"))
+@jax.named_scope("pqt.predicate_mask")
 def predicate_mask_device(values: jnp.ndarray, op: str, lo, hi, exact: bool = True):
     """One leaf predicate as a device boolean mask — the jittable twin of
     core/filter_vec's bracket comparison, so residual filtering of
@@ -411,6 +451,7 @@ def predicate_mask_device(values: jnp.ndarray, op: str, lo, hi, exact: bool = Tr
 
 
 @jax.jit
+@jax.named_scope("pqt.list_contains_mask")
 def list_contains_mask_device(
     rep: jnp.ndarray,  # int32[n]: repetition levels of one LIST leaf
     dfl: jnp.ndarray,  # int32[n]: definition levels of the same leaf
@@ -446,6 +487,7 @@ def list_contains_mask_device(
 
 
 @partial(jax.jit, static_argnames=("out_pad",))
+@jax.named_scope("pqt.mask_take")
 def mask_take_device(values: jnp.ndarray, mask: jnp.ndarray, out_pad: int):
     """Compact `values[mask]` into a static out_pad-sized buffer on device
     (the gather stage of predicate -> mask -> gather; static shapes bound
@@ -469,6 +511,7 @@ def mask_take_device(values: jnp.ndarray, mask: jnp.ndarray, out_pad: int):
 
 
 @partial(jax.jit, static_argnames=("width",))
+@jax.named_scope("pqt.bitpack_encode")
 def bitpack_encode_device(values: jnp.ndarray, width: int) -> jnp.ndarray:
     """LSB-first bit-pack of uint32 `values` at `width` bits — the jittable
     inverse of the two-gather unpack at the top of this module (and of
@@ -503,6 +546,7 @@ def bitpack_encode_device(values: jnp.ndarray, width: int) -> jnp.ndarray:
 
 
 @partial(jax.jit, static_argnames=("width",))
+@jax.named_scope("pqt.rle_hybrid_encode")
 def rle_hybrid_encode_device(values: jnp.ndarray, width: int):
     """The device half of hybrid RLE/bit-pack ENCODE — the inverse of
     expand_hybrid_device, mirroring ops/rle_hybrid.encode_hybrid's run
@@ -570,6 +614,7 @@ def rle_hybrid_encode_device(values: jnp.ndarray, width: int):
 
 
 @jax.jit
+@jax.named_scope("pqt.dict_indices")
 def dict_indices_device(values: jnp.ndarray):
     """First-occurrence dictionary probe on device — the jittable inverse of
     dict_gather_device and the twin of the host u64/bytes probes (same
@@ -611,6 +656,7 @@ def dict_indices_device(values: jnp.ndarray):
 
 
 @partial(jax.jit, static_argnames=("nbits",))
+@jax.named_scope("pqt.delta_block_encode")
 def delta_block_encode_device(values: jnp.ndarray, n, nbits: int):
     """DELTA_BINARY_PACKED block scans + payload pack on device — the encode
     inverse of delta_packed_decode_device, mirroring ops/delta.encode_delta's
@@ -688,6 +734,7 @@ def delta_block_encode_device(values: jnp.ndarray, n, nbits: int):
 
 
 @partial(jax.jit, static_argnames=("out_pad",))
+@jax.named_scope("pqt.plain_bytearray_encode")
 def plain_bytearray_encode_device(
     data: jnp.ndarray,  # uint8: dense value bytes
     offsets: jnp.ndarray,  # int32/int64[nv + 1]: value byte offsets
@@ -721,6 +768,7 @@ def plain_bytearray_encode_device(
 
 
 @partial(jax.jit, static_argnames=("op",))
+@jax.named_scope("pqt.masked_agg")
 def masked_agg_device(values: jnp.ndarray, mask: jnp.ndarray, op: str):
     """One aggregation unit's partial as ONE jnp reduction over the resident
     row mask (count/sum/min/max) — the device half of serve/aggregate's
@@ -747,6 +795,7 @@ def masked_agg_device(values: jnp.ndarray, mask: jnp.ndarray, op: str):
 
 
 @partial(jax.jit, static_argnames=("rows_pad",))
+@jax.named_scope("pqt.merge_mixed_numeric")
 def merge_mixed_numeric_device(
     idx_all: jnp.ndarray,        # int32[D_pad]: dict-row indices, output order
     dictionary: jnp.ndarray,     # dict values (uint bit patterns for floats)
@@ -776,6 +825,7 @@ def merge_mixed_numeric_device(
 
 
 @partial(jax.jit, static_argnames=("rows_pad", "total_bytes_pad"))
+@jax.named_scope("pqt.merge_mixed_bytes")
 def merge_mixed_bytes_device(
     idx_all: jnp.ndarray,        # int32[D_pad]: dict-row indices, output order
     doff: jnp.ndarray,           # int64[n_dict + 1]: dictionary offsets

@@ -59,6 +59,7 @@ from ..ops.packed_levels import PackedLevels
 from ..ops.rle_hybrid import prescan_hybrid
 from ..ops.delta import prescan_delta_packed
 from ..utils import metrics as _metrics
+from ..utils import trace as _trace
 from .device_ops import (
     MAX_DEVICE_BATCH_BITS,
     bytes_to_words32,
@@ -110,7 +111,9 @@ def dispatch_pool():
     with _dispatcher_lock:
         if _dispatcher is None:
             _dispatcher = ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix="pqt-dispatch"
+                max_workers=1,
+                thread_name_prefix="pqt-dispatch",
+                initializer=_trace.name_os_thread,
             )
         return _dispatcher
 
@@ -127,23 +130,29 @@ def device_put_pipelined(
     Sharding laying each batch over a mesh, or None for the process default.
     Order is preserved; an exception from `batches` or from a transfer
     surfaces at the yield that would have produced that batch. Each upload
-    runs under a `stage_name` stage (traced_submit carries the caller's
-    active decode_trace onto the dispatch thread)."""
+    runs under a `stage_name` stage that carries the batch's bytes
+    (instrumented_submit carries the caller's active decode_trace onto the
+    dispatch thread and records the wait for it as pool.wait)."""
     from collections import deque
 
-    from ..utils.trace import stage as _stage, traced_submit
+    from ..obs.pool import instrumented_submit
+
+    def nbytes(b) -> int:
+        if not _trace.active():
+            return 0
+        return sum(getattr(x, "nbytes", 0) for x in jax.tree_util.tree_leaves(b))
 
     if depth <= 0:
         for b in batches:
             # upload INSIDE the stage, yield OUTSIDE it: a yield under the
             # context would bill arbitrary consumer time to the transfer
-            with _stage(stage_name):
+            with _trace.stage(stage_name, nbytes(b)):
                 out = jax.device_put(b, placement)
             yield out
         return
 
     def put(b):
-        with _stage(stage_name):
+        with _trace.stage(stage_name, nbytes(b)):
             return jax.device_put(b, placement)
 
     pool = dispatch_pool()
@@ -168,7 +177,7 @@ def device_put_pipelined(
             except BaseException as e:  # noqa: BLE001 — re-raised in order
                 source_err = e
                 return
-            pending.append(traced_submit(pool, put, b))
+            pending.append(instrumented_submit(pool, put, b))
 
     fill()
     while pending:
@@ -410,10 +419,13 @@ class _HybridBatch:
 
     @staticmethod
     def dispatch_frozen(frozen: "_FrozenHybrid") -> jnp.ndarray:
-        dev = expand_hybrid_device(
-            jnp.asarray(frozen.buf), frozen.width, frozen.n_pad, frozen.run_pad
-        )
-        return dev[: frozen.total]
+        with _trace.stage("dispatch.upload", frozen.buf.nbytes):
+            buf = jnp.asarray(frozen.buf)
+        with _trace.stage("dispatch.launch"):
+            dev = expand_hybrid_device(
+                buf, frozen.width, frozen.n_pad, frozen.run_pad
+            )
+            return dev[: frozen.total]
 
 
 class _DeltaBatch:
@@ -506,15 +518,16 @@ class _DeltaBatch:
 
     @staticmethod
     def dispatch_frozen(frozen: "_FrozenDelta") -> jnp.ndarray:
-        dev = delta_packed_decode_device(
-            jnp.asarray(frozen.meta32),
-            jnp.asarray(frozen.wide),
-            frozen.nbits,
-            frozen.n_pad,
-            frozen.m_pad,
-            frozen.p_pad,
-        )
-        return dev[: frozen.total]
+        with _trace.stage(
+            "dispatch.upload", frozen.meta32.nbytes + frozen.wide.nbytes
+        ):
+            meta32 = jnp.asarray(frozen.meta32)
+            wide = jnp.asarray(frozen.wide)
+        with _trace.stage("dispatch.launch"):
+            dev = delta_packed_decode_device(
+                meta32, wide, frozen.nbits, frozen.n_pad, frozen.m_pad, frozen.p_pad
+            )
+            return dev[: frozen.total]
 
 
 # -- the chunk plan ------------------------------------------------------------
@@ -619,7 +632,11 @@ class _ChunkPlan:
     # -- device dispatch (async; nothing synchronizes here) --------------------
     #
     # The only phase that touches jax: keep it on the dispatching thread so
-    # the jax-free prepare phase can run on worker threads.
+    # the jax-free prepare phase can run on worker threads. Each host->device
+    # copy runs under a dispatch.upload stage that carries the numpy buffers'
+    # bytes, each jitted call under dispatch.launch: both nest in the
+    # reader's dispatch stage, so a trace says which of the two holds the
+    # dispatch thread.
 
     def dispatch_device(self) -> "_ChunkPlan":
         if self._dispatched:
@@ -633,17 +650,18 @@ class _ChunkPlan:
             # agnostic and the mixed-page merge works in the uint domain
             # (f64 itself is not native on TPU — see DeviceDoubleError).
             if d.dtype.kind == "f":
-                u = np.uint32 if d.dtype.itemsize == 4 else np.uint64
-                self.dict_dev = jnp.asarray(d.view(u))
-            else:
+                d = d.view(np.uint32 if d.dtype.itemsize == 4 else np.uint64)
+            with _trace.stage("dispatch.upload", d.nbytes):
                 self.dict_dev = jnp.asarray(d)
         # Homogeneous PLAIN numeric chunks are pure uploads (buffer already
         # concatenated at prepare time).
         if self.plain_host is not None:
-            self.dev_plain = _upload_typed(self.plain_host)
+            with _trace.stage("dispatch.upload", self.plain_host.nbytes):
+                self.dev_plain = _upload_typed(self.plain_host)
             self.plain_host = None
         for streams, nv in self.bss_host:
-            self.dev_bss.append((jnp.asarray(streams), nv))
+            with _trace.stage("dispatch.upload", streams.nbytes):
+                self.dev_bss.append((jnp.asarray(streams), nv))
             if self.stats is not None:
                 self.stats.device_values += nv
                 self.stats.device_batches += 1
@@ -1067,8 +1085,6 @@ def _native_prepare(f, chunk, column, validate_crc, alloc, stats):
     lands in prepare.* stages."""
     import os as _os
 
-    from ..utils import trace as _trace
-
     if _os.environ.get("PQT_FUSED_PREPARE", "1") == "0":
         return None, None  # forced staged path: not a decline, no counter
     plan, fault = _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats)
@@ -1119,8 +1135,6 @@ def _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats):
     if expected < 0:
         return None, None
     import time as _time
-
-    from ..utils import trace as _trace
 
     t_walk = _time.perf_counter()
     res = lib.chunk_prepare(
@@ -1755,7 +1769,6 @@ def prepare_chunk_plan(
     """
     import time as _time
 
-    from ..utils import trace as _trace
 
     plan, fault = _native_prepare(f, chunk, column, validate_crc, alloc, stats)
     if plan is None:

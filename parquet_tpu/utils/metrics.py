@@ -632,7 +632,11 @@ class MetricsRegistry:
     text exposition. One instance (REGISTRY) serves the whole process."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        # Re-entrant: an allocation under the lock can start a garbage
+        # collection, and a finalizer run by it on this very thread (an
+        # abandoned dataset iterator's `finally`) reports metrics too — with a
+        # plain Lock that thread deadlocks on itself and the process behind it.
+        self._lock = threading.RLock()
         self._counters: dict[tuple[str, tuple], int | float] = {}
         self._hists: dict[tuple[str, tuple], _Hist] = {}
         self._gauges: dict[tuple[str, tuple], int | float] = {}

@@ -9,15 +9,29 @@ trace-event JSON for Perfetto / chrome://tracing. The always-on process
 counters live in utils/metrics.py; `bump()` dual-reports into them.
 
 Zero overhead when no trace is active: one contextvar read, no span
-allocations (asserted by test via the span_allocations() counter).
+allocations and no profiler annotation built (asserted by test via the
+span_allocations() / annotation_allocations() counters).
+
+One trace plane: while a trace is active, every stage()/timed_stage()/span()
+that records a span also holds a `jax.profiler.TraceAnnotation("pqt:<name>")`
+open for the same interval on the same thread, so a jax profiler session
+(jax_profile(), or the benchmark's --trace 1) shows the program's own spans
+on the profiler's clock beside the device's ops — idle gaps of the device
+attribute to prepare / upload / launch / deliver by what was open then.
+This module never imports jax: it looks it up in sys.modules, and a process
+that never imported jax has no profiler session to annotate. Not annotated:
+record_span=False stages (per-row micro-stages) and the back-dated
+add_seconds()/add_seconds_batch() sub-clocks (an annotation cannot be
+back-dated; their enclosing span carries them).
 
 Thread model: the active trace propagates through a `contextvars.ContextVar`,
 so concurrent traces on different threads are ISOLATED (the old module-global
 was racy under the 16-thread prepare pool), while pool workers doing a traced
 read's prepare/dispatch work join the submitting read's trace via
-`traced_submit()` (an explicit `copy_context()` carry — ThreadPoolExecutor
-does not propagate context by itself). All merges into a shared trace are
-lock-protected.
+`obs.pool.instrumented_submit()` (an explicit `copy_context()` carry —
+ThreadPoolExecutor does not propagate context by itself — which also
+records the task's queue wait as the `pool.wait` stage). All merges into a
+shared trace are lock-protected.
 
     from parquet_tpu.utils.trace import decode_trace
 
@@ -26,18 +40,20 @@ lock-protected.
     print(t.report())                 # per-stage table, hottest first
     t.write_chrome_trace("trace.json")  # load in ui.perfetto.dev
 
-    with jax_profile("/tmp/trace"):   # wraps jax.profiler.trace
-        reader.read_row_group(0)      # inspect with TensorBoard/XProf
+    with jax_profile("/tmp/trace") as t:  # jax.profiler.trace + decode_trace
+        reader.read_row_groups_device()   # device ops AND pqt:* spans in one
+    print(t.report())                     # .xplane.pb (TensorBoard/XProf)
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
-from contextvars import ContextVar, copy_context
+from contextvars import ContextVar
 from dataclasses import dataclass
 
 from . import metrics as _metrics
@@ -54,9 +70,10 @@ __all__ = [
     "count",
     "active",
     "current",
-    "traced_submit",
     "span_allocations",
+    "annotation_allocations",
     "jax_profile",
+    "name_os_thread",
     "DecodeTrace",
 ]
 
@@ -78,6 +95,11 @@ _stage_depth_var: ContextVar = ContextVar("pqt_stage_depth", default=0)
 # count is exact for single-trace workloads and best-effort across
 # concurrently active traces.
 _span_allocs = 0
+
+# Process-wide count of profiler annotations built: the same oracle for the
+# jax.profiler.TraceAnnotation side. Only a span recorded under an active
+# trace, in a process that has imported jax, moves it.
+_annotation_allocs = 0
 
 # Per-trace span cap: a traced 10M-row assembled read bills stage("assemble")
 # per row; past the cap events drop (counted in events_dropped) while the
@@ -313,10 +335,32 @@ class DecodeTrace:
             json.dump(self.to_chrome_trace(), f)
 
 
+def _open_annotation(name: str, args: dict | None):
+    """Enter a jax.profiler.TraceAnnotation("pqt:<name>", **args) on this
+    thread and return it (None where jax was never imported: no profiler
+    session can exist). Outside a profiler session the annotation is jax's
+    own no-op. Called only under an active trace."""
+    global _annotation_allocs
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None too while jax is mid-import
+    if profiler is None:
+        return None
+    _annotation_allocs += 1
+    ann = profiler.TraceAnnotation("pqt:" + name, **(args or {}))
+    ann.__enter__()
+    return ann
+
+
+def _close_annotation(ann) -> None:
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
 @contextmanager
 def decode_trace():
     """Activate stage + span collection for the enclosed reads (this thread,
-    plus any pool work submitted from it via traced_submit). Nested traces
+    plus any pool work submitted from it via obs.pool.instrumented_submit).
+    Nested traces
     shadow; traces on OTHER threads are unaffected (contextvar isolation)."""
     t = DecodeTrace()
     token = _active_var.set(t)
@@ -352,10 +396,17 @@ def _exit_stage(token) -> None:
 
 
 @contextmanager
-def stage(name: str, nbytes: int = 0, record_span: bool = True):
+def stage(
+    name: str,
+    nbytes: int = 0,
+    record_span: bool = True,
+    args: dict | None = None,
+):
     """Time a pipeline stage: aggregates into stages[name] AND records a
-    span (no-op without an active trace). record_span=False keeps the
-    aggregate but skips the span event — for per-ROW micro-stages (the
+    span (no-op without an active trace), with optional `args` on the span
+    (which chunk it serves), and holds a "pqt:<name>" profiler annotation
+    open for the same interval. record_span=False keeps the aggregate but
+    skips the span event and the annotation — for per-ROW micro-stages (the
     assembled-rows loop) that would otherwise flood the event budget with
     sub-microsecond spans and crowd out the meaningful hierarchy. A stage
     opened while another stage aggregate is already open commits its
@@ -366,11 +417,13 @@ def stage(name: str, nbytes: int = 0, record_span: bool = True):
         yield
         return
     token, nested = _enter_stage()
+    ann = _open_annotation(name, args) if record_span else None
     t0 = time.perf_counter_ns()
     try:
         yield
     finally:
         dt = time.perf_counter_ns() - t0
+        _close_annotation(ann)
         _exit_stage(token)
         t._commit(
             name,
@@ -379,6 +432,7 @@ def stage(name: str, nbytes: int = 0, record_span: bool = True):
             1,
             start_ns=t0 if record_span else None,
             dur_ns=dt,
+            args=args if record_span else None,
             nested=nested,
         )
 
@@ -402,6 +456,7 @@ def timed_stage(name: str, nbytes: int = 0, record_span: bool = True):
     t = _active_var.get()
     out = _Elapsed()
     token, nested = (None, False) if t is None else _enter_stage()
+    ann = _open_annotation(name, None) if t is not None and record_span else None
     t0 = time.perf_counter_ns()
     try:
         yield out
@@ -409,6 +464,7 @@ def timed_stage(name: str, nbytes: int = 0, record_span: bool = True):
         dt = time.perf_counter_ns() - t0
         out.seconds = dt / 1e9
         if t is not None:
+            _close_annotation(ann)
             _exit_stage(token)
             t._commit(
                 name,
@@ -426,16 +482,20 @@ def span(name: str, args: dict | None = None):
     """Pure hierarchy span (file / row_group / chunk levels): records a
     trace event with optional args but does NOT enter the stage aggregates —
     its children (stages) already bill the time, and double-billing would
-    corrupt the TOTAL row."""
+    corrupt the TOTAL row. Holds a "pqt:<name>" profiler annotation with
+    the same args for the same interval."""
     t = _active_var.get()
     if t is None:
         yield
         return
+    ann = _open_annotation(name, args)
     t0 = time.perf_counter_ns()
     try:
         yield
     finally:
-        t._commit(name, start_ns=t0, dur_ns=time.perf_counter_ns() - t0, args=args)
+        dur = time.perf_counter_ns() - t0
+        _close_annotation(ann)
+        t._commit(name, start_ns=t0, dur_ns=dur, args=args)
 
 
 def active() -> bool:
@@ -450,25 +510,23 @@ def current() -> "DecodeTrace | None":
     return _active_var.get()
 
 
-def traced_submit(executor, fn, *args):
-    """Submit `fn(*args)` to `executor` carrying the caller's contextvars —
-    including the active decode_trace — into the worker thread.
-    ThreadPoolExecutor does not do this by itself; every pool hop of a
-    traced read must route through here or its stages vanish."""
-    return executor.submit(copy_context().run, fn, *args)
-
-
 def add_bytes(name: str, nbytes: int) -> None:
     t = _active_var.get()
     if t is not None:
         t._commit(name, 0.0, nbytes, 0)
 
 
-def add_seconds(name: str, seconds: float, nbytes: int = 0) -> None:
+def add_seconds(
+    name: str, seconds: float, nbytes: int = 0, record_span: bool = True
+) -> None:
     """Credit externally-measured wall time to a stage. The span is placed
-    ending 'now' (the measurement must have just finished). When a stage
-    aggregate is open in this context, the credited time is part of that
-    stage's wall and commits as nested (counted once in TOTALs)."""
+    ending 'now' (the measurement must have just finished);
+    record_span=False credits the aggregate alone — for time that was not
+    spent on this thread (a task's wait in a pool's queue, during which the
+    worker ran the task before it: a span would overlap that task's on the
+    worker's lane). When a stage aggregate is open in this context, the
+    credited time is part of that stage's wall and commits as nested
+    (counted once in TOTALs)."""
     t = _active_var.get()
     if t is not None:
         dur = int(seconds * 1e9)
@@ -477,7 +535,7 @@ def add_seconds(name: str, seconds: float, nbytes: int = 0) -> None:
             seconds,
             nbytes,
             1,
-            start_ns=time.perf_counter_ns() - dur,
+            start_ns=time.perf_counter_ns() - dur if record_span else None,
             dur_ns=dur,
             nested=_stage_depth_var.get() > 0,
         )
@@ -531,10 +589,36 @@ def span_allocations() -> int:
     return _span_allocs
 
 
+def annotation_allocations() -> int:
+    """Process-wide count of profiler annotations built — the same oracle:
+    reads with no active trace, record_span=False stages and the add_seconds
+    sub-clocks must not move it."""
+    return _annotation_allocs
+
+
+def name_os_thread() -> None:
+    """Give the calling OS thread its Python thread's name (a pool's
+    `initializer`). The profiler names a trace's host lines after the OS
+    thread, which Python leaves at the process's name: without this every
+    pqt-host_* / pqt-dispatch_* lane of a .xplane.pb reads "python". Linux
+    only (prctl PR_SET_NAME, 15 characters); anywhere else a no-op."""
+    import ctypes
+
+    name = threading.current_thread().name.encode()[:15]
+    try:
+        ctypes.CDLL(None).prctl(15, name, 0, 0, 0)
+    except (OSError, AttributeError):
+        pass
+
+
 @contextmanager
 def jax_profile(logdir: str):
-    """Capture a JAX/XLA device trace for the enclosed block."""
+    """Capture a JAX/XLA profiler trace of the enclosed block TOGETHER with
+    a decode_trace (yielded): the written .xplane.pb holds the device's ops
+    and the program's pqt:* spans on one clock, and the yielded trace the
+    per-stage aggregates. The operator's one entry: this is the only place
+    the tracing module imports jax."""
     import jax
 
-    with jax.profiler.trace(logdir):
-        yield
+    with jax.profiler.trace(logdir), decode_trace() as t:
+        yield t

@@ -365,3 +365,47 @@ class TestReportAndSummary:
             assert col["uncompressed"] > 0
             assert col["ratio"] is not None and col["ratio"] > 0
             assert col["encodings"]
+
+
+class TestFinalizersUnderTheLocks:
+    """An allocation under a lock can start a garbage collection, and a
+    finalizer run by it on the same thread may report metrics (an abandoned
+    ParquetDataset iterator's `finally` drops its in-flight gauge and
+    cancels its futures). The locks such a finalizer reaches must be
+    re-entrant: with plain Locks the thread deadlocks on itself and every
+    other thread behind it — seen as a hung test run."""
+
+    @pytest.mark.parametrize("which", ["registry", "pool", "dataset_inflight"])
+    def test_a_finalizer_reporting_metrics_does_not_deadlock(self, which):
+        import threading
+
+        from parquet_tpu.data import dataset as dataset_mod
+        from parquet_tpu.obs import pool as pool_mod
+
+        lock = {
+            "registry": metrics.REGISTRY._lock,
+            "pool": pool_mod._lock,
+            "dataset_inflight": dataset_mod._inflight_lock,
+        }[which]
+
+        class Reporter:
+            def __del__(self):  # what the iterator's `finally` does
+                dataset_mod._inflight_add(0)
+                pool_mod._adjust("pqt-test-finalizer", dq=+1)
+                pool_mod._adjust("pqt-test-finalizer", dq=-1)
+                metrics.inc("finalizer_reports_total")
+
+        done = threading.Event()
+
+        def body():
+            obj = Reporter()
+            with lock:
+                del obj  # the finalizer runs here, on this thread, under the lock
+            done.set()
+
+        before = metrics.snapshot().get("finalizer_reports_total", 0)
+        t = threading.Thread(target=body, daemon=True)
+        t.start()
+        t.join(timeout=10)
+        assert done.is_set(), f"deadlocked under the {which} lock"
+        assert metrics.snapshot()["finalizer_reports_total"] == before + 1

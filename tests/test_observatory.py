@@ -223,10 +223,24 @@ class TestTenantAccounting:
         cpu_delta = time.process_time() - cpu0
         mdelta = metrics.delta(snap0)
 
-        status, _, body = _request(server, "GET", "/v1/debug/tenants")
-        assert status == 200
-        doc = json.loads(body)
-        rows = {r["tenant"]: r for r in doc["tenants"]}
+        # a handler bills its tenant AFTER the last byte went out, so the
+        # last scans' charges may still be landing when the clients return:
+        # read the ledger until it has settled (what is asserted is the
+        # settled ledger, not the race)
+        deadline = time.monotonic() + 5.0
+        while True:
+            status, _, body = _request(server, "GET", "/v1/debug/tenants")
+            assert status == 200
+            doc = json.loads(body)
+            rows = {r["tenant"]: r for r in doc["tenants"]}
+            settled = all(
+                rows.get(n, {}).get("requests") == per_tenant
+                for n in ("alice", "bob", "carol")
+            )
+            if settled or time.monotonic() > deadline:
+                break
+            time.sleep(0.02)
+        mdelta = metrics.delta(snap0)
         assert set(rows) >= {"alice", "bob", "carol"}
         for name in ("alice", "bob", "carol"):
             r = rows[name]

@@ -14,6 +14,7 @@ from parquet_tpu.core.reader import FileReader
 from parquet_tpu.core.writer import FileWriter
 from parquet_tpu.meta.parquet_types import Type
 from parquet_tpu.schema.builder import message, optional, required, string
+from parquet_tpu.obs.pool import instrumented_submit
 from parquet_tpu.utils import trace as trace_mod
 from parquet_tpu.utils.trace import (
     add_seconds,
@@ -22,7 +23,6 @@ from parquet_tpu.utils.trace import (
     decode_trace,
     span,
     stage,
-    traced_submit,
 )
 
 
@@ -97,7 +97,9 @@ class TestThreadSafety:
                     for _ in range(n_iter):
                         bump("hammer", 3)
 
-                futs = [traced_submit(pool, hammer) for _ in range(n_threads)]
+                futs = [
+                    instrumented_submit(pool, hammer) for _ in range(n_threads)
+                ]
                 for f in futs:
                     f.result()
         s = t.stages["hammer"]
@@ -262,14 +264,13 @@ class TestExclusiveRollup:
         assert t.exclusive_seconds() == pytest.approx(0.04)
 
     def test_nesting_carries_into_pool_workers(self):
-        """instrumented_submit/traced_submit carry the open-stage depth
-        with the context: work a stage submits bills as nested on the
-        worker."""
+        """instrumented_submit carries the open-stage depth with the
+        context: work a stage submits bills as nested on the worker."""
         pool = ThreadPoolExecutor(max_workers=1, thread_name_prefix="pqt-test")
         try:
             with decode_trace() as t:
                 with stage("serve.execute"):
-                    traced_submit(
+                    instrumented_submit(
                         pool, lambda: add_seconds("io", 0.02)
                     ).result(timeout=10)
         finally:
@@ -421,3 +422,352 @@ class TestEventCap:
         assert t.stages["tick"].calls == 50  # aggregates exact past the cap
         assert t.events_dropped > 0
         assert len(t.to_chrome_trace()["traceEvents"]) <= 16 + 1  # + thread M
+
+
+# -- one trace plane: the program's spans in jax.profiler's trace ---------------
+
+
+def _write_device_sample(path: str, rows: int = 6000, groups: int = 2) -> str:
+    """A file whose chunks take the device reader's three upload shapes: a
+    dictionary-encoded int64 (hybrid expansion + gather), a
+    DELTA_BINARY_PACKED int64 and a PLAIN int64."""
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    n = rows * groups
+    rng = np.random.default_rng(26)
+    table = pa.table(
+        {
+            "code": pa.array(rng.integers(0, 7, n), pa.int64()),
+            "ts": pa.array(np.cumsum(rng.integers(0, 90, n)), pa.int64()),
+            "plain": pa.array(rng.integers(0, 1 << 40, n), pa.int64()),
+        }
+    )
+    pq.write_table(
+        table,
+        path,
+        row_group_size=rows,
+        compression="snappy",
+        use_dictionary=["code"],
+        column_encoding={"ts": "DELTA_BINARY_PACKED", "plain": "PLAIN"},
+    )
+    return path
+
+
+@pytest.fixture(scope="module")
+def device_sample(tmp_path_factory):
+    return _write_device_sample(str(tmp_path_factory.mktemp("trace_dev") / "d.parquet"))
+
+
+@pytest.fixture
+def host_pool(monkeypatch):
+    """The device reader's prepare pool at a fixed width, whatever the host's
+    core count (a one-core host prepares on the calling thread)."""
+    import parquet_tpu.core.reader as reader_mod
+
+    monkeypatch.setenv("PQT_HOST_THREADS", "4")
+    pool = ThreadPoolExecutor(
+        max_workers=4,
+        thread_name_prefix="pqt-host",
+        initializer=trace_mod.name_os_thread,
+    )
+    monkeypatch.setattr(reader_mod, "_pool", pool)
+    yield pool
+    pool.shutdown(wait=True)
+
+
+def _device_read(path):
+    import jax
+
+    with FileReader(path) as r:
+        out = r.read_row_groups_device()
+    jax.block_until_ready(
+        [dc.values for group in out for dc in group.values()]
+    )
+    return out
+
+
+class TestProfilerPlane:
+    def test_device_read_leaves_pqt_events_in_the_xplane(
+        self, device_sample, host_pool, tmp_path
+    ):
+        """Under a jax profiler session + decode_trace, the device reader's
+        spans land in the written .xplane.pb as pqt:* events, on the thread
+        that ran them: prepare and io on pqt-host lines, upload and launch
+        inside their dispatch on the pqt-dispatch line, deliver on the
+        caller's — each chunk's three carrying the same group/column."""
+        import jax
+        from jax.profiler import ProfileData
+
+        _device_read(device_sample)  # compile outside the session
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with decode_trace():
+                with jax.profiler.TraceAnnotation("test:window"):
+                    _device_read(device_sample)
+        finally:
+            jax.profiler.stop_trace()
+        (pb,) = tmp_path.rglob("*.xplane.pb")
+        lines: dict = {}  # line name -> [(name, start, end, stats)]
+        for plane in ProfileData.from_file(str(pb)).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("pqt:", "test:")):
+                        # arguments ride after '#' where the runtime did not
+                        # lift them into stats
+                        name, _, tail = ev.name.partition("#")
+                        stats = dict(ev.stats)
+                        for kv in tail.rstrip("#").split(","):
+                            if "=" in kv:
+                                k, v = kv.split("=", 1)
+                                stats.setdefault(k, v)
+                        lines.setdefault(line.name, []).append(
+                            (name, ev.start_ns, ev.start_ns + ev.duration_ns, stats)
+                        )
+        by_name: dict = {}
+        for lname, evs in lines.items():
+            for ev in evs:
+                by_name.setdefault(ev[0], []).append((lname, ev))
+        for want in (
+            "pqt:chunk.prepare",
+            "pqt:io.read",
+            "pqt:dispatch",
+            "pqt:dispatch.upload",
+            "pqt:dispatch.launch",
+            "pqt:deliver",
+        ):
+            assert want in by_name, (want, sorted(by_name))
+        chunks = 2 * 3  # groups x columns
+        assert len(by_name["pqt:chunk.prepare"]) == chunks
+        assert len(by_name["pqt:dispatch"]) == chunks
+        assert len(by_name["pqt:deliver"]) == chunks
+        assert {ln for ln, _ in by_name["pqt:chunk.prepare"]} <= {
+            ln for ln in lines if ln.startswith("pqt-host")
+        }
+        (dispatch_line,) = {ln for ln, _ in by_name["pqt:dispatch"]}
+        assert dispatch_line.startswith("pqt-dispatch"), dispatch_line
+        (main_line,) = {ln for ln, _ in by_name["test:window"]}
+        assert {ln for ln, _ in by_name["pqt:deliver"]} == {main_line}
+        dispatches = [ev for _, ev in by_name["pqt:dispatch"]]
+        for inner in ("pqt:dispatch.upload", "pqt:dispatch.launch"):
+            for ln, (_, s, e, _st) in by_name[inner]:
+                assert ln == dispatch_line
+                assert any(ds <= s and e <= de for _, ds, de, _ in dispatches), inner
+        ident = lambda ev: (str(ev[3]["group"]), str(ev[3]["column"]))  # noqa: E731
+        want_ids = {(str(g), c) for g in range(2) for c in ("code", "ts", "plain")}
+        for name in ("pqt:chunk.prepare", "pqt:dispatch", "pqt:deliver"):
+            assert {ident(ev) for _, ev in by_name[name]} == want_ids, name
+
+    def test_jax_profile_yields_the_trace_and_writes_one_file(
+        self, device_sample, tmp_path
+    ):
+        """The operator's entry: one `with` gives the stage table and a
+        .xplane.pb that holds the pqt:* spans."""
+        _device_read(device_sample)
+        with trace_mod.jax_profile(str(tmp_path)) as t:
+            _device_read(device_sample)
+        assert t.stages["dispatch"].calls == 6 and t.stages["deliver"].calls == 6
+        (pb,) = tmp_path.rglob("*.xplane.pb")
+        assert b"pqt:dispatch.launch" in pb.read_bytes()
+
+    def test_importing_the_tracer_leaves_jax_out(self):
+        """The benchmark's and bench.py's jax-free processes import the
+        tracer: it must never pull jax in."""
+        import subprocess
+        import sys
+
+        code = (
+            "import sys; import parquet_tpu.utils.trace as t\n"
+            "with t.decode_trace() as tr:\n"
+            "    with t.stage('s', args={'group': 0}): pass\n"
+            "    with t.span('p'): pass\n"
+            "assert 'jax' not in sys.modules, 'the tracer imported jax'\n"
+            "assert t.annotation_allocations() == 0 and tr.stages['s'].calls == 1\n"
+        )
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        subprocess.run([sys.executable, "-c", code], check=True, cwd=root, timeout=120)
+
+
+class TestAnnotationOverhead:
+    def test_untraced_device_read_builds_no_annotation(self, device_sample):
+        """No decode_trace, no annotation object — pinned by counter, beside
+        span_allocations(), not by timing."""
+        _device_read(device_sample)  # warm lazy paths
+        spans, anns = trace_mod.span_allocations(), trace_mod.annotation_allocations()
+        _device_read(device_sample)
+        with stage("nothing", 10, args={"group": 0}):
+            pass
+        with span("nothing"):
+            pass
+        assert trace_mod.span_allocations() == spans
+        assert trace_mod.annotation_allocations() == anns
+
+    def test_only_recorded_spans_build_annotations(self):
+        """Under a trace: one annotation per recorded stage/span; none for
+        record_span=False micro-stages nor the back-dated sub-clocks."""
+        import jax  # noqa: F401 - an annotation needs jax in sys.modules
+
+        with decode_trace() as t:
+            before = trace_mod.annotation_allocations()
+            for _ in range(100):
+                with stage("assemble", record_span=False):
+                    pass
+                with trace_mod.timed_stage("assembly.rows", record_span=False):
+                    pass
+            add_seconds("prepare.copy", 0.001)
+            add_seconds_batch([("prepare.decompress", 0.001), ("prepare.crc", 0.001)])
+            bump("event")
+            assert trace_mod.annotation_allocations() == before
+            with stage("dispatch", args={"group": 1, "column": "a"}):
+                with trace_mod.timed_stage("dataset.wait"):
+                    pass
+            with span("chunk.prepare", {"column": "a"}):
+                pass
+            assert trace_mod.annotation_allocations() == before + 3
+        assert t.stages["assemble"].calls == 100
+        args = [e for e in t.to_chrome_trace()["traceEvents"] if e["name"] == "dispatch"]
+        assert args[0]["args"] == {"group": 1, "column": "a"}
+
+
+def _hlo(fn, *args, **kw) -> str:
+    return fn.lower(*args, **kw).compile().as_text()
+
+
+def _kernel_cases():
+    """(kernel, scopes its compiled HLO must carry, lowering thunk): every
+    jitted kernel of device_ops at a small shape."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    import parquet_tpu.kernels.device_ops as d
+
+    i32 = lambda n: jnp.arange(n, dtype=jnp.int32)  # noqa: E731
+    u32 = lambda n: jnp.arange(n, dtype=jnp.uint32)  # noqa: E731
+    mask = jnp.asarray(np.arange(4096) % 3 == 0)
+    inner_hybrid = ("find_run", "unpack", "select")
+    inner_delta = ("find_block", "unpack", "prefix_sum", "rebase")
+    return [
+        ("hybrid_expand", inner_hybrid, lambda: _hlo(
+            d.expand_hybrid_device, u32(4 * 64 + 1024), width=3, num_values=4096, run_pad=64)),
+        ("delta_decode", inner_delta, lambda: _hlo(
+            d.delta_packed_decode_device, u32(3 * 64 + 64),
+            jnp.zeros(64 + 64 + 1024, jnp.uint64), nbits=64, num_values=4096, m_pad=64, p_pad=64)),
+        ("delta_decode", inner_delta, lambda: _hlo(
+            d.delta_packed_decode_device, u32(4 * 64 + 2 * 64 + 1024),
+            jnp.zeros(0, jnp.uint32), nbits=32, num_values=4096, m_pad=64, p_pad=64)),
+        ("dict_gather", (), lambda: _hlo(d.dict_gather_device, i32(16).astype(jnp.int64), i32(4096) % 16)),
+        ("prefix_sum", (), lambda: _hlo(d.prefix_sum, i32(4096))),
+        ("predicate_mask", (), lambda: _hlo(d.predicate_mask_device, i32(4096), "<", 5, 5, True)),
+        ("masked_agg", (), lambda: _hlo(d.masked_agg_device, i32(4096).astype(jnp.int64), mask, "sum")),
+        ("mask_take", (), lambda: _hlo(d.mask_take_device, i32(4096), mask, out_pad=2048)),
+        ("merge_mixed_numeric", (), lambda: _hlo(
+            d.merge_mixed_numeric_device, i32(2048), i32(16).astype(jnp.int64),
+            i32(2048).astype(jnp.int64), i32(8) % 2, i32(9) * 512, i32(8) * 256, rows_pad=4096)),
+        ("merge_mixed_bytes", (), lambda: _hlo(
+            d.merge_mixed_bytes_device, i32(2048), i32(17).astype(jnp.int64) * 4,
+            jnp.zeros(8192, jnp.uint8), i32(2056), i32(8) % 2, i32(9) * 512, i32(8) * 256,
+            i32(8).astype(jnp.int64) * 64, jnp.int32(4000), rows_pad=4096, total_bytes_pad=16384)),
+        ("bss_transpose", (), lambda: _hlo(d._bss_transpose_padded, jnp.zeros((4, 4096), jnp.uint8))),
+        ("record_starts", (), lambda: _hlo(d.record_starts_device, i32(4096) % 2)),
+        ("list_layout", (), lambda: _hlo(d.list_layout_device, i32(4096) % 2, i32(4096) % 3, 0, 2)),
+        ("list_contains_mask", (), lambda: _hlo(
+            d.list_contains_mask_device, i32(4096) % 2, i32(4096) % 3, mask[:2048], 2)),
+        ("bitpack_encode", (), lambda: _hlo(d.bitpack_encode_device, u32(4096) % 8, width=3)),
+        ("rle_hybrid_encode", (), lambda: _hlo(d.rle_hybrid_encode_device, u32(4096) // 64, width=6)),
+        ("dict_indices", (), lambda: _hlo(d.dict_indices_device, i32(4096).astype(jnp.int64) % 9)),
+        ("delta_block_encode", (), lambda: _hlo(
+            d.delta_block_encode_device, i32(4096).astype(jnp.int64), 4000, nbits=64)),
+        ("plain_bytearray_encode", (), lambda: _hlo(
+            d.plain_bytearray_encode_device, jnp.zeros(8192, jnp.uint8), i32(1025) * 8, 1000, out_pad=16384)),
+    ]
+
+
+_KERNEL_IDS = [
+    "hybrid_expand", "delta_decode-64", "delta_decode-32", "dict_gather", "prefix_sum",
+    "predicate_mask", "masked_agg", "mask_take", "merge_mixed_numeric", "merge_mixed_bytes",
+    "bss_transpose", "record_starts", "list_layout", "list_contains_mask", "bitpack_encode",
+    "rle_hybrid_encode", "dict_indices", "delta_block_encode", "plain_bytearray_encode",
+]
+
+
+class TestKernelScopes:
+    @pytest.mark.parametrize("k", range(len(_KERNEL_IDS)), ids=_KERNEL_IDS)
+    def test_compiled_hlo_carries_the_kernel_scope(self, k):
+        """The names the benchmark reads out of the device trace: every
+        kernel's ops carry pqt.<kernel> in their op_name metadata, and the
+        two kernels that hold the reader's device time their inner scopes."""
+        import re
+
+        name, inner, thunk = _kernel_cases()[k]
+        assert _KERNEL_IDS[k].startswith(name)
+        op_names = set(re.findall(r'op_name="([^"]*)"', thunk()))
+        scoped = {n for n in op_names if f"/pqt.{name}/" in f"{n}/"}
+        assert scoped, (name, sorted(op_names)[:8])
+        for part in inner:
+            assert any(f"/pqt.{name}/{part}/" in f"{n}/" for n in scoped), (name, part)
+
+
+class TestDispatchAccounting:
+    def test_upload_bytes_and_nested_seconds(
+        self, device_sample, host_pool, monkeypatch
+    ):
+        """dispatch.upload carries exactly the bytes of the numpy buffers
+        that went to the device; upload + launch seconds commit as nested
+        under dispatch, so TOTAL counts the dispatch thread's wall once."""
+        import numpy as np
+
+        import parquet_tpu.kernels.pipeline as pipe
+
+        _device_read(device_sample)
+        sent = []
+        orig = pipe._ChunkPlan.dispatch_device
+
+        def spy(plan):
+            d = plan.dictionary
+            n = sum(f.buf.nbytes for f in plan.frozen_hybrid)
+            n += sum(f.meta32.nbytes + f.wide.nbytes for f in plan.frozen_delta)
+            if plan.frozen_hybrid and isinstance(d, np.ndarray) and d.ndim == 1:
+                n += d.nbytes
+            if plan.plain_host is not None:
+                n += plan.plain_host.nbytes
+            sent.append(n)
+            return orig(plan)
+
+        monkeypatch.setattr(pipe._ChunkPlan, "dispatch_device", spy)
+        with decode_trace() as t:
+            _device_read(device_sample)
+        assert len(sent) == 6 and all(sent)
+        r = t.stage_rollup()
+        assert r["dispatch.upload"]["bytes"] == sum(sent)
+        assert r["dispatch.launch"]["calls"] == 4  # code and ts, two groups; plain only uploads
+        assert r["dispatch"]["calls"] == 6 and r["dispatch"]["bytes"] == 0
+        assert "nested_seconds" not in r["dispatch"]
+        for name in ("dispatch.upload", "dispatch.launch"):
+            assert r[name]["nested_seconds"] == pytest.approx(r[name]["seconds"])
+        inner = r["dispatch.upload"]["seconds"] + r["dispatch.launch"]["seconds"]
+        assert 0 < inner <= r["dispatch"]["seconds"]
+        assert r["io.read"]["bytes"] > 0 and r["io.read"]["calls"] == 6
+        assert r["deliver"]["calls"] == 6
+        flat = sum(s["seconds"] for s in r.values())
+        assert t.exclusive_seconds() == pytest.approx(flat - inner)
+
+    def test_traced_device_read_records_the_dispatch_queue_wait(
+        self, device_sample, host_pool
+    ):
+        """Both pool hops of the device reader go through
+        instrumented_submit: the wait of a prepared chunk for the single
+        dispatch thread is in the trace (pool.wait) and in the registry."""
+        from parquet_tpu.utils import metrics
+
+        key = 'pool_queue_wait_seconds_count{pool="%s"}'
+        before = metrics.snapshot()
+        with decode_trace() as t:
+            _device_read(device_sample)
+        after = metrics.snapshot()
+        assert t.stages["pool.wait"].calls == 12  # 6 prepares + 6 dispatches
+        for pool in ("pqt-dispatch", "pqt-host"):
+            assert after[key % pool] - before.get(key % pool, 0) == 6, pool
