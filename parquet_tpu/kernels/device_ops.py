@@ -23,16 +23,22 @@ positions/indices stay 32-bit.
 int64 value support requires jax_enable_x64; enabled at import (documented in
 the package README).
 
-Why XLA formulations and not hand-written Pallas kernels: measured, not
-assumed. A fused Pallas hybrid-expansion kernel (kept through round 1 as
-kernels/pallas_ops.py) could not lower on the current Mosaic TPU backend —
-its essential dynamic 1-D gather (words[bitpos >> 5]) trips Mosaic's gather
-lowering rule, which only supports take_along_axis-shaped indices — while
-the XLA formulation of the same expansion measured ~110 G values/s on-chip
-(2^21 values, width 8), ≤2% of end-to-end decode wall time, which is
-host-prepare- and transfer-bound (see bench.py). XLA's fusion of the
-gather/shift/select chain is already near the HBM roofline here; a Pallas
-rewrite has no headroom to matter until the host side is >10x faster.
+Why XLA formulations and not hand-written Pallas kernels: a fused Pallas
+hybrid-expansion kernel (kept through round 1 as kernels/pallas_ops.py) could
+not lower on the Mosaic TPU backend of its day — its essential dynamic 1-D
+gather (words[bitpos >> 5]) trips Mosaic's gather lowering rule, which only
+supports take_along_axis-shaped indices. What the XLA formulations cost on
+a v5e is measured, not assumed (PERF.md sections 5 and 6; jax 0.9.0, libtpu
+0.0.34): the reader is device-bound, the two decode kernels here are 98 % of
+its window, and each is a count of full-length gather passes — one
+table[idx] over 2^20 indices takes 9 ms whatever the table's length above a
+few thousand entries (0.2 ms from a table of 64), against 0.3 ms for
+prefix_sum and 0.2-0.6 ms for a scatter-add of up to 65,536 updates. That is
+why the position -> run/miniblock/page lookups below are a scatter and a scan
+(_segment_of) and not a binary search: searchsorted was 13-17 dependent gather
+passes, 65 % of the device's busy time (PR 26's trace). What is left of
+expand_hybrid_device is its six gathers (about 50 ms per 2^20 values), of
+delta_packed_decode_device its seven.
 """
 
 from __future__ import annotations
@@ -52,7 +58,7 @@ jax.config.update("jax_enable_x64", True)
 # jax's defaults would leave them out of an externally placed cache.
 # What a cold cache costs on a v5e (libtpu 0.0.34; chip_smoke.py at 1M-row
 # groups, PERF.md PR 21): ~190 programs in ~70 s — most under a second, delta
-# decode 4-8 s per shape, and the one 1M-element sort in dict_indices_device
+# decode 5-9 s per shape, and the one 1M-element sort in dict_indices_device
 # 22 s. Nothing is near the O(100 s) per shape bucket this comment once
 # warned of, as long as row-group-sized scans go through prefix_sum.
 COMPILE_CACHE_DIR = os.path.join(
@@ -169,6 +175,21 @@ def prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
     return (inner + carry[:, None]).reshape(-1)[:n]
 
 
+def _segment_of(starts: jnp.ndarray, num_values: int) -> jnp.ndarray:
+    """For every position i in [0, num_values): the index of the last entry
+    of the sorted int32 table `starts` that is <= i, -1 where there is none
+    — the values of searchsorted(starts, arange(num_values), 'right') - 1.
+    The queries are every position in order, so the answer is a running
+    count of segment starts: one scatter-add of a 1 at each start, one
+    prefix_sum, minus one. Entries at or past num_values (the tables'
+    n_pad + 1 padding) fall outside the array and are dropped, not clipped;
+    equal entries (zero-length segments) add up, so the last of them wins."""
+    marks = jnp.zeros(num_values, dtype=jnp.int32).at[starts].add(
+        1, mode="drop", indices_are_sorted=True
+    )
+    return prefix_sum(marks) - 1
+
+
 def bytes_to_words32(data: bytes) -> np.ndarray:
     """Pad bytes to a uint32 LE word array (+1 guard word for the hi gather)."""
     pad = (-len(data)) % 4
@@ -201,9 +222,13 @@ def expand_hybrid_device(
       buf[3*run_pad:4*run_pad]  bit_start   bit offset of payload (int32)
       buf[4*run_pad:]           packed payload words (+1 guard word)
 
-    For output index i: its run r = searchsorted(out_start, i, 'right')-1.
+    For output index i: its run r is the last run with out_start <= i — a
+    running count of run starts (_segment_of: one scatter-add, one prefix
+    sum), not a search. Padding entries of out_start hold n_pad + 1 and are
+    dropped; a zero-length run repeats the next run's start and loses to it.
     RLE runs broadcast their value; bit-packed runs extract bits at
-    bit_start[r] + (i - out_start[r]) * width.
+    bit_start[r] + (i - out_start[r]) * width. Positions past the table's
+    total belong to the last run and carry garbage: the caller slices them off.
     """
     run_is_rle = buf[:run_pad] != 0
     run_out_start = jax.lax.bitcast_convert_type(buf[run_pad : 2 * run_pad], jnp.int32)
@@ -214,7 +239,7 @@ def expand_hybrid_device(
     packed_words = buf[4 * run_pad :]
     i = jnp.arange(num_values, dtype=jnp.int32)
     with jax.named_scope("find_run"):
-        r = jnp.searchsorted(run_out_start, i, side="right").astype(jnp.int32) - 1
+        r = _segment_of(run_out_start, num_values)
         within = i - run_out_start[r]
     if width == 0:
         return jnp.zeros(num_values, dtype=jnp.uint32)
@@ -255,7 +280,12 @@ def delta_packed_decode_device(
         value[i] = first[p(i)] + C[i] - C[page_start[p(i)]]
 
     with C = cumsum of the per-position deltas (positions at page starts
-    contribute 0). This is the SURVEY §7.2 M3c shape — headers prescanned,
+    contribute 0). Miniblock m(i) and page p(i) are running counts of the
+    out_starts / page_start entries <= i (_segment_of; padding entries hold
+    n_pad + 1 and are dropped). A page's miniblocks start one past its first
+    value, so at a page start m is the previous page's last miniblock, -1 at
+    i = 0: what is gathered through it there is masked by is_start. This is
+    the SURVEY §7.2 M3c shape — headers prescanned,
     payload never expanded host-side — and the upload is the wire size, ~5-10x
     smaller than the decoded column (the reason device decode beats
     host-decode-plus-upload on the host<->device link).
@@ -286,10 +316,10 @@ def delta_packed_decode_device(
         words = wide[m_pad + p_pad :]
     i = jnp.arange(num_values, dtype=jnp.int32)
     with jax.named_scope("find_block"):
-        m = jnp.searchsorted(mb_out_start, i, side="right").astype(jnp.int32) - 1
+        m = _segment_of(mb_out_start, num_values)
         w = mb_width[m]
         within = i - mb_out_start[m]
-        p = jnp.searchsorted(page_start, i, side="right").astype(jnp.int32) - 1
+        p = _segment_of(page_start, num_values)
         is_start = i == page_start[p]
     if nbits == 32:
         with jax.named_scope("unpack"):

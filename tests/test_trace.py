@@ -637,9 +637,10 @@ def _hlo(fn, *args, **kw) -> str:
     return fn.lower(*args, **kw).compile().as_text()
 
 
-def _kernel_cases():
+def _kernel_cases(pad=64):
     """(kernel, scopes its compiled HLO must carry, lowering thunk): every
-    jitted kernel of device_ops at a small shape."""
+    jitted kernel of device_ops at a small shape; `pad` is the length of the
+    two decode kernels' run / miniblock tables."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -652,13 +653,13 @@ def _kernel_cases():
     inner_delta = ("find_block", "unpack", "prefix_sum", "rebase")
     return [
         ("hybrid_expand", inner_hybrid, lambda: _hlo(
-            d.expand_hybrid_device, u32(4 * 64 + 1024), width=3, num_values=4096, run_pad=64)),
+            d.expand_hybrid_device, u32(4 * pad + 1024), width=3, num_values=4096, run_pad=pad)),
         ("delta_decode", inner_delta, lambda: _hlo(
-            d.delta_packed_decode_device, u32(3 * 64 + 64),
-            jnp.zeros(64 + 64 + 1024, jnp.uint64), nbits=64, num_values=4096, m_pad=64, p_pad=64)),
+            d.delta_packed_decode_device, u32(3 * pad + 64),
+            jnp.zeros(pad + 64 + 1024, jnp.uint64), nbits=64, num_values=4096, m_pad=pad, p_pad=64)),
         ("delta_decode", inner_delta, lambda: _hlo(
-            d.delta_packed_decode_device, u32(4 * 64 + 2 * 64 + 1024),
-            jnp.zeros(0, jnp.uint32), nbits=32, num_values=4096, m_pad=64, p_pad=64)),
+            d.delta_packed_decode_device, u32(4 * pad + 2 * 64 + 1024),
+            jnp.zeros(0, jnp.uint32), nbits=32, num_values=4096, m_pad=pad, p_pad=64)),
         ("dict_gather", (), lambda: _hlo(d.dict_gather_device, i32(16).astype(jnp.int64), i32(4096) % 16)),
         ("prefix_sum", (), lambda: _hlo(d.prefix_sum, i32(4096))),
         ("predicate_mask", (), lambda: _hlo(d.predicate_mask_device, i32(4096), "<", 5, 5, True)),
@@ -709,6 +710,20 @@ class TestKernelScopes:
         assert scoped, (name, sorted(op_names)[:8])
         for part in inner:
             assert any(f"/pqt.{name}/{part}/" in f"{n}/" for n in scoped), (name, part)
+
+    @pytest.mark.parametrize("k", range(3), ids=_KERNEL_IDS[:3])
+    def test_the_segment_lookups_hold_no_loop(self, k):
+        """find_run / find_block are one scatter-add and one prefix sum: at
+        a table length where a binary search would take 13 dependent gather
+        passes over every value, the compiled kernels hold no while op."""
+        import re
+
+        hlo = _kernel_cases(pad=4096)[k][2]()
+        op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+        lookups = {n for n in op_names if "/find_run/" in f"{n}/" or "/find_block/" in f"{n}/"}
+        assert any(n.endswith("/scatter-add") for n in lookups), sorted(lookups)
+        assert not re.search(r"\bwhile\(", hlo)
+        assert not [n for n in op_names if "searchsorted" in n]
 
 
 class TestDispatchAccounting:
