@@ -55,6 +55,7 @@ KERNEL_LEGS = {
     "expand_hybrid_device": "decode",  # vendor / passenger_count / stops
     "delta_packed_decode_device": "decode",  # ts, and trip_id's repack
     "dict_gather_device": "decode",  # passenger_count's numeric dictionary
+    "double_narrow_device": "decode",  # doubles="float32": fare, and the mixed file
     "predicate_mask_device": "decode",  # filter_rows=True
     "mask_take_device": "decode",
     "record_starts_device": "kernels",
@@ -351,6 +352,57 @@ def leg_decode(args) -> dict:
     say(f"decode: {args.rows} rows x {len(columns)} columns resident and equal to pyarrow; "
         f"host-decoded pages {host_pages}; compile requests cold {cold['requests']} "
         f"({cold['seconds']} s), warm {warm['requests']}")
+
+    # -- DOUBLE in the two forms a TPU holds exactly (doubles=) ----------------
+    # the shapes the benchmark's corpus does not have: PLAIN pages (`fare`,
+    # and `wild`: random bit patterns, overflow, subnormals, NaN, -0.0) and a
+    # chunk whose dictionary outgrew its page and fell back to PLAIN mid-way
+    # (`mixed`); every value against pyarrow + numpy, bit for bit
+    rng = np.random.default_rng([args.seed, 99])
+    n_d = group_rows
+    wild = rng.integers(0, 2**64, n_d, dtype=np.uint64)
+    wild[: n_d // 4] = (rng.standard_normal(n_d // 4) * 10.0 ** rng.uniform(-60, 60, n_d // 4)).view(np.uint64)
+    wild[-8:] = np.array([0.0, -0.0, np.inf, -np.inf, np.nan, 5e-324, 3.4028235677973366e38, 2.0**-150]).view(np.uint64)
+    mixed = np.round(rng.gamma(2.0, 9.0, n_d) * np.linspace(1, 400, n_d), 2)
+    dpath = Path(args.corpus) / "doubles.parquet"
+    pq.write_table(
+        pa.table({"wild": pa.array(wild.view(np.float64)),
+                  "mixed": pa.array(mixed, mask=rng.random(n_d) < 0.04)}),
+        dpath, compression="snappy", row_group_size=n_d, use_dictionary=["mixed"],
+        dictionary_pagesize_limit=256 << 10, column_encoding={"wild": "PLAIN"},
+    )
+    forms = {}
+    for form in ("bits", "float32"):
+        with decode_trace() as tr:
+            with FileReader(str(paths[0])) as r:
+                fare = r.read_row_groups_device(columns=["fare"], doubles=form)
+            with FileReader(str(dpath)) as r:
+                (dg,) = r.read_row_groups_device(doubles=form)
+        got = {"fare": np.concatenate([np.asarray(g[("fare",)].values) for g in fare]),
+               "wild": np.asarray(dg[("wild",)].values), "mixed": np.asarray(dg[("mixed",)].values)}
+        want = {"fare": pq.read_table(paths[0], columns=["fare"])["fare"].to_numpy(),
+                "wild": wild.view(np.float64), "mixed": mixed[np.asarray(dg[("mixed",)].def_levels) == 1]}
+        for name, w in want.items():
+            check(all(on_device(dc.values) and dc.double_form == form
+                      for g in (*fare, dg) for dc in g.values()), f"doubles={form}: not resident in that form")
+            if form == "bits":
+                ok = got[name].dtype == np.uint64 and np.array_equal(got[name], w.view(np.uint64))
+            else:
+                with np.errstate(over="ignore", invalid="ignore"):
+                    w32 = w.astype(np.float32)
+                nan = np.isnan(w32)
+                ok = (got[name].dtype == np.float32 and np.array_equal(np.isnan(got[name]), nan)
+                      and np.array_equal(got[name].view(np.uint32)[~nan], w32.view(np.uint32)[~nan]))
+            check(ok, f"doubles={form}: {name} differs from pyarrow + numpy")
+        forms[form] = {**counters_of(tr, "device_double"), **counters_of(tr, "double_"),
+                       **counters_of(tr, "hybrid_pages_repacked"), **counters_of(tr, "host_decoded_pages")}
+        check(forms[form].get(f"device_double_chunks_{form}") == len(fare) + 2
+              and "host_decoded_pages" not in forms[form], f"doubles={form}: counters {forms[form]}")
+    check(forms["float32"].get("double_pages_narrowed_device", 0) > 0
+          and forms["float32"].get("double_dict_narrowed_host", 0) == 1, f"doubles counters {forms}")
+    out["doubles"] = {"rows": int(len(want["fare"]) + 2 * n_d), "forms": forms}
+    say(f"doubles: fare (PLAIN), wild (PLAIN, adversarial bit patterns) and mixed (dictionary -> PLAIN) "
+        f"equal pyarrow + numpy bit for bit as uint64 bits and as float32; counters {forms}")
 
     # -- batches into a jitted step --------------------------------------------
     @jax.jit

@@ -2103,6 +2103,72 @@ ssize_t ptq_hybrid_encode(const uint64_t* v, int64_t n, int width,
   return hybrid_encode_any(v, 8, n, width, out, out_cap);
 }
 
+// Re-pack bit-packed groups of 8 values from w_from to w_to bits a value
+// (0 <= w_from <= w_to <= 32): the transfer-side widening of a dictionary
+// index page that was written before its chunk's dictionary crossed a power
+// of two (kernels/pipeline.py _repack_pages_to_width), so that a chunk
+// ships at ONE width. src holds n_groups * w_from bytes, dst takes
+// n_groups * w_to. Returns bytes written, -1 on bad args. The worker of
+// ptq_repack_pages below, which is what the binding calls.
+static ssize_t ptq_repack_width(const uint8_t* src, int64_t n_groups, int w_from,
+                         int w_to, uint8_t* dst) {
+  if (w_from < 0 || w_to > 32 || w_from > w_to || n_groups < 0) return -1;
+  const uint64_t mask = (1ull << w_from) - 1;
+  uint64_t in_acc = 0, out_acc = 0;
+  int in_bits = 0, out_bits = 0;
+  uint8_t* dp = dst;
+  for (int64_t i = 0, n = n_groups * 8; i < n; i++) {
+    while (in_bits < w_from) {
+      in_acc |= static_cast<uint64_t>(*src++) << in_bits;
+      in_bits += 8;
+    }
+    out_acc |= (in_acc & mask) << out_bits;
+    in_acc >>= w_from;
+    in_bits -= w_from;
+    out_bits += w_to;
+    while (out_bits >= 8) {
+      *dp++ = static_cast<uint8_t>(out_acc);
+      out_acc >>= 8;
+      out_bits -= 8;
+    }
+  }
+  return dp - dst;
+}
+
+// ptq_repack_width over the pages of one chunk, in ONE call (a call per page
+// hands the GIL back and forth fifty times a chunk, and on a busy pool each
+// return waits for it): page p's packed region is packed[ps[p], pe[p]),
+// whole groups at widths[p] bits; it lands in dst at w_to bits, copied as it
+// is where widths[p] == w_to, skipped where widths[p] == 0 (no payload: the
+// caller turns those runs into RLE runs). ns_out (nullable) takes the wall
+// nanoseconds spent in here, clocked inside like ptq_chunk_prepare's
+// stage_ns: a clock around the call would also count the wait for the GIL
+// on return. Returns bytes written, -1 on bad args, -2 if dst_cap is too
+// small.
+ssize_t ptq_repack_pages(const uint8_t* packed, const int64_t* ps,
+                         const int64_t* pe, const int32_t* widths,
+                         int64_t n_pages, int w_to, uint8_t* dst,
+                         size_t dst_cap, int64_t* ns_out) {
+  const int64_t t0 = ns_out ? StageClock::now() : 0;
+  size_t pos = 0;
+  for (int64_t p = 0; p < n_pages; p++) {
+    const int w = widths[p];
+    const int64_t len = pe[p] - ps[p];
+    if (len < 0 || w < 0 || w > w_to) return -1;
+    if (w == 0) continue;
+    if (len % w) return -1;
+    const size_t out = static_cast<size_t>(len / w) * w_to;
+    if (pos + out > dst_cap) return -2;
+    if (w == w_to)
+      std::memcpy(dst + pos, packed + ps[p], out);
+    else if (ptq_repack_width(packed + ps[p], len / w, w, w_to, dst + pos) < 0)
+      return -1;
+    pos += out;
+  }
+  if (ns_out) *ns_out = StageClock::now() - t0;
+  return static_cast<ssize_t>(pos);
+}
+
 // DELTA_BINARY_PACKED encode (mirrors ops/delta.py encode_delta
 // byte-for-byte, including wrapping min-delta arithmetic and zero-width
 // trailing miniblocks). vals is int32[n] or int64[n] by nbits. Returns

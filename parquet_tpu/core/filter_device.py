@@ -234,6 +234,13 @@ def _dense_compare(dc, leaf, op, vlo, vhi, ckey):
         raise DeviceFilterError(
             f"filter_device: {leaf.path_str}: no orderable physical form"
         )
+    if getattr(dc, "double_form", None) is not None:
+        # a DOUBLE delivered as uint64 patterns or as float32 (doubles=) no
+        # longer orders or compares as the file's float64: the host engine
+        raise DeviceFilterError(
+            f"filter_device: {leaf.path_str}: delivered as {dc.double_form}, "
+            "the float64 comparison stays on the host"
+        )
     if op in ("in", "not_in"):
         if any(lo is None for lo, _ in vlo):
             raise DeviceFilterError(

@@ -668,7 +668,7 @@ class FileReader:
         return jax.default_device(dev)
 
     def read_row_group_device(
-        self, i: int, columns=None, device=None, *, filters=None
+        self, i: int, columns=None, device=None, *, filters=None, doubles=None
     ):
         """Decode one row group straight into device memory (HBM).
 
@@ -679,6 +679,18 @@ class FileReader:
         to one jax.Device (overriding the reader-level `device=`); unlike a
         caller-side jax.default_device context it also reaches the internal
         dispatch thread.
+
+        `doubles` picks the delivered form of DOUBLE columns (here and in
+        read_row_groups_device / iter_device_batches):
+          None (default)  float64 where the platform holds it bit-exactly,
+                          else DeviceDoubleError (any TPU: f64 is emulated)
+          "bits"          DeviceColumn.values is uint64, the IEEE-754 bit
+                          pattern of every non-null value: exact everywhere
+          "float32"       DeviceColumn.values is float32, each value the
+                          round-to-nearest-even narrowing of the file's
+                          float64 — bit for bit numpy's astype(float32)
+        DeviceColumn.double_form names the form; no float64 value enters a
+        device program under either (kernels/pipeline.py DOUBLE_FORMS).
 
         `filters` (same spec as iter_rows) additionally evaluates the
         predicate over the DELIVERED columns and returns ({leaf path:
@@ -692,13 +704,15 @@ class FileReader:
         predicate -> mask -> gather pipeline with one jit cache entry per
         (schema, pad-bucket)."""
         if filters is None:
-            return self._read_row_group_device(i, columns, pack=True, device=device)
+            return self._read_row_group_device(
+                i, columns, pack=True, device=device, doubles=doubles
+            )
         from .filter import normalize_dnf
 
         normalized = normalize_dnf(self.schema, filters)
         read_columns = self._columns_with_filters(columns, normalized)
         cols = self._read_row_group_device(
-            i, read_columns, pack=True, device=device
+            i, read_columns, pack=True, device=device, doubles=doubles
         )
         n = int(self.row_group(i).num_rows or 0)
         with self._devctx(device):
@@ -777,11 +791,13 @@ class FileReader:
             arrs = jax.tree_util.tree_map(lambda a: a[sel][:kept], arrs)
             return arrs, kept
 
-    def _read_row_group_device(self, i: int, columns, pack: bool, device=None):
+    def _read_row_group_device(
+        self, i: int, columns, pack: bool, device=None, doubles=None
+    ):
         """pack=False mirrors _read_row_group: the batch iterator consumes
         levels immediately (mask build), so packing them would be overhead."""
         with span("row_group.device", {"group": i}):
-            plans = self._plan_row_group(i, columns, device=device)
+            plans = self._plan_row_group(i, columns, device=device, doubles=doubles)
             with self._devctx(device):
                 return {
                     path: self._deliver(i, path, plan, pack)
@@ -796,7 +812,9 @@ class FileReader:
             dc = plan.device_column()
             return self._pack_chunk_levels(path, dc) if pack else dc
 
-    def read_row_groups_device(self, row_groups=None, columns=None, device=None):
+    def read_row_groups_device(
+        self, row_groups=None, columns=None, device=None, *, doubles=None
+    ):
         """Decode row groups into device memory with full pipelining.
 
         Unlike per-group read_row_group_device calls — which resolve each
@@ -804,7 +822,7 @@ class FileReader:
         — this plans EVERY chunk of every requested group first (prepare on
         worker threads / dispatch on the dispatch thread, all overlapped) and
         only then materializes results. Returns [{leaf path: DeviceColumn}]
-        in row-group order."""
+        in row-group order. `doubles`: see read_row_group_device."""
         indices = list(
             range(self.num_row_groups) if row_groups is None else row_groups
         )
@@ -814,10 +832,12 @@ class FileReader:
             # groups' decoded buffers at once and spuriously trip it, so
             # ceiling-capped readers stage one group at a time.
             return [
-                self.read_row_group_device(i, columns, device=device)
+                self.read_row_group_device(i, columns, device=device, doubles=doubles)
                 for i in indices
             ]
-        staged = self._plan_row_groups_async(indices, columns, device=device)
+        staged = self._plan_row_groups_async(
+            indices, columns, device=device, doubles=doubles
+        )
         out = []
         for i, group in zip(indices, staged):
             with self._devctx(device):
@@ -829,10 +849,12 @@ class FileReader:
                 )
         return out
 
-    def _plan_row_group_async(self, i: int, columns=None, device=None):
+    def _plan_row_group_async(self, i: int, columns=None, device=None, doubles=None):
         """Stage one row group: prepare (pool or inline) + enqueue dispatch.
         Returns [(path, future-of-dispatched-plan)] without resolving."""
-        return self._plan_row_groups_async([i], columns, device=device)[0]
+        return self._plan_row_groups_async(
+            [i], columns, device=device, doubles=doubles
+        )[0]
 
     def iter_device_batches(
         self,
@@ -846,6 +868,7 @@ class FileReader:
         lists: str = "error",
         max_list_len: int | None = None,
         device=None,
+        doubles=None,
     ):
         """Stream the file as fixed-size device-resident batches.
 
@@ -912,6 +935,10 @@ class FileReader:
         context it also reaches the internal dispatch thread. Mutually
         useful with `sharding`: decode lands on `device`, device_put lays
         each batch out over the mesh.
+
+        `doubles` picks the form of DOUBLE columns ("bits" -> uint64 arrays,
+        "float32" -> float32 arrays; None: float64 where the platform holds
+        it, else DeviceDoubleError): see read_row_group_device.
         """
         if batch_size <= 0:
             raise ValueError("batch_size must be positive")
@@ -941,15 +968,19 @@ class FileReader:
             normalized = normalize_dnf(self.schema, filters)
         if filter_rows and normalized is None:
             raise ValueError("filter_rows=True requires filters")
+        if doubles is not None:
+            from ..kernels.pipeline import check_doubles_form
+
+            check_doubles_form(doubles)
         return self._iter_device_batches(
             batch_size, columns, drop_remainder, sharding, nullable,
-            normalized, lists, max_list_len, device, filter_rows,
+            normalized, lists, max_list_len, device, filter_rows, doubles,
         )
 
     def _iter_device_batches(
         self, batch_size: int, columns, drop_remainder: bool, sharding=None,
         nullable: str = "error", normalized=None, lists: str = "error",
-        max_list_len=None, device=None, filter_rows: bool = False,
+        max_list_len=None, device=None, filter_rows: bool = False, doubles=None,
     ):
         import jax
         import jax.numpy as jnp
@@ -1057,7 +1088,9 @@ class FileReader:
 
         def stage(i):
             if lookahead:
-                return self._plan_row_group_async(i, read_columns, device=device)
+                return self._plan_row_group_async(
+                    i, read_columns, device=device, doubles=doubles
+                )
             return None
 
         staged_next = stage(groups[0]) if groups and lookahead else None
@@ -1081,7 +1114,7 @@ class FileReader:
                     }
                 else:
                     group = self._read_row_group_device(
-                        i, read_columns, pack=False, device=device
+                        i, read_columns, pack=False, device=device, doubles=doubles
                     )
                 arrs = {
                     path: _array_of(path, dc)
@@ -1137,7 +1170,9 @@ class FileReader:
                     pass
             yield carry
 
-    def _plan_row_groups_async(self, indices, columns=None, device=None):
+    def _plan_row_groups_async(
+        self, indices, columns=None, device=None, doubles=None
+    ):
         """Stage chunks of several row groups at once.
 
         Every chunk's prepare is submitted to the worker pool up front (no
@@ -1145,10 +1180,11 @@ class FileReader:
         dispatch is enqueued per chunk in deterministic (group, column) order
         as its prepare resolves. Returns [[(path, future-of-dispatched-plan)]]
         per group, unresolved."""
-        from ..kernels.pipeline import prepare_chunk_plan
+        from ..kernels.pipeline import check_doubles_form, prepare_chunk_plan
         from ..utils.native import get_native
         from .chunk import ChunkWindow, chunk_byte_range
 
+        check_doubles_form(doubles)
         groups = [(i, list(self._selected_chunks(i, columns))) for i in indices]
         # with a block cache attached the planner bills its own io.read
         # (misses only); the direct source read is billed here
@@ -1165,6 +1201,7 @@ class FileReader:
                     column,
                     validate_crc=self.validate_crc,
                     alloc=self.alloc,
+                    doubles=doubles,
                 )
 
         dev = self._effective_device(device)
@@ -1214,7 +1251,7 @@ class FileReader:
             for i, chunks in prep_futs
         ]
 
-    def _plan_row_group(self, i: int, columns=None, device=None):
+    def _plan_row_group(self, i: int, columns=None, device=None, doubles=None):
         """Plan every selected chunk of a row group for device decode.
 
         The host-only prepare phase (one pread per chunk, page walk,
@@ -1225,7 +1262,9 @@ class FileReader:
         """
         return {
             path: fut.result()
-            for path, fut in self._plan_row_group_async(i, columns, device=device)
+            for path, fut in self._plan_row_group_async(
+                i, columns, device=device, doubles=doubles
+            )
         }
 
     def _pread(self, offset: int, size: int) -> bytes:

@@ -584,7 +584,10 @@ class DatasetIterator:
                 ]
                 if doubles:
                     # a TPU hands float64 back an ulp off: typed refusal,
-                    # never an inexact batch (kernels.pipeline.DeviceDoubleError)
+                    # never an inexact batch (kernels.pipeline.DeviceDoubleError).
+                    # The loader takes no other form yet: the two a TPU holds
+                    # exactly are FileReader's device entry points' doubles=
+                    # ("bits", "float32"); here the batch stays on the host
                     check_double_delivery(doubles, placement)
                 states.append(s)  # appended before the yield: stays aligned
                 yield b

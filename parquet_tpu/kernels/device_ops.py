@@ -89,6 +89,7 @@ __all__ = [
     "expand_hybrid_device",
     "delta_packed_decode_device",
     "dict_gather_device",
+    "double_narrow_device",
     "list_layout_device",
     "record_starts_device",
     "predicate_mask_device",
@@ -382,6 +383,44 @@ def bss_transpose_device(streams: jnp.ndarray, num_values: int) -> jnp.ndarray:
 def dict_gather_device(dictionary: jnp.ndarray, indices: jnp.ndarray) -> jnp.ndarray:
     """Dictionary expansion: one gather (reference: type_dict.go lookup loop)."""
     return dictionary[indices]
+
+
+@jax.jit
+@jax.named_scope("pqt.double_narrow")
+def double_narrow_device(bits: jnp.ndarray) -> jnp.ndarray:
+    """IEEE-754 binary64 bit patterns (uint64) -> the binary32 patterns
+    (uint32) of their round-to-nearest-even narrowing: bit for bit numpy's
+    astype(float32) — +-inf on overflow, f32 subnormals, -0.0 kept; a NaN
+    stays a NaN (quiet, the payload's top 22 bits kept).
+
+    Integer arithmetic only, and that is the point: a TPU has no f64 (XLA
+    emulates it as an f32 pair, an ulp off — pipeline.DeviceDoubleError),
+    so `astype` on the device would narrow a value that is already wrong.
+    The 53-bit significand (implicit one included) shifts right by 29 for a
+    normal result, by 30 - e32 when the result is an f32 subnormal (e32 =
+    biased exponent - 896 <= 0), and rounds on what fell off: up when it is
+    over half, or exactly half with the kept part odd. Adding the kept
+    significand — implicit one at bit 23 — to (e32 - 1) << 23 lets a
+    rounding carry walk into the exponent by itself: the largest subnormal
+    rounds to the smallest normal, the largest normal to infinity. A shift
+    of 54 or more keeps nothing and rounds to zero (the value is under half
+    the smallest subnormal), so the shift is clamped to 63."""
+    bits = bits.astype(jnp.uint64)
+    sign = ((bits >> 63) << 31).astype(jnp.uint32)
+    e = ((bits >> 52) & jnp.uint64(0x7FF)).astype(jnp.int32)
+    mant = bits & jnp.uint64((1 << 52) - 1)
+    e32 = e - 896
+    sig = jnp.where(e == 0, mant, mant | jnp.uint64(1 << 52))
+    sh = jnp.where(e32 >= 1, 29, jnp.minimum(30 - e32, 63)).astype(jnp.uint64)
+    kept = (sig >> sh).astype(jnp.uint32)
+    rem = sig & ((jnp.uint64(1) << sh) - 1)
+    half = jnp.uint64(1) << (sh - 1)
+    up = (rem > half) | ((rem == half) & ((kept & 1) == 1))
+    base = jnp.where(e32 >= 1, (e32 - 1) << 23, 0).astype(jnp.uint32)
+    out = base + kept + up.astype(jnp.uint32)
+    out = jnp.where(e32 >= 255, jnp.uint32(0x7F800000), out)  # overflow, inf
+    nan = jnp.uint32(0x7FC00000) | (mant >> 29).astype(jnp.uint32)
+    return sign | jnp.where((e == 2047) & (mant != 0), nan, out)
 
 
 @jax.jit

@@ -68,6 +68,7 @@ from .device_ops import (
     delta_packed_decode_device,
     dict_gather_device,
     dict_indices_device,
+    double_narrow_device,
     expand_hybrid_device,
     plain_bytearray_encode_device,
     rle_hybrid_encode_device,
@@ -78,6 +79,7 @@ __all__ = [
     "plan_chunk_tpu",
     "DeviceColumn",
     "DeviceDoubleError",
+    "DOUBLE_FORMS",
     "check_double_delivery",
     "TpuDecodeStats",
     "dispatch_pool",
@@ -195,8 +197,29 @@ class DeviceDoubleError(ParquetFileError):
     (v5e, libtpu 0.0.34: 19.88 resident in HBM fetches back as
     19.879999999999995, and f64->u64 bitcast is UNIMPLEMENTED in the x64
     rewriter). Exactness is part of the result, so the device path refuses
-    rather than delivering values an ulp off: project the column out, cast
-    it to FLOAT upstream, or read it on the host."""
+    rather than delivering values an ulp off. The device entry points take
+    `doubles=` for the two forms a TPU does hold exactly (DOUBLE_FORMS):
+    "bits" (uint64 IEEE-754 patterns) and "float32" (the round-to-nearest-
+    even narrowing, bit for bit numpy's astype). Or project the column out,
+    or read it on the host."""
+
+
+# What FileReader's device entry points accept as `doubles=` besides None:
+#   "bits"     DeviceColumn.values is uint64, the IEEE-754 bit pattern of every
+#              non-null value: exact on every platform
+#   "float32"  DeviceColumn.values is float32, each value the round-to-nearest-
+#              even narrowing of the file's float64 (numpy's astype(float32):
+#              +-inf on overflow, f32 subnormals, -0.0 kept, NaN stays NaN)
+# Either way no float64 value enters a device program: bit patterns stay
+# unsigned from the upload to the delivery, a dictionary narrows on the host
+# before it is uploaded, PLAIN / BYTE_STREAM_SPLIT values narrow on the device
+# in integer arithmetic (device_ops.double_narrow_device).
+DOUBLE_FORMS = ("bits", "float32")
+
+
+def check_doubles_form(doubles) -> None:
+    if doubles is not None and doubles not in DOUBLE_FORMS:
+        raise ValueError(f'doubles must be None, "bits" or "float32", not {doubles!r}')
 
 
 @functools.lru_cache(maxsize=None)
@@ -223,7 +246,9 @@ def check_double_delivery(names, placement=None) -> None:
         raise DeviceDoubleError(
             f"parquet: DOUBLE column(s) {', '.join(names)} cannot be held "
             f"bit-exactly on {platform} (f64 is emulated as an f32 pair); "
-            "project them out, cast to FLOAT upstream, or read on the host"
+            'ask the device entry points for doubles="bits" (uint64 IEEE-754 '
+            'bit patterns) or doubles="float32" (round-to-nearest-even '
+            "narrowing), project them out, or read on the host"
         )
 
 
@@ -295,6 +320,50 @@ def _skewed_dict_bound(dictionary, dict_rows: int, plain_bytes: int):
     return bound, ok
 
 
+def _index_width(page_width: int, n_dict: int) -> int:
+    """The ONE bit width a chunk's dictionary indices ship at: the widest of
+    its pages, and at least what addresses the dictionary's size plus an
+    eighth. A writer packs each page at the width of the dictionary SO FAR,
+    and a dictionary's final size varies a little from chunk to chunk; taken
+    as written, the static `width` of expand_hybrid_device (a compiled
+    program each) would follow where the dictionary crossed a power of two
+    (TLC tip_amount: 2,012-2,062 entries a row group, 11 or 12 bits; PERF.md
+    section 6, PR 28). The eighth only bites within a ninth of a power of
+    two: 3, 7, 265 or 8,900 entries keep their written width."""
+    n = n_dict + n_dict // 8
+    return min(32, max(page_width, (n - 1).bit_length() if n > 0 else 0))
+
+
+def _repack_groups(src: np.ndarray, n_groups: int, w_from: int, w_to: int) -> np.ndarray:
+    """`n_groups` bit-packed groups of 8 values (uint8 array, w_from bytes a
+    group, w_from >= 1) widened to w_to bits a value: one page's share of
+    _repack_pages_to_width, for the staged walk's per-page batches. Native
+    where the library has it; ops/bitpack.py is the reference and fallback."""
+    from ..ops.bitpack import pack_bits, unpack_bits
+    from ..utils.native import get_native
+
+    lib = get_native()
+    if lib is not None and lib.has_repack_pages:
+        one = np.array([0, len(src), w_from])
+        return lib.repack_pages(
+            np.ascontiguousarray(src), one[:1], one[1:2], one[2:].astype(np.int32),
+            w_to, n_groups * w_to,
+        )[0]
+    return np.frombuffer(
+        pack_bits(unpack_bits(src, n_groups * 8, w_from), w_to), dtype=np.uint8
+    )
+
+
+def _count_repack(pages: int, seconds: float, nbytes: int) -> None:
+    """Account index pages widened at freeze time: the counter, and the
+    prepare.repack_width sub-clock (seconds + bytes written, back-dated like
+    the native walk's prepare.* clocks so it nests in chunk.prepare)."""
+    if pages:
+        _metrics.event("hybrid_pages_repacked", pages)
+        _trace.count("hybrid_pages_repacked", pages)
+        _trace.add_seconds("prepare.repack_width", seconds, nbytes)
+
+
 class _FrozenHybrid(NamedTuple):
     """Upload-ready hybrid batch (built in prepare; dispatched by transfer)."""
 
@@ -356,6 +425,8 @@ class _HybridBatch:
     """
 
     def __init__(self, width: int):
+        # the chunk's ONE shipping width (_index_width): pages written
+        # narrower are re-packed to it as they are added
         self.width = width
         self.is_rle: list[np.ndarray] = []
         self.counts: list[np.ndarray] = []
@@ -364,14 +435,21 @@ class _HybridBatch:
         self.packed: list[bytes] = []
         self.packed_bits = 0
         self.out_count = 0
+        self.repacked = 0  # pages widened, bytes written
+        self.repacked_bytes = 0
+
+    def _bits(self, table, width: int) -> int:
+        """The page's payload bits once it is at this batch's width."""
+        if width == 0:
+            return 0
+        if width == self.width:
+            return len(table.packed) * 8
+        return len(table.packed) // width * self.width * 8
 
     def fits(self, table, width: int) -> bool:
-        return (
-            width == self.width
-            and self.packed_bits + len(table.packed) * 8 <= _BATCH_BITS_CAP
-        )
+        return self.packed_bits + self._bits(table, width) <= _BATCH_BITS_CAP
 
-    def add_page(self, table, take: int) -> None:
+    def add_page(self, table, take: int, width: int | None = None) -> None:
         counts = table.counts.astype(np.int64)
         cum = np.cumsum(counts)
         if take > (int(cum[-1]) if len(cum) else 0):
@@ -379,12 +457,24 @@ class _HybridBatch:
         k = int(np.searchsorted(cum, take, side="left"))
         counts = counts[: k + 1].copy()
         counts[k] = take - (int(cum[k - 1]) if k else 0)
-        self.is_rle.append(table.is_rle[: k + 1])
+        is_rle, bp_offsets, packed = table.is_rle[: k + 1], table.bp_offsets[: k + 1], table.packed
+        if width is not None and width != self.width:
+            self.repacked += 1
+            if width == 0:  # nothing to widen: every value is index 0
+                is_rle, packed = np.ones(k + 1, dtype=is_rle.dtype), b""
+            else:
+                packed = _repack_groups(
+                    np.frombuffer(packed, dtype=np.uint8),
+                    len(packed) // width, width, self.width,
+                ).tobytes()
+                bp_offsets = bp_offsets // width * self.width
+                self.repacked_bytes += len(packed)
+        self.is_rle.append(is_rle)
         self.counts.append(counts)
         self.values.append(table.rle_values[: k + 1])
-        self.bit_starts.append(table.bp_offsets[: k + 1] * 8 + self.packed_bits)
-        self.packed.append(table.packed)
-        self.packed_bits += len(table.packed) * 8
+        self.bit_starts.append(bp_offsets * 8 + self.packed_bits)
+        self.packed.append(packed)
+        self.packed_bits += len(packed) * 8
         self.out_count += take
 
     def freeze(self) -> tuple:
@@ -537,7 +627,9 @@ class _DeltaBatch:
 class DeviceColumn:
     """Decoded column delivered in device memory (HBM) — the TPU-native
     output of the decode pipeline. Numeric columns carry `values` (real
-    dtype; floats bitcast on device from their wire bit patterns). Byte-array
+    dtype; floats bitcast on device from their wire bit patterns; a DOUBLE
+    asked for under doubles= arrives in the form `double_form` names).
+    Byte-array
     columns carry Arrow-style `data` + `offsets`, or — for dictionary-encoded
     chunks — device `indices` plus the (small) dictionary both host-side and
     as device `dict_data`/`dict_offsets`.
@@ -556,6 +648,10 @@ class DeviceColumn:
     dict_offsets: jnp.ndarray | None = None
     def_levels: "np.ndarray | PackedLevels | None" = None
     rep_levels: "np.ndarray | PackedLevels | None" = None
+    # a DOUBLE column delivered under doubles=: "bits" (`values` is uint64
+    # IEEE-754 patterns) or "float32" (`values` is the exact narrowing);
+    # None for every other column and for the default float64 delivery
+    double_form: str | None = None
     # memoized device copies of the level streams (one upload, shared by
     # every list_layout() depth)
     _dev_rep: "jnp.ndarray | None" = None
@@ -599,9 +695,14 @@ class DeviceColumn:
 class _ChunkPlan:
     """Host-side record of one chunk's in-flight device decode."""
 
-    def __init__(self, column: Column, expected: int):
+    def __init__(self, column: Column, expected: int, doubles: str | None = None):
         self.column = column
         self.expected = expected
+        # the delivered form of a DOUBLE column (DOUBLE_FORMS), else None
+        self.doubles = doubles if column.type == Type.DOUBLE else None
+        # under doubles=: the dictionary as it uploads (bit patterns, or
+        # narrowed on the host; padded to its index width's 2^w entries)
+        self.dict_upload: np.ndarray | None = None
         self.page_infos: list[tuple] = []  # (n, def, rep, kind, payload)
         # whole-chunk level arrays from the native walk (page slices view
         # them); when set, finalize/device_column skip the per-page concat
@@ -642,7 +743,7 @@ class _ChunkPlan:
         if self._dispatched:
             return self
         self._dispatched = True
-        d = self.dictionary
+        d = self.dictionary if self.dict_upload is None else self.dict_upload
         if self.frozen_hybrid and isinstance(d, np.ndarray) and d.ndim == 1:
             # Upload the dictionary only when device-decoded indices will
             # gather against it (device_column); host reassembly gathers on
@@ -657,7 +758,7 @@ class _ChunkPlan:
         # concatenated at prepare time).
         if self.plain_host is not None:
             with _trace.stage("dispatch.upload", self.plain_host.nbytes):
-                self.dev_plain = _upload_typed(self.plain_host)
+                self.dev_plain = self._upload(self.plain_host)
             self.plain_host = None
         for streams, nv in self.bss_host:
             with _trace.stage("dispatch.upload", streams.nbytes):
@@ -813,11 +914,16 @@ class _ChunkPlan:
         """Deliver the chunk's decoded values in HBM (no device->host fetch of
         the value data). Falls back to host decode + upload for shapes the
         device path doesn't cover (byte-array delta pages, booleans, ...).
-        Raises DeviceDoubleError for a DOUBLE column on a device that
-        cannot hold float64 bit-exactly (any TPU)."""
+        A DOUBLE column arrives in the form the plan was prepared for
+        (doubles=, DOUBLE_FORMS); with none asked it is float64, and raises
+        DeviceDoubleError on a device that cannot hold float64 bit-exactly
+        (any TPU)."""
         column = self.column
         if column.type == Type.DOUBLE:
-            check_double_delivery([column.path_str])
+            if self.doubles is None:
+                check_double_delivery([column.path_str])
+            else:
+                _trace.bump(f"device_double_chunks_{self.doubles}")
         kinds = {k for _, _, _, k, _ in self.page_infos if k != "empty"}
         if self.native_def is not None or self.native_rep is not None:
             def_levels, rep_levels = self.native_def, self.native_rep
@@ -828,7 +934,8 @@ class _ChunkPlan:
             rep_levels = np.concatenate(all_rep) if all_rep else None
         n_total = sum(n for n, *_ in self.page_infos)
         out = DeviceColumn(
-            num_values=n_total, def_levels=def_levels, rep_levels=rep_levels
+            num_values=n_total, def_levels=def_levels, rep_levels=rep_levels,
+            double_form=self.doubles,
         )
 
         if (
@@ -851,7 +958,7 @@ class _ChunkPlan:
                 out.dict_offsets = jnp.asarray(self.dictionary.offsets)
             else:
                 vals = dict_gather_device(self.dict_dev, idx)
-                out.values = _device_bitcast(vals, column)
+                out.values = self._typed(vals)
             return out
 
         if kinds <= {"delta", "empty"} and self.dev_delta:
@@ -874,11 +981,11 @@ class _ChunkPlan:
 
         if "values" in kinds and kinds <= {"values", "empty"} and column.type in _NUMERIC_DTYPE:
             if self.dev_plain is not None:
-                out.values = self.dev_plain
+                out.values = self._typed(self.dev_plain)
             else:
                 parts = [p for _, _, _, k, p in self.page_infos if k == "values"]
                 host = parts[0] if len(parts) == 1 else np.concatenate(parts)
-                out.values = _upload_typed(host)
+                out.values = self._typed(self._upload(host))
             return out
 
         # Mixed dict+PLAIN numeric chunk (pyarrow's default 1MB dictionary
@@ -888,11 +995,12 @@ class _ChunkPlan:
         # no value ever round-trips to the host.
         if (
             column.type in _NUMERIC_DTYPE
-            # DOUBLE excluded: the merge needs the f64<->u64 bitcast, which
-            # XLA's x64 rewriter does not implement on TPU; mixed doubles
-            # take the host-merge fallback below (FLOAT is fine — u32
-            # bitcasts are native)
-            and column.type != Type.DOUBLE
+            # a DOUBLE delivered as float64 is excluded: the merge needs the
+            # f64<->u64 bitcast, which XLA's x64 rewriter does not implement
+            # on TPU; it takes the host-merge fallback below. Under doubles=
+            # the merge never leaves the unsigned domain (FLOAT is fine
+            # either way — u32 bitcasts are native)
+            and (column.type != Type.DOUBLE or self.doubles is not None)
             and kinds <= {"dict", "values", "empty"}
             and "dict" in kinds
             and self.dev_hybrid
@@ -910,6 +1018,10 @@ class _ChunkPlan:
                 plain_u = jax.lax.bitcast_convert_type(
                     plain_u, jnp.uint32 if plain_u.dtype.itemsize == 4 else jnp.uint64
                 )
+            if self.doubles == "float32":
+                # each side narrows, then they merge at 32 bits: the
+                # dictionary on the host (dict_upload), the PLAIN pages here
+                plain_u = self._narrow(plain_u)
             merged = merge_mixed_numeric_device(
                 _pad_device(self._dev_indices()),
                 _pad_device(self.dict_dev),
@@ -919,7 +1031,7 @@ class _ChunkPlan:
                 jnp.asarray(aux_np),
                 _bucket(max(n_rows, 1)),
             )[:n_rows]
-            out.values = _device_bitcast(merged, column)
+            out.values = self._typed(merged)
             return out
 
         # Mixed dict+PLAIN byte-array chunk (config-3 shape under pyarrow's
@@ -941,8 +1053,41 @@ class _ChunkPlan:
             out.data = jnp.asarray(np.frombuffer(data.values.data, dtype=np.uint8))
             out.offsets = jnp.asarray(data.values.offsets)
         else:
-            out.values = _upload_typed(np.asarray(data.values))
+            out.values = self._typed(self._upload(np.asarray(data.values)))
         return out
+
+    # -- the delivered form of a value array -----------------------------------
+    #
+    # Unsigned bit patterns become typed values in exactly two places,
+    # _device_bitcast and _upload_typed. Under doubles= neither runs for a
+    # DOUBLE: the patterns upload as uint64 (_upload), stay unsigned through
+    # gather and merge, and _typed hands them over as they are ("bits") or
+    # narrowed ("float32") — no float64 value exists in any program.
+
+    def _upload(self, host: np.ndarray) -> jnp.ndarray:
+        if self.doubles is not None and host.dtype == np.float64:
+            return jnp.asarray(host.view(np.uint64))
+        return _upload_typed(host)
+
+    def _narrow(self, bits: jnp.ndarray) -> jnp.ndarray:
+        """uint64 patterns of this chunk's PLAIN / BYTE_STREAM_SPLIT pages ->
+        float32 patterns, on the device (bucket-padded: one program a
+        bucket)."""
+        pages = sum(1 for _, _, _, k, _ in self.page_infos if k == "values")
+        _metrics.event("double_pages_narrowed_device", pages)
+        _trace.count("double_pages_narrowed_device", pages)
+        n = int(bits.shape[0])
+        return double_narrow_device(_pad_device(bits))[:n]
+
+    def _typed(self, vals: jnp.ndarray) -> jnp.ndarray:
+        if self.doubles is None:
+            # already typed (a PLAIN upload) or bit patterns (gather, merge)
+            return vals if vals.dtype.kind == "f" else _device_bitcast(vals, self.column)
+        if self.doubles == "bits":
+            return vals
+        if vals.dtype == jnp.uint64:
+            vals = self._narrow(vals)
+        return jax.lax.bitcast_convert_type(vals, jnp.float32)
 
     def _dev_indices(self) -> jnp.ndarray:
         """All dispatched dict-index batches as one int32 device array."""
@@ -1063,7 +1208,7 @@ _PC_MINIS, _PC_MINIE, _PC_DSTART, _PC_DCONS = 12, 13, 14, 15
 _PC_EXTRA, _PC_DFIRST = 16, 17
 
 
-def _native_prepare(f, chunk, column, validate_crc, alloc, stats):
+def _native_prepare(f, chunk, column, validate_crc, alloc, stats, doubles=None):
     """Whole-chunk native prepare: ONE GIL-free C call walks every page
     (header parse, CRC verify when validate_crc, decompress, level decode,
     value prescan) and returns packed tables; batch assembly is then a
@@ -1087,7 +1232,9 @@ def _native_prepare(f, chunk, column, validate_crc, alloc, stats):
 
     if _os.environ.get("PQT_FUSED_PREPARE", "1") == "0":
         return None, None  # forced staged path: not a decline, no counter
-    plan, fault = _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats)
+    plan, fault = _native_prepare_impl(
+        f, chunk, column, validate_crc, alloc, stats, doubles
+    )
     if plan is None:
         _trace.bump("prepare_fused_declined")
         if fault is not None:
@@ -1097,7 +1244,7 @@ def _native_prepare(f, chunk, column, validate_crc, alloc, stats):
     return plan, fault
 
 
-def _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats):
+def _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats, doubles=None):
     if alloc is not None:
         # a memory ceiling needs the per-page accounting only the staged
         # walk performs (validate_crc, by contrast, is fused natively)
@@ -1172,7 +1319,9 @@ def _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats):
             ]
         )
     try:
-        plan = _plan_from_tables(column, expected, res, stats, np_dt, delta_nbits)
+        plan = _plan_from_tables(
+            column, expected, res, stats, np_dt, delta_nbits, doubles
+        )
     except (PageError, ChunkError):
         raise
     except Exception:
@@ -1205,8 +1354,8 @@ def _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats):
     return plan, None
 
 
-def _plan_from_tables(column, expected, res, stats, np_dt, delta_nbits):
-    plan = _ChunkPlan(column, expected)
+def _plan_from_tables(column, expected, res, stats, np_dt, delta_nbits, doubles=None):
+    plan = _ChunkPlan(column, expected, doubles)
     plan.stats = stats
     pages = res["pages"].tolist()
     values_buf = res["values"]
@@ -1340,16 +1489,22 @@ def _plan_from_tables(column, expected, res, stats, np_dt, delta_nbits):
         return plan
 
     if routes == {1} or (
-        routes == {1, 3} and np_dt is not None and column.type != Type.DOUBLE
-        # DOUBLE mixed chunks can't merge on device (no f64<->u64 bitcast in
-        # the TPU x64 emulation); freezing their batches would only upload
-        # indices that finalize() fetches straight back — demote instead
+        routes == {1, 3}
+        and np_dt is not None
+        and (column.type != Type.DOUBLE or plan.doubles is not None)
+        # mixed chunks of a DOUBLE delivered as float64 can't merge on
+        # device (no f64<->u64 bitcast in the TPU x64 emulation); freezing
+        # their batches would only upload indices that finalize() fetches
+        # straight back — demote instead. Under doubles= the merge stays in
+        # the unsigned domain and the chunk keeps its device batches
     ):
         # Dictionary-encoded chunk, possibly with a mid-chunk fall-back to
         # PLAIN pages (pyarrow's 1MB dictionary ceiling): dict pages build
         # device run batches, PLAIN pages ride the contiguous raw upload,
         # and device_column merges in page order.
-        frozen = _freeze_hybrid_from_tables(data_pages, res)
+        frozen = _freeze_hybrid_from_tables(
+            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0
+        )
         if frozen is not None:
             plan.frozen_hybrid = frozen
             first = None
@@ -1419,7 +1574,9 @@ def _plan_from_tables(column, expected, res, stats, np_dt, delta_nbits):
         # batches stay device-bound; PLAIN pages host-scan their offsets
         # (native byte_array_gather) and device_column's ragged merge joins
         # both in output-index space.
-        frozen = _freeze_hybrid_from_tables(data_pages, res)
+        frozen = _freeze_hybrid_from_tables(
+            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0
+        )
         if frozen is not None:
             from ..core.page import _decode_values
 
@@ -1511,35 +1668,96 @@ def _plan_from_tables(column, expected, res, stats, np_dt, delta_nbits):
     return plan
 
 
-def _freeze_hybrid_from_tables(data_pages, res) -> list | None:
+def _repack_pages_to_width(pages: list, res: dict, width: int):
+    """The route-1 `pages` of one chunk with every page narrower than `width`
+    re-packed to it: (pages with their packed ranges rebased, is_rle,
+    byteoff, packed) replacing the native walk's tables. A page's packed
+    region is whole groups of 8 values, w bytes each, so it widens as one
+    unit and a run's byte offset scales by width / w. A width-0 page has no
+    payload to widen: its bit-packed runs become RLE runs of index 0. The
+    bytes move in ONE native call a chunk (GIL-free; a call a page would
+    wait for the GIL fifty times on a busy pool), the tables in NumPy."""
+    import time as _time
+
+    from ..utils.native import get_native
+
+    t0 = _time.perf_counter()
+    rs, re, ps, pe, w = (
+        np.array([P[k] for P in pages], dtype=np.int64)
+        for k in (_PC_RUNS, _PC_RUNE, _PC_PACKS, _PC_PACKE, _PC_EXTRA)
+    )
+    narrow = w != width
+    size = np.where(narrow, (pe - ps) // np.maximum(w, 1) * width, pe - ps)
+    size[w == 0] = 0
+    new_pe = np.cumsum(size)
+    new_ps = new_pe - size
+    packed_all = np.ascontiguousarray(res["packed"])
+    lib = get_native()
+    if lib is not None and lib.has_repack_pages:
+        # clocked inside the call, as the walk's other prepare.* clocks are:
+        # a clock out here would also count this thread's waits for the GIL
+        packed, seconds = lib.repack_pages(
+            packed_all, ps, pe, w.astype(np.int32), width, int(new_pe[-1])
+        )
+    else:
+        packed = np.concatenate([
+            packed_all[a:b] if wp == width else _repack_groups(packed_all[a:b], (b - a) // wp, wp, width)
+            for a, b, wp in zip(ps.tolist(), pe.tolist(), w.tolist()) if wp
+        ] or [packed_all[:0]])
+        seconds = _time.perf_counter() - t0
+    # the run tables: runs of consecutive route-1 pages are consecutive
+    page_of = np.repeat(np.arange(len(pages)), re - rs)
+    lo, hi = int(rs[0]), int(re[-1])
+    is_rle = res["h_is_rle"].copy()
+    byteoff = res["h_byteoff"].copy()
+    rel = byteoff[lo:hi] - ps[page_of]
+    wide = np.where(narrow[page_of], rel // np.maximum(w[page_of], 1) * width, rel)
+    byteoff[lo:hi] = wide + new_ps[page_of]
+    is_rle[lo:hi] |= (w[page_of] == 0).astype(is_rle.dtype)
+    out = []
+    for P, a, b in zip(pages, new_ps.tolist(), new_pe.tolist()):
+        Q = list(P)
+        Q[_PC_PACKS], Q[_PC_PACKE], Q[_PC_EXTRA] = a, b, width
+        out.append(Q)
+    _count_repack(int(narrow.sum()), seconds, int(size[narrow].sum()))
+    return out, is_rle, byteoff, packed
+
+
+def _freeze_hybrid_from_tables(data_pages, res, n_dict: int = 0) -> list | None:
     """Vectorized _HybridBatch.freeze over the native walk's global run
-    tables. Pages group sequentially per index width under the bit cap (same
-    policy as _commit_routes); returns None when a single page exceeds the
-    cap (demote-all, matching the Python walk)."""
+    tables. A chunk ships at ONE index width (_index_width): pages written
+    narrower are re-packed to it first, so the compiled shapes do not follow
+    where the dictionary crossed a power of two. Pages group sequentially
+    under the bit cap (same policy as _commit_routes); returns None when a
+    single page exceeds the cap (demote-all, matching the Python walk)."""
     cap = _BATCH_BITS_CAP
-    groups: list[list] = []  # [width, rs, re, ps, pe, bits]
+    pages = [P for P in data_pages if P[_PC_ROUTE] == 1]
+    h_is_rle = res["h_is_rle"]
+    h_byteoff = res["h_byteoff"]
+    packed_all = res["packed"]
+    if pages:
+        width = _index_width(max(P[_PC_EXTRA] for P in pages), n_dict)
+        if any(P[_PC_EXTRA] != width for P in pages):
+            pages, h_is_rle, h_byteoff, packed_all = _repack_pages_to_width(
+                pages, res, width
+            )
+    groups: list[list] = []  # [rs, re, ps, pe, bits]
     cur = None
-    for P in data_pages:
-        if P[_PC_ROUTE] != 1:
-            continue
-        width = P[_PC_EXTRA]
+    for P in pages:
         bits = (P[_PC_PACKE] - P[_PC_PACKS]) * 8
         if bits > cap:
             return None
-        if cur is None or cur[0] != width or cur[5] + bits > cap:
-            cur = [width, P[_PC_RUNS], P[_PC_RUNE], P[_PC_PACKS], P[_PC_PACKE], bits]
+        if cur is None or cur[4] + bits > cap:
+            cur = [P[_PC_RUNS], P[_PC_RUNE], P[_PC_PACKS], P[_PC_PACKE], bits]
             groups.append(cur)
         else:
-            cur[2] = P[_PC_RUNE]
-            cur[4] = P[_PC_PACKE]
-            cur[5] += bits
+            cur[1] = P[_PC_RUNE]
+            cur[3] = P[_PC_PACKE]
+            cur[4] += bits
     frozen = []
     h_counts = res["h_counts"]
-    h_is_rle = res["h_is_rle"]
     h_values = res["h_values"]
-    h_byteoff = res["h_byteoff"]
-    packed_all = res["packed"]
-    for width, rs, re, ps, pe, _bits in groups:
+    for rs, re, ps, pe, _bits in groups:
         counts = h_counts[rs:re]
         k = len(counts)
         total = int(counts.sum())
@@ -1754,8 +1972,10 @@ def prepare_chunk_plan(
     validate_crc: bool = False,
     alloc=None,
     stats: TpuDecodeStats | None = None,
+    doubles: str | None = None,
 ) -> _ChunkPlan:
     """Host-only prepare: page walk, decompress, level decode, prescan.
+    `doubles` (DOUBLE_FORMS) is the form a DOUBLE column is bound for.
 
     Touches no jax state, so it is safe to run on worker threads; the
     returned plan's batches go to the device via plan.dispatch_device() on
@@ -1770,10 +1990,12 @@ def prepare_chunk_plan(
     import time as _time
 
 
-    plan, fault = _native_prepare(f, chunk, column, validate_crc, alloc, stats)
+    plan, fault = _native_prepare(
+        f, chunk, column, validate_crc, alloc, stats, doubles
+    )
     if plan is None:
         t0 = _time.perf_counter()
-        plan = _staged_prepare(f, chunk, column, validate_crc, alloc, stats)
+        plan = _staged_prepare(f, chunk, column, validate_crc, alloc, stats, doubles)
         _metrics.observe("chunk_decode_seconds", _time.perf_counter() - t0)
         if fault is not None:
             # the native walk aborted but the staged walk decoded cleanly
@@ -1792,7 +2014,32 @@ def prepare_chunk_plan(
         _metrics.event("host_decoded_pages", plan.host_pages)
         _trace.count("host_decoded_pages", plan.host_pages)
         _trace.count(f"host_decoded_pages.{column.path_str}", plan.host_pages)
+    if plan.doubles is not None:
+        _shape_double_dictionary(plan)
     return plan
+
+
+def _shape_double_dictionary(plan: _ChunkPlan) -> None:
+    """Under doubles=, the dictionary of a device-decoded DOUBLE chunk as it
+    uploads: uint64 bit patterns ("bits") or narrowed here, on the host, to
+    float32 patterns ("float32": numpy's astype is IEEE round-to-nearest-
+    even, at most 2^20 entries, and the gather then moves 32-bit entries).
+    Padded to the 2^w entries its index width addresses (_index_width), so
+    dict_gather_device compiles once per width and not once per dictionary
+    length. Prepare phase: host only, on the pool's threads."""
+    d = plan.dictionary
+    if not (plan.frozen_hybrid and isinstance(d, np.ndarray) and d.ndim == 1):
+        return
+    if plan.doubles == "float32":
+        # overflow to +-inf is the stated result; a signalling NaN quiets
+        with np.errstate(over="ignore", invalid="ignore"):
+            bits = d.astype(np.float32).view(np.uint32)
+        _trace.bump("double_dict_narrowed_host", d.nbytes)
+    else:
+        bits = d.view(np.uint64)
+    up = np.zeros(1 << _index_width(0, len(d)), dtype=bits.dtype)
+    up[: len(d)] = bits
+    plan.dict_upload = up
 
 
 def _staged_prepare(
@@ -1802,12 +2049,13 @@ def _staged_prepare(
     validate_crc: bool = False,
     alloc=None,
     stats: TpuDecodeStats | None = None,
+    doubles: str | None = None,
 ) -> _ChunkPlan:
     """The per-page Python prepare walk (the error-semantics reference)."""
     md = chunk.meta_data
     codec = md.codec or 0
     expected = md.num_values or 0
-    plan = _ChunkPlan(column, expected)
+    plan = _ChunkPlan(column, expected, doubles)
     plan.stats = stats
     ptype = column.type
 
@@ -1954,19 +2202,33 @@ def _commit_routes(plan: _ChunkPlan, pending: list) -> None:
         return
     homogeneous = kinds == pending_kinds and len(pending_kinds) == 1
     if homogeneous:
+        import time as _time
+
         hybrid_batches = plan.hybrid_batches
         delta_batches = plan.delta_batches
+        t0 = _time.perf_counter()
+        if "dict" in pending_kinds:
+            # the chunk's one shipping width, as _freeze_hybrid_from_tables
+            ship_width = _index_width(
+                max(p[3] for p in pending),
+                len(plan.dictionary) if plan.dictionary is not None else 0,
+            )
         for kind, _idx, table, arg, non_null, buf in pending:
             if kind == "dict":
                 width = arg
                 if not hybrid_batches or not hybrid_batches[-1].fits(table, width):
-                    hybrid_batches.append(_HybridBatch(width))
-                hybrid_batches[-1].add_page(table, non_null)
+                    hybrid_batches.append(_HybridBatch(ship_width))
+                hybrid_batches[-1].add_page(table, non_null, width)
             else:
                 nbits = arg
                 if not delta_batches or not delta_batches[-1].fits(table):
                     delta_batches.append(_DeltaBatch(nbits))
                 delta_batches[-1].add_page(table, buf)
+        _count_repack(
+            sum(b.repacked for b in hybrid_batches),
+            _time.perf_counter() - t0,
+            sum(b.repacked_bytes for b in hybrid_batches),
+        )
         plan.frozen_hybrid = [b.freeze() for b in hybrid_batches]
         plan.frozen_delta = [
             f for f in (b.freeze() for b in delta_batches) if f is not None

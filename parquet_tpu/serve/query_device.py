@@ -157,7 +157,10 @@ def device_unit_partial(reader, row_group: int, query, filters, device=None):
         )
     except DeviceDoubleError as e:
         # a DOUBLE filter/count column on a device without native f64:
-        # the unit is exact on the host
+        # the unit is exact on the host (host_fallback). The forms a TPU
+        # holds exactly (read_row_group_device's doubles="bits" /
+        # "float32") neither order nor sum as the file's float64, so this
+        # lane does not ask for them
         raise DeviceQueryError(f"query_device: {e}") from None
 
     mask = None
