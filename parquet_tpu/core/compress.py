@@ -16,6 +16,7 @@ the user registers an implementation.
 
 from __future__ import annotations
 
+import threading
 import zlib
 
 from ..meta.file_meta import ParquetFileError
@@ -127,14 +128,28 @@ class _Zstd(_Codec):
     def __init__(self):
         import zstandard
 
-        self._c = zstandard.ZstdCompressor()
-        self._d = zstandard.ZstdDecompressor()
+        self._zstd = zstandard
+        # a zstandard context is not thread-safe (its calls release the GIL
+        # over one shared ZSTD_CCtx / ZSTD_DCtx), and chunks prepare on pool
+        # threads: each thread keeps its own pair
+        self._local = threading.local()
+
+    def _contexts(self):
+        ctx = getattr(self._local, "ctx", None)
+        if ctx is None:
+            ctx = self._local.ctx = (
+                self._zstd.ZstdCompressor(),
+                self._zstd.ZstdDecompressor(),
+            )
+        return ctx
 
     def compress(self, data):
-        return self._c.compress(bytes(data))
+        return self._contexts()[0].compress(bytes(data))
 
     def decompress(self, data, uncompressed_size):
-        return self._d.decompress(bytes(data), max_output_size=max(uncompressed_size, 1))
+        return self._contexts()[1].decompress(
+            bytes(data), max_output_size=max(uncompressed_size, 1)
+        )
 
 
 class _NativeLz4Raw(_Codec):

@@ -29,16 +29,23 @@ not lower on the Mosaic TPU backend of its day — its essential dynamic 1-D
 gather (words[bitpos >> 5]) trips Mosaic's gather lowering rule, which only
 supports take_along_axis-shaped indices. What the XLA formulations cost on
 a v5e is measured, not assumed (PERF.md sections 5 and 6; jax 0.9.0, libtpu
-0.0.34): the reader is device-bound, the two decode kernels here are 98 % of
+0.0.34): the reader is device-bound, the two decode kernels here are most of
 its window, and each is a count of full-length gather passes — one
-table[idx] over 2^20 indices takes 9 ms whatever the table's length above a
-few thousand entries (0.2 ms from a table of 64), against 0.3 ms for
-prefix_sum and 0.2-0.6 ms for a scatter-add of up to 65,536 updates. That is
-why the position -> run/miniblock/page lookups below are a scatter and a scan
-(_segment_of) and not a binary search: searchsorted was 13-17 dependent gather
-passes, 65 % of the device's busy time (PR 26's trace). What is left of
-expand_hybrid_device is its six gathers (about 50 ms per 2^20 values), of
-delta_packed_decode_device its seven.
+table[idx] over 2^20 indices takes 9.3-9.9 ms whatever the table's length
+above a hundred entries (15-16 ms for 64-bit entries), against 0.3 ms for
+prefix_sum and 0.2-0.6 ms for a scatter-add of up to 65,536 32-bit updates.
+That is why nothing below looks a run, miniblock or page up per value: the
+position -> segment index is a scatter and a scan (_segment_of; searchsorted
+was 13-17 dependent gather passes, 65 % of the device's busy time in PR 26's
+trace), and a segment's fields reach its positions the same way (_spread:
+scatter the differences at the starts, scan; four of expand_hybrid_device's
+six passes and five of delta_packed_decode_device's seven went with it in
+PR 29). What is left of each kernel is its two gathers out of the packed
+words: 16.5 of the 17-18 ms a hybrid stream costs per 2^20 values at any
+width and run count; 36 of the 38 ms of a 64-bit delta stream (four 32-bit
+passes: the emulated halves) while XLA keeps all four word tables in fast
+memory, 49 of 51 once the wire words pass 2^18 — the fourth table is then
+read from HBM, a 22 ms pass (PERF.md section 6, PR 29).
 """
 
 from __future__ import annotations
@@ -191,6 +198,35 @@ def _segment_of(starts: jnp.ndarray, num_values: int) -> jnp.ndarray:
     return prefix_sum(marks) - 1
 
 
+def _spread(starts: jnp.ndarray, field: jnp.ndarray, num_values: int) -> jnp.ndarray:
+    """A per-segment quantity brought to every position of its segment:
+    field[_segment_of(starts, num_values)] wherever that index is >= 0, and 0
+    before the first start — without the gather. Each entry's difference from
+    the entry before it (the first from 0) is scatter-added at its start, and
+    one prefix_sum telescopes them: at position i the sum is the field of the
+    last segment that starts at or before i. The arithmetic wraps in the
+    field's own integer dtype and telescopes exactly all the same. Entries at
+    or past num_values (the tables' n_pad + 1 padding) are dropped with their
+    differences; they are the table's tail, so nothing after them is missed.
+    Equal starts (zero-length segments) add up to the last one's field.
+
+    On a v5e at 2^20 values (PERF.md section 6, PR 29): 0.30 ms from a table
+    of up to 4,096 32-bit entries, 0.57 from 32,768, 0.85 from 65,536, where
+    field[idx] is a 9.3-9.9 ms pass. A uint64 field goes as its two uint32
+    halves, each telescoping on its own: 1.1 ms from 32,768 entries where the
+    emulated 64-bit scatter-add alone makes it 2.75 (and 15.7 the gather)."""
+    if field.dtype == jnp.uint64:
+        lo = _spread(starts, field.astype(jnp.uint32), num_values)
+        hi = _spread(starts, (field >> 32).astype(jnp.uint32), num_values)
+        return (hi.astype(jnp.uint64) << 32) | lo.astype(jnp.uint64)
+    steps = field - jnp.concatenate([jnp.zeros(1, field.dtype), field[:-1]])
+    return prefix_sum(
+        jnp.zeros(num_values, dtype=field.dtype)
+        .at[starts]
+        .add(steps, mode="drop", indices_are_sorted=True)
+    )
+
+
 def bytes_to_words32(data: bytes) -> np.ndarray:
     """Pad bytes to a uint32 LE word array (+1 guard word for the hi gather)."""
     pad = (-len(data)) % 4
@@ -223,15 +259,23 @@ def expand_hybrid_device(
       buf[3*run_pad:4*run_pad]  bit_start   bit offset of payload (int32)
       buf[4*run_pad:]           packed payload words (+1 guard word)
 
-    For output index i: its run r is the last run with out_start <= i — a
-    running count of run starts (_segment_of: one scatter-add, one prefix
-    sum), not a search. Padding entries of out_start hold n_pad + 1 and are
-    dropped; a zero-length run repeats the next run's start and loses to it.
-    RLE runs broadcast their value; bit-packed runs extract bits at
-    bit_start[r] + (i - out_start[r]) * width. Positions past the table's
+    No position looks its run up. A run hands its positions two words by
+    _spread (a scatter of differences at out_start and one prefix sum each,
+    0.3-0.9 ms where a table[r] pass over 2^20 positions is 9): its is_rle flag,
+    and one payload — the value to broadcast if it is an RLE run, else
+    base = bit_start - out_start * width, so that position i of a bit-packed
+    run extracts its bits at base + i * width. Padding entries of out_start
+    hold n_pad + 1 and are dropped; a zero-length run repeats the next run's
+    start and loses to it. What is left is the two gathers out of the packed
+    words (needs the payload's guard word: at least two words), 7.5 + 9.0 ms
+    per 2^20 values on a v5e whatever the width (PERF.md section 6). At positions
+    of RLE runs the bit position means nothing, so its word index is clipped
+    into the payload and the result discarded. Positions past the table's
     total belong to the last run and carry garbage: the caller slices them off.
     """
-    run_is_rle = buf[:run_pad] != 0
+    if width == 0:
+        return jnp.zeros(num_values, dtype=jnp.uint32)
+    run_is_rle = buf[:run_pad]
     run_out_start = jax.lax.bitcast_convert_type(buf[run_pad : 2 * run_pad], jnp.int32)
     run_rle_value = buf[2 * run_pad : 3 * run_pad]
     run_bp_bit_start = jax.lax.bitcast_convert_type(
@@ -240,13 +284,17 @@ def expand_hybrid_device(
     packed_words = buf[4 * run_pad :]
     i = jnp.arange(num_values, dtype=jnp.int32)
     with jax.named_scope("find_run"):
-        r = _segment_of(run_out_start, num_values)
-        within = i - run_out_start[r]
-    if width == 0:
-        return jnp.zeros(num_values, dtype=jnp.uint32)
+        run_base = run_bp_bit_start - run_out_start * width
+        run_payload = jnp.where(
+            run_is_rle != 0,
+            run_rle_value,
+            jax.lax.bitcast_convert_type(run_base, jnp.uint32),
+        )
+        payload = _spread(run_out_start, run_payload, num_values)
+        is_rle = _spread(run_out_start, run_is_rle, num_values) != 0
     with jax.named_scope("unpack"):
-        bitpos = run_bp_bit_start[r] + within * width
-        w0 = bitpos >> 5
+        bitpos = jax.lax.bitcast_convert_type(payload, jnp.int32) + i * width
+        w0 = jnp.clip(bitpos >> 5, 0, packed_words.shape[0] - 2)
         s = (bitpos & 31).astype(jnp.uint32)
         lo = packed_words[w0] >> s
         hi = jnp.where(
@@ -257,7 +305,7 @@ def expand_hybrid_device(
         )
         bp_vals = (lo | hi) & mask
     with jax.named_scope("select"):
-        return jnp.where(run_is_rle[r], run_rle_value[r], bp_vals)
+        return jnp.where(is_rle, payload, bp_vals)
 
 
 @partial(jax.jit, static_argnames=("nbits", "num_values", "m_pad", "p_pad"))
@@ -281,11 +329,20 @@ def delta_packed_decode_device(
         value[i] = first[p(i)] + C[i] - C[page_start[p(i)]]
 
     with C = cumsum of the per-position deltas (positions at page starts
-    contribute 0). Miniblock m(i) and page p(i) are running counts of the
-    out_starts / page_start entries <= i (_segment_of; padding entries hold
-    n_pad + 1 and are dropped). A page's miniblocks start one past its first
-    value, so at a page start m is the previous page's last miniblock, -1 at
-    i = 0: what is gathered through it there is masked by is_start. This is
+    contribute 0). No position looks its miniblock or page up: a miniblock
+    hands its positions its width, its min_delta and base = bit_start -
+    out_start * width (a delta's bits sit at base + i * width) by _spread
+    over out_starts, a scatter of differences and one prefix sum each; the
+    page starts are a scatter of ones; and the page's offset first - C[
+    page_start], a gather of p_pad entries, reaches its values by _spread
+    over page_start. Padding entries hold n_pad + 1 and are dropped. A
+    page's miniblocks start one past its first value, so a page start still
+    carries the previous page's last miniblock (zeros at i = 0) and a bit
+    position that means nothing: its word index is clipped into the payload
+    and what is unpacked there is masked by is_start. What is left is the two
+    gathers out of the wire words: at nbits 64 two 32-bit passes each, 9 ms a
+    pass per 2^20 values on a v5e, 22 for a table XLA leaves in HBM (PERF.md
+    section 6). This is
     the SURVEY §7.2 M3c shape — headers prescanned,
     payload never expanded host-side — and the upload is the wire size, ~5-10x
     smaller than the decoded column (the reason device decode beats
@@ -315,49 +372,37 @@ def delta_packed_decode_device(
         mb_min = wide[:m_pad]
         page_first = wide[m_pad : m_pad + p_pad]
         words = wide[m_pad + p_pad :]
+    ut = jnp.uint32 if nbits == 32 else jnp.uint64
     i = jnp.arange(num_values, dtype=jnp.int32)
     with jax.named_scope("find_block"):
-        m = _segment_of(mb_out_start, num_values)
-        w = mb_width[m]
-        within = i - mb_out_start[m]
-        p = _segment_of(page_start, num_values)
-        is_start = i == page_start[p]
-    if nbits == 32:
-        with jax.named_scope("unpack"):
-            bitpos = mb_bit_start[m] + within * w.astype(jnp.int32)
-            w0 = bitpos >> 5
-            s = (bitpos & 31).astype(jnp.uint32)
-            lo = words[w0] >> s
-            hi = jnp.where(s == 0, jnp.uint32(0), words[w0 + 1] << ((32 - s) & 31))
-            mask = jnp.where(
-                w >= 32, jnp.uint32(0xFFFFFFFF), (jnp.uint32(1) << (w & 31)) - 1
-            )
-            d = ((lo | hi) & mask) + mb_min[m]
-            d = jnp.where(is_start, jnp.uint32(0), d)
-        with jax.named_scope("prefix_sum"):
-            c = prefix_sum(d)
-        with jax.named_scope("rebase"):
-            vals = page_first[p] + c - c[page_start[p]]
-        return jax.lax.bitcast_convert_type(vals, jnp.int32)
+        mb_base = mb_bit_start - mb_out_start * mb_width.astype(jnp.int32)
+        w = _spread(mb_out_start, mb_width, num_values)
+        base = _spread(mb_out_start, mb_base, num_values)
+        min_delta = _spread(mb_out_start, mb_min, num_values)
+        is_start = (
+            jnp.zeros(num_values, dtype=jnp.int32)
+            .at[page_start]
+            .add(1, mode="drop", indices_are_sorted=True)
+        ) != 0
     with jax.named_scope("unpack"):
-        bitpos = mb_bit_start[m] + within * w.astype(jnp.int32)
-        w0 = bitpos >> 6
-        s = (bitpos & 63).astype(jnp.uint64)
+        bitpos = base + i * w.astype(jnp.int32)
+        w0 = jnp.clip(bitpos >> (nbits.bit_length() - 1), 0, words.shape[0] - 2)
+        s = (bitpos & (nbits - 1)).astype(ut)
         lo = words[w0] >> s
-        hi = jnp.where(s == 0, jnp.uint64(0), words[w0 + 1] << ((64 - s) & 63))
-        wmask = w.astype(jnp.uint64)
-        mask = jnp.where(
-            w >= 64,
-            jnp.uint64(0xFFFFFFFFFFFFFFFF),
-            (jnp.uint64(1) << (wmask & 63)) - 1,
+        hi = jnp.where(
+            s == 0, ut(0), words[w0 + 1] << ((nbits - s) & (nbits - 1))
         )
-        d = ((lo | hi) & mask) + mb_min[m]
-        d = jnp.where(is_start, jnp.uint64(0), d)
+        mask = jnp.where(
+            w >= nbits, ~ut(0), (ut(1) << (w & (nbits - 1)).astype(ut)) - 1
+        )
+        d = ((lo | hi) & mask) + min_delta
+        d = jnp.where(is_start, ut(0), d)
     with jax.named_scope("prefix_sum"):
         c = prefix_sum(d)
     with jax.named_scope("rebase"):
-        vals = page_first[p] + c - c[page_start[p]]
-    return jax.lax.bitcast_convert_type(vals, jnp.int64)
+        at_start = c[jnp.minimum(page_start, num_values - 1)]
+        vals = c + _spread(page_start, page_first - at_start, num_values)
+    return jax.lax.bitcast_convert_type(vals, jnp.int32 if nbits == 32 else jnp.int64)
 
 
 @jax.jit

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from parquet_tpu.kernels.device_ops import (
     _segment_of,
+    _spread,
     delta_packed_decode_device,
     expand_hybrid_device,
 )
@@ -40,8 +41,10 @@ def _words(bits: np.ndarray, dtype) -> np.ndarray:
 # -- expand_hybrid_device -------------------------------------------------------
 
 
-def _hybrid_case(counts, is_rle, width, run_pad, n_pad):
-    """(buf, expected[:total]) for runs of `counts` values each."""
+def _hybrid_case(counts, is_rle, width, run_pad, n_pad, rle_bit_start=0):
+    """(buf, expected[:total]) for runs of `counts` values each. An RLE run's
+    bit_start is read by nobody; `rle_bit_start` is what the table holds there
+    (a batch near MAX_DEVICE_BATCH_BITS leaves the payload's end in it)."""
     rng = np.random.default_rng(0)
     counts = np.asarray(counts, dtype=np.int64)
     is_rle = np.asarray(is_rle, dtype=bool)
@@ -59,6 +62,7 @@ def _hybrid_case(counts, is_rle, width, run_pad, n_pad):
         a, c = int(out_start[r]), int(counts[r])
         if is_rle[r]:
             expected[a : a + c] = rle_value[r]
+            bit_start[r] = rle_bit_start
             continue
         vals = rng.integers(0, top, size=c).astype(np.uint32)
         expected[a : a + c] = vals
@@ -98,13 +102,24 @@ _HYBRID_CASES = {
     "rle-only": ([1, 2, 3, 1018], [1, 1, 1, 1], 1, 64, 1024),
     "bitpacked-only": ([8, 16, 1000], [0, 0, 0], 12, 64, 1024),
     "width-32": ([100, 200], [0, 1], 32, 64, 1024),
+    # what bringing base = bit_start - out_start * width to the values could break
+    "long-rle-run-first-negative-base": ([900, 124], [1, 0], 13, 64, 1024),
+    "rle-runs-between-bitpacked-negative-base": ([300, 8, 500, 16, 200], [1, 0, 1, 0, 1], 14, 64, 1024),
+    "rle-run-past-the-payload-end": ([8, 60_000], [0, 1], 13, 64, 65536),
+    "rle-run-past-the-payload-end-width-1": ([8, 100_000, 8], [0, 1, 0], 1, 64, 131072),
+    "width-32-rle-first": ([500, 100, 424], [1, 0, 1], 32, 64, 1024),
+    "width-32-bitpacked-only": ([1024], [0], 32, 64, 1024),
+    "rle-bit_start-near-2^31": ([24, 500, 500], [0, 1, 0], 3, 64, 1024, (1 << 31) - 8),
+    "rle-bit_start-near-2^31-width-32": ([8, 1000, 16], [0, 1, 0], 32, 64, 1024, (1 << 31) - 32),
+    "run_pad-4096-long-rle-runs": (
+        np.tile([3000, 8], 2048), np.tile([1, 0], 2048), 3, 4096, 1 << 23),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_HYBRID_CASES), ids=sorted(_HYBRID_CASES))
 def test_expand_hybrid_equals_numpy_expansion(case):
-    counts, is_rle, width, run_pad, n_pad = _HYBRID_CASES[case]
-    buf, expected = _hybrid_case(counts, is_rle, width, run_pad, n_pad)
+    counts, is_rle, width, run_pad, n_pad, *rest = _HYBRID_CASES[case]
+    buf, expected = _hybrid_case(counts, is_rle, width, run_pad, n_pad, *rest)
     got = np.asarray(expand_hybrid_device(jnp.asarray(buf), width, n_pad, run_pad))
     assert got.shape == (n_pad,) and got.dtype == np.uint32
     # positions past the table's total belong to no run: callers slice them off
@@ -114,9 +129,10 @@ def test_expand_hybrid_equals_numpy_expansion(case):
 # -- delta_packed_decode_device -------------------------------------------------
 
 
-def _delta_case(page_sizes, nbits, m_pad, p_pad, n_pad, max_width=None):
+def _delta_case(page_sizes, nbits, m_pad, p_pad, n_pad, max_width=None, min_or=0):
     """(meta32, wide, expected[:total]): pages of the given value counts,
-    miniblocks of 32 deltas, each with its own width and min."""
+    miniblocks of 32 deltas, each with its own width and min (`min_or` is
+    or-ed into every min, shifted to the top of the value's width)."""
     rng = np.random.default_rng(0)
     ud = np.uint32 if nbits == 32 else np.uint64
     max_width = nbits if max_width is None else max_width
@@ -140,6 +156,7 @@ def _delta_case(page_sizes, nbits, m_pad, p_pad, n_pad, max_width=None):
                 else rng.integers(0, 1 << 63, size=c, dtype=np.uint64) * 2 + 1
             )
             mn = rng.integers(0, np.iinfo(ud).max, dtype=ud, endpoint=True)
+            mn |= ud(min_or << (nbits - 8))
             deltas[a : a + c] = adj.astype(ud) + mn  # wraps, as a negative min does
             widths.append(w)
             bit_starts.append(bits_so_far)
@@ -187,15 +204,23 @@ _DELTA_CASES = {
     "total-below-n_pad": ([300, 301], 64, 64, 2048, None),
     "m_pad-4096": ([20_000, 20_000, 25_000], 4096, 64, 65536, 20),
     "full-miniblock-table": ([2048], 64, 64, 2048, 9),
+    # what bringing width, base, min and the page offset to the values could break
+    "page-of-one-value-last": ([500, 1], 64, 64, 1024, None),
+    "pages-of-one-value-only": ([1] * 40, 64, 64, 1024, None),
+    "mins-with-the-top-bit-set": ([300, 33, 691], 64, 64, 1024, None, 0x80),
+    "mins-all-ones-on-top": ([1024], 64, 64, 1024, 12, 0xFF),
+    "hundreds-of-pages": ([8] * 300 + [70] * 20, 512, 512, 4096, None),
+    "full-page-table": ([16] * 64, 64, 64, 1024, None),
+    "width-0-miniblocks-only": ([700, 324], 64, 64, 1024, 0),
 }
 
 
 @pytest.mark.parametrize("nbits", [32, 64])
 @pytest.mark.parametrize("case", sorted(_DELTA_CASES), ids=sorted(_DELTA_CASES))
 def test_delta_decode_equals_numpy_expansion(case, nbits):
-    page_sizes, m_pad, p_pad, n_pad, max_width = _DELTA_CASES[case]
+    page_sizes, m_pad, p_pad, n_pad, max_width, *rest = _DELTA_CASES[case]
     meta32, wide, expected = _delta_case(
-        page_sizes, nbits, m_pad, p_pad, n_pad, max_width=max_width
+        page_sizes, nbits, m_pad, p_pad, n_pad, max_width, *rest
     )
     got = np.asarray(
         delta_packed_decode_device(
@@ -207,7 +232,7 @@ def test_delta_decode_equals_numpy_expansion(case, nbits):
     np.testing.assert_array_equal(got[: len(expected)], expected.view(got.dtype))
 
 
-# -- the lookup both kernels share ---------------------------------------------
+# -- what both kernels share: the lookup, and a field brought to its segment ---
 
 _SEGMENT_CASES = {
     "one-start": ([0], 64, 1024),
@@ -228,3 +253,46 @@ def test_segment_of_equals_searchsorted(case):
     got = np.asarray(_segment_of(jnp.asarray(table), n))
     want = np.searchsorted(table, np.arange(n), side="right") - 1
     np.testing.assert_array_equal(got, want)
+
+
+def _evenly(k, n):
+    return np.unique(np.linspace(0, n - 1, k).astype(np.int64)).tolist()
+
+
+_SPREAD_CASES = {
+    # name: (starts, table length, num_values)
+    "single-segment": ([0], 64, 1024),
+    "no-start-at-zero": ([3, 700], 64, 1024),  # 0 before the first start
+    "repeated-starts-first": ([0, 0, 0, 40, 900], 64, 1024),
+    "repeated-starts-in-the-middle": ([0, 5, 5, 5, 9, 600], 64, 1024),
+    "repeated-starts-last": ([0, 17, 1023, 1023, 1023], 64, 1024),
+    "start-at-num_values-is-dropped": ([0, 512, 1024, 1024], 64, 1024),
+    "padding-at-n_pad+1-only": ([], 64, 1024),
+    "full-table-of-64": (_evenly(64, 1024), 64, 1024),
+    "table-of-4096": (_evenly(3000, 1 << 16), 4096, 1 << 16),
+    "table-of-65536-every-position-starts": (list(range(65536)), 65536, 65536),
+    "num_values-below-the-scan-block": ([0, 3, 3, 60], 64, 64),
+}
+
+
+@pytest.mark.parametrize("dtype", ["uint32", "int32", "uint64"])
+@pytest.mark.parametrize("case", sorted(_SPREAD_CASES), ids=sorted(_SPREAD_CASES))
+def test_spread_equals_the_gather_through_segment_of(case, dtype):
+    starts, pad, n = _SPREAD_CASES[case]
+    table = np.full(pad, n + 1, dtype=np.int32)
+    table[: len(starts)] = starts
+    info = np.iinfo(dtype)
+    rng = np.random.default_rng(len(starts))
+    field = rng.integers(info.min, info.max, size=pad, dtype=dtype, endpoint=True)
+    # neighbours at the dtype's two ends: every difference between them wraps
+    field[0 : min(len(starts), 8) : 2] = info.max
+    field[1 : min(len(starts), 8) : 2] = info.min
+    field[len(starts) :] = 0  # the freeze functions leave padding at zero
+    got = np.asarray(_spread(jnp.asarray(table), jnp.asarray(field), n))
+    assert got.dtype == field.dtype and got.shape == (n,)
+    segment = np.searchsorted(table, np.arange(n), side="right") - 1
+    want = np.where(segment >= 0, field[segment], 0).astype(dtype)
+    np.testing.assert_array_equal(got, want)
+    # the contract as the docstring words it
+    through = np.asarray(_segment_of(jnp.asarray(table), n))
+    np.testing.assert_array_equal(got[through >= 0], field[through[through >= 0]])

@@ -5,6 +5,8 @@ widths for bit-pack, roundtrips with random data for every codec, plus scalar
 reference decoders as independent oracles.
 """
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -413,6 +415,27 @@ class TestCompress:
     def test_unregistered_codec_rejected(self):
         with pytest.raises(compress.CompressionError):
             compress.compress_block(b"x", CompressionCodec.LZO)
+
+    def test_zstd_pages_decompress_on_pool_threads_at_once(self):
+        """A zstandard context is not thread-safe and its calls release the
+        GIL; chunks prepare on pool threads, so every thread has its own
+        (one shared context gave 'Data corruption detected' on good pages)."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        pages = [bytes(rng.integers(0, 4, 80_000).astype(np.uint8)) for _ in range(8)]
+        comp = [compress.compress_block(p, CompressionCodec.ZSTD) for p in pages]
+        codec = compress._get(CompressionCodec.ZSTD)
+        together = threading.Barrier(8)
+
+        def work(k):
+            together.wait(30)
+            for _ in range(50):
+                assert compress.decompress_block(comp[k], CompressionCodec.ZSTD, len(pages[k])) == pages[k]
+            return codec._contexts()
+
+        with ThreadPoolExecutor(8) as pool:
+            contexts = list(pool.map(work, range(8)))
+        assert len({id(c) for pair in contexts for c in pair}) == 16
 
 
 class TestNativeParity:

@@ -725,6 +725,28 @@ class TestKernelScopes:
         assert not re.search(r"\bwhile\(", hlo)
         assert not [n for n in op_names if "searchsorted" in n]
 
+    @pytest.mark.parametrize("k", range(3), ids=_KERNEL_IDS[:3])
+    def test_only_the_packed_words_are_gathered_at_full_length(self, k):
+        """A run's, miniblock's or page's fields reach its values by a scatter
+        of differences and a scan (_spread): of the gathers in the compiled
+        kernel, the only ones with an index per value are the two reads of
+        the packed words under unpack. A table[r] that comes back is a 9 ms
+        pass per 2^20 values on a v5e (PERF.md section 6)."""
+        import math
+        import re
+
+        num_values = 4096  # _kernel_cases: also the table length here
+        hlo = _kernel_cases(pad=4096)[k][2]()
+        full = [
+            op_name
+            for shape, op_name in re.findall(
+                r"= \w+\[([\d,]*)\]\S* gather\(.*op_name=\"([^\"]*)\"", hlo
+            )
+            if math.prod(int(x) for x in shape.split(",")) >= num_values
+        ]
+        assert len(full) == 2, full
+        assert all("/unpack/" in f"{n}/" for n in full), full
+
 
 class TestDispatchAccounting:
     def test_upload_bytes_and_nested_seconds(
