@@ -1742,7 +1742,7 @@ ssize_t ptq_chunk_prepare(
       // Inline prescan: clamp counts so the page contributes exactly
       // non_null outputs; copy bit-packed payloads (only) into packed_out so
       // batch bit offsets are global (mirrors prescan_hybrid's compaction +
-      // _HybridBatch.add_page's clamping in one pass).
+      // the staged walk's clamping, pipeline.py _hybrid_tables_of, in one pass).
       const size_t vbytes = (width + 7) / 8;
       size_t spos = 0;
       int64_t produced = 0;

@@ -88,13 +88,16 @@ jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 import jax.numpy as jnp
 import numpy as np
 from functools import partial
+from typing import NamedTuple
 
 __all__ = [
     "MAX_DEVICE_BATCH_BITS",
     "bytes_to_words32",
     "bytes_to_words64",
     "expand_hybrid_device",
+    "pack_hybrid_upload",
     "delta_packed_decode_device",
+    "pack_delta_upload",
     "dict_gather_device",
     "double_narrow_device",
     "list_layout_device",
@@ -227,6 +230,15 @@ def _spread(starts: jnp.ndarray, field: jnp.ndarray, num_values: int) -> jnp.nda
     )
 
 
+def _bucket(n: int, floor: int = 1024) -> int:
+    """Next power-of-two bucket >= n (>= floor): every padded length of an
+    upload, so that XLA compiles each kernel a bounded number of times."""
+    b = floor
+    while b < n:
+        b <<= 1
+    return b
+
+
 def bytes_to_words32(data: bytes) -> np.ndarray:
     """Pad bytes to a uint32 LE word array (+1 guard word for the hi gather)."""
     pad = (-len(data)) % 4
@@ -250,14 +262,9 @@ def expand_hybrid_device(
 ) -> jnp.ndarray:
     """Expand a prescanned hybrid RLE/bit-packed stream on device.
 
-    buf packs the four per-run vectors AND the packed payload words into ONE
-    upload (the host<->device link pays a fixed per-transfer latency that
-    dwarfs these tiny tables). Layout, with run_pad static:
-      buf[0*run_pad:1*run_pad]  is_rle      0/1
-      buf[1*run_pad:2*run_pad]  out_start   exclusive cumsum of counts (int32)
-      buf[2*run_pad:3*run_pad]  rle_value   broadcast value of RLE runs
-      buf[3*run_pad:4*run_pad]  bit_start   bit offset of payload (int32)
-      buf[4*run_pad:]           packed payload words (+1 guard word)
+    buf is pack_hybrid_upload's (below): the four per-run vectors and the
+    packed payload words in ONE upload; that function and the five slices
+    here are the only statements of its layout.
 
     No position looks its run up. A run hands its positions two words by
     _spread (a scatter of differences at out_start and one prefix sum each,
@@ -308,6 +315,52 @@ def expand_hybrid_device(
         return jnp.where(is_rle, payload, bp_vals)
 
 
+class FrozenHybrid(NamedTuple):
+    """One upload of expand_hybrid_device (built in prepare, dispatched by
+    transfer) with the kernel's static arguments; `total` values are real."""
+
+    buf: np.ndarray
+    width: int
+    n_pad: int
+    run_pad: int
+    total: int
+
+
+def pack_hybrid_upload(
+    is_rle, counts, rle_values, bit_starts, packed, width: int
+) -> FrozenHybrid:
+    """The upload expand_hybrid_device reads, from one row per run: `counts`
+    values each (already clamped: the runs produce exactly the values wanted,
+    a zero-length run is fine), the value an RLE run repeats, the bit offset
+    into `packed` (uint8 array or bytes, LSB-first groups at `width` bits) at
+    which a bit-packed run's payload starts. Nobody reads a bit-packed run's
+    value or an RLE run's bit offset. ONE uint32 buffer, because the
+    host<->device link pays a fixed latency per transfer that dwarfs these
+    tables; with run_pad = _bucket(runs, 64), n_pad = _bucket(values):
+      buf[0*run_pad:1*run_pad]  is_rle      0/1
+      buf[1*run_pad:2*run_pad]  out_start   exclusive cumsum of counts (int32);
+                                            padding entries hold n_pad + 1
+      buf[2*run_pad:3*run_pad]  rle_value
+      buf[3*run_pad:4*run_pad]  bit_start   (int32)
+      buf[4*run_pad:]           payload words + 1 guard word, padded to
+                                _bucket(words, 1024)"""
+    k = len(counts)
+    total = int(np.sum(counts))
+    n_pad = _bucket(max(total, 1))
+    run_pad = _bucket(k, 64)
+    words = bytes_to_words32(bytes(packed))
+    buf = np.zeros(4 * run_pad + _bucket(len(words), 1024), dtype=np.uint32)
+    buf[run_pad : 2 * run_pad] = np.int32(n_pad + 1).view(np.uint32)
+    out_start = np.zeros(k, dtype=np.int64)
+    np.cumsum(counts[:-1], out=out_start[1:])
+    # every field modulo 2^32: an int32 (a bit offset may be negative) travels
+    # as its bit pattern
+    for row, field in enumerate((is_rle, out_start, rle_values, bit_starts)):
+        buf[row * run_pad : row * run_pad + k] = np.asarray(field).astype(np.uint32)
+    buf[4 * run_pad : 4 * run_pad + len(words)] = words
+    return FrozenHybrid(buf, width, n_pad, run_pad, total)
+
+
 @partial(jax.jit, static_argnames=("nbits", "num_values", "m_pad", "p_pad"))
 @jax.named_scope("pqt.delta_decode")
 def delta_packed_decode_device(
@@ -348,13 +401,8 @@ def delta_packed_decode_device(
     smaller than the decoded column (the reason device decode beats
     host-decode-plus-upload on the host<->device link).
 
-    Everything travels in at most TWO packed uploads — one when nbits=32 —
-    because per-transfer latency on the link dwarfs their size:
-      meta32  [widths(m) | bit_starts(m) | out_starts(m) | page_start(p)]
-              (int32 fields as bit patterns); for nbits=32 the wire words
-              are appended after these four tables and `wide` is empty
-      wide    [mins(m) | page_first(p)] in the value dtype's width; for
-              nbits=64 the wire words (uint64) are appended after
+    meta32 and wide are pack_delta_upload's (below): that function and the
+    slices here are the only statements of their layout.
     """
     mb_width = meta32[:m_pad]
     mb_bit_start = jax.lax.bitcast_convert_type(meta32[m_pad : 2 * m_pad], jnp.int32)
@@ -403,6 +451,63 @@ def delta_packed_decode_device(
         at_start = c[jnp.minimum(page_start, num_values - 1)]
         vals = c + _spread(page_start, page_first - at_start, num_values)
     return jax.lax.bitcast_convert_type(vals, jnp.int32 if nbits == 32 else jnp.int64)
+
+
+class FrozenDelta(NamedTuple):
+    """One upload of delta_packed_decode_device (built in prepare, dispatched
+    by transfer) with the kernel's static arguments; `total` values are real."""
+
+    meta32: np.ndarray
+    wide: np.ndarray
+    nbits: int
+    n_pad: int
+    m_pad: int
+    p_pad: int
+    total: int
+
+
+def pack_delta_upload(
+    widths, bit_starts, out_starts, mins, page_starts, page_firsts, stream,
+    nbits: int, total: int,
+) -> FrozenDelta:
+    """The uploads delta_packed_decode_device reads. One row per miniblock:
+    its bit width, the bit offset of its payload in `stream` (the pages' wire
+    bytes end to end; uint8 array or bytes), the output position of its first
+    delta (one past its page's first value), its block's min_delta; one row
+    per page, none of them empty: the output position of its first value and
+    that value; `total` values in all. At most TWO uploads — one when nbits
+    is 32 — because per-transfer latency on the link dwarfs their size; with
+    m_pad = _bucket(miniblocks, 64), p_pad = _bucket(pages, 64), n_pad =
+    _bucket(total):
+      meta32  [widths(m_pad) | bit_starts(m_pad) | out_starts(m_pad) |
+              page_start(p_pad)], int32 fields as bit patterns, padding
+              entries of the two start tables n_pad + 1
+      wide    [mins(m_pad) | page_first(p_pad) | wire words + 1 guard word,
+              padded to _bucket(words, 1024)] in the value's width
+    For nbits 32, `wide` is the tail of meta32 (followed by m_pad + p_pad
+    words of slack that nothing reads: the compiled shapes are keyed by the
+    length) and the second array is empty."""
+    ud = np.uint32 if nbits == 32 else np.uint64
+    m, p = len(widths), len(page_starts)
+    n_pad = _bucket(total)
+    m_pad = _bucket(max(m, 1), 64)
+    p_pad = _bucket(p, 64)
+    words = (bytes_to_words32 if nbits == 32 else bytes_to_words64)(bytes(stream))
+    head = 3 * m_pad + p_pad
+    tail = m_pad + p_pad + _bucket(len(words), 1024)
+    meta32 = np.zeros(head + (tail + m_pad + p_pad if nbits == 32 else 0), dtype=np.uint32)
+    meta32[2 * m_pad : head] = np.int32(n_pad + 1).view(np.uint32)
+    for row, field in enumerate((widths, bit_starts, out_starts)):
+        meta32[row * m_pad : row * m_pad + m] = np.asarray(field).astype(np.uint32)
+    meta32[3 * m_pad : 3 * m_pad + p] = np.asarray(page_starts).astype(np.uint32)
+    wide = meta32[head:] if nbits == 32 else np.zeros(tail, dtype=np.uint64)
+    wide[:m] = np.asarray(mins).astype(ud)
+    wide[m_pad : m_pad + p] = np.asarray(page_firsts).astype(ud)
+    wide[m_pad + p_pad : m_pad + p_pad + len(words)] = words
+    return FrozenDelta(
+        meta32, wide if nbits == 64 else np.zeros(0, dtype=np.uint32),
+        nbits, n_pad, m_pad, p_pad, total,
+    )
 
 
 @jax.jit

@@ -40,7 +40,6 @@ from __future__ import annotations
 import functools
 import threading
 from dataclasses import dataclass
-from typing import NamedTuple
 
 import numpy as np
 
@@ -62,14 +61,17 @@ from ..utils import metrics as _metrics
 from ..utils import trace as _trace
 from .device_ops import (
     MAX_DEVICE_BATCH_BITS,
-    bytes_to_words32,
-    bytes_to_words64,
+    FrozenDelta,
+    FrozenHybrid,
+    _bucket,
     delta_block_encode_device,
     delta_packed_decode_device,
     dict_gather_device,
     dict_indices_device,
     double_narrow_device,
     expand_hybrid_device,
+    pack_delta_upload,
+    pack_hybrid_upload,
     plain_bytearray_encode_device,
     rle_hybrid_encode_device,
 )
@@ -252,14 +254,6 @@ def check_double_delivery(names, placement=None) -> None:
         )
 
 
-def _bucket(n: int, floor: int = 1024) -> int:
-    """Next power-of-two bucket >= n (>= floor)."""
-    b = floor
-    while b < n:
-        b <<= 1
-    return b
-
-
 def _pad_device(arr):
     """Zero-pad a device array to its power-of-two bucket so kernels taking
     it compile a bounded number of times (one device op; no host copy)."""
@@ -334,58 +328,6 @@ def _index_width(page_width: int, n_dict: int) -> int:
     return min(32, max(page_width, (n - 1).bit_length() if n > 0 else 0))
 
 
-def _repack_groups(src: np.ndarray, n_groups: int, w_from: int, w_to: int) -> np.ndarray:
-    """`n_groups` bit-packed groups of 8 values (uint8 array, w_from bytes a
-    group, w_from >= 1) widened to w_to bits a value: one page's share of
-    _repack_pages_to_width, for the staged walk's per-page batches. Native
-    where the library has it; ops/bitpack.py is the reference and fallback."""
-    from ..ops.bitpack import pack_bits, unpack_bits
-    from ..utils.native import get_native
-
-    lib = get_native()
-    if lib is not None and lib.has_repack_pages:
-        one = np.array([0, len(src), w_from])
-        return lib.repack_pages(
-            np.ascontiguousarray(src), one[:1], one[1:2], one[2:].astype(np.int32),
-            w_to, n_groups * w_to,
-        )[0]
-    return np.frombuffer(
-        pack_bits(unpack_bits(src, n_groups * 8, w_from), w_to), dtype=np.uint8
-    )
-
-
-def _count_repack(pages: int, seconds: float, nbytes: int) -> None:
-    """Account index pages widened at freeze time: the counter, and the
-    prepare.repack_width sub-clock (seconds + bytes written, back-dated like
-    the native walk's prepare.* clocks so it nests in chunk.prepare)."""
-    if pages:
-        _metrics.event("hybrid_pages_repacked", pages)
-        _trace.count("hybrid_pages_repacked", pages)
-        _trace.add_seconds("prepare.repack_width", seconds, nbytes)
-
-
-class _FrozenHybrid(NamedTuple):
-    """Upload-ready hybrid batch (built in prepare; dispatched by transfer)."""
-
-    buf: np.ndarray
-    width: int
-    n_pad: int
-    run_pad: int
-    total: int
-
-
-class _FrozenDelta(NamedTuple):
-    """Upload-ready delta batch (built in prepare; dispatched by transfer)."""
-
-    meta32: np.ndarray
-    wide: np.ndarray
-    nbits: int
-    n_pad: int
-    m_pad: int
-    p_pad: int
-    total: int
-
-
 @dataclass
 class TpuDecodeStats:
     pages: int = 0
@@ -411,213 +353,30 @@ _NUMERIC_DTYPE = {
 }
 
 
-# -- per-chunk batch assembly --------------------------------------------------
+# -- dispatch of a frozen upload ------------------------------------------------
+#
+# What _ChunkPlan.dispatch_device does with each frozen record. What is in
+# the buffers is device_ops.py's business (pack_hybrid_upload,
+# pack_delta_upload): nothing in this module indexes into them.
 
 
-class _HybridBatch:
-    """Concatenated, clamped run tables of dict-encoded pages of a chunk.
+def _dispatch_hybrid(frozen: FrozenHybrid) -> jnp.ndarray:
+    with _trace.stage("dispatch.upload", frozen.buf.nbytes):
+        buf = jnp.asarray(frozen.buf)
+    with _trace.stage("dispatch.launch"):
+        dev = expand_hybrid_device(buf, frozen.width, frozen.n_pad, frozen.run_pad)
+        return dev[: frozen.total]
 
-    Run counts are clamped so each page contributes exactly its real value
-    count to the output index space (the final bit-packed group of a page may
-    encode up to 7 padding values; clamping the last run's count drops them
-    without touching bit offsets). The device expansion therefore yields the
-    concatenation of all pages' values directly.
-    """
 
-    def __init__(self, width: int):
-        # the chunk's ONE shipping width (_index_width): pages written
-        # narrower are re-packed to it as they are added
-        self.width = width
-        self.is_rle: list[np.ndarray] = []
-        self.counts: list[np.ndarray] = []
-        self.values: list[np.ndarray] = []
-        self.bit_starts: list[np.ndarray] = []
-        self.packed: list[bytes] = []
-        self.packed_bits = 0
-        self.out_count = 0
-        self.repacked = 0  # pages widened, bytes written
-        self.repacked_bytes = 0
-
-    def _bits(self, table, width: int) -> int:
-        """The page's payload bits once it is at this batch's width."""
-        if width == 0:
-            return 0
-        if width == self.width:
-            return len(table.packed) * 8
-        return len(table.packed) // width * self.width * 8
-
-    def fits(self, table, width: int) -> bool:
-        return self.packed_bits + self._bits(table, width) <= _BATCH_BITS_CAP
-
-    def add_page(self, table, take: int, width: int | None = None) -> None:
-        counts = table.counts.astype(np.int64)
-        cum = np.cumsum(counts)
-        if take > (int(cum[-1]) if len(cum) else 0):
-            raise PageError("page: hybrid run table shorter than value count")
-        k = int(np.searchsorted(cum, take, side="left"))
-        counts = counts[: k + 1].copy()
-        counts[k] = take - (int(cum[k - 1]) if k else 0)
-        is_rle, bp_offsets, packed = table.is_rle[: k + 1], table.bp_offsets[: k + 1], table.packed
-        if width is not None and width != self.width:
-            self.repacked += 1
-            if width == 0:  # nothing to widen: every value is index 0
-                is_rle, packed = np.ones(k + 1, dtype=is_rle.dtype), b""
-            else:
-                packed = _repack_groups(
-                    np.frombuffer(packed, dtype=np.uint8),
-                    len(packed) // width, width, self.width,
-                ).tobytes()
-                bp_offsets = bp_offsets // width * self.width
-                self.repacked_bytes += len(packed)
-        self.is_rle.append(is_rle)
-        self.counts.append(counts)
-        self.values.append(table.rle_values[: k + 1])
-        self.bit_starts.append(bp_offsets * 8 + self.packed_bits)
-        self.packed.append(packed)
-        self.packed_bits += len(packed) * 8
-        self.out_count += take
-
-    def freeze(self) -> tuple:
-        """Build the packed upload buffer (host-only; runs in the prepare
-        phase so the dispatch thread stays pure transfer/launch I/O).
-
-        ONE packed upload: [is_rle | out_start | rle_value | bit_start |
-        words] — see expand_hybrid_device layout."""
-        counts = np.concatenate(self.counts)
-        out_start = np.zeros(len(counts), dtype=np.int64)
-        np.cumsum(counts[:-1], out=out_start[1:])
-        total = int(counts.sum())
-        assert total == self.out_count
-        n_pad = _bucket(max(total, 1))
-        run_pad = _bucket(len(counts), 64)
-        packed = b"".join(self.packed)
-        words = bytes_to_words32(packed)
-        w_pad = _bucket(len(words), 1024)
-        buf = np.zeros(4 * run_pad + w_pad, dtype=np.uint32)
-        buf[run_pad : 2 * run_pad] = np.int32(n_pad + 1).view(np.uint32)  # sentinel
-        k = len(counts)
-        buf[:k] = np.concatenate(self.is_rle)
-        buf[run_pad : run_pad + k] = out_start.astype(np.int32).view(np.uint32)
-        buf[2 * run_pad : 2 * run_pad + k] = np.concatenate(self.values).astype(
-            np.uint32
+def _dispatch_delta(frozen: FrozenDelta) -> jnp.ndarray:
+    with _trace.stage("dispatch.upload", frozen.meta32.nbytes + frozen.wide.nbytes):
+        meta32 = jnp.asarray(frozen.meta32)
+        wide = jnp.asarray(frozen.wide)
+    with _trace.stage("dispatch.launch"):
+        dev = delta_packed_decode_device(
+            meta32, wide, frozen.nbits, frozen.n_pad, frozen.m_pad, frozen.p_pad
         )
-        buf[3 * run_pad : 3 * run_pad + k] = (
-            np.concatenate(self.bit_starts).astype(np.int32).view(np.uint32)
-        )
-        buf[4 * run_pad : 4 * run_pad + len(words)] = words
-        return _FrozenHybrid(buf, self.width, n_pad, run_pad, total)
-
-    @staticmethod
-    def dispatch_frozen(frozen: "_FrozenHybrid") -> jnp.ndarray:
-        with _trace.stage("dispatch.upload", frozen.buf.nbytes):
-            buf = jnp.asarray(frozen.buf)
-        with _trace.stage("dispatch.launch"):
-            dev = expand_hybrid_device(
-                buf, frozen.width, frozen.n_pad, frozen.run_pad
-            )
-            return dev[: frozen.total]
-
-
-class _DeltaBatch:
-    """Concatenated *packed* delta streams of a chunk's pages.
-
-    Only wire bytes + tiny per-miniblock/per-page tables go to the device;
-    kernels/device_ops.py delta_packed_decode_device unpacks + prefix-sums
-    everything in one program, segmented per page."""
-
-    def __init__(self, nbits: int):
-        self.nbits = nbits
-        self.streams: list[bytes] = []
-        self.stream_bytes = 0
-        self.widths: list[np.ndarray] = []
-        self.byte_starts: list[np.ndarray] = []
-        self.out_starts: list[np.ndarray] = []
-        self.mins: list[np.ndarray] = []
-        self.page_starts: list[int] = []
-        self.page_firsts: list[int] = []
-        self.out_count = 0
-
-    def fits(self, table) -> bool:
-        return (self.stream_bytes + table.consumed) * 8 <= _BATCH_BITS_CAP
-
-    def add_page(self, table, stream: bytes) -> None:
-        if table.total == 0:
-            return  # no values: nothing to contribute
-        b = self.out_count
-        self.widths.append(table.widths)
-        self.byte_starts.append(table.byte_starts + self.stream_bytes)
-        self.out_starts.append(table.out_starts + (b + 1))
-        self.mins.append(table.mins)
-        self.page_starts.append(b)
-        self.page_firsts.append(table.first_value)
-        self.streams.append(stream[: table.consumed])
-        self.stream_bytes += table.consumed
-        self.out_count += table.total
-
-    def freeze(self) -> tuple | None:
-        """Build the packed uploads (host-only; prepare phase — see
-        _HybridBatch.freeze)."""
-        if not self.page_starts:
-            return None
-        nbits = self.nbits
-        ud = np.uint32 if nbits == 32 else np.uint64
-        total = self.out_count
-        n_pad = _bucket(total)
-        m = sum(len(w) for w in self.widths)
-        m_pad = _bucket(max(m, 1), 64)
-        p = len(self.page_starts)
-        p_pad = _bucket(p, 64)
-        sentinel = np.int32(n_pad + 1).view(np.uint32)
-        stream = b"".join(self.streams)
-        words = bytes_to_words32(stream) if nbits == 32 else bytes_to_words64(stream)
-        w_pad = _bucket(len(words), 1024)
-        # Packed uploads — see delta_packed_decode_device field layout. The
-        # wire words ride in the same upload as the tables: one transfer for
-        # 32-bit values, two for 64-bit (tables at 32, words at 64).
-        tail32 = (2 * m_pad + 2 * p_pad + w_pad) if nbits == 32 else 0
-        meta32 = np.zeros(3 * m_pad + p_pad + tail32, dtype=np.uint32)
-        meta32[2 * m_pad : 3 * m_pad] = sentinel  # out_starts padding
-        meta32[3 * m_pad : 3 * m_pad + p_pad] = sentinel  # page_start padding
-        if m:
-            meta32[:m] = np.concatenate(self.widths)
-            meta32[m_pad : m_pad + m] = (
-                (np.concatenate(self.byte_starts) * 8).astype(np.int32).view(np.uint32)
-            )
-            meta32[2 * m_pad : 2 * m_pad + m] = (
-                np.concatenate(self.out_starts).astype(np.int32).view(np.uint32)
-            )
-        meta32[3 * m_pad : 3 * m_pad + p] = (
-            np.asarray(self.page_starts, dtype=np.int32).view(np.uint32)
-        )
-        if nbits == 32:
-            base = 3 * m_pad + p_pad
-            if m:
-                meta32[base : base + m] = np.concatenate(self.mins).astype(ud)
-            meta32[base + m_pad : base + m_pad + p] = np.array(
-                self.page_firsts, dtype=ud
-            )
-            meta32[base + m_pad + p_pad : base + m_pad + p_pad + len(words)] = words
-            wide = np.zeros(0, dtype=np.uint32)
-        else:
-            wide = np.zeros(m_pad + p_pad + w_pad, dtype=np.uint64)
-            if m:
-                wide[:m] = np.concatenate(self.mins).astype(ud)
-            wide[m_pad : m_pad + p] = np.array(self.page_firsts, dtype=ud)
-            wide[m_pad + p_pad : m_pad + p_pad + len(words)] = words
-        return _FrozenDelta(meta32, wide, nbits, n_pad, m_pad, p_pad, total)
-
-    @staticmethod
-    def dispatch_frozen(frozen: "_FrozenDelta") -> jnp.ndarray:
-        with _trace.stage(
-            "dispatch.upload", frozen.meta32.nbytes + frozen.wide.nbytes
-        ):
-            meta32 = jnp.asarray(frozen.meta32)
-            wide = jnp.asarray(frozen.wide)
-        with _trace.stage("dispatch.launch"):
-            dev = delta_packed_decode_device(
-                meta32, wide, frozen.nbits, frozen.n_pad, frozen.m_pad, frozen.p_pad
-            )
-            return dev[: frozen.total]
+        return dev[: frozen.total]
 
 
 # -- the chunk plan ------------------------------------------------------------
@@ -713,13 +472,10 @@ class _ChunkPlan:
         self.dev_hybrid: list[jnp.ndarray] = []  # per batch, page order
         self.dev_delta: list[jnp.ndarray] = []  # per batch, page order
         self.stats: TpuDecodeStats | None = None
-        # host-side batches awaiting device dispatch (set by prepare phase)
-        self.hybrid_batches: list[_HybridBatch] = []
-        self.delta_batches: list[_DeltaBatch] = []
         # frozen upload buffers (built at the END of prepare, host-only, so
         # the dispatch thread does nothing but transfers + kernel launches)
-        self.frozen_hybrid: list[tuple] = []
-        self.frozen_delta: list[tuple] = []
+        self.frozen_hybrid: list[FrozenHybrid] = []
+        self.frozen_delta: list[FrozenDelta] = []
         self.plain_host = None
         self.dev_plain: jnp.ndarray | None = None
         # BYTE_STREAM_SPLIT pages shipped raw: [( (4, n_pad) u8 host staging,
@@ -769,12 +525,12 @@ class _ChunkPlan:
         self.bss_host = []
         stats = self.stats
         for frozen in self.frozen_hybrid:
-            self.dev_hybrid.append(_HybridBatch.dispatch_frozen(frozen))
+            self.dev_hybrid.append(_dispatch_hybrid(frozen))
             if stats is not None:
                 stats.device_values += frozen.total
                 stats.device_batches += 1
         for frozen in self.frozen_delta:
-            self.dev_delta.append(_DeltaBatch.dispatch_frozen(frozen))
+            self.dev_delta.append(_dispatch_delta(frozen))
             if stats is not None:
                 stats.device_values += frozen.total
                 stats.device_batches += 1
@@ -1206,6 +962,7 @@ _PC_VOFF, _PC_VLEN, _PC_LVLBASE = 5, 6, 7
 _PC_RUNS, _PC_RUNE, _PC_PACKS, _PC_PACKE = 8, 9, 10, 11
 _PC_MINIS, _PC_MINIE, _PC_DSTART, _PC_DCONS = 12, 13, 14, 15
 _PC_EXTRA, _PC_DFIRST = 16, 17
+_PC_COLS = 18
 
 
 def _native_prepare(f, chunk, column, validate_crc, alloc, stats, doubles=None):
@@ -1223,15 +980,10 @@ def _native_prepare(f, chunk, column, validate_crc, alloc, stats, doubles=None):
     per-page Python walk — the error-semantics reference — which raises the
     exact typed error if the chunk is genuinely corrupt (the fused -> staged
     -> raise fallback ladder; prepare_fallback_recovered counts chunks the
-    staged walk salvaged after a native abort). PQT_FUSED_PREPARE=0 forces
-    the staged walk (the differential-test control). Under an active
+    staged walk salvaged after a native abort). Under an active
     decode_trace the outcome is pinned by the prepare_fused_engaged /
     prepare_fused_declined counters and the walk's internal stage split
     lands in prepare.* stages."""
-    import os as _os
-
-    if _os.environ.get("PQT_FUSED_PREPARE", "1") == "0":
-        return None, None  # forced staged path: not a decline, no counter
     plan, fault = _native_prepare_impl(
         f, chunk, column, validate_crc, alloc, stats, doubles
     )
@@ -1699,9 +1451,14 @@ def _repack_pages_to_width(pages: list, res: dict, width: int):
         packed, seconds = lib.repack_pages(
             packed_all, ps, pe, w.astype(np.int32), width, int(new_pe[-1])
         )
-    else:
+    else:  # ops/bitpack.py: the reference
+        from ..ops.bitpack import pack_bits, unpack_bits
+
         packed = np.concatenate([
-            packed_all[a:b] if wp == width else _repack_groups(packed_all[a:b], (b - a) // wp, wp, width)
+            packed_all[a:b] if wp == width else np.frombuffer(
+                pack_bits(unpack_bits(packed_all[a:b], (b - a) // wp * 8, wp), width),
+                dtype=np.uint8,
+            )
             for a, b, wp in zip(ps.tolist(), pe.tolist(), w.tolist()) if wp
         ] or [packed_all[:0]])
         seconds = _time.perf_counter() - t0
@@ -1711,25 +1468,32 @@ def _repack_pages_to_width(pages: list, res: dict, width: int):
     is_rle = res["h_is_rle"].copy()
     byteoff = res["h_byteoff"].copy()
     rel = byteoff[lo:hi] - ps[page_of]
-    wide = np.where(narrow[page_of], rel // np.maximum(w[page_of], 1) * width, rel)
-    byteoff[lo:hi] = wide + new_ps[page_of]
+    widened = np.where(narrow[page_of], rel // np.maximum(w[page_of], 1) * width, rel)
+    byteoff[lo:hi] = widened + new_ps[page_of]
     is_rle[lo:hi] |= (w[page_of] == 0).astype(is_rle.dtype)
     out = []
     for P, a, b in zip(pages, new_ps.tolist(), new_pe.tolist()):
         Q = list(P)
         Q[_PC_PACKS], Q[_PC_PACKE], Q[_PC_EXTRA] = a, b, width
         out.append(Q)
-    _count_repack(int(narrow.sum()), seconds, int(size[narrow].sum()))
+    # the counter, and the prepare.repack_width sub-clock (seconds + bytes
+    # written, back-dated like the native walk's prepare.* clocks so that it
+    # nests in chunk.prepare)
+    _metrics.event("hybrid_pages_repacked", int(narrow.sum()))
+    _trace.count("hybrid_pages_repacked", int(narrow.sum()))
+    _trace.add_seconds("prepare.repack_width", seconds, int(size[narrow].sum()))
     return out, is_rle, byteoff, packed
 
 
 def _freeze_hybrid_from_tables(data_pages, res, n_dict: int = 0) -> list | None:
-    """Vectorized _HybridBatch.freeze over the native walk's global run
-    tables. A chunk ships at ONE index width (_index_width): pages written
-    narrower are re-packed to it first, so the compiled shapes do not follow
-    where the dictionary crossed a power of two. Pages group sequentially
-    under the bit cap (same policy as _commit_routes); returns None when a
-    single page exceeds the cap (demote-all, matching the Python walk)."""
+    """THE freeze of a dictionary chunk's index pages, from the whole-chunk
+    run tables of the native walk (the staged walk lays its prescans out the
+    same way: _hybrid_tables_of). A chunk ships at ONE index width
+    (_index_width): pages written narrower are re-packed to it first, so the
+    compiled shapes do not follow where the dictionary crossed a power of
+    two. Pages group sequentially under the bit cap, one upload a group
+    (device_ops.pack_hybrid_upload); returns None when a single page exceeds
+    the cap (the caller demotes the chunk)."""
     cap = _BATCH_BITS_CAP
     pages = [P for P in data_pages if P[_PC_ROUTE] == 1]
     h_is_rle = res["h_is_rle"]
@@ -1754,30 +1518,14 @@ def _freeze_hybrid_from_tables(data_pages, res, n_dict: int = 0) -> list | None:
             cur[1] = P[_PC_RUNE]
             cur[3] = P[_PC_PACKE]
             cur[4] += bits
-    frozen = []
-    h_counts = res["h_counts"]
-    h_values = res["h_values"]
-    for rs, re, ps, pe, _bits in groups:
-        counts = h_counts[rs:re]
-        k = len(counts)
-        total = int(counts.sum())
-        n_pad = _bucket(max(total, 1))
-        run_pad = _bucket(k, 64)
-        words = bytes_to_words32(bytes(packed_all[ps:pe]))
-        w_pad = _bucket(len(words), 1024)
-        buf = np.zeros(4 * run_pad + w_pad, dtype=np.uint32)
-        buf[run_pad : 2 * run_pad] = np.int32(n_pad + 1).view(np.uint32)  # sentinel
-        buf[:k] = h_is_rle[rs:re]
-        out_start = np.zeros(k, dtype=np.int64)
-        np.cumsum(counts[:-1], out=out_start[1:])
-        buf[run_pad : run_pad + k] = out_start.astype(np.int32).view(np.uint32)
-        buf[2 * run_pad : 2 * run_pad + k] = h_values[rs:re].astype(np.uint32)
-        buf[3 * run_pad : 3 * run_pad + k] = (
-            ((h_byteoff[rs:re] - ps) * 8).astype(np.int32).view(np.uint32)
+    # payload bits are addressed from the group's first packed byte
+    return [
+        pack_hybrid_upload(
+            h_is_rle[rs:re], res["h_counts"][rs:re], res["h_values"][rs:re],
+            (h_byteoff[rs:re] - ps) * 8, packed_all[ps:pe], width,
         )
-        buf[4 * run_pad : 4 * run_pad + len(words)] = words
-        frozen.append(_FrozenHybrid(buf, width, n_pad, run_pad, total))
-    return frozen
+        for rs, re, ps, pe, _bits in groups
+    ]
 
 
 def _repack_plain_as_delta(plan: _ChunkPlan, whole: np.ndarray, nbits: int) -> bool:
@@ -1837,38 +1585,30 @@ def _repack_plain_as_delta(plan: _ChunkPlan, whole: np.ndarray, nbits: int) -> b
     if int(total) != n:
         bump("repack_declined", raw_bytes)
         return False
-    first_u = int(first) & ((1 << 64) - 1)
-    first_i64 = first_u - (1 << 64) if first_u >= 1 << 63 else first_u
-    P2 = [0] * 18
-    P2[_PC_ROUTE] = 2
-    P2[_PC_EXTRA] = n
-    P2[_PC_DCONS] = int(consumed)
-    P2[_PC_MINIS] = 0
-    P2[_PC_MINIE] = len(widths)
-    P2[_PC_DSTART] = 0
-    P2[_PC_DFIRST] = first_i64
-    res2 = {
-        "d_widths": np.asarray(widths, dtype=np.uint32),
-        "d_bytestart": np.asarray(byte_starts, dtype=np.int64),
-        "d_outstart": np.asarray(out_starts, dtype=np.int32),
-        "d_mins": np.asarray(mins, dtype=np.uint64),
-        "delta_stream": np.frombuffer(stream, dtype=np.uint8),
-    }
-    plan.frozen_delta = _freeze_delta_from_tables([P2], res2, nbits)
-    if plan.frozen_delta:
-        bump("repack_engaged", len(stream))
-    return bool(plan.frozen_delta)
+    plan.frozen_delta = [
+        pack_delta_upload(
+            widths, np.asarray(byte_starts, dtype=np.int64) * 8,
+            np.asarray(out_starts, dtype=np.int64) + 1, mins,
+            [0], [int(first) & ((1 << 64) - 1)],
+            np.frombuffer(stream, dtype=np.uint8)[: int(consumed)], nbits, n,
+        )
+    ]
+    bump("repack_engaged", len(stream))
+    return True
 
 
 def _freeze_delta_from_tables(data_pages, res, nbits: int) -> list:
-    """Vectorized _DeltaBatch.freeze over the native walk's global miniblock
-    tables (pages group sequentially under the bit cap)."""
+    """THE freeze of a DELTA_BINARY_PACKED chunk, from the whole-chunk
+    miniblock tables of the native walk (the staged walk lays its prescans
+    out the same way: _delta_tables_of). Pages group sequentially under the
+    bit cap, one upload a group (device_ops.pack_delta_upload); a page
+    without values contributes nothing."""
     cap = _BATCH_BITS_CAP
     groups: list[list] = []  # [pages, ms, me, lo, hi, bits]
     cur = None
     for P in data_pages:
         if P[_PC_ROUTE] != 2 or P[_PC_EXTRA] == 0:
-            continue  # empty streams contribute nothing (add_page parity)
+            continue
         bits = P[_PC_DCONS] * 8
         if cur is None or cur[5] + bits > cap:
             cur = [[P], P[_PC_MINIS], P[_PC_MINIE], P[_PC_DSTART],
@@ -1880,62 +1620,26 @@ def _freeze_delta_from_tables(data_pages, res, nbits: int) -> list:
             cur[4] = P[_PC_DSTART] + P[_PC_DCONS]
             cur[5] += bits
     frozen = []
-    ud = np.uint32 if nbits == 32 else np.uint64
-    d_widths = res["d_widths"]
-    d_bytestart = res["d_bytestart"]
-    d_outstart = res["d_outstart"]
-    d_mins = res["d_mins"]
-    stream_all = res["delta_stream"]
     for plist, ms, me, lo, hi, _bits in groups:
         totals = np.array([P[_PC_EXTRA] for P in plist], dtype=np.int64)
-        bases = np.zeros(len(plist), dtype=np.int64)
+        bases = np.zeros(len(plist), dtype=np.int64)  # each page's first output position
         np.cumsum(totals[:-1], out=bases[1:])
-        total = int(totals.sum())
-        minis_per_page = np.array(
-            [P[_PC_MINIE] - P[_PC_MINIS] for P in plist], dtype=np.int64
-        )
-        m = me - ms
-        n_pad = _bucket(total)
-        m_pad = _bucket(max(m, 1), 64)
-        p = len(plist)
-        p_pad = _bucket(p, 64)
-        sentinel = np.int32(n_pad + 1).view(np.uint32)
-        stream = bytes(stream_all[lo:hi])
-        words = bytes_to_words32(stream) if nbits == 32 else bytes_to_words64(stream)
-        w_pad = _bucket(len(words), 1024)
-        tail32 = (2 * m_pad + 2 * p_pad + w_pad) if nbits == 32 else 0
-        meta32 = np.zeros(3 * m_pad + p_pad + tail32, dtype=np.uint32)
-        meta32[2 * m_pad : 3 * m_pad] = sentinel
-        meta32[3 * m_pad : 3 * m_pad + p_pad] = sentinel
-        out_starts = d_outstart[ms:me].astype(np.int64) + np.repeat(
-            bases + 1, minis_per_page
-        )
-        if m:
-            meta32[:m] = d_widths[ms:me]
-            meta32[m_pad : m_pad + m] = (
-                ((d_bytestart[ms:me] - lo) * 8).astype(np.int32).view(np.uint32)
+        minis_per_page = [P[_PC_MINIE] - P[_PC_MINIS] for P in plist]
+        frozen.append(
+            pack_delta_upload(
+                res["d_widths"][ms:me],
+                # payload bits are addressed from the group's first wire byte
+                (res["d_bytestart"][ms:me] - lo) * 8,
+                # a page's k-th delta lands one past its first value
+                res["d_outstart"][ms:me].astype(np.int64) + np.repeat(bases + 1, minis_per_page),
+                res["d_mins"][ms:me],
+                bases,
+                np.array([P[_PC_DFIRST] for P in plist], dtype=np.int64),
+                res["delta_stream"][lo:hi],
+                nbits,
+                int(totals.sum()),
             )
-            meta32[2 * m_pad : 2 * m_pad + m] = (
-                out_starts.astype(np.int32).view(np.uint32)
-            )
-        meta32[3 * m_pad : 3 * m_pad + p] = bases.astype(np.int32).view(np.uint32)
-        firsts = np.array([P[_PC_DFIRST] for P in plist], dtype=np.int64).view(
-            np.uint64
         )
-        if nbits == 32:
-            base = 3 * m_pad + p_pad
-            if m:
-                meta32[base : base + m] = d_mins[ms:me].astype(ud)
-            meta32[base + m_pad : base + m_pad + p] = firsts.astype(ud)
-            meta32[base + m_pad + p_pad : base + m_pad + p_pad + len(words)] = words
-            wide = np.zeros(0, dtype=np.uint32)
-        else:
-            wide = np.zeros(m_pad + p_pad + w_pad, dtype=np.uint64)
-            if m:
-                wide[:m] = d_mins[ms:me]
-            wide[m_pad : m_pad + p] = firsts
-            wide[m_pad + p_pad : m_pad + p_pad + len(words)] = words
-        frozen.append(_FrozenDelta(meta32, wide, nbits, n_pad, m_pad, p_pad, total))
     return frozen
 
 
@@ -2180,8 +1884,8 @@ def _staged_prepare(
 
 
 def _commit_routes(plan: _ChunkPlan, pending: list) -> None:
-    """Build device batches — or demote to host decode if the chunk's pages
-    are not homogeneous.
+    """Freeze the chunk's device uploads — or demote to host decode if its
+    pages are not homogeneous.
 
     Device decode only pays when the whole chunk's values stay on device; a
     chunk that mixes device-kinds with host-kinds (e.g. pyarrow's mid-chunk
@@ -2200,42 +1904,18 @@ def _commit_routes(plan: _ChunkPlan, pending: list) -> None:
         parts = [p for _, _, _, k, p in plan.page_infos if k == "values"]
         plan.plain_host = parts[0] if len(parts) == 1 else np.concatenate(parts)
         return
-    homogeneous = kinds == pending_kinds and len(pending_kinds) == 1
-    if homogeneous:
-        import time as _time
-
-        hybrid_batches = plan.hybrid_batches
-        delta_batches = plan.delta_batches
-        t0 = _time.perf_counter()
-        if "dict" in pending_kinds:
-            # the chunk's one shipping width, as _freeze_hybrid_from_tables
-            ship_width = _index_width(
-                max(p[3] for p in pending),
-                len(plan.dictionary) if plan.dictionary is not None else 0,
-            )
-        for kind, _idx, table, arg, non_null, buf in pending:
-            if kind == "dict":
-                width = arg
-                if not hybrid_batches or not hybrid_batches[-1].fits(table, width):
-                    hybrid_batches.append(_HybridBatch(ship_width))
-                hybrid_batches[-1].add_page(table, non_null, width)
-            else:
-                nbits = arg
-                if not delta_batches or not delta_batches[-1].fits(table):
-                    delta_batches.append(_DeltaBatch(nbits))
-                delta_batches[-1].add_page(table, buf)
-        _count_repack(
-            sum(b.repacked for b in hybrid_batches),
-            _time.perf_counter() - t0,
-            sum(b.repacked_bytes for b in hybrid_batches),
-        )
-        plan.frozen_hybrid = [b.freeze() for b in hybrid_batches]
-        plan.frozen_delta = [
-            f for f in (b.freeze() for b in delta_batches) if f is not None
-        ]
-        plan.hybrid_batches = []
-        plan.delta_batches = []
+    if kinds == pending_kinds == {"delta"}:
+        nbits = pending[0][3]  # the column's: the same in every entry
+        plan.frozen_delta = _freeze_delta_from_tables(*_delta_tables_of(pending), nbits)
         return
+    if kinds == pending_kinds == {"dict"}:
+        frozen = _freeze_hybrid_from_tables(
+            *_hybrid_tables_of(pending),
+            len(plan.dictionary) if plan.dictionary is not None else 0,
+        )
+        if frozen is not None:
+            plan.frozen_hybrid = frozen
+            return
     # Demote: host-decode the would-be device pages in place.
     for kind, idx, table, arg, non_null, buf in pending:
         n, dfl, rep, _k, _p = plan.page_infos[idx]
@@ -2255,6 +1935,83 @@ def _commit_routes(plan: _ChunkPlan, pending: list) -> None:
         parts = [p for _, _, _, k, p in plan.page_infos if k == "values"]
         if parts:
             plan.plain_host = parts[0] if len(parts) == 1 else np.concatenate(parts)
+
+
+# The staged walk's prescans as the whole-chunk tables the native walk returns
+# (native/parquet_tpu_native.cc ptq_chunk_prepare; one page row each), so
+# that both walks feed ONE freeze a kernel. `pending` entries are
+# (kind, page index, table, width | nbits, non-null count, value stream).
+
+
+def _hybrid_tables_of(pending: list) -> tuple[list, dict]:
+    """(page rows, run tables) of a dictionary chunk's pages. A page's run
+    counts are clamped so that it contributes exactly its real value count
+    (its final bit-packed group may encode up to 7 padding values; clamping
+    the last run's count drops them without touching bit offsets). A
+    bit-packed run's byte offset is into the chunk's `packed`; an RLE run's
+    is 0, as the native walk leaves it."""
+    rows, is_rle, counts, values, byteoff, packed = [], [], [], [], [], []
+    n_runs = n_bytes = 0
+    for _kind, _idx, table, width, take, _buf in pending:
+        c = table.counts.astype(np.int64)
+        cum = np.cumsum(c)
+        if take > (int(cum[-1]) if len(cum) else 0):
+            raise PageError("page: hybrid run table shorter than value count")
+        k = int(np.searchsorted(cum, take, side="left")) + 1
+        c = c[:k]
+        c[-1] = take - (int(cum[k - 2]) if k > 1 else 0)
+        P = [0] * _PC_COLS
+        P[_PC_ROUTE], P[_PC_EXTRA] = 1, width
+        P[_PC_RUNS], P[_PC_RUNE] = n_runs, n_runs + k
+        P[_PC_PACKS], P[_PC_PACKE] = n_bytes, n_bytes + len(table.packed)
+        rows.append(P)
+        is_rle.append(table.is_rle[:k])
+        counts.append(c)
+        values.append(table.rle_values[:k])
+        byteoff.append(np.where(table.is_rle[:k], 0, table.bp_offsets[:k] + n_bytes))
+        packed.append(np.frombuffer(table.packed, dtype=np.uint8))
+        n_runs += k
+        n_bytes += len(table.packed)
+    return rows, {
+        "h_is_rle": np.concatenate(is_rle).astype(np.uint8),
+        "h_counts": np.concatenate(counts),
+        "h_values": np.concatenate(values),
+        "h_byteoff": np.concatenate(byteoff),
+        "packed": np.concatenate(packed),
+    }
+
+
+def _delta_tables_of(pending: list) -> tuple[list, dict]:
+    """(page rows, miniblock tables) of a DELTA_BINARY_PACKED chunk's pages;
+    a page without values contributes nothing."""
+    rows, widths, bytestart, outstart, mins, streams = [], [], [], [], [], []
+    n_minis = n_bytes = 0
+    for _kind, _idx, table, _nbits, _non_null, buf in pending:
+        if table.total == 0:
+            continue
+        P = [0] * _PC_COLS
+        P[_PC_ROUTE], P[_PC_EXTRA] = 2, table.total
+        P[_PC_MINIS], P[_PC_MINIE] = n_minis, n_minis + len(table.widths)
+        P[_PC_DSTART], P[_PC_DCONS] = n_bytes, table.consumed
+        # the table holds the first value unsigned; the page row is int64
+        P[_PC_DFIRST] = int(np.uint64(table.first_value).astype(np.int64))
+        rows.append(P)
+        widths.append(table.widths)
+        bytestart.append(table.byte_starts + n_bytes)
+        outstart.append(table.out_starts)
+        mins.append(table.mins)
+        streams.append(np.frombuffer(buf, dtype=np.uint8)[: table.consumed])
+        n_minis += len(table.widths)
+        n_bytes += table.consumed
+    if not rows:
+        return [], {}  # every page empty: nothing to freeze
+    return rows, {
+        "d_widths": np.concatenate(widths),
+        "d_bytestart": np.concatenate(bytestart),
+        "d_outstart": np.concatenate(outstart),
+        "d_mins": np.concatenate(mins),
+        "delta_stream": np.concatenate(streams),
+    }
 
 
 def _host_decode_dict_page(plan, table, width: int, non_null: int):

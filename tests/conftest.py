@@ -18,8 +18,22 @@ if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax
+import pytest
 
 jax.config.update("jax_platforms", "cpu")
+
+
+@pytest.fixture
+def staged_walk():
+    """`with staged_walk():` puts every chunk prepared inside it on the
+    staged per-page walk of kernels/pipeline.py: the native walk declines, as
+    it does under a memory ceiling or without the library. The program has
+    no switch for it; the ladder's own fallback is what is driven."""
+    from unittest import mock
+
+    from parquet_tpu.kernels import pipeline
+
+    return lambda: mock.patch.object(pipeline, "_native_prepare", lambda *a, **kw: (None, None))
 
 
 def pytest_configure(config):

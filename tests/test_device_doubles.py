@@ -247,18 +247,32 @@ class TestOneShippingWidth:
     chunk ships at one width, narrower pages re-packed at freeze time."""
 
     @pytest.mark.parametrize("w_from,w_to", [(1, 2), (1, 32), (3, 5), (7, 8), (8, 9), (11, 12), (13, 14), (31, 32)])
-    def test_repack_groups_native_equals_numpy(self, w_from, w_to, monkeypatch):
+    def test_repack_pages_native_equals_numpy(self, w_from, w_to, monkeypatch):
+        """One page widened and one already at the width, as the native
+        walk's tables: the library's re-pack, then ops/bitpack.py's."""
         from parquet_tpu.ops.bitpack import pack_bits, unpack_bits
+        from parquet_tpu.ops.rle_hybrid import RunTable
+        from parquet_tpu.utils.native import get_native
 
         rng = np.random.default_rng(w_from * 33 + w_to)
         vals = rng.integers(0, 1 << w_from, 8 * 257, dtype=np.uint64)
+        kept = np.frombuffer(pack_bits(rng.integers(0, 1 << w_to, 80, dtype=np.uint64), w_to), dtype=np.uint8)
         src = np.frombuffer(pack_bits(vals, w_from), dtype=np.uint8)
-        native = pipeline._repack_groups(src, 257, w_from, w_to)
-        assert np.array_equal(unpack_bits(native, len(vals), w_to, dtype=np.uint64), vals)
-        from parquet_tpu.utils import native as native_mod
 
-        monkeypatch.setattr(native_mod, "get_native", lambda: None)
-        assert np.array_equal(pipeline._repack_groups(src, 257, w_from, w_to), native)
+        def page(k, packed, n, width):  # one bit-packed run, as the staged walk stages it
+            table = RunTable(np.zeros(1, bool), np.array([n]), np.zeros(1, np.uint64), np.zeros(1, np.int64),
+                             packed.tobytes(), 0)
+            return ("dict", k, table, width, n, None)
+
+        rows, res = pipeline._hybrid_tables_of([page(0, src, len(vals), w_from), page(1, kept, 80, w_to)])
+        pages, is_rle, byteoff, native = pipeline._repack_pages_to_width(rows, res, w_to)
+        wide = 257 * w_to
+        assert np.array_equal(unpack_bits(native[:wide], len(vals), w_to, dtype=np.uint64), vals)
+        assert np.array_equal(native[wide:], kept) and byteoff.tolist() == [0, wide] and not is_rle.any()
+        assert [(P[pipeline._PC_PACKS], P[pipeline._PC_PACKE], P[pipeline._PC_EXTRA]) for P in pages] == [
+            (0, wide, w_to), (wide, wide + len(kept), w_to)]
+        monkeypatch.setattr(get_native(), "has_repack_pages", False)
+        assert np.array_equal(pipeline._repack_pages_to_width(rows, res, w_to)[3], native)
 
     @pytest.mark.parametrize("n_dict,page_width,want", [
         (1, 1, 1), (2, 1, 1), (3, 2, 2), (6, 3, 3), (7, 3, 3), (265, 9, 9), (2026, 11, 12), (2062, 12, 12),
@@ -282,30 +296,30 @@ class TestOneShippingWidth:
 
     def test_two_widths_freeze_to_one_program_equal_to_the_two_program_result(self):
         pages = self._two_width_pages()
-        # as before this change: one batch, one program, per run of equal widths
-        parts, cur = [], None
-        for width, n, _idx, table in pages:
-            if cur is None or cur.width != width:
-                cur = pipeline._HybridBatch(width)
-                parts.append(cur)
-            cur.add_page(table, n, width)
-        old = jnp.concatenate([pipeline._HybridBatch.dispatch_frozen(b.freeze()) for b in parts])
-        one = pipeline._HybridBatch(5)
-        for width, n, _idx, table in pages:
-            assert one.fits(table, width)
-            one.add_page(table, n, width)
-        frozen = one.freeze()
-        assert isinstance(frozen, pipeline._FrozenHybrid) and frozen.width == 5 and one.repacked == 3
-        new = pipeline._HybridBatch.dispatch_frozen(frozen)
+        pending = [("dict", k, table, width, n, None) for k, (width, n, _idx, table) in enumerate(pages)]
+
+        def freeze(part):  # no dictionary size given: the width is the widest page's
+            return pipeline._freeze_hybrid_from_tables(*pipeline._hybrid_tables_of(part))
+
+        # as before PR 28: one upload, one program, per run of equal widths
+        parts = [[pending[0], pending[1]], [pending[2]], [pending[3]], [pending[4]]]
+        old = jnp.concatenate([pipeline._dispatch_hybrid(f) for part in parts for f in freeze(part)])
+        with decode_trace() as tr:
+            (frozen,) = freeze(pending)
+        assert isinstance(frozen, dops.FrozenHybrid) and frozen.width == 5
+        assert tr.stages["hybrid_pages_repacked"].calls == 3
+        new = pipeline._dispatch_hybrid(frozen)
         assert len(parts) == 4 and np.array_equal(np.asarray(new), np.asarray(old))
         assert np.array_equal(np.asarray(new), np.concatenate([p[2] for p in pages]).astype(np.uint32))
 
     @pytest.mark.parametrize("fused", ["1", "1-numpy", "0"])
-    def test_growing_dictionary_chunk_ships_at_one_width(self, tmp_path, monkeypatch, fused):
-        """Both freeze twins (the native walk's tables, the staged walk's
-        batches) on a real file whose pages widen 2 -> 10 bits; the native
-        twin also with the NumPy fallback of the re-pack."""
-        monkeypatch.setenv("PQT_FUSED_PREPARE", fused[0])
+    def test_growing_dictionary_chunk_ships_at_one_width(self, tmp_path, monkeypatch, fused, staged_walk):
+        """Both walks (the native walk's tables, the staged walk's prescans
+        laid out as such) into the one freeze, on a real file whose pages
+        widen 2 -> 10 bits; the native walk also with the NumPy fallback of
+        the re-pack."""
+        from contextlib import nullcontext
+
         if fused == "1-numpy":
             from parquet_tpu.utils.native import get_native
 
@@ -314,12 +328,13 @@ class TestOneShippingWidth:
         v = np.concatenate([rng.integers(0, 4, 30_000), rng.integers(0, 700, 50_000)]).astype(np.int64)
         path = str(tmp_path / "grow.parquet")
         pq.write_table(pa.table({"x": pa.array(v)}), path, data_page_size=4096, row_group_size=len(v))
-        with decode_trace() as tr:
+        with (staged_walk if fused == "0" else nullcontext)(), decode_trace() as tr:
             with FileReader(path) as r:
                 cc, col = r.row_group(0).columns[0], r.schema.column(("x",))
                 plan = prepare_chunk_plan(r._f, cc, col)
                 assert len(plan.frozen_hybrid) == 1 and plan.frozen_hybrid[0].width == 10
                 (g,) = r.read_row_groups_device()
+        assert ("prepare_fused_engaged" in tr.stages) == (fused != "0")
         assert np.array_equal(np.asarray(g[("x",)].values), v)
         assert tr.stages["hybrid_pages_repacked"].calls > 0
         assert tr.stages["prepare.repack_width"].seconds > 0 and tr.stages["prepare.repack_width"].bytes > 0

@@ -1,8 +1,9 @@
 """Kernel-level checks of the two decode kernels against a plain numpy
 expansion of the same tables (tests/test_tpu_backend.py drives them through
-whole files). The tables are built here in the upload layout of
-kernels/pipeline.py's freeze functions: padding entries of every start
-table hold n_pad + 1, zero-length runs repeat a start."""
+whole files). The uploads are built by the packers that live beside the
+kernels (pack_hybrid_upload, pack_delta_upload: the one statement of the
+layout), so each case checks kernel after packer against an independent
+answer; the two literal goldens at the end pin the layout itself."""
 
 import numpy as np
 import pytest
@@ -14,6 +15,8 @@ from parquet_tpu.kernels.device_ops import (
     _spread,
     delta_packed_decode_device,
     expand_hybrid_device,
+    pack_delta_upload,
+    pack_hybrid_upload,
 )
 
 
@@ -24,33 +27,23 @@ def _pack_lsb(values, width: int) -> np.ndarray:
     return ((values[:, None] >> shifts[None, :]) & np.uint64(1)).astype(np.uint8).reshape(-1)
 
 
-def _words(bits: np.ndarray, dtype) -> np.ndarray:
-    """The bit stream as little-endian words, zero-padded to a power-of-two
-    bucket that leaves room for the guard word."""
-    raw = np.packbits(bits, bitorder="little").tobytes()
-    raw += b"\x00" * ((-len(raw)) % np.dtype(dtype).itemsize)
-    got = np.frombuffer(raw, dtype=dtype)
-    w_pad = 1024
-    while w_pad <= len(got):
-        w_pad <<= 1
-    out = np.zeros(w_pad, dtype=dtype)
-    out[: len(got)] = got
-    return out
+def _wire(bits: np.ndarray) -> bytes:
+    return np.packbits(bits, bitorder="little").tobytes()
 
 
 # -- expand_hybrid_device -------------------------------------------------------
 
 
-def _hybrid_case(counts, is_rle, width, run_pad, n_pad, rle_bit_start=0):
-    """(buf, expected[:total]) for runs of `counts` values each. An RLE run's
-    bit_start is read by nobody; `rle_bit_start` is what the table holds there
-    (a batch near MAX_DEVICE_BATCH_BITS leaves the payload's end in it)."""
+def _hybrid_case(counts, is_rle, width, rle_bit_start=0):
+    """(frozen upload, expected[:total]) for runs of `counts` values each. An
+    RLE run's bit_start is read by nobody; `rle_bit_start` is what the table
+    holds there (a batch near MAX_DEVICE_BATCH_BITS leaves the payload's end
+    in it)."""
     rng = np.random.default_rng(0)
     counts = np.asarray(counts, dtype=np.int64)
     is_rle = np.asarray(is_rle, dtype=bool)
     k = len(counts)
     total = int(counts.sum())
-    assert k <= run_pad and total <= n_pad
     out_start = np.concatenate([[0], np.cumsum(counts)[:-1]])
     top = 1 << width
     rle_value = rng.integers(0, top, size=k).astype(np.uint32)
@@ -70,15 +63,10 @@ def _hybrid_case(counts, is_rle, width, run_pad, n_pad, rle_bit_start=0):
         packed.append(vals)
         bits_so_far += c * width
     flat = np.concatenate(packed) if packed else np.zeros(0, np.uint32)
-    words = _words(_pack_lsb(flat, width), np.uint32)
-    buf = np.zeros(4 * run_pad + len(words), dtype=np.uint32)
-    buf[run_pad : 2 * run_pad] = np.int32(n_pad + 1).view(np.uint32)  # sentinel
-    buf[:k] = is_rle
-    buf[run_pad : run_pad + k] = out_start.astype(np.int32).view(np.uint32)
-    buf[2 * run_pad : 2 * run_pad + k] = rle_value
-    buf[3 * run_pad : 3 * run_pad + k] = bit_start.astype(np.int32).view(np.uint32)
-    buf[4 * run_pad :] = words
-    return buf, expected
+    frozen = pack_hybrid_upload(
+        is_rle, counts, rle_value, bit_start, _wire(_pack_lsb(flat, width)), width
+    )
+    return frozen, expected
 
 
 def _many_runs(k, seed):
@@ -87,17 +75,17 @@ def _many_runs(k, seed):
 
 
 _HYBRID_CASES = {
-    # name: (counts, is_rle, width, run_pad, n_pad)
+    # name: (counts, is_rle, width, the run_pad and n_pad the packer's buckets give)
     "single-rle-run": ([1024], [1], 3, 64, 1024),
     "single-bitpacked-run": ([1024], [0], 5, 64, 1024),
     "run_pad-64-mixed": ([8, 100, 16, 1, 899], [0, 1, 0, 1, 0], 3, 64, 1024),
     "run_pad-65536": (*_many_runs(40_000, 1), 2, 65536, 131072),
-    "run_pad-4096-full-table": (*_many_runs(4096, 2), 7, 4096, 16384),
+    "run_pad-4096-full-table": (*_many_runs(4096, 2), 7, 4096, 8192),
     "zero-length-run-in-the-middle": ([40, 0, 60, 0, 0, 924], [1, 0, 0, 1, 0, 1], 4, 64, 1024),
     "zero-length-run-at-the-end": ([500, 524, 0], [0, 1, 0], 3, 64, 1024),
     "zero-length-run-at-the-end-short": ([300, 200, 0, 0], [0, 1, 1, 0], 3, 64, 1024),
     "zero-length-run-first": ([0, 0, 1000], [1, 0, 0], 6, 64, 1024),
-    "total-below-n_pad": ([700, 301], [0, 1], 9, 64, 2048),
+    "total-below-n_pad": ([700, 301], [0, 1], 9, 64, 1024),
     "width-0": ([10, 1014], [1, 0], 0, 64, 1024),
     "rle-only": ([1, 2, 3, 1018], [1, 1, 1, 1], 1, 64, 1024),
     "bitpacked-only": ([8, 16, 1000], [0, 0, 0], 12, 64, 1024),
@@ -119,8 +107,10 @@ _HYBRID_CASES = {
 @pytest.mark.parametrize("case", sorted(_HYBRID_CASES), ids=sorted(_HYBRID_CASES))
 def test_expand_hybrid_equals_numpy_expansion(case):
     counts, is_rle, width, run_pad, n_pad, *rest = _HYBRID_CASES[case]
-    buf, expected = _hybrid_case(counts, is_rle, width, run_pad, n_pad, *rest)
-    got = np.asarray(expand_hybrid_device(jnp.asarray(buf), width, n_pad, run_pad))
+    f, expected = _hybrid_case(counts, is_rle, width, *rest)
+    # the shape the case is named for is the one the packer's buckets give
+    assert (f.width, f.run_pad, f.n_pad, f.total) == (width, run_pad, n_pad, len(expected))
+    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad, f.run_pad))
     assert got.shape == (n_pad,) and got.dtype == np.uint32
     # positions past the table's total belong to no run: callers slice them off
     np.testing.assert_array_equal(got[: len(expected)], expected)
@@ -129,8 +119,8 @@ def test_expand_hybrid_equals_numpy_expansion(case):
 # -- delta_packed_decode_device -------------------------------------------------
 
 
-def _delta_case(page_sizes, nbits, m_pad, p_pad, n_pad, max_width=None, min_or=0):
-    """(meta32, wide, expected[:total]): pages of the given value counts,
+def _delta_case(page_sizes, nbits, max_width=None, min_or=0):
+    """(frozen upload, expected[:total]): pages of the given value counts,
     miniblocks of 32 deltas, each with its own width and min (`min_or` is
     or-ed into every min, shifted to the top of the value's width)."""
     rng = np.random.default_rng(0)
@@ -167,42 +157,21 @@ def _delta_case(page_sizes, nbits, m_pad, p_pad, n_pad, max_width=None, min_or=0
         vals[1:] = first + np.cumsum(deltas, dtype=ud)
         expected.append(vals)
         base += size
-    total = base
-    m, p = len(widths), len(page_sizes)
-    assert m <= m_pad and p <= p_pad and total <= n_pad
     bits = np.concatenate([np.zeros(0, np.uint8)] + [_pack_lsb(a, w) for a, w in adj_all])
-    words = _words(bits, ud)
-    sentinel = np.int32(n_pad + 1).view(np.uint32)
-    tail32 = (m_pad + p_pad + len(words)) if nbits == 32 else 0
-    meta32 = np.zeros(3 * m_pad + p_pad + tail32, dtype=np.uint32)
-    meta32[2 * m_pad : 3 * m_pad] = sentinel
-    meta32[3 * m_pad : 3 * m_pad + p_pad] = sentinel
-    meta32[:m] = widths
-    meta32[m_pad : m_pad + m] = np.asarray(bit_starts, np.int32).view(np.uint32)
-    meta32[2 * m_pad : 2 * m_pad + m] = np.asarray(out_starts, np.int32).view(np.uint32)
-    meta32[3 * m_pad : 3 * m_pad + p] = np.asarray(page_start, np.int32).view(np.uint32)
-    if nbits == 32:
-        b = 3 * m_pad + p_pad
-        meta32[b : b + m] = mins
-        meta32[b + m_pad : b + m_pad + p] = page_first
-        meta32[b + m_pad + p_pad :] = words
-        wide = np.zeros(0, dtype=np.uint32)
-    else:
-        wide = np.zeros(m_pad + p_pad + len(words), dtype=np.uint64)
-        wide[:m] = mins
-        wide[m_pad : m_pad + p] = page_first
-        wide[m_pad + p_pad :] = words
-    return meta32, wide, np.concatenate(expected)
+    frozen = pack_delta_upload(
+        widths, bit_starts, out_starts, mins, page_start, page_first, _wire(bits), nbits, base
+    )
+    return frozen, np.concatenate(expected)
 
 
 _DELTA_CASES = {
-    # name: (page_sizes, m_pad, p_pad, n_pad, max_width)
+    # name: (page_sizes, the m_pad, p_pad and n_pad the packer's buckets give, max_width)
     "one-page-p_pad-64": ([1024], 64, 64, 1024, None),
     "one-page-one-value": ([1], 64, 64, 1024, None),
     "several-pages": ([100, 33, 1, 500, 34, 356], 64, 64, 1024, None),
     "page-of-one-value-between-pages": ([65, 1, 1, 957], 64, 64, 1024, None),
-    "total-below-n_pad": ([300, 301], 64, 64, 2048, None),
-    "m_pad-4096": ([20_000, 20_000, 25_000], 4096, 64, 65536, 20),
+    "total-below-n_pad": ([300, 301], 64, 64, 1024, None),
+    "m_pad-4096": ([30_000, 30_000, 40_000], 4096, 64, 131072, 20),
     "full-miniblock-table": ([2048], 64, 64, 2048, 9),
     # what bringing width, base, min and the page offset to the values could break
     "page-of-one-value-last": ([500, 1], 64, 64, 1024, None),
@@ -219,12 +188,11 @@ _DELTA_CASES = {
 @pytest.mark.parametrize("case", sorted(_DELTA_CASES), ids=sorted(_DELTA_CASES))
 def test_delta_decode_equals_numpy_expansion(case, nbits):
     page_sizes, m_pad, p_pad, n_pad, max_width, *rest = _DELTA_CASES[case]
-    meta32, wide, expected = _delta_case(
-        page_sizes, nbits, m_pad, p_pad, n_pad, max_width, *rest
-    )
+    f, expected = _delta_case(page_sizes, nbits, max_width, *rest)
+    assert (f.nbits, f.m_pad, f.p_pad, f.n_pad, f.total) == (nbits, m_pad, p_pad, n_pad, len(expected))
     got = np.asarray(
         delta_packed_decode_device(
-            jnp.asarray(meta32), jnp.asarray(wide), nbits, n_pad, m_pad, p_pad
+            jnp.asarray(f.meta32), jnp.asarray(f.wide), f.nbits, f.n_pad, f.m_pad, f.p_pad
         )
     )
     assert got.shape == (n_pad,)
@@ -296,3 +264,79 @@ def test_spread_equals_the_gather_through_segment_of(case, dtype):
     # the contract as the docstring words it
     through = np.asarray(_segment_of(jnp.asarray(table), n))
     np.testing.assert_array_equal(got[through >= 0], field[through[through >= 0]])
+
+
+# -- the upload format itself, word for word ------------------------------------
+#
+# What the packers write where, as literals: a change of the format (the next
+# perf_opt's business) has to change these on purpose. Everything not listed is 0.
+
+
+def _nonzero(a: np.ndarray) -> dict:
+    return {int(i): int(a[i]) for i in np.flatnonzero(a)}
+
+
+def test_hybrid_upload_golden():
+    # width 3: 5 x 6 | 0..7 bit-packed at bit 0 | a zero-length run | 3 of the
+    # group 7..0 at bit 24 | 3 x 2. The RLE runs' bit offsets are what the
+    # native walk leaves there for an upload's second group of pages: garbage,
+    # negative, and read by nobody.
+    f = pack_hybrid_upload(
+        is_rle=np.array([1, 0, 1, 0, 1], dtype=np.uint8),
+        counts=np.array([5, 8, 0, 3, 3], dtype=np.int64),
+        rle_values=np.array([6, 0, 7, 0, 2], dtype=np.uint64),
+        bit_starts=np.array([-8, 0, -8, 24, -8], dtype=np.int64),
+        packed=bytes([0x88, 0xC6, 0xFA, 0x77, 0x39, 0x05]),
+        width=3,
+    )
+    assert (f.width, f.n_pad, f.run_pad, f.total) == (3, 1024, 64, 19)
+    assert f.buf.dtype == np.uint32 and f.buf.shape == (4 * 64 + 1024,)
+    want = {0: 1, 2: 1, 4: 1}  # is_rle
+    want.update({64 + 1: 5, 64 + 2: 13, 64 + 3: 13, 64 + 4: 16})  # out_start
+    want.update({k: 1025 for k in range(64 + 5, 128)})  # its padding: n_pad + 1
+    want.update({128: 6, 128 + 2: 7, 128 + 4: 2})  # rle_value
+    want.update({192: 0xFFFFFFF8, 192 + 2: 0xFFFFFFF8, 192 + 3: 24, 192 + 4: 0xFFFFFFF8})  # bit_start
+    want.update({256: 0x77FAC688, 256 + 1: 0x00000539})  # payload words, then the guard word: 0
+    assert _nonzero(f.buf) == want
+    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad, f.run_pad))
+    assert got[: f.total].tolist() == [6] * 5 + list(range(8)) + [7, 6, 5] + [2] * 3
+
+
+@pytest.mark.parametrize("nbits", [32, 64])
+def test_delta_upload_golden(nbits):
+    # two pages: 10, 13, 12 (deltas 3, -1: min -1, two 3-bit residues 4, 0)
+    # and F, F + 5 (one delta of width 0, min 5), F past 32 bits where it fits
+    far = 1 << (40 if nbits == 64 else 20)
+    ones = (1 << nbits) - 1
+    f = pack_delta_upload(
+        widths=np.array([3, 0], dtype=np.uint32),
+        bit_starts=np.array([0, 8], dtype=np.int64),
+        out_starts=np.array([1, 4], dtype=np.int64),
+        mins=np.array([(1 << 64) - 1, 5], dtype=np.uint64),
+        page_starts=np.array([0, 3], dtype=np.int64),
+        page_firsts=np.array([10, far], dtype=np.int64),
+        stream=bytes([0x04]),
+        nbits=nbits,
+        total=5,
+    )
+    assert (f.nbits, f.n_pad, f.m_pad, f.p_pad, f.total) == (nbits, 1024, 64, 64, 5)
+    head = {0: 3}  # widths
+    head.update({64 + 1: 8})  # bit_starts
+    head.update({128: 1, 128 + 1: 4})  # out_starts ...
+    head.update({192 + 1: 3})  # ... page_start ...
+    head.update({k: 1025 for k in [*range(128 + 2, 192), *range(192 + 2, 256)]})  # ... padded with n_pad + 1
+    tail = {0: ones, 1: 5, 64: 10, 64 + 1: far, 128: 4}  # mins | page_first | wire words (+ guard word: 0)
+    assert f.meta32.dtype == np.uint32
+    if nbits == 64:
+        assert f.meta32.shape == (256,) and f.wide.dtype == np.uint64 and f.wide.shape == (64 + 64 + 1024,)
+        assert _nonzero(f.meta32) == head and _nonzero(f.wide) == tail
+    else:
+        # one upload: the wide tables follow, then 64 + 64 words that nothing reads
+        assert f.meta32.shape == (256 + 64 + 64 + 1024 + 128,) and f.wide.shape == (0,)
+        assert _nonzero(f.meta32) == {**head, **{256 + k: v for k, v in tail.items()}}
+    got = np.asarray(
+        delta_packed_decode_device(
+            jnp.asarray(f.meta32), jnp.asarray(f.wide), f.nbits, f.n_pad, f.m_pad, f.p_pad
+        )
+    )
+    assert got[: f.total].tolist() == [10, 13, 12, far, far + 5]

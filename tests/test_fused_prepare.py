@@ -1,10 +1,14 @@
 """The fused GIL-free native chunk prepare (ptq_chunk_prepare via
 _native_ext.chunk_prepare / ctypes).
 
-Three contracts pinned here:
+Four contracts pinned here:
   * byte-identical ChunkData between the fused walk and the staged per-page
-    Python walk (PQT_FUSED_PREPARE=0) across the encoding x codec x page
-    version x nullable/nested matrix, with read_chunk as a third oracle;
+    Python walk (conftest.py's staged_walk: the native walk declines) across
+    the encoding x codec x page version x nullable/nested matrix, with
+    read_chunk as a third oracle;
+  * one freeze: over the same matrix, and over chunks whose index pages were
+    written at three widths, the staged walk's frozen upload records equal
+    the native walk's field for field and byte for byte;
   * observability: prepare_fused_engaged / prepare_fused_declined trace
     counters say which path a chunk took, and the fused walk's internal
     stage split lands in prepare.* stages;
@@ -18,7 +22,7 @@ from __future__ import annotations
 import concurrent.futures as cf
 import os
 import time
-from contextlib import contextmanager
+from contextlib import nullcontext
 
 import numpy as np
 import pyarrow as pa
@@ -28,6 +32,7 @@ import pytest
 from parquet_tpu.core.arrays import ByteArrayData
 from parquet_tpu.core.chunk import ChunkWindow, chunk_byte_range, read_chunk
 from parquet_tpu.core.reader import FileReader
+from parquet_tpu.kernels import pipeline
 from parquet_tpu.kernels.pipeline import plan_chunk_tpu, prepare_chunk_plan
 from parquet_tpu.utils.native import get_native
 from parquet_tpu.utils.trace import decode_trace
@@ -39,23 +44,12 @@ requires_native = pytest.mark.skipif(
 )
 
 
-@contextmanager
-def _env(**kv):
-    old = {k: os.environ.get(k) for k in kv}
-    os.environ.update(kv)
-    try:
-        yield
-    finally:
-        for k, v in old.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
 # -- the differential matrix ---------------------------------------------------
 
 ROWS = 20_000
+_MATRIX_KINDS = [
+    "plain_i64", "plain_f32", "dict_str", "delta_i64", "bss_f32", "nullable_i64", "nested_list",
+]
 
 
 def _column(kind):
@@ -117,29 +111,26 @@ def _build(tmp_path, kind, codec, version):
     return p
 
 
-def _prepare_chunks(path, fused: bool):
-    """Every chunk's ChunkData via the device-plan pipeline, fused or staged."""
-    env = {"PQT_FUSED_PREPARE": "1" if fused else "0"}
-    out = []
-    with _env(**env), decode_trace() as tr:
+def _chunk_windows(r):
+    """(window, chunk, column) of every chunk of an open FileReader."""
+    for i in range(r.num_row_groups):
+        for _p, cc, col in r._selected_chunks(i):
+            off, total = chunk_byte_range(cc)
+            yield ChunkWindow(r._pread(off, total), off), cc, col
+
+
+def _prepare_chunks(path, walk=nullcontext):
+    """Every chunk's ChunkData via the device-plan pipeline: fused, or staged
+    under walk=staged_walk."""
+    with walk(), decode_trace() as tr:
         with FileReader(path) as r:
-            for i in range(r.num_row_groups):
-                for _p, cc, col in r._selected_chunks(i):
-                    off, total = chunk_byte_range(cc)
-                    win = ChunkWindow(r._pread(off, total), off)
-                    out.append(plan_chunk_tpu(win, cc, col).finalize())
+            out = [plan_chunk_tpu(*c).finalize() for c in _chunk_windows(r)]
     return out, tr
 
 
 def _host_chunks(path):
-    out = []
     with FileReader(path) as r:
-        for i in range(r.num_row_groups):
-            for _p, cc, col in r._selected_chunks(i):
-                off, total = chunk_byte_range(cc)
-                win = ChunkWindow(r._pread(off, total), off)
-                out.append(read_chunk(win, cc, col))
-    return out
+        return [read_chunk(*c) for c in _chunk_windows(r)]
 
 
 def _assert_chunkdata_equal(a, b, ctx):
@@ -166,22 +157,11 @@ def _assert_chunkdata_equal(a, b, ctx):
 @requires_native
 @pytest.mark.parametrize("codec", ["none", "snappy", "gzip"])
 @pytest.mark.parametrize("version", ["1.0", "2.0"])
-@pytest.mark.parametrize(
-    "kind",
-    [
-        "plain_i64",
-        "plain_f32",
-        "dict_str",
-        "delta_i64",
-        "bss_f32",
-        "nullable_i64",
-        "nested_list",
-    ],
-)
-def test_fused_matches_staged_and_host(tmp_path, kind, codec, version):
+@pytest.mark.parametrize("kind", _MATRIX_KINDS)
+def test_fused_matches_staged_and_host(tmp_path, kind, codec, version, staged_walk):
     path = _build(tmp_path, kind, codec, version)
-    fused, tr_fused = _prepare_chunks(path, fused=True)
-    staged, tr_staged = _prepare_chunks(path, fused=False)
+    fused, tr_fused = _prepare_chunks(path)
+    staged, tr_staged = _prepare_chunks(path, staged_walk)
     host = _host_chunks(path)
     ctx = (kind, codec, version)
     assert len(fused) == len(staged) == len(host), ctx
@@ -192,15 +172,105 @@ def test_fused_matches_staged_and_host(tmp_path, kind, codec, version):
     engaged = tr_fused.stages.get("prepare_fused_engaged")
     assert engaged is not None and engaged.calls == len(fused), ctx
     assert "prepare_fused_declined" not in tr_fused.stages, ctx
-    # the kill-switch run must not have touched the fused walk
+    # the staged run must not have touched the fused walk
     assert "prepare_fused_engaged" not in tr_staged.stages, ctx
+
+
+# -- one freeze: the staged walk's upload records are the native walk's --------
+
+def _frozen_records(path, walk=nullcontext, **kw):
+    """[(frozen_hybrid, frozen_delta)] of every chunk, prepared and not dispatched."""
+    with walk(), FileReader(path) as r:
+        plans = [prepare_chunk_plan(*c, **kw) for c in _chunk_windows(r)]
+    return [(plan.frozen_hybrid, plan.frozen_delta) for plan in plans]
+
+
+def _assert_records_equal(native, staged, ctx):
+    assert len(native) == len(staged), ctx
+    for a, b in zip(native, staged):
+        assert type(a) is type(b) and a._fields == b._fields, ctx
+        for name in a._fields:
+            x, y = getattr(a, name), getattr(b, name)
+            if isinstance(x, np.ndarray):
+                assert x.dtype == y.dtype and x.shape == y.shape, (ctx, name)
+                assert x.tobytes() == y.tobytes(), (ctx, name)
+            else:
+                assert x == y, (ctx, name)
+
+
+@requires_native
+@pytest.mark.parametrize("codec", ["none", "snappy", "gzip"])
+@pytest.mark.parametrize("version", ["1.0", "2.0"])
+@pytest.mark.parametrize("kind", _MATRIX_KINDS)
+def test_staged_freeze_equals_native_freeze(tmp_path, kind, codec, version, staged_walk):
+    path = _build(tmp_path, kind, codec, version)
+    native = _frozen_records(path)
+    staged = _frozen_records(path, staged_walk)
+    assert len(native) == len(staged) >= 1
+    for (nh, nd), (sh, sd) in zip(native, staged):
+        _assert_records_equal(nh, sh, (kind, codec, version, "hybrid"))
+        _assert_records_equal(nd, sd, (kind, codec, version, "delta"))
+        # the two kinds that freeze an upload do
+        assert len(nh) == (kind == "dict_str") and len(nd) == (kind == "delta_i64")
+
+
+def _three_width_table():
+    """Dictionary columns whose pages widen 2 -> 10 -> 13 bits as the
+    dictionary grows, with a stretch of one value (RLE runs) in every width's
+    pages — an RLE run's bit offset, which no kernel reads, is where the two
+    walks differed before they shared a freeze — plain, nullable and DOUBLE;
+    and two delta columns."""
+    rng = np.random.default_rng(4)
+    v = np.concatenate(
+        [rng.integers(0, 4, 30_000), rng.integers(0, 700, 50_000), rng.integers(0, 5000, 80_000)]
+    ).astype(np.int64)
+    for a in (1_000, 22_000, 41_000, 75_000, 90_000, 150_000):
+        v[a : a + 3_000] = v[a]
+    mask = rng.random(len(v)) < 0.1
+    ts = np.cumsum(rng.integers(0, 5000, len(v))).astype(np.int64)
+    return pa.table({
+        "x": pa.array(v),
+        "xn": pa.array(v, mask=mask),
+        "xd": pa.array(v.astype(np.float64) / 4),
+        "ts": pa.array(ts, mask=mask),
+        "t32": pa.array((ts % (1 << 30)).astype(np.int32)),
+    })
+
+
+@requires_native
+@pytest.mark.parametrize("cap", [None, 1 << 19], ids=["one-upload", "uploads-split-at-2^19-bits"])
+@pytest.mark.parametrize("version", ["1.0", "2.0"])
+def test_staged_freeze_equals_native_freeze_three_widths(tmp_path, version, cap, staged_walk, monkeypatch):
+    t = _three_width_table()
+    path = str(tmp_path / "widths.parquet")
+    pq.write_table(
+        t, path, data_page_size=4096, row_group_size=t.num_rows, data_page_version=version,
+        use_dictionary=["x", "xn", "xd"],
+        column_encoding={"ts": "DELTA_BINARY_PACKED", "t32": "DELTA_BINARY_PACKED"},
+    )
+    if cap is not None:
+        monkeypatch.setattr(pipeline, "_BATCH_BITS_CAP", cap)
+    with decode_trace() as tr_native:
+        native = _frozen_records(path, doubles="float32")
+    with decode_trace() as tr_staged:
+        staged = _frozen_records(path, staged_walk, doubles="float32")
+    for name, (nh, nd), (sh, sd) in zip(t.column_names, native, staged):
+        _assert_records_equal(nh, sh, (name, "hybrid"))
+        _assert_records_equal(nd, sd, (name, "delta"))
+        uploads = len(nh) + len(nd)
+        assert (uploads == 1) if cap is None else (uploads > 1), (name, uploads)
+        assert all(f.width == 13 for f in nh), name
+    # both walks widened the same pages, through the same re-pack
+    repacked = tr_native.stages["hybrid_pages_repacked"].calls
+    assert repacked > 0 and tr_staged.stages["hybrid_pages_repacked"].calls == repacked
+    assert "host_decoded_pages" not in tr_native.stages and "host_decoded_pages" not in tr_staged.stages
 
 
 @requires_native
 def test_fused_stage_breakdown_collected(tmp_path):
     """Under an active trace the walk reports its internal stage split."""
     path = _build(tmp_path, "dict_str", "snappy", "2.0")
-    _, tr = _prepare_chunks(path, fused=True)
+    _, tr = _prepare_chunks(path)
     assert tr.stages["prepare.decompress"].seconds > 0
     # dict-index pages prescan their run headers inside the walk
     assert "prepare.prescan" in tr.stages
@@ -221,16 +291,10 @@ def test_fused_crc_validation_stays_engaged(tmp_path):
     )
     with decode_trace() as tr:
         with FileReader(path) as r:
-            plans = []
-            for i in range(r.num_row_groups):
-                for _p, cc, col in r._selected_chunks(i):
-                    off, total = chunk_byte_range(cc)
-                    win = ChunkWindow(r._pread(off, total), off)
-                    plans.append(
-                        prepare_chunk_plan(win, cc, col, validate_crc=True)
-                        .dispatch_device()
-                        .finalize()
-                    )
+            plans = [
+                prepare_chunk_plan(*c, validate_crc=True).dispatch_device().finalize()
+                for c in _chunk_windows(r)
+            ]
     engaged = tr.stages.get("prepare_fused_engaged")
     assert engaged is not None and engaged.calls == len(plans)
     assert "prepare_fused_declined" not in tr.stages
