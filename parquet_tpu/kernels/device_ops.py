@@ -11,7 +11,11 @@ Key formulation — bit-unpack without byte loops: value i of width W occupies
 bits [i*W, (i+1)*W) of the LSB-first stream. Load the stream as uint32 words;
 then val = (words[b>>5] >> (b&31)) | (words[b>>5+1] << (32-(b&31))), masked to
 W bits: two gathers + two shifts per value, fully vectorized. 64-bit widths use
-the same two-gather trick on uint64 words.
+the same two-gather trick on uint64 words. That is the delta kernel's read,
+whose width is data. Where the width is static and the payload one dense
+stream (the hybrid kernel), W words hold exactly 32 values at fixed bit
+positions: the words are first re-packed by constant shifts of strided slices
+so that no value crosses a word (_align_words), and a value is ONE gather.
 
 All index arithmetic is int32: TPU v5e has no native 64-bit integer ALU path
 (XLA emulates i64 as i32 pairs, ~10-100x slower for gather/scan-heavy code),
@@ -40,12 +44,16 @@ was 13-17 dependent gather passes, 65 % of the device's busy time in PR 26's
 trace), and a segment's fields reach its positions the same way (_spread:
 scatter the differences at the starts, scan; four of expand_hybrid_device's
 six passes and five of delta_packed_decode_device's seven went with it in
-PR 29). What is left of each kernel is its two gathers out of the packed
-words: 16.5 of the 17-18 ms a hybrid stream costs per 2^20 values at any
-width and run count; 36 of the 38 ms of a 64-bit delta stream (four 32-bit
-passes: the emulated halves) while XLA keeps all four word tables in fast
-memory, 49 of 51 once the wire words pass 2^18 — the fourth table is then
-read from HBM, a 22 ms pass (PERF.md section 6, PR 29).
+PR 29). What is left of each kernel is its reads of the packed words. The
+hybrid kernel read two words a value through PR 30, 16.5 of the 17-18 ms a
+stream cost per 2^20 values at any width and run count; since PR 31 it aligns
+the payload first (under 0.2 ms) and reads one: 8.1-8.4 ms a stream, 9.2
+with 65,536 runs, 6.2 from a 1,024-word payload (PERF.md section 6, PR 31;
+the gather is 7.5 ms with the kernel's near-sequential indices). A
+64-bit delta stream still reads two: 36 of its 38 ms (four 32-bit passes:
+the emulated halves) while XLA keeps all four word tables in fast memory, 49
+of 51 once the wire words pass 2^18 — the fourth table is then read from HBM,
+a 22 ms pass (PERF.md section 6, PR 29).
 """
 
 from __future__ import annotations
@@ -252,6 +260,53 @@ def bytes_to_words64(data: bytes) -> np.ndarray:
     return np.frombuffer(buf, dtype="<u8")
 
 
+def _align_words(packed_words: jnp.ndarray, width: int):
+    """A bit-packed payload (uint32 words, LSB-first values of `width` bits
+    end to end from bit 0) as a table in which no value crosses a word:
+    (table, bits, row_stride, word_stride). Value v sits in word
+    (v >> 5) * row_stride + ((v & 31) * bits >> 5) * word_stride of the
+    table, `bits` bits from bit (v * bits) & 31; `bits` is the next power of
+    two >= width, so 32 // bits values share a word and the table is under
+    twice the payload.
+
+    `width` words hold exactly 32 values, at bit positions that depend on
+    the static width alone: column c of the payload viewed as rows of `width`
+    words is one strided slice, and each of a row's 32 values is a shift of
+    one column or an or of two, by Python constants — no index array, no
+    gather (the reference's unpack8Int32FuncByWidth, SURVEY.md L1). The
+    `bits` words that re-pack a row come out column by column, so the table
+    keeps them that way — word k of every row, then word k + 1: row_stride 1,
+    word_stride rows — and nothing is transposed. At a width that is a power
+    of two the payload is such a table as it stands (row_stride `width`,
+    word_stride 1). The payload's length is a power-of-two bucket: it is
+    padded with zeros to whole rows, never cut — to a multiple of 1,024 rows,
+    so that every column is whole 1,024-word tiles (a width-17 shape then
+    compiles in 1.8 s on a v5e, not 4.4, and runs no slower; PERF.md
+    section 6, PR 31)."""
+    bits = 1 << (width - 1).bit_length()
+    if bits == width:
+        return packed_words, bits, width, 1
+    pad = (-packed_words.shape[0]) % (width * 1024)
+    if pad:
+        packed_words = jnp.concatenate([packed_words, jnp.zeros(pad, jnp.uint32)])
+    n = packed_words.shape[0]
+    columns = [jax.lax.slice(packed_words, (c,), (n,), (width,)) for c in range(width)]
+    mask = jnp.uint32((1 << width) - 1)
+    per_word = 32 // bits
+    words = []
+    for k in range(bits):
+        word = None
+        for t in range(per_word):
+            c, s = divmod((k * per_word + t) * width, 32)
+            value = columns[c] >> s
+            if s + width > 32:
+                value = value | (columns[c + 1] << (32 - s))
+            value = (value & mask) << (t * bits)
+            word = value if word is None else word | value
+        words.append(word)
+    return jnp.concatenate(words), bits, 1, n // width
+
+
 @partial(jax.jit, static_argnames=("width", "num_values", "run_pad"))
 @jax.named_scope("pqt.hybrid_expand")
 def expand_hybrid_device(
@@ -270,15 +325,25 @@ def expand_hybrid_device(
     _spread (a scatter of differences at out_start and one prefix sum each,
     0.3-0.9 ms where a table[r] pass over 2^20 positions is 9): its is_rle flag,
     and one payload — the value to broadcast if it is an RLE run, else
-    base = bit_start - out_start * width, so that position i of a bit-packed
-    run extracts its bits at base + i * width. Padding entries of out_start
-    hold n_pad + 1 and are dropped; a zero-length run repeats the next run's
-    start and loses to it. What is left is the two gathers out of the packed
-    words (needs the payload's guard word: at least two words), 7.5 + 9.0 ms
-    per 2^20 values on a v5e whatever the width (PERF.md section 6). At positions
-    of RLE runs the bit position means nothing, so its word index is clipped
-    into the payload and the result discarded. Positions past the table's
-    total belong to the last run and carry garbage: the caller slices them off.
+    value_off = bit_start // width - out_start. The payload holds bit-packed
+    groups only, so a run's bit_start is a multiple of `width` and the payload
+    is ONE dense stream of `width`-bit values: position i of a bit-packed run
+    is value i + value_off of it. Padding entries of out_start hold n_pad + 1
+    and are dropped; a zero-length run repeats the next run's start and loses
+    to it.
+
+    What is left is ONE gather a value: the payload is first aligned so that
+    no value crosses a word (_align_words: fixed shifts of strided slices,
+    under unpack/align), then position i reads the one word that holds its
+    value (unpack/gather) and shifts it out. Per 2^20 values on a v5e
+    (PERF.md section 6, PR 31): the alignment 0.02-0.19 ms, the gather 7.5
+    (5.6 from a 1,024-word table) whatever the width, the whole call 8.1-8.4
+    (9.2 with 65,536 runs); through PR 30 a value was read as the two words
+    it might straddle, 7.5 + 9.0 ms and 17.1-18.2 the call. At positions of
+    RLE runs the value index means nothing, so the word index is clipped
+    into the table and the result discarded. Positions past the table's
+    total belong to the last run and carry garbage: the caller slices them
+    off.
     """
     if width == 0:
         return jnp.zeros(num_values, dtype=jnp.uint32)
@@ -291,26 +356,26 @@ def expand_hybrid_device(
     packed_words = buf[4 * run_pad :]
     i = jnp.arange(num_values, dtype=jnp.int32)
     with jax.named_scope("find_run"):
-        run_base = run_bp_bit_start - run_out_start * width
+        run_value_off = run_bp_bit_start // width - run_out_start
         run_payload = jnp.where(
             run_is_rle != 0,
             run_rle_value,
-            jax.lax.bitcast_convert_type(run_base, jnp.uint32),
+            jax.lax.bitcast_convert_type(run_value_off, jnp.uint32),
         )
         payload = _spread(run_out_start, run_payload, num_values)
         is_rle = _spread(run_out_start, run_is_rle, num_values) != 0
     with jax.named_scope("unpack"):
-        bitpos = jax.lax.bitcast_convert_type(payload, jnp.int32) + i * width
-        w0 = jnp.clip(bitpos >> 5, 0, packed_words.shape[0] - 2)
-        s = (bitpos & 31).astype(jnp.uint32)
-        lo = packed_words[w0] >> s
-        hi = jnp.where(
-            s == 0, jnp.uint32(0), packed_words[w0 + 1] << ((32 - s) & 31)
-        )
-        mask = (
-            jnp.uint32((1 << width) - 1) if width < 32 else jnp.uint32(0xFFFFFFFF)
-        )
-        bp_vals = (lo | hi) & mask
+        with jax.named_scope("align"):
+            table, bits, row_stride, word_stride = _align_words(packed_words, width)
+        with jax.named_scope("gather"):
+            v = jax.lax.bitcast_convert_type(payload, jnp.int32) + i
+            bit = (v & 31) * bits
+            w = (v >> 5) * row_stride + (bit >> 5) * word_stride
+            bp_vals = table[jnp.clip(w, 0, table.shape[0] - 1)]
+            if width < 32:
+                bp_vals = (bp_vals >> (bit & 31).astype(jnp.uint32)) & jnp.uint32(
+                    (1 << width) - 1
+                )
     with jax.named_scope("select"):
         return jnp.where(is_rle, payload, bp_vals)
 
@@ -333,8 +398,13 @@ def pack_hybrid_upload(
     values each (already clamped: the runs produce exactly the values wanted,
     a zero-length run is fine), the value an RLE run repeats, the bit offset
     into `packed` (uint8 array or bytes, LSB-first groups at `width` bits) at
-    which a bit-packed run's payload starts. Nobody reads a bit-packed run's
-    value or an RLE run's bit offset. ONE uint32 buffer, because the
+    which a bit-packed run's payload starts. `packed` holds bit-packed groups
+    only (no headers, no RLE values), each run whole groups of 8 values, so
+    every bit-packed run's bit_start % (8 * width) == 0 — both walks, re-packed
+    pages included (tests/test_fused_prepare.py) — and the payload is one dense
+    stream of `width`-bit values: the kernel divides bit_start by `width` and
+    reads value, not bit, positions. Nobody reads a bit-packed run's value or
+    an RLE run's bit offset. ONE uint32 buffer, because the
     host<->device link pays a fixed latency per transfer that dwarfs these
     tables; with run_pad = _bucket(runs, 64), n_pad = _bucket(values):
       buf[0*run_pad:1*run_pad]  is_rle      0/1

@@ -18,6 +18,13 @@ from parquet_tpu.kernels.device_ops import (
     pack_delta_upload,
     pack_hybrid_upload,
 )
+from parquet_tpu.ops.rle_hybrid import (
+    _emit_bitpacked,
+    _emit_uvarint,
+    decode_hybrid,
+    encode_hybrid,
+    prescan_hybrid,
+)
 
 
 def _pack_lsb(values, width: int) -> np.ndarray:
@@ -114,6 +121,163 @@ def test_expand_hybrid_equals_numpy_expansion(case):
     assert got.shape == (n_pad,) and got.dtype == np.uint32
     # positions past the table's total belong to no run: callers slice them off
     np.testing.assert_array_equal(got[: len(expected)], expected)
+
+
+# -- the aligned read: wire streams through the numpy reference ------------------
+#
+# Every case is pages of real hybrid wire (ops/rle_hybrid.encode_hybrid, or
+# written by hand where the encoder would not produce the shape), prescanned
+# and clamped the way the walks do it, and frozen by pack_hybrid_upload. The
+# kernel answers to two oracles: the numpy decode of the same wire
+# (ops/rle_hybrid.decode_hybrid), and the formula the kernel had through PR 30
+# (two reads of the packed words a value, a value may straddle them), kept
+# here in numpy on the same upload.
+
+def _two_gather_formula(buf, width, num_values, run_pad):
+    """expand_hybrid_device as PR 30 left it, in numpy: position i of a
+    bit-packed run extracts its bits at bit_start + (i - out_start) * width
+    from the two words that hold them."""
+    is_rle = buf[:run_pad]
+    out_start = buf[run_pad : 2 * run_pad].view(np.int32)
+    rle_value = buf[2 * run_pad : 3 * run_pad]
+    bit_start = buf[3 * run_pad : 4 * run_pad].view(np.int32)
+    words = buf[4 * run_pad :]
+    i = np.arange(num_values, dtype=np.int32)
+    run = np.maximum(np.searchsorted(out_start, i, side="right") - 1, 0)
+    bitpos = bit_start[run] + (i - out_start[run]) * np.int32(width)
+    w0 = np.clip(bitpos >> 5, 0, len(words) - 2)
+    s = (bitpos & 31).astype(np.uint32)
+    lo = words[w0] >> s
+    hi = np.where(s == 0, np.uint32(0), words[w0 + 1] << ((np.uint32(32) - s) & np.uint32(31)))
+    mask = np.uint32((1 << width) - 1 if width < 32 else 0xFFFFFFFF)
+    return np.where(is_rle[run] != 0, rle_value[run], (lo | hi) & mask)
+
+
+def _wire_runs(runs, width):
+    """A hybrid stream written run by run: ('rle', count, value) or
+    ('bp', values) with len(values) a multiple of 8."""
+    out = bytearray()
+    for run in runs:
+        if run[0] == "rle":
+            _emit_uvarint(out, run[1] << 1)
+            out += int(run[2]).to_bytes((width + 7) // 8, "little")
+        else:
+            _emit_bitpacked(out, np.asarray(run[1], dtype=np.uint64), width)
+    return bytes(out)
+
+
+def _freeze_pages(pages, width, zero_length_runs=False):
+    """(frozen upload, numpy decode) of `pages` = [(wire, values wanted)]:
+    each page prescanned, its last run clamped to the page's count, the
+    payloads end to end — kernels/pipeline.py's _hybrid_tables_of, by hand.
+    With zero_length_runs, an empty run goes in after every run, RLE and
+    bit-packed (at the payload's end) in turn."""
+    is_rle, counts, values, bit_starts, packed, expected = [], [], [], [], [], []
+    n_bytes = 0
+    for wire, n in pages:
+        t = prescan_hybrid(wire, n, width)
+        c = t.counts.astype(np.int64)
+        c[-1] -= int(c.sum()) - n
+        assert c[-1] > 0
+        is_rle.append(np.asarray(t.is_rle, dtype=np.uint8))
+        counts.append(c)
+        values.append(np.asarray(t.rle_values, dtype=np.uint64))
+        bit_starts.append(np.where(t.is_rle, 0, (t.bp_offsets + n_bytes) * 8))
+        packed.append(np.frombuffer(bytes(t.packed), dtype=np.uint8))
+        n_bytes += len(t.packed)
+        expected.append(decode_hybrid(wire, n, width))
+    is_rle, counts, values, bit_starts = (np.concatenate(x) for x in (is_rle, counts, values, bit_starts))
+    if zero_length_runs:
+        k = len(counts)
+        kind = np.arange(k) % 2
+        is_rle = np.stack([is_rle, kind.astype(np.uint8)], axis=1).reshape(-1)
+        counts = np.stack([counts, np.zeros(k, np.int64)], axis=1).reshape(-1)
+        values = np.stack([values, np.full(k, (1 << width) - 1, np.uint64)], axis=1).reshape(-1)
+        bit_starts = np.stack([bit_starts, np.where(kind == 1, 0, n_bytes * 8)], axis=1).reshape(-1)
+    f = pack_hybrid_upload(is_rle, counts, values, bit_starts, np.concatenate(packed), width)
+    return f, np.concatenate(expected)
+
+
+def _groups_that_fill_a_bucket(width):
+    """Groups of 8 values whose payload words + the guard word are exactly a
+    power-of-two bucket (nothing of the bucket is padding), or None."""
+    for bucket in (1 << b for b in range(10, 18)):
+        for nbytes in range(4 * (bucket - 2) + 1, 4 * (bucket - 1) + 1):
+            if nbytes % width == 0:
+                return nbytes // width
+    return None
+
+
+def _aligned_read_pages(shape, width, rng):
+    top = 1 << width
+    draw = lambda n: rng.integers(0, top, size=n, dtype=np.uint64)  # noqa: E731
+    if shape == "one-bit-packed-run":
+        v = draw(2000)
+        return [(_wire_runs([("bp", v)], width), 2000)]
+    if shape == "rle-only":
+        return [(_wire_runs([("rle", int(c), int(v)) for c, v in zip(rng.integers(1, 90, 40), draw(40))], width), 1500)]
+    if shape in ("alternating-run_pad-64", "alternating-run_pad-4096"):
+        pairs = 30 if shape.endswith("-64") else 1100
+        runs = []
+        for v in draw(pairs):
+            runs += [("bp", draw(8)), ("rle", 8, int(v))]
+        return [(_wire_runs(runs, width), 16 * pairs)]
+    if shape == "clamped-mid-group-at-page-ends":
+        # each page ends inside a bit-packed group: the run's count is clamped,
+        # the next page's payload starts at the next group
+        return [(encode_hybrid(draw(n), width), n) for n in (13, 27, 100, 5, 1, 8, 403)] + [
+            (_wire_runs([("rle", 50, int(draw(1)[0])), ("bp", draw(24))], width), 50 + 17)
+        ]
+    if shape == "zero-length-runs":
+        runs = []
+        for v in draw(12):
+            runs += [("bp", draw(16)), ("rle", 11, int(v))]
+        return [(_wire_runs(runs, width), 27 * 12)]
+    if shape == "payload-fills-its-bucket":
+        groups = _groups_that_fill_a_bucket(width)
+        return [(_wire_runs([("rle", 40, int(draw(1)[0])), ("bp", draw(8 * groups))], width), 40 + 8 * groups - 3)]
+    raise AssertionError(shape)
+
+
+_ALIGNED_WIDTHS = [1, 2, 3, 5, 7, 8, 9, 12, 14, 16, 17, 24, 31, 32]
+_ALIGNED_SHAPES = [
+    "one-bit-packed-run", "rle-only", "alternating-run_pad-64", "alternating-run_pad-4096",
+    "clamped-mid-group-at-page-ends", "zero-length-runs", "payload-fills-its-bucket",
+]
+
+
+# 4 * (bucket - 1) bytes are never whole groups at a width that is a multiple
+# of 8: such a payload always leaves padding, and the other shapes cover it
+_ALIGNED_CASES = [
+    (shape, width)
+    for shape in _ALIGNED_SHAPES
+    for width in _ALIGNED_WIDTHS
+    if shape != "payload-fills-its-bucket" or _groups_that_fill_a_bucket(width)
+]
+
+
+@pytest.mark.parametrize("shape,width", _ALIGNED_CASES, ids=[f"{s}-{w}" for s, w in _ALIGNED_CASES])
+def test_aligned_read_equals_numpy_decode_and_the_two_gather_formula(shape, width):
+    rng = np.random.default_rng(width * 131 + _ALIGNED_SHAPES.index(shape))
+    f, expected = _freeze_pages(
+        _aligned_read_pages(shape, width, rng), width, zero_length_runs=shape == "zero-length-runs"
+    )
+    assert f.total == len(expected)
+    if shape == "payload-fills-its-bucket":
+        # words + guard are the whole bucket: a kernel that truncated the
+        # payload to whole rows of `width` words would lose the last values
+        nbytes = _groups_that_fill_a_bucket(width) * width
+        assert len(f.buf) - 4 * f.run_pad == (nbytes + 3) // 4 + 1
+    if shape.startswith("alternating"):
+        assert f.run_pad == int(shape.rsplit("-", 1)[1])
+    # the property the aligned read rests on (pack_hybrid_upload's docstring)
+    bp = f.buf[: f.run_pad] == 0
+    assert not np.any(f.buf[3 * f.run_pad : 4 * f.run_pad].view(np.int32)[bp] % (8 * width))
+    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad, f.run_pad))
+    np.testing.assert_array_equal(got[: f.total], expected)
+    np.testing.assert_array_equal(
+        _two_gather_formula(f.buf, f.width, f.n_pad, f.run_pad)[: f.total], expected
+    )
 
 
 # -- delta_packed_decode_device -------------------------------------------------

@@ -237,10 +237,7 @@ def _three_width_table():
     })
 
 
-@requires_native
-@pytest.mark.parametrize("cap", [None, 1 << 19], ids=["one-upload", "uploads-split-at-2^19-bits"])
-@pytest.mark.parametrize("version", ["1.0", "2.0"])
-def test_staged_freeze_equals_native_freeze_three_widths(tmp_path, version, cap, staged_walk, monkeypatch):
+def _write_three_widths(tmp_path, version):
     t = _three_width_table()
     path = str(tmp_path / "widths.parquet")
     pq.write_table(
@@ -248,6 +245,14 @@ def test_staged_freeze_equals_native_freeze_three_widths(tmp_path, version, cap,
         use_dictionary=["x", "xn", "xd"],
         column_encoding={"ts": "DELTA_BINARY_PACKED", "t32": "DELTA_BINARY_PACKED"},
     )
+    return t, path
+
+
+@requires_native
+@pytest.mark.parametrize("cap", [None, 1 << 19], ids=["one-upload", "uploads-split-at-2^19-bits"])
+@pytest.mark.parametrize("version", ["1.0", "2.0"])
+def test_staged_freeze_equals_native_freeze_three_widths(tmp_path, version, cap, staged_walk, monkeypatch):
+    t, path = _write_three_widths(tmp_path, version)
     if cap is not None:
         monkeypatch.setattr(pipeline, "_BATCH_BITS_CAP", cap)
     with decode_trace() as tr_native:
@@ -264,6 +269,55 @@ def test_staged_freeze_equals_native_freeze_three_widths(tmp_path, version, cap,
     repacked = tr_native.stages["hybrid_pages_repacked"].calls
     assert repacked > 0 and tr_staged.stages["hybrid_pages_repacked"].calls == repacked
     assert "host_decoded_pages" not in tr_native.stages and "host_decoded_pages" not in tr_staged.stages
+
+
+# -- what the hybrid kernel rests on: bit-packed runs start on group boundaries --
+
+def _assert_runs_start_on_groups(frozen, ctx):
+    """Every bit-packed run of every upload starts a whole number of 8-value
+    groups into the payload (device_ops.pack_hybrid_upload's docstring): the
+    payload is one dense stream of `width`-bit values, which is what lets
+    expand_hybrid_device align it by fixed shifts and read each value once."""
+    runs = 0
+    for f in frozen:
+        k = int(np.count_nonzero(f.buf[f.run_pad : 2 * f.run_pad] != f.n_pad + 1))
+        bit_packed = f.buf[:k] == 0
+        bit_start = f.buf[3 * f.run_pad : 3 * f.run_pad + k].view(np.int32)[bit_packed]
+        assert f.width == 0 or not np.any(bit_start % (8 * f.width)), ctx
+        assert np.all(bit_start >= 0), ctx
+        runs += int(bit_packed.sum())
+    assert runs, ctx  # the case does hold bit-packed runs
+
+
+@requires_native
+@pytest.mark.parametrize("walk", ["native", "staged"])
+@pytest.mark.parametrize("codec", ["none", "snappy", "gzip"])
+@pytest.mark.parametrize("version", ["1.0", "2.0"])
+def test_bit_packed_runs_start_on_group_boundaries(tmp_path, version, codec, walk, staged_walk):
+    path = _build(tmp_path, "dict_str", codec, version)
+    records = _frozen_records(path, staged_walk if walk == "staged" else nullcontext)
+    assert records
+    for hybrid, _delta in records:
+        _assert_runs_start_on_groups(hybrid, (version, codec, walk))
+
+
+@requires_native
+@pytest.mark.parametrize("walk", ["native", "staged"])
+@pytest.mark.parametrize("cap", [None, 1 << 19], ids=["one-upload", "uploads-split-at-2^19-bits"])
+@pytest.mark.parametrize("version", ["1.0", "2.0"])
+def test_bit_packed_runs_start_on_group_boundaries_three_widths(
+    tmp_path, version, cap, walk, staged_walk, monkeypatch
+):
+    """Pages re-packed from 2 and 10 bits to the chunk's 13, RLE runs between
+    the bit-packed ones, nulls, a DOUBLE dictionary, uploads split under the
+    bit cap (a later group's offsets count from its own first byte)."""
+    t, path = _write_three_widths(tmp_path, version)
+    if cap is not None:
+        monkeypatch.setattr(pipeline, "_BATCH_BITS_CAP", cap)
+    records = _frozen_records(path, staged_walk if walk == "staged" else nullcontext, doubles="float32")
+    for name, (hybrid, _delta) in zip(t.column_names, records):
+        if name in ("x", "xn", "xd"):
+            _assert_runs_start_on_groups(hybrid, (name, version, cap, walk))
 
 
 @requires_native
