@@ -1,15 +1,19 @@
-"""Seeded NYC-TLC-shaped corpus: one Parquet file per month, written in parallel.
+"""A cell's corpus, written once per (spec, seed, queries) by worker processes.
 
-Host only (numpy + pyarrow; never jax), so worker processes and the load
-generator can import it. Everything a run compares against is computed here,
-by numpy/pyarrow on the table in memory while it is written: per-column
-wrapped int64 sums per batch of `sum_rows` rows, and each file's share of the
-reference answers to the cell's queries (lib/reference.py).
+What is particular to a table lives in benchmark/corpora/<spec["kind"]>.py
+(found by name, lib/byname.py), host only (numpy + pyarrow; never jax):
 
-Every seed gives the same SHAPES in another order: each row group holds exactly
-`nulls_per_group` nulls in the optional columns and every value of every small
-domain, so the device path sees the same array sizes whatever the seed, and a
-seed changes values and positions only.
+    file_name(index) -> str
+    write_file(spec, seed, index, directory, queries) -> dict
+        one file written, and what later comparisons need of it. The harness
+        reads `index` and `rows`; the rest is the kind's and its traffic's.
+    rehearsal(spec, rows) -> (spec, scale)
+        the kind's own way to shrink itself for run.py --rehearse, and the
+        factor by which the cell's `*_rows` shrink with it. Optional: a kind
+        without it cannot be rehearsed.
+
+Of the spec the harness reads `kind` and `files` (how many: indices 0..files-1);
+every other key is the kind's own.
 """
 
 from __future__ import annotations
@@ -21,112 +25,7 @@ from concurrent.futures import ProcessPoolExecutor
 from multiprocessing import get_context
 from pathlib import Path
 
-MONEY = (
-    "fare_amount", "extra", "mta_tax", "tip_amount", "tolls_amount",
-    "improvement_surcharge", "total_amount", "congestion_surcharge", "airport_fee",
-)
-COLUMNS = (
-    "VendorID", "tpep_pickup_datetime", "tpep_dropoff_datetime", "passenger_count",
-    "trip_distance", "RatecodeID", "store_and_fwd_flag", "PULocationID",
-    "DOLocationID", "payment_type", *MONEY,
-)
-ZONES = 265
-JAN_1_2023_US = 1_672_531_200_000_000
-MONTH_US = 2_629_800_000_000  # a twelfth of a year
-
-
-def zone_weights():
-    """The corpus's own zone skew, shared with the tile generator: harmonic,
-    floor high enough that every zone appears in every row group."""
-    import numpy as np
-
-    w = 1.0 / (np.arange(ZONES) + 10.0)
-    return w / w.sum()
-
-
-def build_table(spec: dict, seed: int, index: int):
-    """Month `index` of the year as a pyarrow table, from (seed, index)."""
-    import numpy as np
-    import pyarrow as pa
-
-    n, group = spec["rows_per_file"], spec["row_group_rows"]
-    rng = np.random.default_rng([seed, index])
-    null = np.zeros(n, dtype=bool)
-    for start in range(0, n, group):
-        size = min(group, n - start)
-        k = spec["nulls_per_group"] * size // group
-        null[start + rng.choice(size, k, replace=False)] = True
-
-    def pick(values, probs):
-        return np.asarray(values, dtype=np.int64)[rng.choice(len(values), n, p=probs)]
-
-    zones = np.arange(1, ZONES + 1)
-    gaps = rng.poisson(MONTH_US / 1e6 / n, n)  # whole seconds, as TLC's are
-    pickup = JAN_1_2023_US + index * MONTH_US + (np.cumsum(gaps) + rng.integers(-60, 61, n)) * 1_000_000
-    dropoff = pickup + (60 + rng.gamma(2.0, 420.0, n).astype(np.int64)) * 1_000_000
-
-    def money(shape, scale, step=0.01):
-        return np.round(rng.gamma(shape, scale, n) / step) * step
-
-    fare = money(2.0, 9.0)
-    tip = np.where(rng.random(n) < 0.7, np.round(fare * 0.2, 2), 0.0)
-    tolls = np.where(rng.random(n) < 0.08, 6.55, 0.0)
-    extra = pick([0, 1, 2, 5], [0.4, 0.3, 0.2, 0.1]) * 0.5
-    cong = np.where(rng.random(n) < 0.9, 2.5, 0.0)
-    airport = np.where(rng.random(n) < 0.08, 1.75, 0.0)
-    cols = {
-        "VendorID": pa.array(pick([1, 2, 6], [0.27, 0.7295, 0.0005])),
-        "tpep_pickup_datetime": pa.array(pickup).cast(pa.timestamp("us")),
-        "tpep_dropoff_datetime": pa.array(dropoff).cast(pa.timestamp("us")),
-        "passenger_count": pa.array(
-            pick(range(7), [0.015, 0.73, 0.15, 0.04, 0.025, 0.02, 0.02]), mask=null),
-        "trip_distance": pa.array(money(1.5, 2.3)),
-        "RatecodeID": pa.array(
-            pick([1, 2, 3, 4, 5, 6, 99], [0.93, 0.04, 0.005, 0.005, 0.01, 0.005, 0.005]), mask=null),
-        "store_and_fwd_flag": pa.array(np.where(rng.random(n) < 0.006, "Y", "N")),
-        "PULocationID": pa.array(zones[rng.choice(ZONES, n, p=zone_weights())]),
-        "DOLocationID": pa.array(zones[rng.choice(ZONES, n, p=zone_weights()[::-1])]),
-        "payment_type": pa.array(pick(range(6), [0.03, 0.78, 0.16, 0.01, 0.015, 0.005])),
-        "fare_amount": pa.array(fare), "extra": pa.array(extra),
-        "mta_tax": pa.array(np.full(n, 0.5)), "tip_amount": pa.array(tip),
-        "tolls_amount": pa.array(tolls), "improvement_surcharge": pa.array(np.full(n, 1.0)),
-        "total_amount": pa.array(np.round(fare + extra + tip + tolls + cong + airport + 1.5, 2)),
-        "congestion_surcharge": pa.array(cong), "airport_fee": pa.array(airport),
-    }
-    return pa.table({c: cols[c] for c in COLUMNS})
-
-
-def file_name(index: int) -> str:
-    return f"yellow_tripdata_2023-{index + 1:02d}.parquet"
-
-
-def write_file(spec: dict, seed: int, index: int, directory: str, queries: list) -> dict:
-    """Write one month and return what later comparisons need of it."""
-    import numpy as np
-    import pyarrow.parquet as pq
-
-    from reference import partial_answers  # benchmark/lib is on sys.path
-
-    table = build_table(spec, seed, index)
-    delta = list(spec["delta_columns"])
-    pq.write_table(
-        table, str(Path(directory) / file_name(index)),
-        compression=spec["compression"], row_group_size=spec["row_group_rows"],
-        use_dictionary=[c for c in COLUMNS if c not in delta],
-        column_encoding={c: "DELTA_BINARY_PACKED" for c in delta},
-    )
-    sums = {}
-    for c in spec["sum_columns"]:
-        col = table[c].combine_chunks()
-        if col.type != "int64":
-            col = col.cast("int64")
-        v = col.fill_null(0).to_numpy(zero_copy_only=False)
-        sums[c] = v.reshape(-1, spec["sum_rows"]).sum(axis=1, dtype=np.int64).tolist()
-    return {
-        "index": index, "rows": table.num_rows, "sums": sums,
-        "nulls": {c: table[c].null_count for c in spec["sum_columns"]},
-        "partials": partial_answers(table, file_name(index), queries),
-    }
+from byname import load_by_name
 
 
 def corpus_key(spec: dict, seed: int, queries: list) -> str:
@@ -134,13 +33,20 @@ def corpus_key(spec: dict, seed: int, queries: list) -> str:
     return hashlib.sha256(blob).hexdigest()[:16]
 
 
+def write_one(spec: dict, seed: int, index: int, directory: str, queries: list) -> dict:
+    """What a worker runs: a module loaded from a path cannot be pickled by
+    name, so the spawned process loads the kind itself."""
+    return load_by_name("corpora", spec["kind"]).write_file(spec, seed, index, directory, queries)
+
+
 class CorpusJob:
     """The corpus being written by worker processes while the caller does
     other set-up (imports jax, builds the native library). `result()` waits.
     One corpus is kept per cache directory: another key replaces it, so a
-    checkout never holds more than one year on disk."""
+    checkout never holds more than one corpus on disk."""
 
     def __init__(self, spec: dict, seed: int, queries: list, cache: Path, workers: int):
+        self.kind = load_by_name("corpora", spec["kind"])  # an unknown kind ends the run before any worker starts
         self.dir = cache / "corpus"
         self.key = corpus_key(spec, seed, queries)
         self.pool = None
@@ -154,7 +60,7 @@ class CorpusJob:
         self.dir.mkdir(parents=True)
         self.pool = ProcessPoolExecutor(max_workers=workers, mp_context=get_context("spawn"))
         self.futures = [
-            self.pool.submit(write_file, spec, seed, i, str(self.dir), queries)
+            self.pool.submit(write_one, spec, seed, i, str(self.dir), queries)
             for i in range(spec["files"])
         ]
 
@@ -167,7 +73,7 @@ class CorpusJob:
             self.facts = {"files": files}
             (self.dir / "facts.json").write_text(json.dumps(self.facts))
             (self.dir / "DONE").write_text(self.key + "\n")
-        self.facts["paths"] = [str(self.dir / file_name(i)) for i in range(len(self.facts["files"]))]
+        self.facts["paths"] = [str(self.dir / self.kind.file_name(i)) for i in range(len(self.facts["files"]))]
         return self.facts
 
     def close(self) -> None:

@@ -1,7 +1,7 @@
 """The plain reference of the wide stream cell: pyarrow's read of the same
 file, reduced per column to what a delivery is compared with.
 
-The corpus facts (lib/corpus.py) hold sums of the 8 integer columns only, and
+The corpus facts (corpora/tlc_yellow_2023.py) hold sums of the 8 integer columns only, and
 the corpus object is shared key for key with tlc-year-stream, so the other 11
 columns' reference is taken here, from the files, while the benchmark sets up:
 per file and per column the row count, the non-null count and one wrapped

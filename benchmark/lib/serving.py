@@ -10,7 +10,6 @@ import sys
 import urllib.request
 from pathlib import Path
 
-from corpus import file_name
 from reference import expected, request_body
 
 
@@ -22,7 +21,7 @@ def start(ctx) -> None:
     root = str(Path(ctx.facts["paths"][0]).parent)
     device = ctx.device if ctx.config["serve"]["device"] else None
     ctx.server = ScanServer(ServeConfig(host="127.0.0.1", port=0, root=root, device=device)).start_background()
-    names = [file_name(i) for i in range(len(ctx.facts["files"]))]
+    names = [Path(p).name for p in ctx.facts["paths"]]
     answers = expected(ctx.facts, len(ctx.queries))
     ctx.requests = [{"body": request_body(q, names), "want": a} for q, a in zip(ctx.queries, answers)]
     for r in ctx.requests[: ctx.cell["warmup_requests"]]:

@@ -1,6 +1,6 @@
 """Bytes recorded on the program's decode_trace stages, summed over the named
-stages. A count: it repeats exactly. (No stage on today's upload path records
-bytes, so no metric uses this yet: PERF.md section 3, upload_bytes_per_row.)"""
+stages. A count: it repeats exactly. upload_bytes_per_row reads the
+`dispatch.upload` stages through it (PERF.md section 3)."""
 
 from per import scaled, stage_total
 

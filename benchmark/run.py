@@ -5,12 +5,15 @@
 One process: it holds the chip, sets the cell up (corpus from --seed, native
 build, warm-up of the cell's own shapes, full comparison with pyarrow),
 measures one window, checks every delivery or response, and prints the
-contract's one JSON object as the LAST line of stdout. This file knows no cell
-by name. Everything particular is found by name:
+contract's one JSON object as the LAST line of stdout; its last key,
+`compared`, holds every number the verdict compares beside its limit, and the
+last lines of stderr repeat them. This file knows no cell and no table by
+name. Everything particular is found by name:
 
     BENCHMARK.json                        cells, metrics, bounds
     benchmark/workloads/<cell>.json       the cell: config, traffic kind, parameters
     <config "file">                       the deployment: corpus, serve settings, guarantees
+    benchmark/corpora/<corpus.kind>.py    the table: file_name / write_file [/ rehearsal] (lib/corpus.py)
     benchmark/traffic/<kind>.py           setup(ctx) / window(ctx, seconds) / close(ctx) [/ queries(ctx)]
     benchmark/layer_metrics/<name>.json   one per-layer metric: reader kind + arguments
     benchmark/readers/<kind>.py           read(obs, **arguments) -> number or None
@@ -37,21 +40,17 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 sys.path[:0] = [str(HERE / "lib"), str(ROOT)]  # spawn workers inherit this
 
+from byname import load_by_name  # noqa: E402
+
 
 def say(msg: str) -> None:
     print(f"bench: {msg}", flush=True)
 
 
-def load_by_name(kind: str, name: str):
-    """benchmark/<kind>/<name>.py as a module: how traffic and reader kinds
-    are found, so a new kind is a new file."""
-    path = HERE / kind / f"{name}.py"
-    if not path.is_file():
-        raise SystemExit(f"bench: no {kind} kind {name!r} ({path} is missing)")
-    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def plain(counter: str) -> str:
+    """`events_total{event="host_decoded_pages"}` -> `host_decoded_pages`: a
+    counter's short name for the result's `compared`."""
+    return counter.partition('="')[2].rstrip('"}') or counter
 
 
 def applies(metric: dict, cell: str) -> bool:
@@ -89,19 +88,20 @@ def main() -> int:
     config_entry = next(c for c in bench["configs"] if c["name"] == entry["config"])
     config = json.loads((ROOT / config_entry["file"]).read_text())
     corpus = dict(config["corpus"])
+    kind = load_by_name("corpora", corpus["kind"])
     if args.rehearse:
         if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
             raise SystemExit("bench: --rehearse is the CPU rehearsal: set JAX_PLATFORMS=cpu")
-        scale = args.rehearse / corpus["row_group_rows"]
-        corpus.update(row_group_rows=args.rehearse, rows_per_file=3 * args.rehearse,
-                      nulls_per_group=int(corpus["nulls_per_group"] * scale),
-                      sum_rows=max(1, int(corpus["sum_rows"] * scale)))
+        if not hasattr(kind, "rehearsal"):
+            raise SystemExit(f"bench: corpus kind {corpus['kind']!r} has no rehearsal(spec, rows): "
+                             "it cannot be rehearsed")
+        corpus, scale = kind.rehearsal(corpus, args.rehearse)
         # a cell's own row counts (batch_rows, ...) shrink with the corpus
         cell.update((k, max(1, int(v * scale))) for k, v in cell.items() if k.endswith("_rows"))
     traffic = load_by_name("traffic", entry["traffic"])
     cache = HERE / ".cache"
     ctx = SimpleNamespace(
-        args=args, cell=cell, config=config, corpus=corpus, seed=args.seed,
+        args=args, cell=cell, config=config, corpus=corpus, corpus_kind=kind, seed=args.seed,
         trace=bool(args.trace), rehearsal=bool(args.rehearse), cache=cache, say=say,
     )
 
@@ -182,12 +182,16 @@ def main() -> int:
     finally:
         traffic.close(ctx)
 
-    problems = []
-    if in_window["requests"] and not ctx.rehearsal:  # a tiny corpus has other shapes than the cell's
-        problems.append(f"{in_window['requests']} compilation(s) inside the measured window")
+    # every number the verdict compares; each comparison is exact, so each limit is 0
+    problems, compared = [], {"failed": out["failed"]}
+    if not ctx.rehearsal:  # a tiny corpus has other shapes than the cell's
+        compared["compilations_in_window"] = in_window["requests"]
+        if in_window["requests"]:
+            problems.append(f"{in_window['requests']} compilation(s) inside the measured window")
     counters = {k: after.get(k, 0) - before.get(k, 0) for k in after
                 if isinstance(after[k], (int, float))}
     for key, why in cell.get("must_stay_zero", {}).items():
+        compared[plain(key)] = counters.get(key, 0)
         if counters.get(key, 0):
             problems.append(f"{key} rose by {counters[key]} in the window: {why}")
 
@@ -238,7 +242,10 @@ def main() -> int:
     for p in problems:
         say(f"NOT CORRECT: {p}")
     line["correct"] = not problems and out["failed"] == 0 and out["attempted"] > 0
+    line["compared"] = {name: {"value": v, "limit": 0} for name, v in compared.items()}
     print(json.dumps(line), flush=True)
+    for name, v in compared.items():  # the contract's last lines of standard error
+        print(f"bench: compared {name} = {v} (limit 0)", file=sys.stderr, flush=True)
     return 0
 
 

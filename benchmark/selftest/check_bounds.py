@@ -16,7 +16,11 @@ every cell that reports it:
            TOO LOOSE if it is over 8 x the widest `loose` over all cells,
            unless it is 1 % (never too loose);
   drift    |median of set 1 - median of set 0| / median of set 0, which may not
-           pass the bound (setup_s: only getting worse counts).
+           pass the bound (setup_s: only getting worse counts);
+  range    the wider of the two sets' (max - min) / median: what the driver's
+           note on an accepted PR holds against half of the bound ("the runs
+           spread by ... more than 50% of the bound", ledger, PR 31), so a bound
+           under 2 x range is TOO TIGHT as well.
 
 setup_s leaves out the first run of the records (the one that compiles) and is
 judged by drift alone; its bound is 0.25 by contract. Exits 1 if any pair
@@ -61,7 +65,7 @@ def main() -> int:
                     {k: v["value"] for k, v in r["line"]["metrics"].items()})
     rc = 0
     print(f"{'metric':<16}{'cell':<20}{'n':>6}{'median0':>14}{'median1':>14}{'spread0':>9}{'spread1':>9}"
-          f"{'tight':>8}{'loose':>8}{'drift':>8}")
+          f"{'tight':>8}{'loose':>8}{'drift':>8}{'range':>8}")
     for m in bench["end_to_end"]:
         name, bound = m["name"], m["bound"]
         cells = [w["name"] for w in bench["workloads"] if w["name"] in m.get("workloads", [w["name"]])]
@@ -84,9 +88,10 @@ def main() -> int:
             tight = (spread(trimmed(s0)) + spread(trimmed(s1))) / 2
             loose = max(sp0, sp1)
             drift = (m1 - m0) / m0
+            widest_range = max((max(s0) - min(s0)) / m0, (max(s1) - min(s1)) / m1)
             worse = drift if m["better"] == "lower" else -drift
             print(f"{name:<16}{cell:<20}{f'{len(s0)}+{len(s1)}':>6}{m0:>14.4f}{m1:>14.4f}{sp0:>9.2%}{sp1:>9.2%}"
-                  f"{tight:>8.2%}{loose:>8.2%}{drift:>+8.2%}")
+                  f"{tight:>8.2%}{loose:>8.2%}{drift:>+8.2%}{widest_range:>8.2%}")
             if name == "setup_s":
                 if worse > bound:
                     verdicts.append(f"{cell}: set 1's median is {worse:.1%} worse than set 0's")
@@ -94,6 +99,8 @@ def main() -> int:
             widest_loose = max(widest_loose, loose)
             if tight > bound / 2:
                 verdicts.append(f"TOO TIGHT in {cell}: tight spread {tight:.2%} > bound/2 = {bound / 2:.2%}")
+            if widest_range > bound / 2:
+                verdicts.append(f"TOO TIGHT in {cell}: range {widest_range:.2%} > bound/2 = {bound / 2:.2%}")
             if abs(drift) > bound:
                 verdicts.append(f"{cell}: the two sets' medians differ by {abs(drift):.2%} > bound")
         if name != "setup_s" and bound > max(8 * widest_loose, 0.01):

@@ -11,7 +11,7 @@
 2. dict_gather_device at 2^20 random indices: 32-bit and 64-bit entries at
    the cell's dictionary sizes (1, 2, 4, 4096, 16384) and the int64 tables of
    the 8-column cell (3, 7, 265).
-3. One month of the corpus (lib/corpus.py, --seed), column by column: prepare
+3. One month of the corpus (corpora/tlc_yellow_2023.py, --seed), column by column: prepare
    on the host, then dispatch + deliver + block_until_ready of the column's
    three row groups, timed; doubles="float32". Says which of the 19 streams
    cost what, which the traced cell's per-scope totals cannot.
@@ -100,16 +100,18 @@ def main() -> int:
     out["dict_gather_ms"] = gathers
 
     # 3. one month, column by column
-    from corpus import COLUMNS, file_name, write_file
+    from byname import load_by_name
 
     from parquet_tpu import FileReader
     from parquet_tpu.core.chunk import ChunkWindow, chunk_byte_range
     from parquet_tpu.kernels.pipeline import prepare_chunk_plan
 
     config = json.loads((ROOT / "benchmark" / "configs" / "tlc-year-wide.json").read_text())
+    kind = load_by_name("corpora", config["corpus"]["kind"])
+    COLUMNS, file_name, write_file = kind.COLUMNS, kind.file_name, kind.write_file
     spec = dict(config["corpus"], sum_columns=[])
-    if n != spec["row_group_rows"]:  # rehearsal
-        spec.update(row_group_rows=n, rows_per_file=3 * n, nulls_per_group=spec["nulls_per_group"] * n // (1 << 20))
+    if n != spec["row_group_rows"]:
+        spec, _ = kind.rehearsal(spec, n)
     columns = {}
     with tempfile.TemporaryDirectory() as d:
         write_file(spec, a.seed, 0, d, [])

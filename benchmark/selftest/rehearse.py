@@ -10,11 +10,13 @@
    under a device metric's name.
 2. The real command on this CPU (no --rehearse): exit code other than 0 and no
    result line.
-3. In a scratch copy (.bench_scratch/, git-ignored): a dummy configuration,
-   cell and per-layer metric are added as NEW files plus BENCHMARK.json
-   entries; the dummy cell runs and reports the dummy metric; and no file that
-   was there differs from the original. The cells that wait for their proof on
-   the chip (selftest/waiting/) are added the same way and rehearsed too.
+3. In a scratch copy (.bench_scratch/, git-ignored): a dummy corpus kind
+   (selftest/dummy/: another table, files of unequal row counts), a dummy
+   configuration that names it, a cell and a per-layer metric are added as
+   NEW files plus BENCHMARK.json entries; the dummy cell reads the
+   dummy table and reports the dummy metric; and no file that was there differs
+   from the original. The cells that wait for their proof on the chip
+   (selftest/waiting/) are added the same way and rehearsed too.
 Not under tests/: it takes a minute and needs no pytest.
 """
 
@@ -118,12 +120,9 @@ def main() -> int:
         (scratch / name).symlink_to(ROOT / name)
     before = digest_tree(scratch)
     first = bench["configs"][0]
-    config = json.loads((ROOT / first["file"]).read_text())
-    config["name"] = "dummy-config"
-    config["corpus"] = dict(config["corpus"], files=2)
-    (scratch / "benchmark/configs/dummy-config.json").write_text(json.dumps(config))
-    (scratch / "benchmark/workloads/dummy.cell.json").write_text(json.dumps(
-        {"name": "dummy.cell", "config": "dummy-config", "traffic": "stream_reader"}))
+    for name, where in (("parts_uneven.py", "corpora"), ("dummy-config.json", "configs"),
+                        ("dummy.cell.json", "workloads")):
+        shutil.copy(HERE / "dummy" / name, scratch / "benchmark" / where / name)
     (scratch / "benchmark/layer_metrics/dummy_calls_per_mrow.json").write_text(json.dumps(
         {"name": "dummy_calls_per_mrow", "reader": "stage_seconds", "args": {"stages": ["dispatch"], "per": "mrow"}}))
     grown = json.loads(json.dumps(bench))
@@ -146,7 +145,8 @@ def main() -> int:
     changed = [k for k in before if after.get(k) != before[k]]
     added = sorted(set(after) - set(before))
     assert not changed, f"files that were there changed: {changed}"
-    print(f"rehearse: a configuration, a cell and a per-layer metric added as new files only ({added}): ok")
+    print(f"rehearse: a corpus kind, a configuration, a cell and a per-layer metric added as new files only "
+          f"({added}): ok")
     shutil.rmtree(scratch, ignore_errors=True)
     return 0
 

@@ -4,7 +4,7 @@ the configuration, or do they follow the seed and the month?
     python benchmark/selftest/shapes_check.py [--config tlc-year-wide] [--seeds 7,11,3000000019]
                                               [--files 12] [--workers 6]
 
-Host only: the corpus writer (lib/corpus.py) and the program's prepare phase
+Host only: the configuration's corpus kind (benchmark/corpora/) and the program's prepare phase
 (kernels/pipeline.py prepare_chunk_plan: page walk, freeze, no dispatch, no
 device program). For every seed, file, row group and delivered column it takes
 the static part of what the chunk would dispatch — everything a jitted kernel's
@@ -20,7 +20,8 @@ and prints each column's distinct shapes with the number of chunks that had
 them. The harness warms up ONE file and fails a window that compiles, so a
 cell is safe only if every column has one shape over all months and seeds:
 exits 1 if a column has two, or if two seeds differ. Files are written one at
-a time into a scratch directory under benchmark/.cache/ and deleted.
+a time into a scratch directory under benchmark/.cache/, a directory a seed
+(two seeds' files of one month have one name), and deleted.
 """
 
 from __future__ import annotations
@@ -43,14 +44,17 @@ sys.path[:0] = [str(ROOT / "benchmark" / "lib"), str(ROOT)]
 def shapes_of_file(spec: dict, columns: list, doubles, seed: int, index: int, scratch: str) -> list:
     """[(column, shape)] over the row groups of month `index` of `seed`."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")  # prepare runs no device program
-    from corpus import file_name, write_file
+    from byname import load_by_name
 
     from parquet_tpu import FileReader
     from parquet_tpu.core.chunk import ChunkWindow, chunk_byte_range
     from parquet_tpu.kernels.pipeline import prepare_chunk_plan
 
-    write_file(dict(spec, sum_columns=[]), seed, index, scratch, [])
-    path = os.path.join(scratch, file_name(index))
+    kind = load_by_name("corpora", spec["kind"])
+    scratch = os.path.join(scratch, str(seed))
+    os.makedirs(scratch, exist_ok=True)
+    kind.write_file(dict(spec, sum_columns=[]), seed, index, scratch, [])
+    path = os.path.join(scratch, kind.file_name(index))
     out = []
     try:
         with FileReader(path) as r:
