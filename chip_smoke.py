@@ -58,6 +58,9 @@ KERNEL_LEGS = {
     "double_narrow_device": "decode",  # doubles="float32": fare, and the mixed file
     "predicate_mask_device": "decode",  # filter_rows=True
     "mask_take_device": "decode",
+    "pack_append_device": "decode",  # stops packed: iter_device_batches(lists="pack")
+    "pack_emit_device": "decode",
+    "pack_carry_device": "decode",
     "record_starts_device": "kernels",
     "list_layout_device": "kernels",
     "list_contains_mask_device": "kernels",
@@ -482,7 +485,27 @@ def leg_decode(args) -> dict:
               f"loader batch {k} differs from pyarrow")
     ds.close()
     out["loader"] = {"steps": 4, "columns": ds_cols}
-    say(f"batches, filter ({len(want_ids)} rows kept, counters {filt}) and loader agree with pyarrow")
+
+    # -- packed sequences: the LIST column as [sequences, seq_len] batches -------
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "reference_packed", ROOT / "benchmark" / "lib" / "reference_packed.py")
+    reference = importlib.util.module_from_spec(spec)  # numpy + pyarrow: the benchmark's plain reference
+    spec.loader.exec_module(reference)
+    seq_len, sequences = 2048, 16
+    want = reference.pack(pq.read_table(paths[2], columns=["stops"])["stops"], seq_len)
+    got = [[], [], []]
+    with FileReader(str(paths[2])) as r:
+        for b in r.iter_device_batches(sequences, columns=["stops"], lists="pack", seq_len=seq_len,
+                                       drop_remainder=False):
+            check(all(on_device(a) for a in b), "packed batch not on device")
+            for parts, a in zip(got, b):
+                parts.append(np.asarray(a))
+    check(all(np.array_equal(np.concatenate(parts), w) for parts, w in zip(got, want)),
+          "packed sequences (tokens, segment ids, positions) differ from the reference")
+    out["packed"] = {"sequences": int(want[0].shape[0]), "seq_len": seq_len, "batches": len(got[0])}
+    say(f"batches, filter ({len(want_ids)} rows kept, counters {filt}), loader and packed sequences agree with pyarrow")
 
     # -- leg 2: kernels the read path does not reach ---------------------------
     check({n for n in dops.__all__ if n.endswith("_device")} == set(KERNEL_LEGS),

@@ -49,6 +49,7 @@ Layout:
 
 __version__ = "0.1.0"
 
+from .core.packing import PackedBatch  # noqa: F401
 from .core.reader import FileReader, MaskedColumn, RaggedColumn  # noqa: F401
 from .ops.packed_levels import PackedLevels  # noqa: F401
 from .core.writer import FileWriter, WriterError  # noqa: F401

@@ -849,11 +849,13 @@ class FileReader:
                 )
         return out
 
-    def _plan_row_group_async(self, i: int, columns=None, device=None, doubles=None):
+    def _plan_row_group_async(
+        self, i: int, columns=None, device=None, doubles=None, list_lengths=False
+    ):
         """Stage one row group: prepare (pool or inline) + enqueue dispatch.
         Returns [(path, future-of-dispatched-plan)] without resolving."""
         return self._plan_row_groups_async(
-            [i], columns, device=device, doubles=doubles
+            [i], columns, device=device, doubles=doubles, list_lengths=list_lengths
         )[0]
 
     def iter_device_batches(
@@ -869,6 +871,7 @@ class FileReader:
         max_list_len: int | None = None,
         device=None,
         doubles=None,
+        seq_len: int | None = None,
     ):
         """Stream the file as fixed-size device-resident batches.
 
@@ -909,6 +912,28 @@ class FileReader:
                              for sequence data. Requires max_list_len; a row
                              exceeding it raises. Null and empty lists both
                              have length 0.
+          "pack"             yield PackedBatch(tokens, segment_ids,
+                             positions), each int32[batch_size, seq_len] and
+                             resident on the device: the ONE selected leaf —
+                             a single-level LIST of INT32 or INT64 elements,
+                             one document a row — as a token stream cut every
+                             seq_len elements (core/packing.py). Documents
+                             are concatenated in row order with nothing
+                             between them (a null or empty document adds
+                             nothing); a document cut by a sequence's end
+                             continues in the next sequence, across row
+                             groups too; only the file's last sequence is
+                             padded. segment_ids counts the pieces of a
+                             sequence from 1 (a piece starts at slot 0 and at
+                             every document's first token), positions
+                             restarts at 0 in every piece; padding reads 0 in
+                             all three. batch_size counts sequences: with
+                             drop_remainder=False the file's last batch has
+                             fewer. INT64 elements are delivered as their low
+                             32 bits. Requires seq_len; takes `device` and
+                             `sharding`, none of nullable=, filters=,
+                             max_list_len=, doubles=. No compiled shape
+                             follows a row group's element or document count.
 
         `filters` pushes a predicate (a (column, op, value) conjunction, or
         a list of lists — the OR-of-ANDs DNF convention) down to ROW-GROUP
@@ -944,8 +969,18 @@ class FileReader:
             raise ValueError("batch_size must be positive")
         if nullable not in ("error", "mask"):
             raise ValueError('nullable must be "error" or "mask"')
-        if lists not in ("error", "pad"):
-            raise ValueError('lists must be "error" or "pad"')
+        if lists not in ("error", "pad", "pack"):
+            raise ValueError('lists must be "error", "pad" or "pack"')
+        if (seq_len is not None) != (lists == "pack"):
+            raise ValueError('seq_len goes with lists="pack", and only with it')
+        if lists == "pack":
+            return self._iter_packed_batches(
+                batch_size, self._packed_leaf(
+                    columns, seq_len, nullable=nullable != "error", filters=filters,
+                    filter_rows=filter_rows, max_list_len=max_list_len, doubles=doubles,
+                ),
+                seq_len, drop_remainder, sharding, device,
+            )
         if lists == "pad":
             if max_list_len is None or max_list_len <= 0:
                 raise ValueError('lists="pad" requires a positive max_list_len')
@@ -995,32 +1030,22 @@ class FileReader:
                     "repetition levels; ragged batching covers single-level "
                     "LIST columns only"
                 )
-            rl = np.asarray(dc.rep_levels)
-            starts = np.nonzero(rl == 0)[0]
-            if dc.def_levels is not None:
-                dl = np.asarray(dc.def_levels)
-                present = dl == leaf.max_def
+            from ..ops.levels import LevelError, list_lengths
+
+            try:
+                lengths, elements = list_lengths(
+                    dc.rep_levels, dc.def_levels, leaf.max_def,
+                    leaf.repetition == FieldRepetitionType.OPTIONAL,
+                )
+            except LevelError as e:
                 # a null ELEMENT (optional leaf, def one below max) would
                 # silently left-shift its row's survivors — corruption for
                 # position-sensitive sequences, so refuse
-                if leaf.repetition == FieldRepetitionType.OPTIONAL and bool(
-                    (dl == leaf.max_def - 1).any()
-                ):
-                    raise ParquetFileError(
-                        f"parquet: column {'.'.join(path)} has null elements "
-                        "inside lists; ragged batching would shift positions "
-                        "(fill nulls upstream)"
-                    )
-            else:
-                present = np.ones(len(rl), dtype=bool)
-            # every row owns >= 1 level entry (null/empty lists carry one
-            # below-max entry), so reduceat over row starts counts elements
-            lengths = (
-                np.add.reduceat(present.astype(np.int32), starts)
-                if len(starts)
-                else np.zeros(0, dtype=np.int32)
-            )
-            if arr.shape[0] != int(present.sum()):
+                raise ParquetFileError(
+                    f"parquet: column {'.'.join(path)} has {e}; ragged "
+                    "batching would shift positions (fill nulls upstream)"
+                ) from e
+            if arr.shape[0] != elements:
                 raise ParquetFileError(
                     f"parquet: column {'.'.join(path)} level/value mismatch"
                 )
@@ -1170,10 +1195,112 @@ class FileReader:
                     pass
             yield carry
 
-    def _plan_row_groups_async(
-        self, indices, columns=None, device=None, doubles=None
+    def _packed_leaf(self, columns, seq_len, **unused):
+        """The one leaf lists="pack" batches, or the refusal — eager, like
+        every other argument of iter_device_batches."""
+        from ..meta.parquet_types import Type
+
+        given = sorted(k for k, v in unused.items() if v)
+        if given:
+            raise ValueError(f'lists="pack" takes no {", ".join(given)}')
+        if seq_len <= 0:
+            raise ValueError('lists="pack" requires a positive seq_len')
+        sel = self._resolve_columns(columns) if columns else self._selected
+        leaves = [lf for lf in self.schema.leaves if sel is None or lf.path in sel]
+        if len(leaves) != 1:
+            raise ValueError(
+                f'lists="pack" batches ONE leaf; {len(leaves)} are selected '
+                "(name the token column with columns=)"
+            )
+        leaf = leaves[0]
+        if leaf.max_rep != 1:
+            raise ParquetFileError(
+                f"parquet: column {leaf.path_str} has {leaf.max_rep} "
+                "repetition levels; sequence packing covers single-level "
+                "LIST columns only"
+            )
+        if leaf.type not in (Type.INT32, Type.INT64):
+            raise ParquetFileError(
+                f"parquet: column {leaf.path_str} holds {Type(leaf.type).name} "
+                "elements; sequence packing covers INT32 and INT64 token ids"
+            )
+        return leaf
+
+    def _iter_packed_batches(
+        self, batch_size: int, leaf, seq_len: int, drop_remainder: bool,
+        sharding=None, device=None,
     ):
-        """Stage chunks of several row groups at once.
+        """lists="pack": the row groups' decoded ids, delivered at their
+        padded lengths, go through one SequencePacker (core/packing.py) with
+        the same one-group lookahead as _iter_device_batches. The packer's
+        launches are the deliver.pack stage, inside deliver."""
+        import jax
+
+        from .packing import PackedBatch, SequencePacker
+
+        columns = [leaf.path]
+        lookahead = self.alloc is None  # see _iter_device_batches
+
+        def plan_of(i, staged):
+            if staged is not None:
+                return staged[0][1].result()
+            return self._plan_row_group(
+                i, columns, device=device, list_lengths=True
+            )[leaf.path]
+
+        def stage_group(i):
+            return self._plan_row_group_async(
+                i, columns, device=device, list_lengths=True
+            )
+
+        def placed(batch):
+            return batch if sharding is None else jax.device_put(batch, sharding)
+
+        packer = SequencePacker(batch_size, seq_len)
+        groups = range(self.num_row_groups)
+        staged_next = stage_group(0) if lookahead and groups else None
+        for i in groups:
+            # device work scoped so the pin never leaks across a yield
+            with self._devctx(device):
+                staged = staged_next
+                if lookahead:
+                    staged_next = stage_group(i + 1) if i + 1 < len(groups) else None
+                plan = plan_of(i, staged)
+                with stage("deliver", args=_chunk_args(i, leaf.path)):
+                    values, count = plan.device_values_padded()
+                    with stage("deliver.pack"):
+                        packer.append(values, plan.dev_lengths, plan.list_lengths, count)
+            while packer.ready():
+                with self._devctx(device), stage("deliver"), stage("deliver.pack"):
+                    batch = placed(packer.emit())
+                yield batch
+        sequences = packer.sequences_left()
+        if not sequences or (drop_remainder and sequences < batch_size):
+            return
+        with self._devctx(device), stage("deliver"), stage("deliver.pack"):
+            batch = packer.tail()
+            if sequences == batch_size:
+                batch = placed(batch)
+            else:
+                # the short last batch is cut on the host: a device slice is
+                # a compiled program a remainder, and the remainder is data.
+                # Once a file, at most batch_size * seq_len * 12 bytes
+                batch = PackedBatch(*(
+                    jax.device_put(np.asarray(a)[:sequences], a.sharding) for a in batch
+                ))
+                if sharding is not None:
+                    try:
+                        batch = jax.device_put(batch, sharding)
+                    except ValueError:
+                        pass  # not divisible over the mesh axis: as _iter_device_batches
+        yield batch
+
+    def _plan_row_groups_async(
+        self, indices, columns=None, device=None, doubles=None, list_lengths=False
+    ):
+        """Stage chunks of several row groups at once. `list_lengths`
+        prepares the padded delivery of a LIST leaf (lists="pack":
+        kernels/pipeline.py prepare_chunk_plan).
 
         Every chunk's prepare is submitted to the worker pool up front (no
         per-group barrier — the pool never drains between groups); device
@@ -1202,6 +1329,7 @@ class FileReader:
                     validate_crc=self.validate_crc,
                     alloc=self.alloc,
                     doubles=doubles,
+                    list_lengths=list_lengths,
                 )
 
         dev = self._effective_device(device)
@@ -1251,7 +1379,9 @@ class FileReader:
             for i, chunks in prep_futs
         ]
 
-    def _plan_row_group(self, i: int, columns=None, device=None, doubles=None):
+    def _plan_row_group(
+        self, i: int, columns=None, device=None, doubles=None, list_lengths=False
+    ):
         """Plan every selected chunk of a row group for device decode.
 
         The host-only prepare phase (one pread per chunk, page walk,
@@ -1263,7 +1393,7 @@ class FileReader:
         return {
             path: fut.result()
             for path, fut in self._plan_row_group_async(
-                i, columns, device=device, doubles=doubles
+                i, columns, device=device, doubles=doubles, list_lengths=list_lengths
             )
         }
 

@@ -110,6 +110,9 @@ __all__ = [
     "double_narrow_device",
     "list_layout_device",
     "record_starts_device",
+    "pack_append_device",
+    "pack_emit_device",
+    "pack_carry_device",
     "predicate_mask_device",
     "list_contains_mask_device",
     "mask_take_device",
@@ -392,7 +395,7 @@ class FrozenHybrid(NamedTuple):
 
 
 def pack_hybrid_upload(
-    is_rle, counts, rle_values, bit_starts, packed, width: int
+    is_rle, counts, rle_values, bit_starts, packed, width: int, dense: bool = False
 ) -> FrozenHybrid:
     """The upload expand_hybrid_device reads, from one row per run: `counts`
     values each (already clamped: the runs produce exactly the values wanted,
@@ -413,13 +416,22 @@ def pack_hybrid_upload(
       buf[2*run_pad:3*run_pad]  rle_value
       buf[3*run_pad:4*run_pad]  bit_start   (int32)
       buf[4*run_pad:]           payload words + 1 guard word, padded to
-                                _bucket(words, 1024)"""
+                                _bucket(words, 1024)
+    `dense` (the padded delivery, kernels/pipeline.py: a chunk whose counts
+    are data and must not reach a compiled shape) floors both buckets at
+    what n_pad values can need, so that the shape is a function of (width,
+    n_pad) for every stream that is mostly bit-packed: run_pad at n_pad / 256
+    (pyarrow writes bit-packed runs of 504 values; only a stream whose runs
+    average under 256 values leaves the floor) and the payload at n_pad
+    values of `width` bits plus 1,024 words (the guard word and the up to
+    7 values by which each page's last group overshoots)."""
     k = len(counts)
     total = int(np.sum(counts))
     n_pad = _bucket(max(total, 1))
-    run_pad = _bucket(k, 64)
     words = bytes_to_words32(bytes(packed))
-    buf = np.zeros(4 * run_pad + _bucket(len(words), 1024), dtype=np.uint32)
+    run_floor, words_floor = (n_pad >> 8, n_pad * width // 32 + 1024) if dense else (0, 0)
+    run_pad = _bucket(k, max(64, run_floor))
+    buf = np.zeros(4 * run_pad + _bucket(len(words), max(1024, words_floor)), dtype=np.uint32)
     buf[run_pad : 2 * run_pad] = np.int32(n_pad + 1).view(np.uint32)
     out_start = np.zeros(k, dtype=np.int64)
     np.cumsum(counts[:-1], out=out_start[1:])
@@ -705,6 +717,132 @@ def list_layout_device(
         .add(jnp.where(boundary, dfl, 0).astype(jnp.int32))
     )
     return offsets, first_def, jnp.sum(boundary.astype(jnp.int32))
+
+
+# -- sequence packing: a LIST<int> leaf as fixed [sequences, seq_len] batches ----
+#
+# The stream of a token corpus is every document's elements in row order; a
+# training step wants it cut every seq_len tokens, with segment ids and
+# positions that restart at document boundaries. The packer's state is a
+# carry of fewer than `span` = batch * seq_len tokens and their start flags;
+# a row group appends to it (pack_append_device), whole batches are cut out
+# of the work buffers it returns (pack_emit_device) and what is left becomes
+# the next carry (pack_carry_device). No shape below follows the data: the
+# values come at their bucketed length, the lengths at theirs, and every
+# count is a runtime scalar. Integer arithmetic only. core/packing.py drives
+# the three; benchmark/lib/reference_packed.py states the semantics in numpy.
+
+
+def _scan(x: jnp.ndarray, op) -> jnp.ndarray:
+    """Inclusive scan of a 1-D array of non-negative int32 under `op`
+    (jnp.add or jnp.maximum; 0 is the identity of both here), in prefix_sum's
+    two levels, but each level by doubling: log2(row) steps of `x = op(x, x
+    shifted right by k)`. Every step is an elementwise op on a slice, so the
+    whole scan stays under the caller's named scope in the device trace;
+    jnp.cumsum / lax.cummax lower to reduce_window, which XLA:TPU rewrites
+    into ops that carry no scope at all (PERF.md section 3), and the packer's
+    time would be read at a fifth of what it is. Twenty passes over a batch
+    of 2^19 slots instead of a windowed reduction's few, and faster on a v5e:
+    pack_emit_device 0.053 ms a batch against 0.28 with prefix_sum and a
+    two-level lax.cummax (PERF.md section 6, PR 33). A second scan beside
+    prefix_sum until one of them is measured in the other's callers and goes
+    (ROADMAP 3.19)."""
+    n = x.shape[0]
+    if n > _SCAN_BLOCK:
+        pad = (-n) % _SCAN_BLOCK
+        if pad:
+            x = jnp.concatenate([x, jnp.zeros(pad, x.dtype)])
+        x = x.reshape(-1, _SCAN_BLOCK)
+    k = 1
+    while k < x.shape[-1]:
+        x = op(x, jnp.concatenate([jnp.zeros_like(x[..., :k]), x[..., :-k]], axis=-1))
+        k *= 2
+    if x.ndim == 1:
+        return x
+    totals = _scan(x[:, -1], op)
+    before = jnp.concatenate([jnp.zeros(1, x.dtype), totals[:-1]])
+    return op(x, before[:, None]).reshape(-1)[:n]
+
+
+@jax.jit
+@jax.named_scope("pqt.pack_sequences")
+def pack_append_device(
+    carry_tokens: jnp.ndarray,  # int32[span]: the stream's tail not yet emitted
+    carry_flags: jnp.ndarray,  # int32[span]: 1 where a document starts
+    values: jnp.ndarray,  # int32|int64[n_pad]: a row group's elements, padded
+    lengths: jnp.ndarray,  # int32[d_pad]: its documents' lengths, zero-padded
+    carry_n,  # int32 scalar: valid slots of the carry (< span)
+) -> tuple:
+    """Append one row group to the packer's carry: (tokens, flags), two
+    int32[2 * span + n_pad] work buffers whose first carry_n + sum(lengths)
+    slots are the stream so far. The padded values land at carry_n in one
+    dynamic_update_slice; what they carry past their true count is garbage
+    that the next append overwrites or the last emit masks, so nothing ever
+    reads a slot at or past the fill. A document's first token is flagged by
+    one scatter-add at carry_n + the exclusive prefix sum of the lengths;
+    empty (and null) documents add a 0 at their successor's start. The
+    buffers leave `span` slots of room past the furthest fill, so that the
+    carry can be sliced out at any whole-batch offset without clamping."""
+    span = carry_tokens.shape[0]
+    room = span + values.shape[0]
+    with jax.named_scope("append"):
+        tokens = jnp.concatenate([carry_tokens, jnp.zeros(room, jnp.int32)])
+        tokens = jax.lax.dynamic_update_slice(
+            tokens, values.astype(jnp.int32), (carry_n,)
+        )
+    with jax.named_scope("flags"):
+        kept = jnp.where(jnp.arange(span, dtype=jnp.int32) < carry_n, carry_flags, 0)
+        starts = carry_n + _scan(lengths, jnp.add) - lengths
+        flags = (
+            jnp.concatenate([kept, jnp.zeros(room, jnp.int32)])
+            .at[starts]
+            .add((lengths > 0).astype(jnp.int32), mode="drop", indices_are_sorted=True)
+        )
+    return tokens, flags
+
+
+@partial(jax.jit, static_argnames=("batch", "seq_len"))
+@jax.named_scope("pqt.pack_sequences")
+def pack_emit_device(
+    tokens: jnp.ndarray, flags: jnp.ndarray, offset, n_valid, batch: int, seq_len: int
+) -> tuple:
+    """One batch out of pack_append_device's work buffers: (tokens,
+    segment_ids, positions), each int32[batch, seq_len], from the
+    batch * seq_len slots at `offset`; slots at or past n_valid (the file's
+    last, padded sequence) read 0 in all three. A piece starts at every
+    sequence's slot 0 and at every flagged slot: segment_ids counts the
+    piece starts of its sequence up to the slot (>= 1 on a real token),
+    positions is the distance to the latest one. Both are scans over the
+    flat batch (_scan) — every sequence opens a piece, so neither leaks
+    across sequences: a prefix sum of the starts, rebased per sequence, and
+    a running maximum of the starts' own slot numbers."""
+    span = batch * seq_len
+    with jax.named_scope("emit"):
+        t = jax.lax.dynamic_slice(tokens, (offset,), (span,))
+        f = jax.lax.dynamic_slice(flags, (offset,), (span,))
+        i = jnp.arange(span, dtype=jnp.int32)
+        start = (f != 0) | (i % seq_len == 0)
+        count = _scan(start.astype(jnp.int32), jnp.add).reshape(batch, seq_len)
+        segment = count - count[:, :1] + 1
+        position = i - _scan(jnp.where(start, i, 0), jnp.maximum)
+        valid = i < n_valid
+        return (
+            jnp.where(valid, t, 0).reshape(batch, seq_len),
+            jnp.where(valid.reshape(batch, seq_len), segment, 0),
+            jnp.where(valid, position, 0).reshape(batch, seq_len),
+        )
+
+
+@partial(jax.jit, static_argnames=("span",))
+@jax.named_scope("pqt.pack_sequences")
+def pack_carry_device(tokens: jnp.ndarray, flags: jnp.ndarray, offset, span: int) -> tuple:
+    """What the emitted batches left of the work buffers, as the next
+    append's carry: the `span` slots at `offset` of both."""
+    with jax.named_scope("carry"):
+        return (
+            jax.lax.dynamic_slice(tokens, (offset,), (span,)),
+            jax.lax.dynamic_slice(flags, (offset,), (span,)),
+        )
 
 
 # -- query push-down: predicate -> mask -> gather, device-resident --------------

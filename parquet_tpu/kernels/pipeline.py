@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from ..meta import ParquetFileError
-from ..meta.parquet_types import Encoding, PageType, Type
+from ..meta.parquet_types import Encoding, FieldRepetitionType, PageType, Type
 from ..core.alloc import decoded_nbytes
 from ..core.arrays import ByteArrayData
 from ..core.chunk import ChunkData, ChunkError, iter_chunk_pages, _check_crc
@@ -93,6 +93,10 @@ __all__ = [
 
 # Patchable in tests to force multi-batch splitting on small inputs.
 _BATCH_BITS_CAP = MAX_DEVICE_BATCH_BITS
+# Floor of the bucket a LIST leaf's per-document lengths upload at (the padded
+# delivery): 16 KB, so that a row group's document count, which is data, stays
+# in one bucket unless its documents average under 256 elements a 2^20 of them.
+_LENGTHS_FLOOR = 4096
 
 
 # -- the dispatch thread -------------------------------------------------------
@@ -360,15 +364,17 @@ _NUMERIC_DTYPE = {
 # pack_delta_upload): nothing in this module indexes into them.
 
 
-def _dispatch_hybrid(frozen: FrozenHybrid) -> jnp.ndarray:
+def _dispatch_hybrid(frozen: FrozenHybrid, padded: bool = False) -> jnp.ndarray:
+    """`padded` keeps the kernel's n_pad output whole (the positions past
+    `total` carry garbage): the exact-length slice is a program a length."""
     with _trace.stage("dispatch.upload", frozen.buf.nbytes):
         buf = jnp.asarray(frozen.buf)
     with _trace.stage("dispatch.launch"):
         dev = expand_hybrid_device(buf, frozen.width, frozen.n_pad, frozen.run_pad)
-        return dev[: frozen.total]
+        return dev if padded else dev[: frozen.total]
 
 
-def _dispatch_delta(frozen: FrozenDelta) -> jnp.ndarray:
+def _dispatch_delta(frozen: FrozenDelta, padded: bool = False) -> jnp.ndarray:
     with _trace.stage("dispatch.upload", frozen.meta32.nbytes + frozen.wide.nbytes):
         meta32 = jnp.asarray(frozen.meta32)
         wide = jnp.asarray(frozen.wide)
@@ -376,7 +382,15 @@ def _dispatch_delta(frozen: FrozenDelta) -> jnp.ndarray:
         dev = delta_packed_decode_device(
             meta32, wide, frozen.nbits, frozen.n_pad, frozen.m_pad, frozen.p_pad
         )
-        return dev[: frozen.total]
+        return dev if padded else dev[: frozen.total]
+
+
+def _pad_host(host: np.ndarray, floor: int = 1024) -> np.ndarray:
+    """A host array zero-padded to its power-of-two bucket before it uploads
+    (the padded delivery: the array's length must not reach a compiled shape)."""
+    out = np.zeros(_bucket(max(len(host), 1), floor), dtype=host.dtype)
+    out[: len(host)] = host
+    return out
 
 
 # -- the chunk plan ------------------------------------------------------------
@@ -454,9 +468,15 @@ class DeviceColumn:
 class _ChunkPlan:
     """Host-side record of one chunk's in-flight device decode."""
 
-    def __init__(self, column: Column, expected: int, doubles: str | None = None):
+    def __init__(
+        self, column: Column, expected: int, doubles: str | None = None, padded: bool = False
+    ):
         self.column = column
         self.expected = expected
+        # the padded delivery (prepare_chunk_plan list_lengths=True): every
+        # upload at a bucket that the chunk's counts cannot move within the
+        # row group's budget, every kernel output left at its padded length
+        self.padded = padded
         # the delivered form of a DOUBLE column (DOUBLE_FORMS), else None
         self.doubles = doubles if column.type == Type.DOUBLE else None
         # under doubles=: the dictionary as it uploads (bit patterns, or
@@ -484,6 +504,17 @@ class _ChunkPlan:
         self.bss_host: list[tuple] = []
         self.dev_bss: list[tuple] = []  # [(device streams, num_values)]
         self.host_pages = 0  # pages whose values decoded on the host
+        # the padded delivery of a single-level LIST leaf (prepare_chunk_plan
+        # list_lengths=True, for lists="pack"): the record structure as
+        # per-document element counts, O(documents), uploaded at a bucketed
+        # length in place of the level streams; values stay at their kernels'
+        # padded lengths (device_values_padded) with the counts as host ints
+        self.list_lengths: np.ndarray | None = None
+        self.list_elements = 0
+        self.dev_lengths: jnp.ndarray | None = None
+        # true counts of the device arrays a padded dispatch left unsliced:
+        # [plain, [hybrid batches], [delta batches]]
+        self.padded_totals: list = [0, [], []]
         self._dispatched = False
 
     # -- device dispatch (async; nothing synchronizes here) --------------------
@@ -499,7 +530,16 @@ class _ChunkPlan:
         if self._dispatched:
             return self
         self._dispatched = True
+        padded = self.padded
         d = self.dictionary if self.dict_upload is None else self.dict_upload
+        if padded and self.dict_upload is None and isinstance(d, np.ndarray) and d.ndim == 1:
+            d = _pad_host(d)
+        if padded:
+            lengths = _pad_host(self.list_lengths, _LENGTHS_FLOOR)
+            with _trace.stage("dispatch.upload", lengths.nbytes):
+                self.dev_lengths = jnp.asarray(lengths)
+            _metrics.event("list_structure_upload_bytes", lengths.nbytes)
+            _trace.count("list_structure_upload_bytes", lengths.nbytes)
         if self.frozen_hybrid and isinstance(d, np.ndarray) and d.ndim == 1:
             # Upload the dictionary only when device-decoded indices will
             # gather against it (device_column); host reassembly gathers on
@@ -513,8 +553,10 @@ class _ChunkPlan:
         # Homogeneous PLAIN numeric chunks are pure uploads (buffer already
         # concatenated at prepare time).
         if self.plain_host is not None:
-            with _trace.stage("dispatch.upload", self.plain_host.nbytes):
-                self.dev_plain = self._upload(self.plain_host)
+            host = _pad_host(self.plain_host) if padded else self.plain_host
+            with _trace.stage("dispatch.upload", host.nbytes):
+                self.dev_plain = self._upload(host)
+            self.padded_totals[0] = len(self.plain_host)
             self.plain_host = None
         for streams, nv in self.bss_host:
             with _trace.stage("dispatch.upload", streams.nbytes):
@@ -525,12 +567,14 @@ class _ChunkPlan:
         self.bss_host = []
         stats = self.stats
         for frozen in self.frozen_hybrid:
-            self.dev_hybrid.append(_dispatch_hybrid(frozen))
+            self.dev_hybrid.append(_dispatch_hybrid(frozen, padded))
+            self.padded_totals[1].append(frozen.total)
             if stats is not None:
                 stats.device_values += frozen.total
                 stats.device_batches += 1
         for frozen in self.frozen_delta:
-            self.dev_delta.append(_dispatch_delta(frozen))
+            self.dev_delta.append(_dispatch_delta(frozen, padded))
+            self.padded_totals[2].append(frozen.total)
             if stats is not None:
                 stats.device_values += frozen.total
                 stats.device_batches += 1
@@ -812,6 +856,37 @@ class _ChunkPlan:
             out.values = self._typed(self._upload(np.asarray(data.values)))
         return out
 
+    def device_values_padded(self) -> tuple:
+        """The padded delivery (prepare_chunk_plan list_lengths=True): (values,
+        count) with `values` the chunk's non-null values in HBM at a
+        power-of-two length and only the first `count` of them real. What
+        device_column does to hand over an exact-length array — the slice
+        after the decode kernel, and every program downstream of it — is a
+        compiled program for every count, and a chunk's count is data; here
+        the decode kernel's own padded output goes on whole (index widening
+        and dictionary gather included, at the bucket), and the consumer
+        takes the count as a runtime value. A chunk shape with more than one
+        device array (several batches, mixed dictionary and PLAIN pages, host
+        decoded pages) is delivered exactly first and padded after: correct,
+        and a program a count — so it is counted, as the event
+        padded_delivery_exact_chunks, for a cell or a test to pin at 0."""
+        kinds = {k for _, _, _, k, _ in self.page_infos if k != "empty"}
+        count = self.list_elements
+        if kinds == {"dict"} and len(self.dev_hybrid) == 1 and self.dict_dev is not None:
+            return self._typed(dict_gather_device(self.dict_dev, self._dev_indices())), count
+        if kinds == {"delta"} and len(self.dev_delta) == 1:
+            return self.dev_delta[0], count
+        if kinds == {"values"} and self.dev_plain is not None:
+            return self._typed(self.dev_plain), count
+        _metrics.event("padded_delivery_exact_chunks")
+        _trace.count("padded_delivery_exact_chunks")
+        plain, hybrid, delta = self.padded_totals
+        if self.dev_plain is not None:
+            self.dev_plain = self.dev_plain[:plain]
+        self.dev_hybrid = [d[:n] for d, n in zip(self.dev_hybrid, hybrid)]
+        self.dev_delta = [d[:n] for d, n in zip(self.dev_delta, delta)]
+        return _pad_device(self.device_column().values), count
+
     # -- the delivered form of a value array -----------------------------------
     #
     # Unsigned bit patterns become typed values in exactly two places,
@@ -965,7 +1040,7 @@ _PC_EXTRA, _PC_DFIRST = 16, 17
 _PC_COLS = 18
 
 
-def _native_prepare(f, chunk, column, validate_crc, alloc, stats, doubles=None):
+def _native_prepare(f, chunk, column, validate_crc, alloc, stats, doubles=None, padded=False):
     """Whole-chunk native prepare: ONE GIL-free C call walks every page
     (header parse, CRC verify when validate_crc, decompress, level decode,
     value prescan) and returns packed tables; batch assembly is then a
@@ -985,7 +1060,7 @@ def _native_prepare(f, chunk, column, validate_crc, alloc, stats, doubles=None):
     prepare_fused_declined counters and the walk's internal stage split
     lands in prepare.* stages."""
     plan, fault = _native_prepare_impl(
-        f, chunk, column, validate_crc, alloc, stats, doubles
+        f, chunk, column, validate_crc, alloc, stats, doubles, padded
     )
     if plan is None:
         _trace.bump("prepare_fused_declined")
@@ -996,7 +1071,9 @@ def _native_prepare(f, chunk, column, validate_crc, alloc, stats, doubles=None):
     return plan, fault
 
 
-def _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats, doubles=None):
+def _native_prepare_impl(
+    f, chunk, column, validate_crc, alloc, stats, doubles=None, padded=False
+):
     if alloc is not None:
         # a memory ceiling needs the per-page accounting only the staged
         # walk performs (validate_crc, by contrast, is fused natively)
@@ -1072,7 +1149,7 @@ def _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats, doubles=N
         )
     try:
         plan = _plan_from_tables(
-            column, expected, res, stats, np_dt, delta_nbits, doubles
+            column, expected, res, stats, np_dt, delta_nbits, doubles, padded
         )
     except (PageError, ChunkError):
         raise
@@ -1106,8 +1183,10 @@ def _native_prepare_impl(f, chunk, column, validate_crc, alloc, stats, doubles=N
     return plan, None
 
 
-def _plan_from_tables(column, expected, res, stats, np_dt, delta_nbits, doubles=None):
-    plan = _ChunkPlan(column, expected, doubles)
+def _plan_from_tables(
+    column, expected, res, stats, np_dt, delta_nbits, doubles=None, padded=False
+):
+    plan = _ChunkPlan(column, expected, doubles, padded)
     plan.stats = stats
     pages = res["pages"].tolist()
     values_buf = res["values"]
@@ -1255,7 +1334,8 @@ def _plan_from_tables(column, expected, res, stats, np_dt, delta_nbits, doubles=
         # device run batches, PLAIN pages ride the contiguous raw upload,
         # and device_column merges in page order.
         frozen = _freeze_hybrid_from_tables(
-            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0
+            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0,
+            plan.padded,
         )
         if frozen is not None:
             plan.frozen_hybrid = frozen
@@ -1327,7 +1407,8 @@ def _plan_from_tables(column, expected, res, stats, np_dt, delta_nbits, doubles=
         # (native byte_array_gather) and device_column's ragged merge joins
         # both in output-index space.
         frozen = _freeze_hybrid_from_tables(
-            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0
+            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0,
+            plan.padded,
         )
         if frozen is not None:
             from ..core.page import _decode_values
@@ -1485,15 +1566,17 @@ def _repack_pages_to_width(pages: list, res: dict, width: int):
     return out, is_rle, byteoff, packed
 
 
-def _freeze_hybrid_from_tables(data_pages, res, n_dict: int = 0) -> list | None:
+def _freeze_hybrid_from_tables(
+    data_pages, res, n_dict: int = 0, dense: bool = False
+) -> list | None:
     """THE freeze of a dictionary chunk's index pages, from the whole-chunk
     run tables of the native walk (the staged walk lays its prescans out the
     same way: _hybrid_tables_of). A chunk ships at ONE index width
     (_index_width): pages written narrower are re-packed to it first, so the
     compiled shapes do not follow where the dictionary crossed a power of
     two. Pages group sequentially under the bit cap, one upload a group
-    (device_ops.pack_hybrid_upload); returns None when a single page exceeds
-    the cap (the caller demotes the chunk)."""
+    (device_ops.pack_hybrid_upload, which says what `dense` floors); returns
+    None when a single page exceeds the cap (the caller demotes the chunk)."""
     cap = _BATCH_BITS_CAP
     pages = [P for P in data_pages if P[_PC_ROUTE] == 1]
     h_is_rle = res["h_is_rle"]
@@ -1522,7 +1605,7 @@ def _freeze_hybrid_from_tables(data_pages, res, n_dict: int = 0) -> list | None:
     return [
         pack_hybrid_upload(
             h_is_rle[rs:re], res["h_counts"][rs:re], res["h_values"][rs:re],
-            (h_byteoff[rs:re] - ps) * 8, packed_all[ps:pe], width,
+            (h_byteoff[rs:re] - ps) * 8, packed_all[ps:pe], width, dense,
         )
         for rs, re, ps, pe, _bits in groups
     ]
@@ -1677,9 +1760,13 @@ def prepare_chunk_plan(
     alloc=None,
     stats: TpuDecodeStats | None = None,
     doubles: str | None = None,
+    list_lengths: bool = False,
 ) -> _ChunkPlan:
     """Host-only prepare: page walk, decompress, level decode, prescan.
     `doubles` (DOUBLE_FORMS) is the form a DOUBLE column is bound for.
+    `list_lengths` prepares the padded delivery of a single-level LIST leaf
+    (_ChunkPlan.device_values_padded): its record structure is derived here,
+    once, as per-document element counts.
 
     Touches no jax state, so it is safe to run on worker threads; the
     returned plan's batches go to the device via plan.dispatch_device() on
@@ -1695,11 +1782,13 @@ def prepare_chunk_plan(
 
 
     plan, fault = _native_prepare(
-        f, chunk, column, validate_crc, alloc, stats, doubles
+        f, chunk, column, validate_crc, alloc, stats, doubles, list_lengths
     )
     if plan is None:
         t0 = _time.perf_counter()
-        plan = _staged_prepare(f, chunk, column, validate_crc, alloc, stats, doubles)
+        plan = _staged_prepare(
+            f, chunk, column, validate_crc, alloc, stats, doubles, list_lengths
+        )
         _metrics.observe("chunk_decode_seconds", _time.perf_counter() - t0)
         if fault is not None:
             # the native walk aborted but the staged walk decoded cleanly
@@ -1720,7 +1809,50 @@ def prepare_chunk_plan(
         _trace.count(f"host_decoded_pages.{column.path_str}", plan.host_pages)
     if plan.doubles is not None:
         _shape_double_dictionary(plan)
+    if list_lengths:
+        _shape_list_lengths(plan)
     return plan
+
+
+def _shape_list_lengths(plan: _ChunkPlan) -> None:
+    """The record structure of a single-level LIST leaf as it uploads under
+    the padded delivery: one int32 element count a document (ops/levels.
+    list_lengths), O(documents) where the two level streams are O(elements).
+    Clocked as prepare.levels.lengths, beside the native walk's
+    prepare.levels: together the host's share of the Dremel half."""
+    import time as _time
+
+    from ..ops.levels import LevelError, list_lengths
+
+    column = plan.column
+    t0 = _time.perf_counter()
+    if plan.native_rep is not None or plan.native_def is not None:
+        rep, dfl = plan.native_rep, plan.native_def
+    else:
+        reps = [r for _, _, r, _, _ in plan.page_infos if r is not None]
+        defs = [d for _, d, _, _, _ in plan.page_infos if d is not None]
+        rep = np.concatenate(reps) if reps else np.zeros(0, dtype=np.uint16)
+        dfl = np.concatenate(defs) if defs else None
+    try:
+        plan.list_lengths, plan.list_elements = list_lengths(
+            rep, dfl, column.max_def,
+            column.repetition == FieldRepetitionType.OPTIONAL,
+        )
+    except LevelError as e:
+        raise ParquetFileError(
+            f"parquet: column {column.path_str} has {e}; packing would shift "
+            "positions (fill nulls upstream)"
+        ) from e
+    decoded = sum(
+        (len(p) if k in ("values", "indices") else p)
+        for _, _, _, k, p in plan.page_infos
+        if k != "empty"
+    )
+    if decoded != plan.list_elements:
+        raise ParquetFileError(
+            f"parquet: column {column.path_str} level/value mismatch"
+        )
+    _trace.add_seconds("prepare.levels.lengths", _time.perf_counter() - t0)
 
 
 def _shape_double_dictionary(plan: _ChunkPlan) -> None:
@@ -1754,12 +1886,13 @@ def _staged_prepare(
     alloc=None,
     stats: TpuDecodeStats | None = None,
     doubles: str | None = None,
+    padded: bool = False,
 ) -> _ChunkPlan:
     """The per-page Python prepare walk (the error-semantics reference)."""
     md = chunk.meta_data
     codec = md.codec or 0
     expected = md.num_values or 0
-    plan = _ChunkPlan(column, expected, doubles)
+    plan = _ChunkPlan(column, expected, doubles, padded)
     plan.stats = stats
     ptype = column.type
 
@@ -1912,6 +2045,7 @@ def _commit_routes(plan: _ChunkPlan, pending: list) -> None:
         frozen = _freeze_hybrid_from_tables(
             *_hybrid_tables_of(pending),
             len(plan.dictionary) if plan.dictionary is not None else 0,
+            plan.padded,
         )
         if frozen is not None:
             plan.frozen_hybrid = frozen

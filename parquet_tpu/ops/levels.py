@@ -25,6 +25,7 @@ __all__ = [
     "rows_from_rep",
     "slot_ids",
     "list_layout",
+    "list_lengths",
     "validity_from_def",
 ]
 
@@ -180,6 +181,32 @@ def list_layout(rep, dfl, slot_of, n_slots: int, elem_rep: int, elem_def: int):
     offsets = np.zeros(n_slots + 1, dtype=np.int64)
     np.cumsum(counts, out=offsets[1:])
     return offsets, elem_start, exists
+
+
+def list_lengths(rep, dfl, max_def: int, optional_leaf: bool):
+    """Per-record element counts of a single-level LIST leaf (max_rep == 1)
+    from its level streams: (lengths int32[records], n_elements). A record
+    starts at every rep == 0 entry and owns at least one entry (a null or
+    empty list carries one entry below max_def), so a reduceat over the
+    record starts counts the entries at max_def: the elements. `dfl is None`
+    means every entry is a fully defined element. The record structure in
+    O(records), what both list batch forms of iter_device_batches ("pad",
+    "pack") are built from. Raises LevelError where an OPTIONAL leaf holds a
+    null element (def one below max): dropping it would shift the positions
+    of its record's later elements."""
+    rep = np.asarray(rep)
+    starts = np.flatnonzero(rep == 0)
+    if dfl is None:
+        present = np.ones(len(rep), dtype=np.int32)
+    else:
+        dfl = np.asarray(dfl)
+        if optional_leaf and bool((dfl == max_def - 1).any()):
+            raise LevelError("null elements inside lists")
+        present = (dfl == max_def).astype(np.int32)
+    if not len(starts):
+        return np.zeros(0, dtype=np.int32), 0
+    lengths = np.add.reduceat(present, starts).astype(np.int32, copy=False)
+    return lengths, int(lengths.sum())
 
 
 def validity_from_def(first_def, null_def: int):

@@ -219,6 +219,40 @@ class TestNoFloat64:
                       if getattr(a, "dtype", None) == jnp.float64})
         assert not bad, f"float64 values in programs run under doubles={form!r}: {bad}"
 
+    def test_packing_programs_hold_no_float_at_all(self, tmp_path, monkeypatch):
+        """Sequence packing (lists="pack") is integer arithmetic only: the
+        same seam, walked for any floating dtype, over a dictionary and a
+        PLAIN writing of the ids."""
+        from jax._src.interpreters import mlir
+
+        rng = np.random.default_rng(3)
+        docs = pa.array([rng.integers(0, 900, k).tolist() for k in rng.integers(0, 300, 400)],
+                        type=pa.list_(pa.int32()))
+        seen = []
+        real = mlir.lower_jaxpr_to_module
+
+        def spy(module_name, jaxpr, *a, **kw):
+            seen.append((module_name, jaxpr))
+            return real(module_name, jaxpr, *a, **kw)
+
+        monkeypatch.setattr(mlir, "lower_jaxpr_to_module", spy)
+        jax.clear_caches()
+        try:
+            for name, use_dictionary in (("d.parquet", True), ("p.parquet", False)):
+                pq.write_table(pa.table({"input_ids": docs}), tmp_path / name, use_dictionary=use_dictionary,
+                               row_group_size=150)
+                with FileReader(str(tmp_path / name)) as r:
+                    jax.block_until_ready(list(r.iter_device_batches(
+                        4, columns=["input_ids"], lists="pack", seq_len=128, drop_remainder=False)))
+        finally:
+            jax.clear_caches()
+        names = {n for n, _ in seen}
+        assert {"jit(pack_append_device)", "jit(pack_emit_device)", "jit(pack_carry_device)",
+                "jit(expand_hybrid_device)", "jit(dict_gather_device)"} <= names, names
+        bad = sorted({(n, str(a)) for n, j in seen for a in _avals(j.jaxpr)
+                      if jnp.issubdtype(getattr(a, "dtype", jnp.int32), jnp.floating)})
+        assert not bad, f"floating values in the packing programs: {bad}"
+
     def test_the_default_path_would_be_caught(self, files, monkeypatch):
         """The spy sees the float64 bitcast of the default delivery: the
         guard above is not vacuous."""
