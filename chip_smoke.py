@@ -57,6 +57,7 @@ KERNEL_LEGS = {
     "dict_gather_device": "decode",  # passenger_count's numeric dictionary
     "double_narrow_device": "decode",  # doubles="float32": fare, and the mixed file
     "predicate_mask_device": "decode",  # filter_rows=True
+    "dict_verdict_device": "daemon",  # /v1/query: a predicate on vendor, a byte-array dictionary
     "mask_take_device": "decode",
     "pack_append_device": "decode",  # stops packed: iter_device_batches(lists="pack")
     "pack_emit_device": "decode",
@@ -70,6 +71,7 @@ KERNEL_LEGS = {
     "delta_block_encode_device": "kernels",  # ... DELTA_BINARY_PACKED pages
     "plain_bytearray_encode_device": "kernels",  # ... PLAIN BYTE_ARRAY pages
     "masked_agg_device": "daemon",  # /v1/query device units
+    "expr_agg_device": "daemon",  # ... TPC-H Q6: sum(l_extendedprice*l_discount) over a small lineitem
 }
 
 
@@ -644,6 +646,40 @@ def http(url: str, body: dict | None = None, timeout: float = 300.0):
         return resp.read()
 
 
+def query_q6(run: Run, url: str, corpus: Path, group_rows: int) -> dict:
+    """TPC-H Q6 (validation parameters) over one small LINEITEM file — the
+    benchmark's own corpus kind, three row groups of `group_rows` rows, the
+    price column a mixed dictionary + PLAIN chunk — through the daemon's
+    device lane: equal to the plain reference (pyarrow.compute over the
+    decimal columns), and byte for byte the host lane's answer by its CLI."""
+    import pyarrow.parquet as pq
+
+    sys.path.insert(0, str(ROOT / "benchmark" / "lib"))
+    from byname import load_by_name
+
+    kind, ref = load_by_name("corpora", "tpch_lineitem"), load_by_name("lib", "reference_tpch")
+    spec = json.loads((ROOT / "benchmark" / "configs" / "tpch-sf10-lineitem.json").read_text())["corpus"]
+    spec, _ = kind.rehearsal(spec, group_rows)
+    path = corpus / kind.file_name(0)
+    if not path.exists():
+        kind.write_file(spec, run.args.seed, 0, str(corpus), [])
+    params = {"date": "1994-01-01", "discount": "0.06", "quantity": "24"}
+    body = {"paths": path.name, "filters": ref.filters(params), "aggregates": ["count", ref.REVENUE]}
+    raw = http(url + "/v1/query", body, timeout=run.remaining())
+    r6 = json.loads(raw)
+    want = ref.q6(pq.read_table(path, columns=list(ref.COLUMNS)), params)
+    want = {"count": want["count"], ref.REVENUE: str(want["revenue"])}
+    check(r6["result"] == want and r6["rows_scanned"] == spec["rows_per_file"],
+          f"/v1/query Q6: {r6['result']} over {r6['rows_scanned']} rows != the reference {want}")
+    host = subprocess.run(
+        [sys.executable, "-m", "parquet_tpu.tools.parquet_tool", "scan", str(path), "--filters",
+         json.dumps(body["filters"]), "--aggregate", json.dumps(body["aggregates"])],
+        capture_output=True, timeout=run.remaining(), env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    check(host.returncode == 0 and host.stdout == raw,
+          f"Q6: the host lane's CLI said {host.stdout[-300:]!r} {host.stderr[-300:]!r}, the daemon {raw!r}")
+    return r6
+
+
 def run_daemon(run: Run, corpus: Path) -> dict:
     """The daemon by its CLI, asked over HTTP; answers are pyarrow's."""
     import re
@@ -712,6 +748,14 @@ def run_daemon(run: Run, corpus: Path) -> dict:
     want3 = {v: (c, s) for v, c, s in zip(
         t3["vendor"].to_pylist(), t3["count_all"].to_pylist(), t3["passenger_count_sum"].to_pylist())}
 
+    q4 = {
+        "paths": "trips-*.parquet",
+        "filters": [["vendor", "==", "vendor_007"]],
+        "aggregates": ["count", ["sum", "trip_id"]],
+    }
+    t4 = table.filter(pc.equal(table["vendor"].cast("string"), "vendor_007"))
+    want4 = {"count": t4.num_rows, "sum(trip_id)": pc.sum(t4["trip_id"]).as_py()}
+
     r1 = json.loads(http(url + "/v1/query", q1, timeout=run.remaining()))
     check(r1["result"] == want1, f"/v1/query 1: {r1['result']} != pyarrow {want1}")
     r2 = json.loads(http(url + "/v1/query", q2, timeout=run.remaining()))
@@ -720,6 +764,9 @@ def run_daemon(run: Run, corpus: Path) -> dict:
     got3 = {g["key"][0]: (g["aggregates"]["count"], g["aggregates"]["sum(passenger_count)"])
             for g in r3["groups"]}
     check(got3 == want3, "/v1/query group_by differs from pyarrow")
+    r4 = json.loads(http(url + "/v1/query", q4, timeout=run.remaining()))
+    check(r4["result"] == want4, f"/v1/query 4: {r4['result']} != pyarrow {want4}")
+    r6 = query_q6(run, url, corpus, group_rows)
     rows = [json.loads(x) for x in http(
         url + "/v1/scan",
         {"paths": "trips-0.parquet", "columns": ["trip_id", "vendor", "passenger_count"], "limit": 1000},
@@ -734,7 +781,7 @@ def run_daemon(run: Run, corpus: Path) -> dict:
         return int(m.group(1)) if m else 0
 
     got = {"device": units("device"), "host_fallback": units("host_fallback")}
-    want = {"device": r1["units"] + r2["units"], "host_fallback": r3["units"]}
+    want = {"device": r1["units"] + r2["units"] + r4["units"] + r6["units"], "host_fallback": r3["units"]}
     check(got == want, f"query units by engine {got}, expected {want}")
     child.send_signal(signal.SIGTERM)
     tail = child.stdout.read()
@@ -744,9 +791,10 @@ def run_daemon(run: Run, corpus: Path) -> dict:
     except subprocess.TimeoutExpired:
         raise SmokeFailure("the daemon did not drain on SIGTERM") from None
     check(rc == 0 and "serve: drained, bye" in tail, f"the daemon exited {rc} without draining")
-    say(f"daemon: 3 queries + 1 scan equal pyarrow; units by engine {got}; drained")
+    say(f"daemon: 4 queries + 1 scan equal pyarrow, Q6 equals its reference and the host lane's bytes; "
+        f"units by engine {got}; drained")
     return {
-        "device": health["device"], "query_units": [r1["units"], r2["units"], r3["units"]],
+        "device": health["device"], "query_units": [r1["units"], r2["units"], r3["units"], r4["units"], r6["units"]],
         "query_device_units": got, "unexpected_host_fallback_units": 0, "scan_rows": len(rows),
     }
 

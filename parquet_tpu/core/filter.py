@@ -262,16 +262,22 @@ def _coerce_logical(leaf, kind, value):
             return v, lo, hi
         return v, None, None  # binary-backed decimals: sign-magnitude bytes unordered
     if kind == "date":
+        value = _from_iso(value, "DATE")
         if isinstance(value, dt.datetime):
             value = value.date()
         if not isinstance(value, dt.date):
-            raise FilterError("filter: DATE column takes a date")
+            raise FilterError(
+                "filter: DATE column takes a date or an ISO-8601 string"
+            )
         days = (value - _EPOCH_DATE).days
         return value, days, days
     if kind[0] == "timestamp":
         _, unit, utc = kind
+        value = _from_iso(value, "TIMESTAMP")
         if not isinstance(value, dt.datetime):
-            raise FilterError("filter: TIMESTAMP column takes a datetime")
+            raise FilterError(
+                "filter: TIMESTAMP column takes a datetime or an ISO-8601 string"
+            )
         aware = value if value.tzinfo is not None else value.replace(tzinfo=dt.timezone.utc)
         micros = (aware - _EPOCH_UTC) // dt.timedelta(microseconds=1)
         lo, hi = _unit_bracket(micros, unit)
@@ -313,6 +319,20 @@ def _coerce_logical(leaf, kind, value):
             )
         return row_value, lo, hi
     raise FilterError(f"filter: unsupported logical type on {leaf.path_str}")
+
+
+def _from_iso(value, what: str):
+    """A JSON request has no date type: an ISO-8601 string ("1994-01-01",
+    "2023-01-31T12:00:00+00:00") stands for the date or datetime it spells.
+    Anything that is not a string passes through to the caller's type check."""
+    if not isinstance(value, str):
+        return value
+    try:
+        return dt.datetime.fromisoformat(value)
+    except ValueError:
+        raise FilterError(
+            f"filter: {what} column takes an ISO-8601 string, got {value!r}"
+        ) from None
 
 
 def _unit_bracket(micros: int, unit: str) -> tuple:

@@ -23,7 +23,9 @@ Semantics are pinned to the host vec engine bracket-for-bracket:
     filter_vec._numeric_view);
   * dictionary-preserved chunks compare their (small, host-side)
     dictionary ONCE with the host engine's own comparators, then one
-    device gather through the resident indices lifts the verdict to rows;
+    device gather through the resident indices lifts the verdict to rows
+    (dict_verdict_device: with predicate_mask_device, what the device
+    trace shows under the scope pqt.query_mask);
   * both null conventions ("row" and "arrow") are implemented, matching
     filter_vec._leaf_mask including pyarrow's null-keeping not_in and the
     float32 in-list cast decline.
@@ -46,6 +48,7 @@ except ImportError:  # pragma: no cover - callers gate on jax availability
     jnp = None
 
 from ..kernels.device_ops import (
+    dict_verdict_device,
     list_contains_mask_device,
     predicate_mask_device,
     prefix_sum,
@@ -257,7 +260,7 @@ def _dense_compare(dc, leaf, op, vlo, vhi, ckey):
         # dictionary-preserved chunk: the host engine compares the (small)
         # dictionary once, one device gather lifts it through the indices
         dcmp = _host_compare(dc.dictionary, leaf, op, vlo, vhi, ckey)
-        return jnp.asarray(dcmp)[dc.indices]
+        return dict_verdict_device(jnp.asarray(dcmp), dc.indices)
     if dc.values is None:
         raise DeviceFilterError(
             f"filter_device: {leaf.path_str}: no device value form "
@@ -276,7 +279,7 @@ def _member_mask(dc, leaf, brackets, ckey):
     if via_dict:
         exact = [lo for lo, hi in brackets if lo == hi]
         m = _host_dict_members(dc.dictionary, leaf, exact, ckey)
-        return jnp.asarray(m)[dc.indices]
+        return dict_verdict_device(jnp.asarray(m), dc.indices)
     if dc.values is None:
         raise DeviceFilterError(
             f"filter_device: {leaf.path_str}: no device value form "

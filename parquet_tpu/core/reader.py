@@ -742,7 +742,7 @@ class FileReader:
         from ..utils.trace import bump as trace_bump
         from .filter_device import DeviceFilterError, device_dnf_mask
 
-        with span("query.mask", {"group": i, "terms": len(normalized)}):
+        with stage("query.mask", args={"group": i, "terms": len(normalized)}):
             try:
                 mask = device_dnf_mask(group, normalized, n, null_mode=null_mode)
             except DeviceFilterError:

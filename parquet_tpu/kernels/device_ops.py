@@ -116,6 +116,7 @@ __all__ = [
     "pack_emit_device",
     "pack_carry_device",
     "predicate_mask_device",
+    "dict_verdict_device",
     "list_contains_mask_device",
     "mask_take_device",
     "bitpack_encode_device",
@@ -124,6 +125,7 @@ __all__ = [
     "delta_block_encode_device",
     "plain_bytearray_encode_device",
     "masked_agg_device",
+    "expr_agg_device",
 ]
 
 
@@ -932,12 +934,13 @@ def pack_carry_device(tokens: jnp.ndarray, flags: jnp.ndarray, offset, span: int
 
 
 @partial(jax.jit, static_argnames=("op", "exact"))
-@jax.named_scope("pqt.predicate_mask")
+@jax.named_scope("pqt.query_mask/predicate")
 def predicate_mask_device(values: jnp.ndarray, op: str, lo, hi, exact: bool = True):
     """One leaf predicate as a device boolean mask — the jittable twin of
     core/filter_vec's bracket comparison, so residual filtering of
     device-resident columns (read_row_group_device / DeviceColumn values)
-    never round-trips the host.
+    never round-trips the host. With dict_verdict_device it is the row
+    mask's device time: both trace under pqt.query_mask.
 
     `lo`/`hi` bracket the filter value in the column's physical domain
     exactly like normalize_filters computes them; `exact` (static) is
@@ -958,6 +961,15 @@ def predicate_mask_device(values: jnp.ndarray, op: str, lo, hi, exact: bool = Tr
     if op == ">=":
         return values >= hi
     raise ValueError(f"predicate_mask_device: unsupported op {op!r}")
+
+
+@jax.jit
+@jax.named_scope("pqt.query_mask/lift")
+def dict_verdict_device(verdict: jnp.ndarray, indices: jnp.ndarray) -> jnp.ndarray:
+    """A dictionary-preserved chunk's predicate: the host compared the
+    (small) dictionary once, bool[entries]; one gather through the resident
+    indices lifts that verdict to the chunk's values."""
+    return verdict[indices]
 
 
 @jax.jit
@@ -1302,6 +1314,33 @@ def masked_agg_device(values: jnp.ndarray, mask: jnp.ndarray, op: str):
     if op == "max":
         return jnp.max(masked)
     raise ValueError(f"masked_agg_device: unsupported op {op!r}")
+
+
+@partial(jax.jit, static_argnames=("program", "op"))
+@jax.named_scope("pqt.expr_agg")
+def expr_agg_device(columns: tuple, mask: jnp.ndarray, program: tuple, op: str):
+    """An aggregate over an arithmetic expression of resident integer columns
+    as ONE program: the tree evaluated row by row in wrapping int64 and
+    reduced under the row mask (sum/min/max), integers only. `program`
+    (static) is the tree in the columns' unscaled integer domain — ("col", i)
+    reads columns[i], ("lit", v) is a Python int, (op, left, right) with op
+    in * + - — as serve/query_device binds it from a request's expression:
+    decimal scales already aligned by literal powers of ten, and every node
+    PROVED inside int64 from the chunks' statistics before this runs, so the
+    wrapping arithmetic never wraps. min/max of zero matching rows is the
+    dtype's identity: the caller gates on the matched count."""
+
+    def value(node):
+        if node[0] == "col":
+            return columns[node[1]].astype(jnp.int64)
+        if node[0] == "lit":
+            return jnp.int64(node[1])
+        left, right = value(node[1]), value(node[2])
+        if node[0] == "*":
+            return left * right
+        return left + right if node[0] == "+" else left - right
+
+    return masked_agg_device(jnp.broadcast_to(value(program), mask.shape), mask, op)
 
 
 @partial(jax.jit, static_argnames=("rows_pad",))

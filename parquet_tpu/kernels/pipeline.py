@@ -424,6 +424,9 @@ class DeviceColumn:
     # IEEE-754 patterns) or "float32" (`values` is the exact narrowing);
     # None for every other column and for the default float64 delivery
     double_form: str | None = None
+    # `values` were merged from dictionary and PLAIN pages in HBM
+    # (merge_mixed_numeric_device): the chunk passed its writer's dictionary limit
+    mixed: bool = False
     # memoized device copies of the level streams (one upload, shared by
     # every list_layout() depth)
     _dev_rep: "jnp.ndarray | None" = None
@@ -831,6 +834,7 @@ class _ChunkPlan:
                 _bucket(max(n_rows, 1)),
             )[:n_rows]
             out.values = self._typed(merged)
+            out.mixed = True
             return out
 
         # Mixed dict+PLAIN byte-array chunk (config-3 shape under pyarrow's

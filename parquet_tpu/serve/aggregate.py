@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import json
 
+from . import expr as _expr
 from .protocol import QueryRequest, ServeError, agg_name, json_default
 
 __all__ = [
@@ -55,8 +56,9 @@ def query_columns(query: QueryRequest) -> list:
         if c not in cols:
             cols.append(c)
     for a in query.aggregates:
-        if a.column is not None and a.column not in cols:
-            cols.append(a.column)
+        for c in agg_inputs(a):
+            if c not in cols:
+                cols.append(c)
     if not cols and query.filters is not None:
         first = query.filters[0]
         if isinstance(first, (list, tuple)) and first and isinstance(
@@ -65,6 +67,28 @@ def query_columns(query: QueryRequest) -> list:
             first = first[0]  # DNF: first conjunction's first triple
         cols.append(first[0])
     return cols
+
+
+def agg_inputs(a) -> list:
+    """The columns one aggregate reads: its column, or its expression's."""
+    if a.expr is not None:
+        return _expr.columns(a.expr)
+    return [] if a.column is None else [a.column]
+
+
+def _agg_input(table, a):
+    """One aggregate's input over a unit's filtered table: the column, or
+    its expression evaluated by pyarrow.compute (serve/expr.py)."""
+    import pyarrow as pa
+
+    if a.expr is None:
+        return _agg_column(table, a.column)
+    try:
+        return _expr.evaluate(a.expr, lambda name: _agg_column(table, name))
+    except (pa.ArrowInvalid, pa.ArrowNotImplementedError) as e:
+        raise ServeError(
+            400, "bad_aggregates", f"cannot evaluate {a.column!r}: {e}"
+        ) from None
 
 
 def _agg_column(table, name: str):
@@ -98,7 +122,7 @@ def unit_partial(table, query: QueryRequest):
             if a.column is None:
                 vals.append(table.num_rows)
                 continue
-            col = _agg_column(table, a.column)
+            col = _agg_input(table, a)
             try:
                 if a.op == "count":
                     vals.append(int(pc.count(col).as_py()))
@@ -114,13 +138,15 @@ def unit_partial(table, query: QueryRequest):
         return {(): vals}, types
     keys = list(query.group_by)
     spec = []
-    for a in aggs:
+    for j, a in enumerate(aggs):
         if a.column is None:
             spec.append(([], "count_all"))
-        elif a.op == "count":
-            spec.append((a.column, "count"))
-        else:
-            spec.append((a.column, a.op))
+            continue
+        name = a.column
+        if a.expr is not None:  # grouped as a column of its own
+            name = f"__agg{j}"
+            table = table.append_column(name, _agg_input(table, a))
+        spec.append((name, a.op))
     try:
         res = table.group_by(keys).aggregate(spec)
     except (pa.ArrowInvalid, pa.ArrowNotImplementedError, KeyError) as e:

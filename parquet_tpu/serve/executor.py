@@ -405,7 +405,9 @@ def execute_query(
     `device` (ServeConfig(device=...)) attaches an accelerator backend:
     each unit first tries the device-resident path (serve/query_device —
     decode into HBM, resident residual mask, one masked reduction per
-    aggregate) and falls back, typed and counted
+    aggregate, expressions and integer-backed DECIMAL / DATE leaves
+    included, one fetch of the unit's scalars) and falls back, typed and
+    counted
     (query_device_units_total{engine=...}), to the host vec engine for any
     shape outside the device envelope. True means the process-default jax
     device; a jax.Device pins one."""

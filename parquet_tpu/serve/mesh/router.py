@@ -63,7 +63,7 @@ from ...obs.slo import SLOObjective as _SLOObjective
 from ...utils import metrics as _metrics
 from ..admission import AdmissionController
 from ..aggregate import QueryState, agg_name, result_dict
-from ..protocol import QueryRequest, ScanRequest, ServeError
+from ..protocol import QueryRequest, ScanRequest, ServeError, agg_input
 from ..server import (
     ScanServer,
     ScanService,
@@ -164,7 +164,7 @@ def _query_obj(req: QueryRequest) -> dict:
     obj: dict = {
         "paths": list(req.paths),
         "aggregates": [
-            [a.op] if a.column is None else [a.op, a.column]
+            [a.op] if a.column is None else [a.op, agg_input(a)]
             for a in req.aggregates
         ],
         "max_groups": req.max_groups,

@@ -37,6 +37,7 @@ __all__ = [
     "scan_request_from_query",
     "json_default",
     "agg_name",
+    "agg_input",
 ]
 
 FORMATS = ("jsonl", "arrow-ipc")
@@ -93,16 +94,38 @@ class ServeError(ValueError):
 
 class AggregateSpec(NamedTuple):
     """One validated aggregate: op in AGG_OPS; column None only for the
-    row-count form of count (count(*))."""
+    row-count form of count (count(*)). An aggregate over an arithmetic
+    expression (serve/expr.py) carries its tree in `expr` and the tree's
+    canonical text in `column`, so the result key reads the same way for
+    both: sum(v), sum(l_extendedprice*l_discount)."""
 
     op: str
     column: str | None
+    expr: tuple | None = None
 
 
 def agg_name(a: AggregateSpec) -> str:
     """The stable result key of one aggregate — shared by the daemon body
     and the CLI output so the rendered bytes match."""
     return a.op if a.column is None else f"{a.op}({a.column})"
+
+
+def agg_input(a: AggregateSpec) -> str | None:
+    """The wire text of one aggregate's input — what aggregates_from_spec
+    parses back into `a` (a column name that holds an operator character
+    goes back in backticks), for whoever forwards a validated request."""
+    from . import expr as _expr
+
+    if a.column is None or a.expr is not None:
+        return a.column
+    return _expr.render(("col", a.column)) if _is_expression(a.column) else a.column
+
+
+def _is_expression(text: str) -> bool:
+    """Whether an aggregate's input text goes through the expression
+    grammar: a text without an operator character is the column name it
+    always was."""
+    return any(c in text for c in "*+-()`/")
 
 
 class QueryRequest(NamedTuple):
@@ -261,10 +284,14 @@ def aggregates_from_spec(spec):
     """Validate a JSON-decoded aggregate spec into AggregateSpec tuples.
 
     Accepts a list whose entries are "count" (count(*)), [op] / [op,
-    column] pairs, or {"op": ..., "column": ...} objects. Column existence
-    is checked later against each file's schema — like filters_from_spec,
-    this pins the SHAPE so a bad spec fails the request typed before any
-    file is touched."""
+    input] pairs, {"op": ..., "column": ...} objects, or the result key's
+    own text, "op(input)". The input is a column name or an arithmetic
+    expression over columns and integer / decimal literals with * + -
+    (serve/expr.py: "l_extendedprice*l_discount"; a column whose name holds
+    an operator character goes in backticks). Column existence is checked
+    later against each file's schema — like filters_from_spec, this pins
+    the SHAPE so a bad spec fails the request typed before any file is
+    touched."""
     if not isinstance(spec, (list, tuple)) or not spec:
         raise ServeError(
             400, "bad_aggregates",
@@ -274,7 +301,15 @@ def aggregates_from_spec(spec):
     out = []
     for a in spec:
         if isinstance(a, str):
-            op, column = a, None
+            op, paren, column = a.partition("(")
+            if paren:
+                if not column.endswith(")"):
+                    raise ServeError(
+                        400, "bad_aggregates", f"bad aggregate entry {a!r}"
+                    )
+                op, column = op.strip(), column[:-1]
+            else:
+                column = None
         elif isinstance(a, dict):
             unknown = set(a) - {"op", "column"}
             if unknown:
@@ -304,8 +339,25 @@ def aggregates_from_spec(spec):
             raise ServeError(
                 400, "bad_aggregates", f"aggregate {op!r} needs a column"
             )
-        out.append(AggregateSpec(op=op, column=column))
+        out.append(_aggregate(op, column))
     return tuple(out)
+
+
+def _aggregate(op: str, column) -> AggregateSpec:
+    """A text without an operator character stays the column name it always
+    was; anything else is an expression, parsed now so that a bad one fails
+    the request."""
+    from . import expr as _expr
+
+    if column is None or not _is_expression(column):
+        return AggregateSpec(op=op, column=column)
+    try:
+        tree = _expr.parse(column)
+    except ValueError as e:
+        raise ServeError(400, "bad_aggregates", f"aggregate {op!r}: {e}") from None
+    if tree[0] == "col":  # `a-b`, (v): a column after all
+        return AggregateSpec(op=op, column=tree[1])
+    return AggregateSpec(op=op, column=_expr.render(tree), expr=tree)
 
 
 def _build_query_request(obj: dict) -> QueryRequest:

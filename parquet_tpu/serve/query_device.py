@@ -5,32 +5,65 @@ routes each unit (one row group of one file) through the reader's device
 delivery instead of to_arrow: columns decode straight into device memory,
 the residual predicate evaluates as a resident boolean mask
 (core/filter_device — host vec engine fallback, typed and counted), and
-each aggregate reduces to ONE masked jnp reduction
-(kernels/device_ops.masked_agg_device) whose scalar result is the only
-byte that crosses back to the host. The partial feeds the exact
-pyarrow-pinned merge in serve/aggregate.py unchanged — device and host
-units mix freely within one request because both produce the same
-((groups, types), scanned, matched) shape with the same value semantics.
+each aggregate reduces to ONE masked reduction (kernels/device_ops
+masked_agg_device, or expr_agg_device for an expression) whose scalar is the
+only byte that crosses back to the host — all of a unit's scalars in one
+fetch. The partial feeds the exact pyarrow-pinned merge in
+serve/aggregate.py unchanged — device and host units mix freely within one
+request because both produce the same ((groups, types), scanned, matched)
+shape with the same value semantics.
 
 The ENGAGEMENT ENVELOPE is deliberately narrow and typed: global (no
-group_by) count/sum/min/max over flat integer leaves (signed and unsigned,
-compared and summed in their bit-pattern view domain), count over anything
-flat. Everything else — group_by (pyarrow's hash-groupby semantics),
-float sum (reduction order), decimal/temporal logicals (arrow type
-domains) — raises DeviceQueryError and the executor reruns the unit on
-the host vec engine, counted per query_device_units_total{engine=...}.
-Exactness always wins over residency: int sums wrap in two's complement
-exactly like pyarrow's unchecked int64/uint64 kernels, min/max of zero
-matching rows is null, count skips nulls — the differential suite pins
-device == host byte-for-byte.
+group_by) aggregates over flat leaves —
+
+  * count over anything flat;
+  * sum/min/max over plain integers, signed and unsigned, compared and
+    summed in their bit-pattern view domain (a sum wraps in two's
+    complement exactly like pyarrow's unchecked int64/uint64 kernels);
+  * min/max over integer-backed DECIMAL (INT32 / INT64) and DATE leaves, in
+    their unscaled integer domain, the partial typed as the leaf
+    (decimal128(p, s), date32);
+  * sum over an integer-backed DECIMAL, and sum/min/max over an arithmetic
+    expression (serve/expr.py: * + - over signed integer and integer-backed
+    DECIMAL columns and literals, no nulls in the chunk), as ONE fused
+    program in wrapping int64 (expr_agg_device). Arrow computes these in
+    128 bits, so the unit engages only where int64 is PROVED enough: from
+    the chunks' own min/max statistics every node of the tree is bounded by
+    interval arithmetic (|a*b| <= max|a| * max|b|, decimal scales aligned
+    the way Arrow aligns them), a node of Arrow integer type has to fit
+    that type and every other node int64, and for a sum the root's bound
+    times the unit's rows has to stay under 2^63. A chunk without
+    statistics, or a bound that does not fit, declines the unit: typed and
+    counted (query_expr_overflow_declined), answered by the host. The
+    partial's Arrow type is what pyarrow.compute gives the same tree over
+    empty arrays of the leaves' types (decimal128(15,2) * decimal128(15,2)
+    summed: decimal128(38,4)), so the merge runs in Arrow's own domain.
+
+Everything else — group_by (pyarrow's hash-groupby semantics), float sum
+(reduction order), FIXED_LEN_BYTE_ARRAY decimals, timestamps — raises
+DeviceQueryError and the executor reruns the unit on the host vec engine,
+counted per query_device_units_total{engine=...}. Exactness always wins over
+residency: min/max of zero matching rows is null, count skips nulls — the
+differential suite pins device == host byte-for-byte.
+
+Under a trace a unit shows four stages inside serve.aggregate: query.decode
+(the device read), query.mask, query.aggregate (the launches) and query.sync
+(the one wait for the unit's scalars).
 """
 
 from __future__ import annotations
+
+import datetime as dt
+import decimal
+from typing import NamedTuple
 
 import numpy as np
 
 from ..core.filter_vec import VecFilterError
 from ..meta.parquet_types import Type
+from . import expr as _expr
+
+_EPOCH_DATE = dt.date(1970, 1, 1)
 
 __all__ = ["DeviceQueryError", "device_unit_partial"]
 
@@ -57,10 +90,15 @@ def _agg_leaf(schema, name: str):
     return leaf
 
 
-def _int_domain(leaf):
-    """(unsigned,) engagement check for sum/min/max: plain signed or
-    unsigned integers only — every other logical domain (decimal, temporal,
-    float NaN skipping, int96) keeps pyarrow's kernels authoritative."""
+def _leaf_domain(leaf):
+    """(unsigned, logical Arrow type | None) of a sum/min/max input: plain
+    signed or unsigned integers, and the two logical domains that are
+    integers underneath — DECIMAL (unscaled) and DATE (days). Every other
+    logical domain (timestamps, times, float NaN skipping, int96) keeps
+    pyarrow's kernels authoritative."""
+    import pyarrow as pa
+
+    from ..core.arrow_nested import _leaf_arrow_type
     from ..core.assembly import logical_kind
     from ..core.stats import column_is_unsigned
 
@@ -68,13 +106,42 @@ def _int_domain(leaf):
         leaf.type in (Type.INT32, Type.INT64),
         f"column {leaf.path_str}: non-integer physical type",
     )
-    unsigned = column_is_unsigned(leaf)
-    if not unsigned:
-        _require(
-            logical_kind(leaf) is None,
-            f"column {leaf.path_str}: logical domain needs pyarrow semantics",
-        )
-    return unsigned
+    if column_is_unsigned(leaf):
+        return True, None
+    kind = logical_kind(leaf)
+    if kind is None:
+        return False, None
+    typ = _leaf_arrow_type(pa, leaf)
+    _require(
+        kind in ("decimal", "date")
+        and (pa.types.is_decimal128(typ) or pa.types.is_date32(typ)),
+        f"column {leaf.path_str}: logical domain needs pyarrow semantics",
+    )
+    return False, typ
+
+
+def _from_domain(r: int, typ):
+    """A reduced integer back in its Arrow type's Python form."""
+    import pyarrow as pa
+
+    if typ is None or pa.types.is_integer(typ):
+        return int(r)
+    if pa.types.is_decimal(typ):
+        return decimal.Decimal(int(r)).scaleb(-typ.scale)
+    return _EPOCH_DATE + dt.timedelta(days=int(r))
+
+
+def _reduced_type(op: str, typ):
+    """The Arrow type pyarrow's sum/min/max gives an input of type `typ`;
+    where pyarrow has no such kernel (the sum of a DATE) the unit is the
+    host's, which renders pyarrow's refusal as the request's 400."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    try:
+        return getattr(pc, op)(pa.array([], typ)).type
+    except (pa.ArrowInvalid, pa.ArrowNotImplementedError) as e:
+        raise DeviceQueryError(f"query_device: {e}") from None
 
 
 def _dense_values(dc, leaf):
@@ -91,7 +158,9 @@ def _dense_values(dc, leaf):
     if dc.indices is not None and dc.dictionary is not None:
         d = dc.dictionary
         if isinstance(d, np.ndarray) and d.ndim == 1:
-            return jnp.asarray(d)[dc.indices]
+            from ..kernels.device_ops import dict_gather_device
+
+            return dict_gather_device(jnp.asarray(d), dc.indices)
     raise DeviceQueryError(
         f"query_device: column {leaf.path_str}: no device value form"
     )
@@ -106,39 +175,201 @@ def _validity(dc, leaf):
     return None
 
 
+# -- expressions: bound, bind, prove ---------------------------------------------
+
+
+class _OverflowDecline(DeviceQueryError):
+    """The statistics cannot prove the expression inside int64 (or hold
+    nothing to prove it from): the unit is the host's, counted apart."""
+
+
+def _chunk_bounds(rg, leaf):
+    """(min, max) of one chunk's values from its own statistics, in the
+    leaf's physical integers."""
+    from ..core.filter import _decode_stat, chunks_by_path
+
+    cc = chunks_by_path(rg).get(leaf.path)
+    st = None if cc is None else cc.meta_data.statistics
+    if st is None or st.min_value is None or st.max_value is None:
+        raise _OverflowDecline(
+            f"query_device: column {leaf.path_str}: no min/max statistics to "
+            "bound the expression with"
+        )
+    _require(
+        leaf.max_def == 0 or st.null_count == 0,
+        f"column {leaf.path_str}: nulls under an expression",
+    )
+    lo = _decode_stat(leaf, st.min_value, legacy=False)
+    hi = _decode_stat(leaf, st.max_value, legacy=False)
+    if not isinstance(lo, int) or not isinstance(hi, int) or lo > hi:
+        raise _OverflowDecline(
+            f"query_device: column {leaf.path_str}: unusable statistics"
+        )
+    return lo, hi
+
+
+def _bind(tree, column):
+    """(program, Arrow type, lo, hi) of an expression tree: the tree in the
+    columns' unscaled integer domain as expr_agg_device takes it, the type
+    pyarrow.compute gives the node (from the same operator over empty arrays
+    of its operands' types), and the interval its values lie in. `column`
+    gives (index, Arrow type, lo, hi) per column name. Raises
+    _OverflowDecline where a node cannot be proved inside its domain."""
+    import pyarrow as pa
+
+    if tree[0] == "col":
+        index, typ, lo, hi = column(tree[1])
+        return ("col", index), typ, lo, hi
+    if tree[0] == "lit":
+        v = _expr.literal(tree[1])
+        typ = pa.scalar(v).type
+        u = v if isinstance(v, int) else int(v.scaleb(typ.scale))
+        return _fits(("lit", u), typ, u, u)
+    op = tree[0]
+    left, lt, llo, lhi = _bind(tree[1], column)
+    right, rt, rlo, rhi = _bind(tree[2], column)
+    empty = {"l": pa.array([], lt), "r": pa.array([], rt)}
+    try:
+        typ = _expr.evaluate((op, ("col", "l"), ("col", "r")), empty.__getitem__).type
+    except (pa.ArrowInvalid, pa.ArrowNotImplementedError) as e:
+        # the host lane raises the same, as the request's typed 400
+        raise DeviceQueryError(f"query_device: {e}") from None
+    if op == "*":
+        ends = (llo * rlo, llo * rhi, lhi * rlo, lhi * rhi)
+        return _fits((op, left, right), typ, min(ends), max(ends))
+    if pa.types.is_decimal(typ):
+        # Arrow adds decimals at the larger scale: the other side steps up
+        left, llo, lhi = _rescaled(left, llo, lhi, typ.scale - _scale(lt))
+        right, rlo, rhi = _rescaled(right, rlo, rhi, typ.scale - _scale(rt))
+    if op == "+":
+        return _fits((op, left, right), typ, llo + rlo, lhi + rhi)
+    return _fits((op, left, right), typ, llo - rhi, lhi - rlo)
+
+
+def _scale(typ) -> int:
+    return getattr(typ, "scale", 0)
+
+
+def _rescaled(program, lo, hi, digits: int):
+    if digits == 0:
+        return program, lo, hi
+    by = 10 ** digits
+    return ("*", program, ("lit", by)), lo * by, hi * by
+
+
+def _fits(program, typ, lo, hi):
+    """A node of Arrow integer type wraps at that type's width and the
+    kernel computes in int64: both are exact only inside the narrower."""
+    import pyarrow as pa
+
+    bits = typ.bit_width if pa.types.is_integer(typ) else 64
+    limit = 1 << (min(bits, 64) - 1)
+    if lo < -limit or hi >= limit:
+        raise _OverflowDecline(
+            f"query_device: expression values in [{lo}, {hi}] are not proved "
+            f"inside {bits} bits"
+        )
+    return program, typ, lo, hi
+
+
+def _bind_expression(schema, rg, op, tree, rows) -> "_Plan":
+    """One expression aggregate, ready for expr_agg_device. The proof is made
+    here, from the row group's metadata alone."""
+    import pyarrow as pa
+
+    from ..core.arrow_nested import _leaf_arrow_type
+
+    leaves: list = []
+
+    def column(name):
+        leaf = _agg_leaf(schema, name)
+        unsigned, _ = _leaf_domain(leaf)
+        _require(not unsigned, f"column {name!r}: unsigned under an expression")
+        typ = _leaf_arrow_type(pa, leaf)
+        if leaf not in leaves:
+            leaves.append(leaf)
+        return (leaves.index(leaf), typ, *_chunk_bounds(rg, leaf))
+
+    program, typ, lo, hi = _bind(tree, column)
+    reduced = _reduced_type(op, typ)
+    if op == "sum" and max(-lo, hi) * max(rows, 1) >= 1 << 63:
+        raise _OverflowDecline(
+            f"query_device: a sum of {rows} values in [{lo}, {hi}] is not "
+            "proved inside int64"
+        )
+    return _Plan("expr", op, tuple(leaves), typ=typ, program=program, reduced=reduced)
+
+
+class _Plan(NamedTuple):
+    """How one aggregate of a unit is computed."""
+
+    kind: str  # "count*" | "count" | "leaf" (masked_agg_device) | "expr" (expr_agg_device)
+    op: str = "count"
+    leaves: tuple = ()  # the input leaf; an expression's leaves, in column order
+    unsigned: bool = False
+    typ: object = None  # the input's logical Arrow type (None: a plain integer)
+    program: tuple | None = None  # the expression in integers, as expr_agg_device takes it
+    reduced: object = None  # the aggregate's Arrow type, where the plan knows it
+
+
+def _plan(schema, rg, a, rows: int) -> _Plan:
+    if a.column is None:
+        return _Plan("count*")
+    _require(a.op in ("count", "sum", "min", "max"), f"unsupported op {a.op!r}")
+    if a.expr is not None:
+        _require(a.op != "count", "count over an expression")
+        return _bind_expression(schema, rg, a.op, a.expr, rows)
+    leaf = _agg_leaf(schema, a.column)
+    if a.op == "count":
+        return _Plan("count", leaves=(leaf,))
+    unsigned, typ = _leaf_domain(leaf)
+    if a.op == "sum" and typ is not None:
+        # Arrow sums a decimal in 128 bits: the fused kernel and its proof,
+        # over the one-column tree
+        return _bind_expression(schema, rg, "sum", ("col", a.column), rows)
+    reduced = _reduced_type(a.op, typ) if typ is not None else _int64_type(unsigned)
+    return _Plan("leaf", a.op, (leaf,), unsigned, typ, reduced=reduced)
+
+
+def _int64_type(unsigned: bool):
+    import pyarrow as pa
+
+    return pa.uint64() if unsigned else pa.int64()
+
+
 def device_unit_partial(reader, row_group: int, query, filters, device=None):
     """One unit's ((groups, types), scanned, matched) partial, computed
     device-resident. Raises DeviceQueryError when the query shape is
     outside the device envelope — the caller falls back to the host path
     (and counts it)."""
     try:
+        import jax
         import jax.numpy as jnp
 
         from ..core.filter_device import _device_numeric_view
-        from ..kernels.device_ops import masked_agg_device
+        from ..kernels.device_ops import expr_agg_device, masked_agg_device
         from ..kernels.pipeline import DeviceDoubleError
     except ImportError as e:  # pragma: no cover - jax-less deployment
         raise DeviceQueryError(f"query_device: jax unavailable: {e}") from None
+    from ..utils import metrics as _metrics
+    from ..utils.trace import stage
 
     _require(not query.group_by, "group_by needs pyarrow's hash groupby")
     schema = reader.schema
-    aggs = query.aggregates
-    plans = []  # (op, leaf|None, unsigned)
-    paths = []
-    for a in aggs:
-        if a.column is None:
-            plans.append(("count*", None, False))
-            continue
-        leaf = _agg_leaf(schema, a.column)
-        _require(
-            a.op in ("count", "sum", "min", "max"), f"unsupported op {a.op!r}"
-        )
-        unsigned = False
-        if a.op != "count":
-            unsigned = _int_domain(leaf)
-        plans.append((a.op, leaf, unsigned))
-        if leaf.path not in paths:
-            paths.append(leaf.path)
+    rg = reader.row_group(row_group)
+    n = int(rg.num_rows or 0)
+    try:
+        # an expression the statistics cannot bound declines here, before a
+        # byte of the unit is read
+        plans = [_plan(schema, rg, a, n) for a in query.aggregates]
+    except _OverflowDecline:
+        _metrics.inc("query_expr_overflow_declined")
+        raise
+    paths: list = []
+    for plan in plans:
+        for leaf in plan.leaves:
+            if leaf.path not in paths:
+                paths.append(leaf.path)
 
     normalized = None
     if filters is not None:
@@ -150,11 +381,11 @@ def device_unit_partial(reader, row_group: int, query, filters, device=None):
                 if e[0] not in paths:
                     paths.append(e[0])
 
-    n = int(reader.row_group(row_group).num_rows or 0)
     try:
-        group = reader.read_row_group_device(
-            row_group, paths or None, device=device
-        )
+        with stage("query.decode", args={"group": row_group}):
+            group = reader.read_row_group_device(
+                row_group, paths or None, device=device
+            )
     except DeviceDoubleError as e:
         # a DOUBLE filter/count column on a device without native f64:
         # the unit is exact on the host (host_fallback). The forms a TPU
@@ -163,8 +394,32 @@ def device_unit_partial(reader, row_group: int, query, filters, device=None):
         # lane does not ask for them
         raise DeviceQueryError(f"query_device: {e}") from None
 
+    # every number the unit needs, device scalars and host counts alike, by
+    # its slot in one list: fetched together at the end, one wait a unit
+    wanted: list = []
+
+    def later(value) -> int:
+        wanted.append(value)
+        return len(wanted) - 1
+
+    def count(m):
+        return masked_agg_device(m, m, "count")
+
+    def delivered(leaf):
+        dc = group.get(leaf.path)
+        _require(dc is not None, f"column {leaf.path_str} not delivered")
+        return dc, _validity(dc, leaf)
+
+    def dense_of(dc, leaf, expected: int):
+        dense = _dense_values(dc, leaf)
+        _require(
+            dense.shape[0] == expected,
+            f"column {leaf.path_str}: dense length mismatch",
+        )
+        return dense
+
     mask = None
-    matched = n
+    matched = later(n)
     if normalized is not None:
         # the to_arrow host path filters with pyarrow null conventions, so
         # the resident mask uses the SAME "arrow" mode; the engine ladder
@@ -175,68 +430,78 @@ def device_unit_partial(reader, row_group: int, query, filters, device=None):
                 mask = reader._device_group_mask(
                     row_group, group, normalized, n, null_mode="arrow"
                 )
-                matched = int(jnp.sum(mask))
+                matched = later(count(mask))
         except VecFilterError as e:
             raise DeviceQueryError(f"query_device: {e}") from None
 
-    vals: list = []
-    types: list = [None] * len(aggs)
-    import pyarrow as pa
-
-    from ..utils.trace import span
-
-    with reader._devctx(device), span(
-        "query.aggregate", {"group": row_group, "aggs": len(aggs)}
+    # per aggregate: the slot of a count, or (slot of the reduced scalar,
+    # slot of the count of values it reduced)
+    outs: list = []
+    mixed = False
+    with reader._devctx(device), stage(
+        "query.aggregate", args={"group": row_group, "aggs": len(plans)}
     ):
-        for j, (op, leaf, unsigned) in enumerate(plans):
-            if op == "count*":
-                vals.append(matched)
+        for plan in plans:
+            if plan.kind == "count*":
+                outs.append(matched)
                 continue
-            dc = group.get(leaf.path)
-            _require(dc is not None, f"column {leaf.path_str} not delivered")
-            valid = _validity(dc, leaf)
-            if op == "count":
+            if plan.kind == "expr":
+                columns = []
+                for leaf in plan.leaves:
+                    dc, valid = delivered(leaf)
+                    _require(
+                        valid is None,
+                        f"column {leaf.path_str}: nulls under an expression",
+                    )
+                    columns.append(dense_of(dc, leaf, n))
+                    mixed |= dc.mixed
+                dm = jnp.ones(n, dtype=bool) if mask is None else mask
+                reduced = expr_agg_device(tuple(columns), dm, plan.program, plan.op)
+                outs.append((later(reduced), matched))
+                _metrics.inc("query_expr_rows", n)
+                continue
+            (leaf,) = plan.leaves
+            dc, valid = delivered(leaf)
+            if plan.kind == "count":
                 # count skips nulls: |mask & valid| with no value math at all
                 if valid is None:
-                    cnt = (
-                        matched
-                        if mask is not None
-                        else int(dc.num_values)
-                    )
+                    outs.append(matched if mask is not None else later(int(dc.num_values)))
                 elif mask is None:
-                    cnt = int(valid.sum())
+                    outs.append(later(int(valid.sum())))
                 else:
-                    cnt = int(jnp.sum(mask & jnp.asarray(valid)))
-                vals.append(cnt)
+                    outs.append(later(count(mask & jnp.asarray(valid))))
                 continue
-            dense = _dense_values(dc, leaf)
-            nd = int(valid.sum()) if valid is not None else n
-            _require(
-                dense.shape[0] == nd,
-                f"column {leaf.path_str}: dense length mismatch",
-            )
+            nd = n if valid is None else int(valid.sum())
+            dense = dense_of(dc, leaf, nd)
+            mixed |= dc.mixed
             # the aggregate runs in the column's COMPARISON domain (unsigned
             # bit-pattern views), widened to the 64-bit merge domain pyarrow
             # uses (sum promotes; min/max values embed exactly)
             view = _device_numeric_view(dense, leaf)
-            c64 = view.astype(jnp.uint64 if unsigned else jnp.int64)
+            c64 = view.astype(jnp.uint64 if plan.unsigned else jnp.int64)
             if mask is None:
-                dm = jnp.ones(nd, dtype=bool)
-                live = nd
+                dm, live = jnp.ones(nd, dtype=bool), later(nd)
             elif valid is None:
-                dm = mask
-                live = matched
+                dm, live = mask, matched
             else:
                 dm = mask[jnp.asarray(np.flatnonzero(valid))]
-                live = None
-            if live is None:
-                live = int(masked_agg_device(c64, dm, "count"))
-            if live == 0:
-                # pyarrow sum/min/max over zero (non-null, matching) values
-                # is null
-                vals.append(None)
-                continue
-            r = masked_agg_device(c64, dm, op)
-            vals.append(int(r))
-            types[j] = pa.uint64() if unsigned else pa.int64()
-    return ({(): vals}, types), n, matched
+                live = later(count(dm))
+            outs.append((later(masked_agg_device(c64, dm, plan.op)), live))
+    _metrics.inc("query_expr_units", int(any(p.kind == "expr" for p in plans)))
+    _metrics.inc("query_mixed_chunks", int(mixed))
+
+    with stage("query.sync", args={"group": row_group, "scalars": len(wanted)}):
+        got = jax.device_get(wanted)
+
+    vals: list = []
+    types: list = [None] * len(plans)
+    for j, (plan, out) in enumerate(zip(plans, outs)):
+        if isinstance(out, int):
+            vals.append(int(got[out]))
+        elif int(got[out[1]]) == 0:
+            # pyarrow sum/min/max over zero (non-null, matching) values is null
+            vals.append(None)
+        else:
+            vals.append(_from_domain(got[out[0]], plan.typ))
+            types[j] = plan.reduced
+    return ({(): vals}, types), n, int(got[matched])

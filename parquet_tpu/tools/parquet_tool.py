@@ -1821,7 +1821,8 @@ def main(argv=None) -> int:
         "--aggregate",
         metavar="JSON",
         help="aggregation push-down instead of a throughput scan: a JSON "
-        'list of aggregates — e.g. \'["count", ["sum", "v"]]\' — exactly '
+        'list of aggregates — e.g. \'["count", ["sum", "v"]]\', or over an '
+        'expression, \'["sum(l_extendedprice*l_discount)"]\' — exactly '
         "what POST /v1/query accepts; prints the canonical query body "
         "(byte-identical to the daemon's response for the same corpus)",
     )

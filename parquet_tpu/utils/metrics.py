@@ -236,9 +236,25 @@ Key families (all under the `parquet_tpu_` prefix in exposition):
                                     partial aggregate reduced in HBM
                                     (serve/query_device), "host_fallback"
                                     = shape outside the device envelope
-                                    (float sums, group_by, decimals),
-                                    answered by the exact pyarrow host
-                                    path — rendered bytes identical
+                                    (float sums, group_by, binary-backed
+                                    decimals), answered by the exact
+                                    pyarrow host path — rendered bytes
+                                    identical
+  query_expr_units                  device units that reduced at least one
+                                    expression aggregate (or a DECIMAL
+                                    sum) with the fused int64 kernel
+                                    expr_agg_device; query_expr_rows is
+                                    the rows those kernels reduced (a
+                                    unit's rows, once per such aggregate)
+  query_expr_overflow_declined      units whose expression the chunks'
+                                    min/max statistics could not prove
+                                    inside int64 (or that had none): the
+                                    host's, where Arrow computes in 128
+                                    bits — same answer
+  query_mixed_chunks                device units whose aggregate input
+                                    was a mixed dictionary + PLAIN chunk,
+                                    merged in HBM by
+                                    merge_mixed_numeric_device
   query_device_unavailable_total    units that wanted the device path but
                                     jax was not importable (device=
                                     misconfiguration made visible)

@@ -3,10 +3,12 @@ of token_docs.
 
 A `benchmark` PR may add files only under benchmark/, so two of its test files
 live there (PERF.md section 7) and tier-1, which collects tests/ only, never
-ran them: benchmark/selftest/test_corpora.py (a corpus is found by name) and
+ran them: benchmark/selftest/test_corpora.py (a corpus is found by name),
 benchmark/selftest/test_faults_packed.py (`correct` is false exactly when
-something is planted in the packed cell). They are loaded by path and their
-tests, with the fixtures they use, collected here under their own names.
+something is planted in the packed cell), and the TPC-H table's
+test_corpora_tpch.py (dbgen's laws hold) and test_faults_tpch.py (the Q6
+cell's faults). They are loaded by path and their tests, with the fixtures
+they use, collected here under their own names.
 """
 
 import importlib.util
@@ -28,8 +30,8 @@ def _load(path: Path):
     return module
 
 
-FIXTURES = ("tree",)  # test_corpora.py's scratch copy of the benchmark
-for _name in ("test_corpora", "test_faults_packed"):
+FIXTURES = ("tree", "columns")  # test_corpora.py's scratch copy of the benchmark; the TPC-H file's arrays
+for _name in ("test_corpora", "test_faults_packed", "test_corpora_tpch", "test_faults_tpch"):
     _module = _load(BENCH / "selftest" / f"{_name}.py")
     globals().update({k: v for k, v in vars(_module).items() if k.startswith("test_") or k in FIXTURES})
 

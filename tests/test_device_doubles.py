@@ -253,6 +253,58 @@ class TestNoFloat64:
                       if jnp.issubdtype(getattr(a, "dtype", jnp.int32), jnp.floating)})
         assert not bad, f"floating values in the packing programs: {bad}"
 
+    def test_query_programs_hold_no_float_at_all(self, tmp_path, monkeypatch):
+        """The device query lane answers TPC-H Q6 in integers only: every
+        program lowered while a unit decodes DATE and DECIMAL columns, masks
+        them (pqt.query_mask: compares on values, a dictionary's verdict
+        lifted through its indices) and reduces the decimal product
+        (pqt.expr_agg) is walked for any floating dtype."""
+        import importlib.util
+        import json
+        import sys
+        from pathlib import Path
+
+        from jax._src.interpreters import mlir
+
+        from parquet_tpu.serve.protocol import parse_query_request
+        from parquet_tpu.serve.query_device import device_unit_partial
+
+        bench = Path(__file__).resolve().parents[1] / "benchmark"
+        sys.path.insert(0, str(bench / "lib"))  # the kind imports reference_tpch as the corpus's workers do
+        spec = importlib.util.spec_from_file_location("bench_corpora_tpch_lineitem", bench / "corpora" / "tpch_lineitem.py")
+        kind = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(kind)
+        corpus = json.loads((bench / "configs" / "tpch-sf10-lineitem.json").read_text())["corpus"]
+        kind.write_file(kind.rehearsal(corpus, 2048)[0], 7, 0, str(tmp_path), [])
+        path = str(tmp_path / kind.file_name(0))
+        q = parse_query_request(json.dumps({
+            "paths": [path],
+            "filters": [["l_shipdate", ">=", "1994-01-01"], ["l_shipdate", "<", "1995-01-01"], ["l_discount", ">=", "0.05"],
+                        ["l_discount", "<=", "0.07"], ["l_quantity", "<", "24"], ["l_returnflag", "!=", "N"]],
+            "aggregates": ["count", "sum(l_extendedprice*l_discount)", ["max", "l_extendedprice"], ["min", "l_shipdate"]],
+        }).encode())
+        seen = []
+        real = mlir.lower_jaxpr_to_module
+
+        def spy(module_name, jaxpr, *a, **kw):
+            seen.append((module_name, jaxpr))
+            return real(module_name, jaxpr, *a, **kw)
+
+        monkeypatch.setattr(mlir, "lower_jaxpr_to_module", spy)
+        jax.clear_caches()
+        try:
+            with FileReader(path) as r:
+                (groups, _), scanned, matched = device_unit_partial(r, 0, q, q.filters)
+        finally:
+            jax.clear_caches()
+        assert scanned == 2048 and 0 < matched == groups[()][0]
+        names = {n for n, _ in seen}
+        assert {"jit(expr_agg_device)", "jit(predicate_mask_device)", "jit(dict_verdict_device)", "jit(masked_agg_device)",
+                "jit(merge_mixed_numeric_device)", "jit(expand_hybrid_device)", "jit(dict_gather_device)"} <= names, names
+        bad = sorted({(n, str(a)) for n, j in seen for a in _avals(j.jaxpr)
+                      if jnp.issubdtype(getattr(a, "dtype", jnp.int32), jnp.floating)})
+        assert not bad, f"floating values in the query lane's programs: {bad}"
+
     def test_the_default_path_would_be_caught(self, files, monkeypatch):
         """The spy sees the float64 bitcast of the default delivery: the
         guard above is not vacuous."""

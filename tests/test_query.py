@@ -161,7 +161,7 @@ class TestSpec:
             "timeout_ms": 1000,
         }).encode())
         assert q.aggregates[0].op == "count" and q.aggregates[0].column is None
-        assert q.aggregates[1] == ("sum", "v")
+        assert q.aggregates[1] == ("sum", "v", None)
         assert q.group_by == ("name",) and q.max_groups == 5
         assert q.shard == (0, 2) and q.timeout_ms == 1000
 

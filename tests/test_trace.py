@@ -662,7 +662,11 @@ def _kernel_cases(pad=64):
             nbits=32, width=16, num_values=4096, p_pad=pad)),
         ("dict_gather", (), lambda: _hlo(d.dict_gather_device, i32(16).astype(jnp.int64), i32(4096) % 16)),
         ("prefix_sum", (), lambda: _hlo(d.prefix_sum, i32(4096))),
-        ("predicate_mask", (), lambda: _hlo(d.predicate_mask_device, i32(4096), "<", 5, 5, True)),
+        ("query_mask", ("predicate",), lambda: _hlo(d.predicate_mask_device, i32(4096), "<", 5, 5, True)),
+        ("query_mask", ("lift",), lambda: _hlo(d.dict_verdict_device, mask[:16], i32(4096) % 16)),
+        ("expr_agg", (), lambda: _hlo(
+            d.expr_agg_device, (i32(4096).astype(jnp.int64), i32(4096)), mask,
+            ("*", ("col", 0), ("-", ("lit", 100), ("col", 1))), "sum")),
         ("masked_agg", (), lambda: _hlo(d.masked_agg_device, i32(4096).astype(jnp.int64), mask, "sum")),
         ("mask_take", (), lambda: _hlo(d.mask_take_device, i32(4096), mask, out_pad=2048)),
         ("merge_mixed_numeric", (), lambda: _hlo(
@@ -689,7 +693,7 @@ def _kernel_cases(pad=64):
 
 _KERNEL_IDS = [
     "hybrid_expand", "delta_decode-64", "delta_decode-32", "dict_gather", "prefix_sum",
-    "predicate_mask", "masked_agg", "mask_take", "merge_mixed_numeric", "merge_mixed_bytes",
+    "query_mask-predicate", "query_mask-lift", "expr_agg", "masked_agg", "mask_take", "merge_mixed_numeric", "merge_mixed_bytes",
     "bss_transpose", "record_starts", "list_layout", "list_contains_mask", "bitpack_encode",
     "rle_hybrid_encode", "dict_indices", "delta_block_encode", "plain_bytearray_encode",
 ]
