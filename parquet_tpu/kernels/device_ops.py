@@ -1346,31 +1346,50 @@ def expr_agg_device(columns: tuple, mask: jnp.ndarray, program: tuple, op: str):
 @partial(jax.jit, static_argnames=("rows_pad",))
 @jax.named_scope("pqt.merge_mixed_numeric")
 def merge_mixed_numeric_device(
-    idx_all: jnp.ndarray,        # int32[D_pad]: dict-row indices, output order
+    idx_all: jnp.ndarray,        # [D_pad]: dict-row indices, output order
     dictionary: jnp.ndarray,     # dict values (uint bit patterns for floats)
     plain: jnp.ndarray,          # plain values, page pools concatenated
-    page_kind: jnp.ndarray,      # int32[P_pad]: 1 dict page, 0 plain page
-    page_row_start: jnp.ndarray, # int32[P_pad + 1]: first output row per page
-    page_aux: jnp.ndarray,       # int32[P_pad]: base into idx_all / plain
+    seg_kind: jnp.ndarray,       # int32[S_pad]: 1 dict segment, 0 plain segment
+    seg_row_start: jnp.ndarray,  # int32[S_pad + 1]: first output row per segment
+    seg_src: jnp.ndarray,        # int32[S_pad]: base into idx_all / plain
     rows_pad: int,
 ) -> jnp.ndarray:
-    """Merge a mixed dict/PLAIN numeric chunk in output-index space: dict
-    rows gather through idx_all -> dictionary, PLAIN rows read their upload
-    directly — one fused program, one dispatch (a per-page slice/concat loop
-    costs one host->device dispatch per page over the transfer link). Rows
-    past the true count carry padding; the caller slices them off."""
+    """Merge a mixed dict/PLAIN numeric chunk by segments. A segment is a run
+    of adjacent pages of one kind whose rows are contiguous in BOTH their
+    source and the output (pipeline._mixed_segments): a chunk that fell back
+    to PLAIN once has two. Only dictionary rows gather, compactly, over
+    idx_all's own length; each segment then lands as its source shifted by
+    src - row_start, one dynamic_slice and one select — no per-row source map.
+    Every input arrives at a bucket length (entries past the true counts are
+    never selected: the counts travel in the table), a padding segment has
+    row_start == row_end, rows past the last segment read 0 and the caller
+    slices them off. A segment costs one shifted copy and one select over the
+    output (8.4 us at 2^20 int64 rows on a v5e: PERF.md section 6, PR 36), and
+    a segment is at least a page, which costs the host more than that to walk:
+    the table's static length S_pad needs no cap."""
+    idx_all = idx_all.astype(jnp.int32)
+    dv = dictionary[jnp.clip(idx_all, 0, dictionary.shape[0] - 1)]
+    # XLA clamps a dynamic_slice's start so that the slice stays in bounds,
+    # which would shift the data: rows_pad zeros on both sides keep every
+    # start (src - row_start, in [-rows_pad, len]) inside the array
+    edge = jnp.zeros(rows_pad, dtype=dv.dtype)
+    source = jnp.concatenate([edge, dv, plain, edge])
+    start = (
+        rows_pad
+        + seg_src
+        + jnp.where(seg_kind == 1, 0, idx_all.shape[0])
+        - seg_row_start[:-1]
+    )
     rows = jnp.arange(rows_pad, dtype=jnp.int32)
-    pg = jnp.searchsorted(page_row_start[1:], rows, side="right").astype(jnp.int32)
-    pg = jnp.minimum(pg, page_kind.shape[0] - 1)
-    rel = rows - page_row_start[pg]
-    is_dict = page_kind[pg] == 1
-    src = jnp.clip(page_aux[pg] + rel, 0, None)
-    dv = dictionary[
-        jnp.clip(idx_all[jnp.minimum(src, idx_all.shape[0] - 1)], 0,
-                 dictionary.shape[0] - 1)
-    ]
-    pv = plain[jnp.minimum(src, plain.shape[0] - 1)]
-    return jnp.where(is_dict, dv, pv)
+
+    def place(s, out):
+        shifted = jax.lax.dynamic_slice(source, (start[s],), (rows_pad,))
+        here = (rows >= seg_row_start[s]) & (rows < seg_row_start[s + 1])
+        return jnp.where(here, shifted, out)
+
+    return jax.lax.fori_loop(
+        0, seg_kind.shape[0], place, jnp.zeros(rows_pad, dtype=source.dtype)
+    )
 
 
 @partial(jax.jit, static_argnames=("rows_pad", "total_bytes_pad"))

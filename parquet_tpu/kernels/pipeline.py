@@ -260,7 +260,13 @@ def check_double_delivery(names, placement=None) -> None:
 
 def _pad_device(arr):
     """Zero-pad a device array to its power-of-two bucket so kernels taking
-    it compile a bounded number of times (one device op; no host copy)."""
+    it compile a bounded number of times (one device op; no host copy) — an
+    eager concatenate, itself a program a length: what the padded delivery
+    and the mixed numeric merge avoid by padding on the host (_pad_host).
+    Callers left: the index stream of the byte-array merge
+    (_merge_ragged_bytes), device_values_padded's exact fallback, _narrow
+    outside the mixed merge, the device PLAIN BYTE_ARRAY encoder and
+    core/reader's ragged and nullable expansions."""
     import jax.numpy as jnp
 
     n = int(arr.shape[0])
@@ -301,6 +307,51 @@ def _page_merge_tables(page_infos, plain_entries):
     aux_np = np.zeros(P_pad, dtype=np.int32)
     aux_np[:P] = aux
     return page_kind, prs, aux_np, rowpos
+
+
+def _mixed_segments(page_infos, batch_totals, batch_lens):
+    """The segment table of merge_mixed_numeric_device: (seg_kind,
+    seg_row_start, seg_src, n_rows), the first three padded to a power-of-two
+    bucket of segments (floor 4; a padding segment is empty). Adjacent pages
+    of one kind whose rows are contiguous in their source as in the output
+    are ONE segment: the PLAIN pool is one concatenation, and dictionary rows
+    are contiguous within an index batch — `batch_totals` real indices each,
+    laid end to end at their padded lengths `batch_lens`, so a batch boundary
+    closes a segment. A chunk that fell back to PLAIN once has two. The true
+    counts travel here, as data: no array the kernel takes is cut to them."""
+    kinds: list[int] = []
+    row_starts: list[int] = []
+    src: list[int] = []
+    rowpos = plain_base = batch = in_batch = batch_base = 0
+    for _n, _d, _r, kind, payload in page_infos:
+        if kind == "dict":
+            rows = payload
+            while rows and batch < len(batch_totals) - 1 and in_batch == batch_totals[batch]:
+                batch_base += batch_lens[batch]
+                batch += 1
+                in_batch = 0
+            k, base = 1, batch_base + in_batch
+            in_batch += rows
+        elif kind == "values":
+            rows = len(payload)
+            k, base = 0, plain_base
+            plain_base += rows
+        else:
+            continue
+        if rows and not (kinds and kinds[-1] == k and src[-1] + rowpos - row_starts[-1] == base):
+            kinds.append(k)
+            row_starts.append(rowpos)
+            src.append(base)
+        rowpos += rows
+    S = len(kinds)
+    S_pad = _bucket(max(S, 1), 4)
+    seg_kind = np.zeros(S_pad, dtype=np.int32)
+    seg_kind[:S] = kinds
+    seg_row_start = np.full(S_pad + 1, rowpos, dtype=np.int32)
+    seg_row_start[:S] = row_starts
+    seg_src = np.zeros(S_pad, dtype=np.int32)
+    seg_src[:S] = src
+    return seg_kind, seg_row_start, seg_src, rowpos
 
 
 def _skewed_dict_bound(dictionary, dict_rows: int, plain_bytes: int):
@@ -517,6 +568,11 @@ class _ChunkPlan:
         # true counts of the device arrays a padded dispatch left unsliced:
         # [plain, [hybrid batches], [delta batches]]
         self.padded_totals: list = [0, [], []]
+        # dictionary and PLAIN pages of a numeric column that device_column
+        # merges in HBM (merge_mixed_numeric_device). The one predicate,
+        # decided by dispatch_device, which pads every upload to its bucket
+        # for it whatever `padded` says
+        self.mixed_numeric = False
         self._dispatched = False
 
     # -- device dispatch (async; nothing synchronizes here) --------------------
@@ -534,7 +590,28 @@ class _ChunkPlan:
         self._dispatched = True
         padded = self.padded
         d = self.dictionary if self.dict_upload is None else self.dict_upload
-        if padded and self.dict_upload is None and isinstance(d, np.ndarray) and d.ndim == 1:
+        # a mixed chunk's dictionary and PLAIN row counts are its own: were
+        # they the lengths of what uploads, every program that touches the
+        # arrays would compile once a chunk. They pad here, on the host, and
+        # the expansion stays whole; the merge takes the counts as data
+        numeric_dict = isinstance(d, np.ndarray) and d.ndim == 1
+        kinds = {k for _, _, _, k, _ in self.page_infos if k != "empty"}
+        self.mixed_numeric = (
+            self.column.type in _NUMERIC_DTYPE
+            # a DOUBLE delivered as float64 is excluded: the merge needs the
+            # f64<->u64 bitcast, which XLA's x64 rewriter does not implement
+            # on TPU; it takes device_column's host-merge fallback. Under
+            # doubles= the merge never leaves the unsigned domain (FLOAT is
+            # fine either way: u32 bitcasts are native)
+            and (self.column.type != Type.DOUBLE or self.doubles is not None)
+            and "dict" in kinds
+            and kinds <= {"dict", "values"}
+            and bool(self.frozen_hybrid)
+            and numeric_dict
+            and self.plain_host is not None
+        )
+        whole = padded or self.mixed_numeric
+        if whole and self.dict_upload is None and numeric_dict:
             d = _pad_host(d)
         if padded:
             lengths = _pad_host(self.list_lengths, _LENGTHS_FLOOR)
@@ -542,7 +619,7 @@ class _ChunkPlan:
                 self.dev_lengths = jnp.asarray(lengths)
             _metrics.event("list_structure_upload_bytes", lengths.nbytes)
             _trace.count("list_structure_upload_bytes", lengths.nbytes)
-        if self.frozen_hybrid and isinstance(d, np.ndarray) and d.ndim == 1:
+        if self.frozen_hybrid and numeric_dict:
             # Upload the dictionary only when device-decoded indices will
             # gather against it (device_column); host reassembly gathers on
             # host. Floats travel as bit patterns: a gather is dtype-
@@ -555,7 +632,10 @@ class _ChunkPlan:
         # Homogeneous PLAIN numeric chunks are pure uploads (buffer already
         # concatenated at prepare time).
         if self.plain_host is not None:
-            host = _pad_host(self.plain_host) if padded else self.plain_host
+            host = _pad_host(self.plain_host) if whole else self.plain_host
+            if self.mixed_numeric and host.dtype.kind == "f":
+                # the merge works on bit patterns: they upload as they are
+                host = host.view(np.uint32 if host.dtype.itemsize == 4 else np.uint64)
             with _trace.stage("dispatch.upload", host.nbytes):
                 self.dev_plain = self._upload(host)
             self.padded_totals[0] = len(self.plain_host)
@@ -569,7 +649,7 @@ class _ChunkPlan:
         self.bss_host = []
         stats = self.stats
         for frozen in self.frozen_hybrid:
-            self.dev_hybrid.append(_dispatch_hybrid(frozen, padded))
+            self.dev_hybrid.append(_dispatch_hybrid(frozen, whole))
             self.padded_totals[1].append(frozen.total)
             if stats is not None:
                 stats.device_values += frozen.total
@@ -590,7 +670,10 @@ class _ChunkPlan:
         column = self.column
         hybrid_flat = None
         if self.dev_hybrid:
-            fetched = [np.asarray(d) for d in self.dev_hybrid]
+            # a batch left whole by the dispatch is cut to its true count here
+            fetched = [
+                np.asarray(d)[:n] for d, n in zip(self.dev_hybrid, self.padded_totals[1])
+            ]
             hybrid_flat = fetched[0] if len(fetched) == 1 else np.concatenate(fetched)
         if keep_dict_indices and self.dictionary is not None:
             kinds = {k for _, _, _, k, _ in self.page_infos if k != "empty"}
@@ -792,45 +875,35 @@ class _ChunkPlan:
 
         # Mixed dict+PLAIN numeric chunk (pyarrow's default 1MB dictionary
         # ceiling makes this the common large-dictionary case): dict pages
-        # keep their device expansion+gather, PLAIN pages ride the raw
-        # upload, and one fused kernel merges both in output-index space —
-        # no value ever round-trips to the host.
-        if (
-            column.type in _NUMERIC_DTYPE
-            # a DOUBLE delivered as float64 is excluded: the merge needs the
-            # f64<->u64 bitcast, which XLA's x64 rewriter does not implement
-            # on TPU; it takes the host-merge fallback below. Under doubles=
-            # the merge never leaves the unsigned domain (FLOAT is fine
-            # either way — u32 bitcasts are native)
-            and (column.type != Type.DOUBLE or self.doubles is not None)
-            and kinds <= {"dict", "values", "empty"}
-            and "dict" in kinds
-            and self.dev_hybrid
-            and self.dict_dev is not None
-            and self.dev_plain is not None
-        ):
+        # keep their device expansion, PLAIN pages ride the raw upload, and
+        # one kernel merges both by segments — no value ever round-trips to
+        # the host. Every array arrives at a bucket length: dispatch_device
+        # decided that this chunk merges (mixed_numeric) and padded for it,
+        # so nothing here is cut or padded to the chunk's own counts.
+        if self.mixed_numeric:
             from .device_ops import merge_mixed_numeric_device
 
-            page_kind, prs, aux_np, n_rows = _page_merge_tables(
-                self.page_infos, lambda p: (len(p), len(p))
+            seg_kind, seg_row_start, seg_src, n_rows = _mixed_segments(
+                self.page_infos,
+                self.padded_totals[1],
+                [int(b.shape[0]) for b in self.dev_hybrid],
             )
-            # merge in the uint bit-pattern domain; floats bitcast once after
-            plain_u = self.dev_plain
-            if plain_u.dtype.kind == "f":
-                plain_u = jax.lax.bitcast_convert_type(
-                    plain_u, jnp.uint32 if plain_u.dtype.itemsize == 4 else jnp.uint64
-                )
+            _metrics.event("mixed_chunks_by_segments")
+            _trace.count("mixed_chunks_by_segments")
+            plain_u = self.dev_plain  # bit patterns (dispatch_device)
             if self.doubles == "float32":
                 # each side narrows, then they merge at 32 bits: the
                 # dictionary on the host (dict_upload), the PLAIN pages here
                 plain_u = self._narrow(plain_u)
             merged = merge_mixed_numeric_device(
-                _pad_device(self._dev_indices()),
-                _pad_device(self.dict_dev),
-                _pad_device(plain_u),
-                jnp.asarray(page_kind),
-                jnp.asarray(prs),
-                jnp.asarray(aux_np),
+                self.dev_hybrid[0]
+                if len(self.dev_hybrid) == 1
+                else jnp.concatenate(self.dev_hybrid),
+                self.dict_dev,
+                plain_u,
+                jnp.asarray(seg_kind),
+                jnp.asarray(seg_row_start),
+                jnp.asarray(seg_src),
                 _bucket(max(n_rows, 1)),
             )[:n_rows]
             out.values = self._typed(merged)
@@ -883,11 +956,12 @@ class _ChunkPlan:
             return self._typed(self.dev_plain), count
         _metrics.event("padded_delivery_exact_chunks")
         _trace.count("padded_delivery_exact_chunks")
-        plain, hybrid, delta = self.padded_totals
-        if self.dev_plain is not None:
-            self.dev_plain = self.dev_plain[:plain]
-        self.dev_hybrid = [d[:n] for d, n in zip(self.dev_hybrid, hybrid)]
-        self.dev_delta = [d[:n] for d, n in zip(self.dev_delta, delta)]
+        if not self.mixed_numeric:  # whose merge takes its arrays whole
+            plain, hybrid, delta = self.padded_totals
+            if self.dev_plain is not None:
+                self.dev_plain = self.dev_plain[:plain]
+            self.dev_hybrid = [d[:n] for d, n in zip(self.dev_hybrid, hybrid)]
+            self.dev_delta = [d[:n] for d, n in zip(self.dev_delta, delta)]
         return _pad_device(self.device_column().values), count
 
     # -- the delivered form of a value array -----------------------------------
@@ -906,11 +980,14 @@ class _ChunkPlan:
     def _narrow(self, bits: jnp.ndarray) -> jnp.ndarray:
         """uint64 patterns of this chunk's PLAIN / BYTE_STREAM_SPLIT pages ->
         float32 patterns, on the device (bucket-padded: one program a
-        bucket)."""
+        bucket; an array that came padded — a mixed chunk's PLAIN pool, the
+        padded delivery — goes in as it is)."""
         pages = sum(1 for _, _, _, k, _ in self.page_infos if k == "values")
         _metrics.event("double_pages_narrowed_device", pages)
         _trace.count("double_pages_narrowed_device", pages)
         n = int(bits.shape[0])
+        if n == _bucket(n):
+            return double_narrow_device(bits)
         return double_narrow_device(_pad_device(bits))[:n]
 
     def _typed(self, vals: jnp.ndarray) -> jnp.ndarray:

@@ -255,6 +255,14 @@ Key families (all under the `parquet_tpu_` prefix in exposition):
                                     was a mixed dictionary + PLAIN chunk,
                                     merged in HBM by
                                     merge_mixed_numeric_device
+  events_total{event="mixed_chunks_by_segments"}
+                                    one per mixed dictionary + PLAIN
+                                    numeric chunk merged in HBM on any
+                                    device read, not only under a query
+                                    (merge_mixed_numeric_device: one
+                                    compact dictionary gather, each run
+                                    of adjacent pages of one kind placed
+                                    as a contiguous range)
   query_device_unavailable_total    units that wanted the device path but
                                     jax was not importable (device=
                                     misconfiguration made visible)

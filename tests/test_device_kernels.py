@@ -566,3 +566,223 @@ def test_delta_upload_golden(nbits):
     )
     page3 = g + np.cumsum([0] + [-1] * 256 + list(range(32)) + [-1] * 11)
     assert got[: f.total].tolist() == [10, 13, 12, far, far + 5, *page3.tolist()]
+
+
+# -- merge_mixed_numeric_device ---------------------------------------------------
+#
+# Driven through its one call site, _ChunkPlan.device_column, on plans built by
+# hand (pyarrow writes dictionary pages, then PLAIN pages, and nothing else):
+# the dispatch pads every upload on the host, the segment table carries the
+# counts, and the answer is a numpy merge of the same pages, bit for bit.
+
+_MIXED_TYPES = {
+    # name: (schema type, page dtype, doubles=, delivered bit pattern dtype)
+    "int32": ("int32", np.int32, None, np.uint32),
+    "int64": ("int64", np.int64, None, np.uint64),
+    "float-u32-patterns": ("float", np.float32, None, np.uint32),
+    "double-u64-patterns": ("double", np.float64, "bits", np.uint64),
+    "double-float32": ("double", np.float64, "float32", np.uint32),
+}
+
+_MIXED_LAYOUTS = {
+    # pages in order: ("dict" | "values" | "empty", rows)
+    "dict-then-plain": [("dict", 300), ("dict", 211), ("values", 500), ("values", 402), ("values", 77)],
+    "one-dict-page-then-plain": [("dict", 1), ("values", 1500)],
+    "plain-dict-plain": [("values", 40), ("dict", 700), ("dict", 3), ("values", 90)],
+    "one-under-the-bucket": [("dict", 1000), ("values", 1047)],
+    "at-the-bucket": [("dict", 1000), ("values", 1048)],
+    "at-the-bucket-dict-last": [("values", 1025), ("dict", 1023)],
+    "empty-pages-between": [("empty", 0), ("dict", 64), ("empty", 0), ("dict", 8), ("values", 0), ("values", 31), ("empty", 0)],
+    "two-index-batches": [("dict", 96), ("dict", 32), ("dict", 40), ("values", 500)],
+    # dictionary and PLAIN pages interleaved page by page (legal, unseen): one
+    # segment a page, 96 of them in a table of 128
+    "a-segment-a-page": [("dict", 3), ("values", 5)] * 48,
+}
+
+
+def _mixed_plan(type_name, layout, doubles=None, batch_pages=None, seed=0, padded=False):
+    """(plan before dispatch, the numpy merge of its pages). `batch_pages`
+    splits the dictionary pages into index batches of that many pages;
+    `padded` makes it the padded delivery's plan (one list of all the rows)."""
+    from parquet_tpu.kernels.pipeline import _ChunkPlan, _shape_double_dictionary
+    from parquet_tpu.schema.dsl import parse_schema
+
+    schema_type, dt, _, _ = _MIXED_TYPES[type_name]
+    column = parse_schema(f"message m {{ required {schema_type} x; }}").column(("x",))
+    rng = np.random.default_rng(seed)
+    n_dict = 37
+
+    def draw(n):
+        if np.dtype(dt).kind == "f":
+            v = rng.standard_normal(n) * 1e3
+            v[::5] = -0.0
+            return v.astype(dt)
+        return rng.integers(np.iinfo(dt).min, np.iinfo(dt).max, n, dtype=dt)
+
+    dictionary = draw(n_dict)
+    plan = _ChunkPlan(column, sum(n for _, n in layout), doubles, padded)
+    if padded:
+        plan.list_elements = plan.expected
+        plan.list_lengths = np.array([plan.expected], np.int32)
+    plan.dictionary = dictionary
+    merged, plain_pages, batches, batch = [], [], [], []
+    for kind, n in layout:
+        if kind == "dict":
+            idx = rng.integers(0, n_dict, n).astype(np.uint32)
+            merged.append(dictionary[idx])
+            batch.append(idx)
+            if batch_pages and len(batch) == batch_pages:
+                batches.append(batch)
+                batch = []
+            plan.page_infos.append((n, None, None, "dict", n))
+        elif kind == "values":
+            page = draw(n)
+            merged.append(page)
+            plain_pages.append(page)
+            plan.page_infos.append((n, None, None, "values", page))
+        else:
+            plan.page_infos.append((0, None, None, "empty", None))
+    if batch:
+        batches.append(batch)
+    width = 6
+    for pages in batches:
+        idx = np.concatenate(pages)
+        plan.frozen_hybrid.append(pack_hybrid_upload(
+            np.array([False]), np.array([len(idx)]), np.array([0], np.uint32), np.array([0]),
+            _wire(_pack_lsb(idx, width)), width,
+        ))
+    plan.plain_host = np.concatenate(plain_pages)
+    if doubles is not None:
+        _shape_double_dictionary(plan)
+    return plan, np.concatenate(merged)
+
+
+def _mixed_cases():
+    for layout in _MIXED_LAYOUTS:
+        yield pytest.param("int64", layout, id=f"int64-{layout}")
+    for type_name in _MIXED_TYPES:
+        if type_name != "int64":
+            yield pytest.param(type_name, "plain-dict-plain", id=f"{type_name}-plain-dict-plain")
+            yield pytest.param(type_name, "at-the-bucket", id=f"{type_name}-at-the-bucket")
+    yield pytest.param("double-float32", "a-segment-a-page", id="double-float32-a-segment-a-page")
+
+
+@pytest.mark.parametrize("type_name, layout_name", _mixed_cases())
+def test_merge_mixed_numeric_is_the_numpy_merge(type_name, layout_name):
+    from parquet_tpu.kernels.pipeline import _mixed_segments
+    from parquet_tpu.utils import metrics
+    from parquet_tpu.utils.trace import decode_trace
+
+    _, dt, doubles, bits = _MIXED_TYPES[type_name]
+    plan, want = _mixed_plan(
+        type_name, _MIXED_LAYOUTS[layout_name], doubles,
+        batch_pages=2 if layout_name == "two-index-batches" else None,
+    )
+    if doubles == "float32":
+        want = want.astype(np.float32)
+    name = 'events_total{event="mixed_chunks_by_segments"}'
+    before = metrics.snapshot().get(name, 0)
+    with decode_trace() as tr:
+        dc = plan.dispatch_device().device_column()
+    assert metrics.snapshot().get(name, 0) == before + 1
+    assert tr.counters().get("mixed_chunks_by_segments") == 1
+    assert dc.mixed and dc.num_values == len(want)
+    got = np.asarray(dc.values)
+    assert got.dtype == (want.dtype if doubles != "bits" else np.uint64)
+    np.testing.assert_array_equal(got.view(bits), want.view(bits))
+    # every array the kernel took is at a bucket, whatever the chunk's counts
+    assert plan.mixed_numeric
+    for arr in (*plan.dev_hybrid, plan.dict_dev, plan.dev_plain):
+        assert arr.shape[0] & (arr.shape[0] - 1) == 0
+    seg_kind, seg_row_start, seg_src, n_rows = _mixed_segments(
+        plan.page_infos, plan.padded_totals[1], [int(b.shape[0]) for b in plan.dev_hybrid]
+    )
+    assert n_rows == len(want) and len(seg_kind) >= 4 and len(seg_kind) & (len(seg_kind) - 1) == 0
+    if layout_name == "a-segment-a-page":
+        assert len(seg_kind) == 128 and seg_row_start[96] == n_rows
+    # the round trip through the host (finalize) cuts the whole batches itself
+    host = np.asarray(plan.finalize().values)
+    if doubles == "float32":
+        host = host.astype(np.float32)
+    np.testing.assert_array_equal(host.view(bits), want.view(bits))
+
+
+@pytest.mark.parametrize("type_name, layout_name", [
+    ("int64", "dict-then-plain"),
+    ("int64", "at-the-bucket"),
+    ("int32", "two-index-batches"),
+    ("double-float32", "plain-dict-plain"),
+])
+def test_a_mixed_chunk_under_the_padded_delivery(type_name, layout_name):
+    """device_values_padded's exact fallback on a plan prepared padded: the
+    dispatch left every array whole for the merge, so the fallback must not
+    cut them to the counts (it does for every other chunk shape), and the
+    merged values come back at their bucket with the count beside them."""
+    from parquet_tpu.utils import metrics
+
+    _, dt, doubles, bits = _MIXED_TYPES[type_name]
+    plan, want = _mixed_plan(
+        type_name, _MIXED_LAYOUTS[layout_name], doubles, padded=True,
+        batch_pages=2 if layout_name == "two-index-batches" else None,
+    )
+    if doubles == "float32":
+        want = want.astype(np.float32)
+    names = ['events_total{event="%s"}' % e for e in ("padded_delivery_exact_chunks", "mixed_chunks_by_segments")]
+    before = [metrics.snapshot().get(n, 0) for n in names]
+    values, count = plan.dispatch_device().device_values_padded()
+    assert [metrics.snapshot().get(n, 0) - b for n, b in zip(names, before)] == [1, 1]
+    assert plan.mixed_numeric and plan.dev_lengths is not None
+    got = np.asarray(values)
+    assert count == len(want) and len(got) >= count and len(got) & (len(got) - 1) == 0
+    np.testing.assert_array_equal(got[:count].view(bits), want.view(bits))
+    for arr in (*plan.dev_hybrid, plan.dict_dev, plan.dev_plain):
+        assert arr.shape[0] & (arr.shape[0] - 1) == 0
+
+
+@pytest.mark.parametrize("layout_name, segments", [
+    ("dict-then-plain", [(1, 0, 0), (0, 511, 0)]),
+    ("plain-dict-plain", [(0, 0, 0), (1, 40, 0), (0, 743, 40)]),
+    ("empty-pages-between", [(1, 0, 0), (0, 72, 0)]),
+    # two batches of 128 and 40 indices at their buckets of 1,024: the second starts at 1,024
+    ("two-index-batches", [(1, 0, 0), (1, 128, 1024), (0, 168, 0)]),
+])
+def test_mixed_segments_coalesce_adjacent_pages(layout_name, segments):
+    from parquet_tpu.kernels.pipeline import _mixed_segments
+
+    plan, want = _mixed_plan(
+        "int64", _MIXED_LAYOUTS[layout_name], batch_pages=2 if layout_name == "two-index-batches" else None
+    )
+    totals = [f.total for f in plan.frozen_hybrid]
+    seg_kind, seg_row_start, seg_src, n_rows = _mixed_segments(
+        plan.page_infos, totals, [f.n_pad for f in plan.frozen_hybrid]
+    )
+    S = len(segments)
+    assert len(seg_kind) == 4 and n_rows == len(want)
+    assert list(zip(seg_kind[:S], seg_row_start[:S], seg_src[:S])) == segments
+    # the padding: empty segments at the chunk's end
+    assert (seg_row_start[S:] == n_rows).all() and not seg_kind[S:].any() and not seg_src[S:].any()
+
+
+def test_merge_mixed_numeric_kernel_does_not_clamp_its_slices():
+    """XLA clamps a dynamic_slice's start to keep the slice in bounds, which
+    would shift the rows silently: starts at both ends of the source, on
+    sources shorter and longer than the output."""
+    from parquet_tpu.kernels.device_ops import merge_mixed_numeric_device
+
+    rng = np.random.default_rng(3)
+    rows_pad = 2048
+    dictionary = rng.integers(-(1 << 62), 1 << 62, 1024)
+    idx = np.full(1024, 0xFFFFFFFF, np.uint32)  # past the true count: garbage
+    idx[:1000] = rng.integers(0, 1024, 1000)
+    for plain_len in (1024, 4096):
+        plain = rng.integers(-(1 << 62), 1 << 62, plain_len)
+        # the PLAIN rows come from the END of their pool, the dictionary rows last
+        seg_kind = np.array([0, 1, 0, 0], np.int32)
+        seg_row_start = np.array([0, 1024, 2024, 2048, 2048], np.int32)
+        seg_src = np.array([plain_len - 1024, 0, 0, 0], np.int32)
+        got = np.asarray(merge_mixed_numeric_device(
+            jnp.asarray(idx), jnp.asarray(dictionary), jnp.asarray(plain),
+            jnp.asarray(seg_kind), jnp.asarray(seg_row_start), jnp.asarray(seg_src), rows_pad=rows_pad,
+        ))
+        want = np.concatenate([plain[plain_len - 1024:], dictionary[idx[:1000]], plain[:24]])
+        np.testing.assert_array_equal(got, want)

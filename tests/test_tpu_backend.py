@@ -428,6 +428,66 @@ class TestDecodeToDevice:
         with FileReader(path, backend="tpu_roundtrip") as r:
             assert_chunks_identical(host[p], r.read_row_group(0)[p])
 
+    def test_mixed_numeric_chunks_compile_a_bounded_set(self, tmp_path):
+        """Two files whose mixed chunks differ in dictionary size, dictionary
+        rows and PLAIN rows — each inside one bucket — run the same programs:
+        the counts reach the merge as data (the segment table), so the second
+        read lowers nothing. Before PR 36 the exact-length slice of the
+        expansion and three eager pads compiled once a chunk. What is left is
+        the delivery's exact length: the `[:n_rows]` cut after the merge is a
+        program a row count, as after every device kernel, so a third file
+        with another row count lowers that cut and nothing else."""
+        from jax import monitoring
+
+        from parquet_tpu.kernels.pipeline import plan_chunk_tpu
+
+        def write(name, seed, repeated, rows):
+            rng = np.random.default_rng(seed)
+            x = rng.integers(0, 1 << 60, rows).astype(np.int64)
+            # rows drawn from 100 values: the dictionary reaches its limit later
+            x[:repeated] = x[rng.integers(0, 100, repeated)]
+            path = str(tmp_path / name)
+            pq.write_table(
+                pa.table({"x": x}), path, use_dictionary=["x"],
+                dictionary_pagesize_limit=40_000, data_page_size=8 << 10,
+            )
+            return path, x
+
+        lowered: list = []
+
+        def listen(name, seconds, **kw):
+            if name == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+                lowered.append(kw.get("fun_name"))
+
+        counts = []
+        monitoring.register_event_duration_secs_listener(listen)
+        try:
+            for name, seed, repeated, rows in (
+                ("a.parquet", 1, 0, 30_000), ("b.parquet", 2, 2_000, 30_000), ("c.parquet", 3, 1_000, 29_000),
+            ):
+                path, x = write(name, seed, repeated, rows)
+                with FileReader(path) as r:
+                    cc = r.row_group(0).columns[0]
+                    plan = plan_chunk_tpu(r._f, cc, r.schema.column(("x",)))
+                    kinds = {k for _, _, _, k, _ in plan.page_infos if k != "empty"}
+                    if kinds != {"dict", "values"}:
+                        pytest.skip(f"pyarrow no longer mixes page encodings (kinds={kinds})")
+                    counts.append((len(plan.dictionary), plan.padded_totals[1], plan.padded_totals[0]))
+                    lowered.clear()
+                    dc = r.read_row_group_device(0)[("x",)]
+                    np.testing.assert_array_equal(np.asarray(dc.values), x)
+                    assert dc.mixed
+                    if name == "a.parquet":
+                        assert "jit(merge_mixed_numeric_device)" in lowered, lowered
+                    elif name == "b.parquet":
+                        assert lowered == [], lowered
+            # another row count in the same bucket: only the delivery's cut
+            assert lowered == ["jit(dynamic_slice)"], lowered
+        finally:
+            monitoring.unregister_event_duration_listener(listen)
+        for field in zip(*counts):  # dictionary entries, dictionary rows, PLAIN rows
+            assert len(set(map(str, field))) == len(counts), counts
+
     def test_values_live_on_device(self, tmp_path):
         import jax
 
