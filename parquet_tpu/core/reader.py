@@ -45,7 +45,7 @@ from ..meta.thrift import ThriftError
 from ..obs.log import log_event as _log_event
 from ..obs.pool import instrumented_submit
 from ..utils import metrics as _metrics
-from ..utils.trace import bump, name_os_thread, span, stage, timed_stage
+from ..utils.trace import active as _trace_active, bump, name_os_thread, span, stage, timed_stage
 
 __all__ = ["FileReader", "PARQUET_ERRORS", "resolve_column_prefixes"]
 
@@ -133,6 +133,20 @@ def _chunk_args(group: int, path) -> dict:
     """The identifier one chunk's chunk.prepare -> dispatch -> deliver spans
     share across the three threads that serve it."""
     return {"group": group, "column": ".".join(path)}
+
+
+def _wait(name: str, fut, group: int, path):
+    """Block on a chunk's pool future as the `name` stage, on the waiting
+    thread: plan.wait_prepare (the planning thread, before it may enqueue
+    the chunk's dispatch) or plan.wait_dispatch (the consumer, before
+    _deliver). Time WAITED, beside chunk.prepare's and dispatch's time
+    busy: where an idle gap of the device falls under a wait and under no
+    producer, it is the hop between threads. A future that is done costs
+    the stage() call; with no trace active, one contextvar read."""
+    if not _trace_active():
+        return fut.result()
+    with stage(name, args=_chunk_args(group, path)):
+        return fut.result()
 
 
 def _dispatch_traced(fn, device, args):
@@ -807,7 +821,8 @@ class FileReader:
     def _deliver(self, i: int, path, plan, pack: bool = True):
         """The consumer thread's share of one chunk, as the 'deliver' stage:
         the plan's last device launches (dictionary gather, concatenation)
-        and the level packing. Waiting for the plan's future stays outside."""
+        and the level packing. Waiting for the plan's future stays outside:
+        the plan.wait_dispatch stage (_wait)."""
         with stage("deliver", args=_chunk_args(i, path)):
             dc = plan.device_column()
             return self._pack_chunk_levels(path, dc) if pack else dc
@@ -843,7 +858,9 @@ class FileReader:
             with self._devctx(device):
                 out.append(
                     {
-                        path: self._deliver(i, path, fut.result())
+                        path: self._deliver(
+                            i, path, _wait("plan.wait_dispatch", fut, i, path)
+                        )
                         for path, fut in group
                     }
                 )
@@ -1134,7 +1151,10 @@ class FileReader:
                     # no level packing here: _array_of consumes the levels
                     # (mask build) within this iteration, so they never rest
                     group = {
-                        path: self._deliver(i, path, fut.result(), pack=False)
+                        path: self._deliver(
+                            i, path, _wait("plan.wait_dispatch", fut, i, path),
+                            pack=False,
+                        )
                         for path, fut in staged
                     }
                 else:
@@ -1243,7 +1263,7 @@ class FileReader:
 
         def plan_of(i, staged):
             if staged is not None:
-                return staged[0][1].result()
+                return _wait("plan.wait_dispatch", staged[0][1], i, leaf.path)
             return self._plan_row_group(
                 i, columns, device=device, list_lengths=True
             )[leaf.path]
@@ -1375,7 +1395,10 @@ class FileReader:
             for i, chunks in groups
         ]
         return [
-            [(path, dispatch(i, path, fut.result())) for path, fut in chunks]
+            [
+                (path, dispatch(i, path, _wait("plan.wait_prepare", fut, i, path)))
+                for path, fut in chunks
+            ]
             for i, chunks in prep_futs
         ]
 
@@ -1391,7 +1414,7 @@ class FileReader:
         overlapped with the next chunk's prepare.
         """
         return {
-            path: fut.result()
+            path: _wait("plan.wait_dispatch", fut, i, path)
             for path, fut in self._plan_row_group_async(
                 i, columns, device=device, doubles=doubles, list_lengths=list_lengths
             )

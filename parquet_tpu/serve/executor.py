@@ -40,7 +40,7 @@ from ..io.source import SourceError
 from ..obs.cost import unit_clock
 from ..obs.pool import instrumented_submit
 from ..utils import metrics as _metrics
-from ..utils.trace import stage
+from ..utils.trace import span, stage
 from .protocol import ServeError, json_default
 
 __all__ = ["serve_pool", "execute_stream", "execute_query"]
@@ -104,22 +104,29 @@ class _Check:
 
 
 def _open_reader(session, planned, unit) -> FileReader:
+    """One unit's FileReader, as the serve.open_reader stage (one call a
+    unit), nested in serve.execute / serve.aggregate."""
     meta = planned.plan.metas[unit.file_index]
-    return FileReader(
-        session.open_source(unit.path),
-        columns=planned.request.columns,
-        metadata=meta,
-        block_cache=session.block_cache,
-        coalesce_gap=getattr(session, "coalesce_gap", None),
-    )
+    with stage("serve.open_reader", args={"group": unit.row_group}):
+        return FileReader(
+            session.open_source(unit.path),
+            columns=planned.request.columns,
+            metadata=meta,
+            block_cache=session.block_cache,
+            coalesce_gap=getattr(session, "coalesce_gap", None),
+        )
 
 
 def _close_unit_reader(session, reader) -> None:
     # factory-built sources (chaos/remote seam) are caller-owned per the
-    # ByteSource contract: the reader won't close them, so we must
-    reader.close()
-    if session.source_factory is not None:
-        reader._source.close()
+    # ByteSource contract: the reader won't close them, so we must.
+    # A span under the opening stage's name: the close is in the trace (an
+    # idle gap of the device can be put down to it) and the stage's calls
+    # stay one a unit
+    with span("serve.open_reader"):
+        reader.close()
+        if session.source_factory is not None:
+            reader._source.close()
 
 
 def _run_jsonl_unit(session, planned, unit, max_rows, check):

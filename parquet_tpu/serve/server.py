@@ -80,7 +80,7 @@ from ..obs.recorder import sanitize_request_id as _sanitize_request_id
 from ..obs.slo import BurnRateEngine as _BurnRateEngine
 from ..obs.slo import SLOObjective as _SLOObjective
 from ..utils import metrics as _metrics
-from ..utils.trace import decode_trace
+from ..utils.trace import decode_trace, span, stage
 from .admission import AdmissionController
 from .executor import execute_query, execute_stream
 from .protocol import (
@@ -372,23 +372,36 @@ class ScanService:
         cached; hammering /v1/plan cannot starve scans of pool threads)."""
         return self.session.plan(request).summary()
 
+    def _admit(self, tenant: str, timeout_ms):
+        """The gate of a scan or a query, as the serve.admit stage (one
+        call a request): the deadline clamp and the wait for a ticket."""
+        with stage("serve.admit"):
+            deadline = self.admission.deadline_for(timeout_ms)
+            return deadline, self.admission.admit(tenant)
+
+    def _charge(self, ticket, planned) -> None:
+        """The tenant's byte budget, charged with the plan's estimate: the
+        second half of admission, which has to follow serve.plan. A span
+        under the gate's name — in the trace, while the stage's calls stay
+        one a request. ticket.tenant is the RESOLVED accounting key (it may
+        have collapsed to the overflow bucket under tenant-table pressure)."""
+        with span("serve.admit"):
+            self.admission.charge(ticket.tenant, planned.estimated_bytes)
+
     def scan(self, request, tenant: str, timeout_ms=None, record=None):
         """Admit, plan, charge, and open the result stream. Returns
         (ticket, content_type, chunk iterator); the caller MUST close the
         iterator and release the ticket (both context-manage safely).
         `record` (a flight-recorder RequestRecord) receives the plan's
         pruning summary as soon as planning finishes."""
-        deadline = self.admission.deadline_for(
-            timeout_ms if timeout_ms is not None else request.timeout_ms
+        deadline, ticket = self._admit(
+            tenant, timeout_ms if timeout_ms is not None else request.timeout_ms
         )
-        ticket = self.admission.admit(tenant)
         try:
             planned = self.session.plan(request)
             if record is not None:
                 record.plan = planned.summary()
-            # ticket.tenant is the RESOLVED accounting key (it may have
-            # collapsed to the overflow bucket under tenant-table pressure)
-            self.admission.charge(ticket.tenant, planned.estimated_bytes)
+            self._charge(ticket, planned)
             deadline.check()
             chunks = execute_stream(
                 planned,
@@ -415,10 +428,9 @@ class ScanService:
         caller renders and must release the ticket."""
         from .aggregate import query_columns
 
-        deadline = self.admission.deadline_for(
-            timeout_ms if timeout_ms is not None else request.timeout_ms
+        deadline, ticket = self._admit(
+            tenant, timeout_ms if timeout_ms is not None else request.timeout_ms
         )
-        ticket = self.admission.admit(tenant)
         try:
             cols = query_columns(request)
             planned = self.session.plan(
@@ -436,7 +448,7 @@ class ScanService:
             )
             if record is not None:
                 record.plan = planned.summary()
-            self.admission.charge(ticket.tenant, planned.estimated_bytes)
+            self._charge(ticket, planned)
             deadline.check()
             body = execute_query(
                 planned,
@@ -1052,7 +1064,8 @@ class _Handler(BaseHTTPRequestHandler):
         """POST /v1/scan under the record discipline."""
 
         def run(rec):
-            request = parse_scan_request(self._read_body())
+            with stage("serve.parse"):
+                request = parse_scan_request(self._read_body())
             ticket, content_type, chunks = self.service.scan(
                 request, tenant, timeout_ms=self._timeout_ms(), record=rec
             )
@@ -1069,11 +1082,12 @@ class _Handler(BaseHTTPRequestHandler):
         from .aggregate import render_query_body
 
         def run(rec):
-            request = parse_query_request(self._read_body())
+            with stage("serve.parse"):
+                request = parse_query_request(self._read_body())
             ticket, body = self.service.query(
                 request, tenant, timeout_ms=self._timeout_ms(), record=rec
             )
-            with ticket:
+            with ticket, stage("serve.respond"):
                 payload = render_query_body(body)
                 self._send_payload(200, payload)
                 return 200, len(payload), None

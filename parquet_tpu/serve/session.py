@@ -27,7 +27,7 @@ from ..io.cache import BlockCache, FooterCache
 from ..io.source import SourceError
 from ..utils import metrics as _metrics
 from ..utils.trace import count as _trace_count
-from ..utils.trace import span
+from ..utils.trace import stage
 from .protocol import ScanRequest, ServeError
 
 __all__ = ["ScanSession", "PlannedScan"]
@@ -193,7 +193,7 @@ class ScanSession:
         """Plan one request: prune, stripe, estimate. Zero source reads
         when the footer cache is warm (and bloom/page-index consultation
         hits the block cache)."""
-        with span("serve.plan", {"paths": ",".join(request.paths)}):
+        with stage("serve.plan", args={"paths": ",".join(request.paths)}):
             files = self.resolve_paths(request.paths)
             try:
                 plan = build_plan(

@@ -18,6 +18,41 @@ open for the same interval on the same thread, so a jax profiler session
 (jax_profile(), or the benchmark's --trace 1) shows the program's own spans
 on the profiler's clock beside the device's ops — idle gaps of the device
 attribute to prepare / upload / launch / deliver by what was open then.
+The spans of a device read and of a daemon request, by the thread that
+holds them (the catalogue; PERF.md section 3 says which metric reads which):
+
+  consumer / planning thread   row_group.device (span) > plan.wait_prepare
+                               (blocked on a chunk's prepare future, before it
+                               may enqueue the chunk's dispatch), plan.wait_dispatch
+                               (blocked on a dispatched plan, before deliver),
+                               deliver > deliver.pack; query.take
+  pqt-host_*                   chunk.prepare (span) > io.read, and the
+                               back-dated prepare.* sub-clocks
+  pqt-dispatch_0               dispatch > dispatch.upload, dispatch.launch
+  request handler              serve.parse, serve.admit (the gate; the byte
+                               charge after the plan is a span of the same
+                               name), serve.plan, serve.merge, serve.respond
+                               (serve.stream around a scan's chunked write).
+                               _finish (recorder, cost ledger) runs after the
+                               request's trace has closed: unclocked
+  pqt-serve_*                  serve.aggregate / serve.execute > serve.open_reader
+                               (the close is a span of the same name),
+                               query.decode > [the read above], query.mask,
+                               query.aggregate, query.sync
+
+The waits are time WAITED beside the producers' time busy: where an idle gap
+of the device falls under a wait and under no producer on any thread, it is
+the hop between two threads (benchmark/lib/xsweep.py).
+
+The span that caused it: every span event holds a small integer `id` and
+the id of the span that was open in its context when it began (`parent`;
+the root span, decode_trace, is 0). The open span is a contextvar beside the
+trace, so it rides instrumented_submit's copy_context(): a chunk.prepare on a
+pqt-host thread names the row_group.device (or query.decode) that submitted
+it, a dispatch on pqt-dispatch the span open on the planning thread that
+enqueued it. to_chrome_trace() writes both under `args`; an annotation that
+carries args carries `parent` too. Nothing in the program reads them.
+
 This module never imports jax: it looks it up in sys.modules, and a process
 that never imported jax has no profiler session to annotate. Not annotated:
 record_span=False stages (per-row micro-stages) and the back-dated
@@ -47,6 +82,7 @@ shared trace are lock-protected.
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import sys
@@ -88,6 +124,15 @@ _active_var: ContextVar = ContextVar("pqt_decode_trace", default=None)
 # copy_context() carry as the trace itself, so nesting detected inside a
 # pool worker attributes against the stage open on that worker.
 _stage_depth_var: ContextVar = ContextVar("pqt_stage_depth", default=0)
+
+# The id of the span OPEN in this context (None outside any trace): what a
+# span records as its `parent` when it begins. It rides copy_context() like
+# the two above, so a pool task's first span names the span that was open on
+# the thread that submitted it — a chunk.prepare on a pqt-host thread its
+# row_group.device or query.decode, a dispatch on pqt-dispatch the span open
+# on the planning thread that enqueued it. Ids are small integers, per trace;
+# the root span (decode_trace) is 0.
+_span_var: ContextVar = ContextVar("pqt_span", default=None)
 
 # Process-wide count of span-event allocations: the zero-overhead oracle.
 # A read with no trace active must leave it untouched — tests assert that by
@@ -132,8 +177,10 @@ class DecodeTrace:
         self.trace_id: str | None = None
         self._lock = threading.Lock()
         self._t0 = time.perf_counter_ns()
-        # finished spans: (name, tid, start_ns rel to _t0, dur_ns, args|None)
+        # finished spans: (name, tid, start_ns rel to _t0, dur_ns, args|None,
+        # id, parent id|None)
         self._events: list[tuple] = []
+        self._ids = itertools.count(1)  # 0 is the root span's
         self._threads: dict[int, str] = {}
 
     # -- collection (lock-protected merge; called from pool threads) ----------
@@ -155,6 +202,8 @@ class DecodeTrace:
         dur_ns: int = 0,
         args: dict | None = None,
         nested: bool = False,
+        ident: int | None = None,
+        parent: int | None = None,
     ) -> None:
         global _span_allocs
         with self._lock:
@@ -173,8 +222,10 @@ class DecodeTrace:
                     self.events_dropped += 1
                 else:
                     _span_allocs += 1
+                    if ident is None:  # a back-dated sub-clock: never open
+                        ident, parent = next(self._ids), _span_var.get()
                     self._events.append(
-                        (name, tid, start_ns - self._t0, dur_ns, args)
+                        (name, tid, start_ns - self._t0, dur_ns, args, ident, parent)
                     )
 
     # -- reporting -------------------------------------------------------------
@@ -271,8 +322,10 @@ class DecodeTrace:
         format Perfetto and chrome://tracing load). Every span is a complete
         ("X") event with microsecond ts/dur relative to trace start, on its
         real thread lane; one thread_name metadata ("M") event names each
-        lane (MainThread / pqt-host_* / pqt-dispatch_*). Aggregates and
-        bump() counters ride in otherData."""
+        lane (MainThread / pqt-host_* / pqt-dispatch_*). Every span's args
+        hold its `id` and, but for the root, `parent`: the id of the span
+        that was open in its context when it began — across a pool hop, the
+        submitter's. Aggregates and bump() counters ride in otherData."""
         pid = os.getpid()
         with self._lock:
             events = list(self._events)
@@ -304,8 +357,9 @@ class DecodeTrace:
             for tid, tname in sorted(threads.items())
         ]
         events.sort(key=lambda e: (e[1], e[2], -e[3]))  # (tid, start, -dur)
-        for name, tid, rel_ns, dur_ns, args in events:
-            ev = {
+        for name, tid, rel_ns, dur_ns, args, ident, parent in events:
+            links = {"id": ident} if parent is None else {"id": ident, "parent": parent}
+            out.append({
                 "ph": "X",
                 "name": name,
                 "cat": name.split(".", 1)[0],
@@ -313,10 +367,8 @@ class DecodeTrace:
                 "tid": tid,
                 "ts": rel_ns / 1e3,
                 "dur": dur_ns / 1e3,
-            }
-            if args:
-                ev["args"] = dict(args)
-            out.append(ev)
+                "args": {**(args or {}), **links},
+            })
         doc = {
             "traceEvents": out,
             "displayTimeUnit": "ms",
@@ -335,17 +387,21 @@ class DecodeTrace:
             json.dump(self.to_chrome_trace(), f)
 
 
-def _open_annotation(name: str, args: dict | None):
+def _open_annotation(name: str, args: dict | None, parent: int | None = None):
     """Enter a jax.profiler.TraceAnnotation("pqt:<name>", **args) on this
     thread and return it (None where jax was never imported: no profiler
-    session can exist). Outside a profiler session the annotation is jax's
-    own no-op. Called only under an active trace."""
+    session can exist). An annotation that carries args also carries its
+    span's `parent`; one without stays a bare name. Outside a profiler
+    session the annotation is jax's own no-op. Called only under an active
+    trace."""
     global _annotation_allocs
     jax = sys.modules.get("jax")
     profiler = getattr(jax, "profiler", None)  # None too while jax is mid-import
     if profiler is None:
         return None
     _annotation_allocs += 1
+    if args and parent is not None:
+        args = {**args, "parent": parent}
     ann = profiler.TraceAnnotation("pqt:" + name, **(args or {}))
     ann.__enter__()
     return ann
@@ -364,15 +420,18 @@ def decode_trace():
     shadow; traces on OTHER threads are unaffected (contextvar isolation)."""
     t = DecodeTrace()
     token = _active_var.set(t)
+    span_token = _span_var.set(0)
     try:
         yield t
     finally:
+        _span_var.reset(span_token)
         _active_var.reset(token)
         # root span: the whole traced region, on the activating thread
         t._commit(
             "decode_trace",
             start_ns=t._t0,
             dur_ns=time.perf_counter_ns() - t._t0,
+            ident=0,
         )
 
 
@@ -385,14 +444,32 @@ def _enter_stage() -> tuple:
     return _stage_depth_var.set(depth + 1), depth > 0
 
 
-def _exit_stage(token) -> None:
+def _reset(var: ContextVar, token) -> None:
     try:
-        _stage_depth_var.reset(token)
+        var.reset(token)
     except ValueError:  # pragma: no cover - exotic cross-context consumer
         # a generator suspended inside the stage was resumed from another
         # context: losing the reset mis-tags later commits there as
-        # nested at worst — never break the decode over bookkeeping
+        # nested (or under a closed parent) at worst — never break the
+        # decode over bookkeeping
         pass
+
+
+_NO_SPAN = (None, None, None, None)  # what a record_span=False stage opens
+
+
+def _open_span(t: DecodeTrace, name: str, args: dict | None) -> tuple:
+    """Begin a recorded span in this context: (its id, the id of the span
+    that was open here, the reset token, its profiler annotation)."""
+    ident = next(t._ids)
+    parent = _span_var.get()
+    return ident, parent, _span_var.set(ident), _open_annotation(name, args, parent)
+
+
+def _close_span(token, ann) -> None:
+    _close_annotation(ann)
+    if token is not None:
+        _reset(_span_var, token)
 
 
 @contextmanager
@@ -417,14 +494,16 @@ def stage(
         yield
         return
     token, nested = _enter_stage()
-    ann = _open_annotation(name, args) if record_span else None
+    ident, parent, span_token, ann = (
+        _open_span(t, name, args) if record_span else _NO_SPAN
+    )
     t0 = time.perf_counter_ns()
     try:
         yield
     finally:
         dt = time.perf_counter_ns() - t0
-        _close_annotation(ann)
-        _exit_stage(token)
+        _close_span(span_token, ann)
+        _reset(_stage_depth_var, token)
         t._commit(
             name,
             dt / 1e9,
@@ -434,6 +513,8 @@ def stage(
             dur_ns=dt,
             args=args if record_span else None,
             nested=nested,
+            ident=ident,
+            parent=parent,
         )
 
 
@@ -456,7 +537,9 @@ def timed_stage(name: str, nbytes: int = 0, record_span: bool = True):
     t = _active_var.get()
     out = _Elapsed()
     token, nested = (None, False) if t is None else _enter_stage()
-    ann = _open_annotation(name, None) if t is not None and record_span else None
+    ident, parent, span_token, ann = (
+        _open_span(t, name, None) if t is not None and record_span else _NO_SPAN
+    )
     t0 = time.perf_counter_ns()
     try:
         yield out
@@ -464,8 +547,8 @@ def timed_stage(name: str, nbytes: int = 0, record_span: bool = True):
         dt = time.perf_counter_ns() - t0
         out.seconds = dt / 1e9
         if t is not None:
-            _close_annotation(ann)
-            _exit_stage(token)
+            _close_span(span_token, ann)
+            _reset(_stage_depth_var, token)
             t._commit(
                 name,
                 out.seconds,
@@ -474,6 +557,8 @@ def timed_stage(name: str, nbytes: int = 0, record_span: bool = True):
                 start_ns=t0 if record_span else None,
                 dur_ns=dt,
                 nested=nested,
+                ident=ident,
+                parent=parent,
             )
 
 
@@ -488,14 +573,16 @@ def span(name: str, args: dict | None = None):
     if t is None:
         yield
         return
-    ann = _open_annotation(name, args)
+    ident, parent, span_token, ann = _open_span(t, name, args)
     t0 = time.perf_counter_ns()
     try:
         yield
     finally:
         dur = time.perf_counter_ns() - t0
-        _close_annotation(ann)
-        t._commit(name, start_ns=t0, dur_ns=dur, args=args)
+        _close_span(span_token, ann)
+        t._commit(
+            name, start_ns=t0, dur_ns=dur, args=args, ident=ident, parent=parent
+        )
 
 
 def active() -> bool:

@@ -1357,6 +1357,88 @@ class TestFlightRecorder:
         reqs = json.loads(b)["requests"]
         assert any(r["id"] == "demo" for r in reqs)
 
+    def test_query_record_holds_every_request_phase(self, sampled_server):
+        """PR 37: a request's own phases are stages of its trace, so the
+        flight record's rollup (and the trace served beside it) accounts for
+        the handler thread from the first body byte to the last answer byte:
+        serve.parse, serve.admit (the gate; the byte charge after the plan
+        is a span of the same name), serve.plan — a stage now, so it has
+        seconds — and serve.respond once a request, serve.open_reader once a
+        unit, nested in the unit's serve.aggregate."""
+        server = sampled_server
+        body = {
+            "paths": ["a.parquet", "b.parquet"],
+            "filters": [["id", ">=", 100]],
+            "aggregates": ["count", ["sum", "id"]],
+        }
+        status, _h, payload = _request(
+            server, "POST", "/v1/query", body, headers={"X-Request-Id": "phases"}
+        )
+        assert status == 200, payload
+        units = json.loads(payload)["units"]
+        assert units == -(-ROWS_A // ROW_GROUP) + -(-ROWS_B // ROW_GROUP)
+        s, doc = _settled_record(server, "phases")
+        assert s == 200
+        stages = doc["stages"]
+        for name in ("serve.parse", "serve.admit", "serve.plan", "serve.respond"):
+            assert stages[name]["calls"] == 1, (name, stages.get(name))
+            assert stages[name]["seconds"] > 0, name
+            assert "nested_seconds" not in stages[name], name  # the request's own wall
+        assert stages["serve.open_reader"]["calls"] == units
+        assert stages["serve.aggregate"]["calls"] == units
+        assert stages["serve.open_reader"]["nested_seconds"] == pytest.approx(
+            stages["serve.open_reader"]["seconds"]
+        )
+        assert stages["serve.merge"]["calls"] == units
+
+        s, _h, b = _request(server, "GET", "/v1/debug/requests/phases/trace")
+        assert s == 200
+        events = [e for e in json.loads(b)["traceEvents"] if e["ph"] == "X"]
+        by_name: dict = {}
+        for e in events:
+            by_name.setdefault(e["name"], []).append(e)
+        # the two halves of admission and of a unit's reader are both in the trace
+        assert len(by_name["serve.admit"]) == 2
+        assert len(by_name["serve.open_reader"]) == 2 * units
+        assert by_name["serve.plan"][0]["args"]["paths"] == "a.parquet,b.parquet"
+        # in time order on the handler's lane, and every span names its parent
+        (lane,) = {e["tid"] for n in ("serve.parse", "serve.plan", "serve.respond") for e in by_name[n]}
+        order = sorted(
+            (e for e in events if e["tid"] == lane and e["name"].startswith("serve.")
+             and e["name"] != "serve.merge"),
+            key=lambda e: e["ts"],
+        )
+        assert [e["name"] for e in order] == [
+            "serve.parse", "serve.admit", "serve.plan", "serve.admit", "serve.respond",
+        ]
+        ids = {e["args"]["id"] for e in events}
+        assert len(ids) == len(events)
+        assert all(e["args"].get("parent", 0) in ids for e in events)
+        aggregates = {e["args"]["id"] for e in by_name["serve.aggregate"]}
+        assert {e["args"]["parent"] for e in by_name["serve.open_reader"]} == aggregates
+
+    def test_scan_record_holds_parse_admit_plan_and_streams_its_answer(
+        self, sampled_server
+    ):
+        """A scan's answer is the chunked write serve.stream brackets: it
+        has the head's three phases and no serve.respond."""
+        server = sampled_server
+        status, _h, _b = _scan(
+            server,
+            {"paths": "a.parquet", "columns": ["id"]},
+            headers={"X-Request-Id": "scan-phases"},
+        )
+        assert status == 200
+        s, doc = _settled_record(server, "scan-phases")
+        assert s == 200
+        stages = doc["stages"]
+        for name in ("serve.parse", "serve.admit", "serve.plan"):
+            assert stages[name]["calls"] == 1 and stages[name]["seconds"] > 0, name
+        units = doc["plan"]["units_admitted"]
+        assert stages["serve.open_reader"]["calls"] == units == stages["serve.execute"]["calls"]
+        assert stages["serve.stream"]["calls"] >= 1
+        assert "serve.respond" not in stages
+
     def test_hostile_request_id_sanitized_everywhere(self, sampled_server):
         server = sampled_server
         raw = "e{vil}|id;" + "x" * 200

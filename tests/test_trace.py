@@ -630,7 +630,9 @@ class TestAnnotationOverhead:
             assert trace_mod.annotation_allocations() == before + 3
         assert t.stages["assemble"].calls == 100
         args = [e for e in t.to_chrome_trace()["traceEvents"] if e["name"] == "dispatch"]
-        assert args[0]["args"] == {"group": 1, "column": "a"}
+        assert args[0]["args"] == {  # its own args, then id / parent (the root is 0)
+            "group": 1, "column": "a", "id": args[0]["args"]["id"], "parent": 0,
+        }
 
 
 def _hlo(fn, *args, **kw) -> str:
@@ -822,3 +824,287 @@ class TestDispatchAccounting:
         assert t.stages["pool.wait"].calls == 12  # 6 prepares + 6 dispatches
         for pool in ("pqt-dispatch", "pqt-host"):
             assert after[key % pool] - before.get(key % pool, 0) == 6, pool
+
+
+# -- PR 37: the waits are stages, and a span names the span that caused it -------
+
+
+def _write_token_sample(path: str) -> str:
+    """A LIST<int32> file of three small row groups: what lists="pack" reads."""
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(37)
+    docs = [rng.integers(0, 500, int(n)).astype(np.int32) for n in rng.integers(3, 40, 90)]
+    pq.write_table(
+        pa.table({"input_ids": pa.array(docs, pa.list_(pa.int32()))}), path, row_group_size=30
+    )
+    return path
+
+
+@pytest.fixture(scope="module")
+def token_sample(tmp_path_factory):
+    return _write_token_sample(str(tmp_path_factory.mktemp("trace_tok") / "tok.parquet"))
+
+
+def _read_whole(path):
+    with FileReader(path) as r:
+        return r.read_row_groups_device()
+
+
+def _read_by_group(path):
+    with FileReader(path) as r:
+        return [r.read_row_group_device(i) for i in range(r.num_row_groups)]
+
+
+def _read_batches(path):
+    with FileReader(path) as r:
+        return list(r.iter_device_batches(1000, drop_remainder=False))
+
+
+def _read_packed(path):
+    with FileReader(path) as r:
+        return list(r.iter_device_batches(
+            4, columns=["input_ids"], lists="pack", seq_len=64, drop_remainder=False
+        ))
+
+
+# (the read, its file, row groups x columns, whether the prepares fan out:
+# a one-chunk plan prepares on the planning thread and has no prepare to wait for)
+_WAIT_SITES = {
+    "read_row_groups_device": (_read_whole, "device", (2, ("code", "ts", "plain")), True),
+    "read_row_group_device": (_read_by_group, "device", (2, ("code", "ts", "plain")), True),
+    "iter_device_batches": (_read_batches, "device", (2, ("code", "ts", "plain")), True),
+    "iter_device_batches-pack": (_read_packed, "token", (3, ("input_ids.list.element",)), False),
+}
+
+
+class TestWaitStages:
+    """The five places where core/reader.py blocks on a pool future are
+    stages: plan.wait_prepare on the planning thread (before it may enqueue
+    the chunk's dispatch), plan.wait_dispatch on the consumer (before
+    _deliver). Each carries its chunk's args and is recorded on the thread
+    that waited."""
+
+    @pytest.fixture
+    def files(self, device_sample, token_sample):
+        return {"device": device_sample, "token": token_sample}
+
+    @pytest.mark.parametrize("site", list(_WAIT_SITES))
+    def test_each_site_records_its_wait_with_the_chunks_args(self, site, files, host_pool):
+        read, which, (groups, columns), fans_out = _WAIT_SITES[site]
+        read(files[which])  # compile and warm lazy paths outside the trace
+        with decode_trace() as t:
+            read(files[which])
+        chunks = {(g, c) for g in range(groups) for c in columns}
+        events = [e for e in t.to_chrome_trace()["traceEvents"] if e["ph"] == "X"]
+        waits = {n: [e for e in events if e["name"] == n]
+                 for n in ("plan.wait_prepare", "plan.wait_dispatch")}
+        wanted = ["plan.wait_prepare", "plan.wait_dispatch"] if fans_out else ["plan.wait_dispatch"]
+        for name in wanted:
+            assert t.stages[name].calls == len(chunks), (name, t.stages[name])
+            assert {(e["args"]["group"], e["args"]["column"]) for e in waits[name]} == chunks, name
+            assert {e["tid"] for e in waits[name]} == {threading.get_ident()}, name
+        if not fans_out:
+            assert "plan.wait_prepare" not in t.stages
+        # every chunk's dispatch was enqueued after its prepare had been waited for,
+        # and delivered after its dispatch had been: the waits sit between the stages
+        deliver = {(e["args"]["group"], e["args"]["column"]): e for e in events
+                   if e["name"] == "deliver" and "column" in e.get("args", {})}
+        for e in waits["plan.wait_dispatch"]:
+            d = deliver[(e["args"]["group"], e["args"]["column"])]
+            assert e["ts"] + e["dur"] <= d["ts"] + 1e-3
+
+    @pytest.mark.parametrize("site", list(_WAIT_SITES))
+    def test_no_trace_no_span_and_no_annotation(self, site, files, host_pool):
+        read, which, _, _ = _WAIT_SITES[site]
+        read(files[which])
+        spans, anns = trace_mod.span_allocations(), trace_mod.annotation_allocations()
+        read(files[which])
+        assert trace_mod.span_allocations() == spans
+        assert trace_mod.annotation_allocations() == anns
+
+    def test_a_wait_on_a_finished_future_is_one_stage_call(self):
+        """The helper is the stage() call and nothing else: a done future
+        comes back at once, traced or not."""
+        from concurrent.futures import Future
+
+        from parquet_tpu.core.reader import _wait
+
+        fut = Future()
+        fut.set_result("plan")
+        before = trace_mod.span_allocations()
+        assert _wait("plan.wait_dispatch", fut, 0, ("a", "b")) == "plan"
+        assert trace_mod.span_allocations() == before
+        with decode_trace() as t:
+            assert _wait("plan.wait_dispatch", fut, 3, ("a", "b")) == "plan"
+        assert t.stages["plan.wait_dispatch"].calls == 1
+        (ev,) = [e for e in t.to_chrome_trace()["traceEvents"] if e["name"] == "plan.wait_dispatch"]
+        assert ev["args"] == {"group": 3, "column": "a.b", "id": 1, "parent": 0}
+
+    def test_under_query_decode_the_waits_are_nested_and_counted_once(
+        self, device_sample, host_pool
+    ):
+        """Inside a stage (the daemon's query.decode) the waits are part of
+        its wall: nested, so exclusive_seconds() and the report's TOTAL count
+        them once. Under the row_group.device SPAN alone nothing encloses
+        them, and they count as their own wall."""
+        _read_by_group(device_sample)
+        with decode_trace() as t:
+            with FileReader(device_sample) as r:
+                with stage("query.decode", args={"group": 0}):
+                    r.read_row_group_device(0)
+        roll = t.stage_rollup()
+        for name in ("plan.wait_prepare", "plan.wait_dispatch"):
+            assert roll[name]["calls"] == 3
+            assert roll[name]["nested_seconds"] == pytest.approx(roll[name]["seconds"])
+        waited = roll["plan.wait_prepare"]["seconds"] + roll["plan.wait_dispatch"]["seconds"]
+        assert waited <= roll["query.decode"]["seconds"]
+        assert t.exclusive_seconds() == pytest.approx(
+            sum(s["seconds"] - s.get("nested_seconds", 0.0) for s in roll.values())
+        )
+        # what is left outside query.decode is the pool threads' own work, not the waits
+        flat = sum(s["seconds"] for s in roll.values())
+        assert t.exclusive_seconds() <= flat - waited + 1e-9
+
+        with decode_trace() as bare:
+            _read_by_group(device_sample)
+        roll = bare.stage_rollup()
+        assert "nested_seconds" not in roll["plan.wait_dispatch"]
+        assert "nested_seconds" not in roll["plan.wait_prepare"]
+
+    def test_the_new_names_are_read_by_their_own_metrics_only(self):
+        """Every per-layer metric that sums stage seconds or bytes selects its
+        stages by name or by prefix (benchmark/layer_metrics/*.json: e.g.
+        `prepare.*`, `dispatch`, `io`, `io.read`). The stages this PR added are
+        matched by the two metrics that were added to read them and by no
+        other: every older metric reads exactly the stages it read."""
+        import json
+        from pathlib import Path
+
+        new = ("plan.wait_prepare", "plan.wait_dispatch", "serve.parse", "serve.admit",
+               "serve.plan", "serve.open_reader", "serve.respond")
+        readers: dict = {name: set() for name in new}
+        metrics_dir = Path(__file__).resolve().parents[1] / "benchmark" / "layer_metrics"
+        for path in sorted(metrics_dir.glob("*.json")):
+            spec = json.loads(path.read_text())
+            for sel in spec.get("args", {}).get("stages", []):
+                for name in new:
+                    if name == sel or (sel.endswith("*") and name.startswith(sel[:-1])):
+                        readers[name].add(spec["name"])
+        assert readers == {
+            "plan.wait_prepare": {"consumer_wait_ms_per_mrow"},
+            "plan.wait_dispatch": {"consumer_wait_ms_per_mrow"},
+            "serve.parse": {"request_host_ms_per_query"},
+            "serve.admit": {"request_host_ms_per_query"},
+            "serve.plan": {"request_host_ms_per_query"},
+            "serve.open_reader": set(),
+            "serve.respond": {"request_host_ms_per_query"},
+        }
+
+
+class TestSpanLinks:
+    """A span event holds a small integer `id` and the id of the span that
+    was open in its context when it began (`parent`); the context rides
+    instrumented_submit, so a pool task's span names its submitter's."""
+
+    def test_ids_are_unique_and_the_root_is_zero(self):
+        with decode_trace() as t:
+            with span("row_group.device", {"group": 0}):
+                with stage("deliver"):
+                    add_seconds("prepare.copy", 0.001)
+            with stage("assemble", record_span=False):  # no span: no id, and no parent to anyone
+                with stage("inner"):
+                    pass
+        ev = {e["name"]: e["args"] for e in t.to_chrome_trace()["traceEvents"] if e["ph"] == "X"}
+        assert ev["decode_trace"] == {"id": 0}
+        assert ev["row_group.device"] == {"group": 0, "id": 1, "parent": 0}
+        assert ev["deliver"] == {"id": 2, "parent": 1}
+        assert ev["prepare.copy"] == {"id": 3, "parent": 2}  # a back-dated sub-clock, under what was open
+        assert ev["inner"] == {"id": 4, "parent": 0}
+
+    def test_a_pool_tasks_span_names_the_span_open_where_it_was_submitted(self):
+        pool = ThreadPoolExecutor(max_workers=1, thread_name_prefix="pqt-test")
+
+        def prepare():
+            with span("chunk.prepare", {"column": "a"}):
+                with stage("io.read"):
+                    pass
+            return threading.get_ident()
+
+        def dispatch():
+            with stage("dispatch"):
+                pass
+
+        try:
+            with decode_trace() as t:
+                with span("row_group.device", {"group": 7}):
+                    worker = instrumented_submit(pool, prepare).result(timeout=10)
+                    with stage("plan.wait_prepare"):
+                        pass
+                    instrumented_submit(pool, dispatch).result(timeout=10)
+                instrumented_submit(pool, dispatch).result(timeout=10)
+        finally:
+            pool.shutdown(wait=True)
+        events = [e for e in t.to_chrome_trace()["traceEvents"] if e["ph"] == "X"]
+        by_name: dict = {}
+        for e in events:
+            by_name.setdefault(e["name"], []).append(e)
+        (group,) = by_name["row_group.device"]
+        (prep,) = by_name["chunk.prepare"]
+        assert prep["tid"] == worker != group["tid"]
+        assert prep["args"]["parent"] == group["args"]["id"]
+        assert by_name["io.read"][0]["args"]["parent"] == prep["args"]["id"]
+        # enqueued after the wait had closed: the dispatch names the span open then, not the wait
+        inside, after = sorted(by_name["dispatch"], key=lambda e: e["ts"])
+        assert inside["args"]["parent"] == group["args"]["id"]
+        assert after["args"]["parent"] == 0
+        assert len({e["args"]["id"] for e in events}) == len(events)
+
+    def test_a_device_reads_chunks_name_their_row_group(self, device_sample, host_pool):
+        _read_by_group(device_sample)
+        with decode_trace() as t:
+            with FileReader(device_sample) as r:
+                r.read_row_group_device(1)
+        events = [e for e in t.to_chrome_trace()["traceEvents"] if e["ph"] == "X"]
+        (group,) = [e for e in events if e["name"] == "row_group.device"]
+        gid = group["args"]["id"]
+        by_id = {e["args"]["id"]: e for e in events}
+        for name in ("chunk.prepare", "dispatch", "plan.wait_prepare", "plan.wait_dispatch", "deliver"):
+            mine = [e for e in events if e["name"] == name]
+            assert len(mine) == 3 and {e["args"]["parent"] for e in mine} == {gid}, name
+        for e in events:
+            if e["name"] in ("dispatch.upload", "dispatch.launch"):
+                assert by_id[e["args"]["parent"]]["name"] == "dispatch"
+                assert by_id[e["args"]["parent"]]["tid"] == e["tid"] != group["tid"]
+            if e["name"] == "io.read":
+                assert by_id[e["args"]["parent"]]["name"] == "chunk.prepare"
+
+    def test_an_annotation_carries_parent_only_beside_its_own_args(self, monkeypatch):
+        import jax
+
+        built = []
+
+        class Recorder:
+            def __init__(self, name, **kw):
+                built.append((name, kw))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+        with decode_trace():
+            with span("row_group.device", {"group": 2}):
+                with stage("deliver", args={"group": 2, "column": "a"}):
+                    pass
+                with stage("deliver.pack"):
+                    pass
+        assert built == [
+            ("pqt:row_group.device", {"group": 2, "parent": 0}),
+            ("pqt:deliver", {"group": 2, "column": "a", "parent": 1}),
+            ("pqt:deliver.pack", {}),
+        ]
