@@ -2192,6 +2192,22 @@ struct FramePlane {
       words[slot & ((int64_t{1} << log_len) - 1)] |= (v & mask)
                                                      << ((slot >> log_len) * bits);
   }
+  // n consecutive slots from `slot`, slot + k taking bits [from_bit, +bits)
+  // of v[k]: the plane's words in order at one shift, a stretch a wrap.
+  inline void store(int64_t slot, const uint32_t* v, int64_t n, int from_bit) const {
+    if (!bits) return;
+    const int64_t len = int64_t{1} << log_len;
+    while (n > 0) {
+      const int64_t at = slot & (len - 1);
+      const int64_t m = n < len - at ? n : len - at;
+      const int sh = static_cast<int>(slot >> log_len) * bits;  // 0 at 32 bits
+      uint32_t* w = words + at;
+      for (int64_t k = 0; k < m; k++) w[k] |= ((v[k] >> from_bit) & mask) << sh;
+      slot += m;
+      v += m;
+      n -= m;
+    }
+  }
 };
 }  // namespace
 
@@ -2265,6 +2281,79 @@ ssize_t ptq_delta_frame(const uint8_t* stream, size_t stream_len,
   if (width < 64 && (seen >> width)) return -3;
   if (ns_out) *ns_out = StageClock::now() - t0;
   return static_cast<ssize_t>(written);
+}
+
+// The hybrid frame of one upload (kernels/device_ops.py pack_hybrid_upload,
+// whose NumPy writer is this function's reference, byte for byte): the
+// values of the runs (counts[r] each, end to end from slot 0) written
+// position by position at ONE static width. An RLE run (is_rle[r] != 0)
+// puts rle_values[r] into each of its slots; a bit-packed run reads its
+// counts[r] values of `width` bits from bit bit_starts[r] of `packed` (the
+// chunk's bit-packed groups end to end, no headers) — an RLE run's bit_start
+// is read by nobody. Slot s takes its value's low lo_bits bits in the first
+// plane of dst and the hi_bits above them in the second (each 0, 1, 2, 4, 8,
+// 16 or 32; lo_bits + hi_bits >= width, at most 32); a value is cut to
+// lo_bits + hi_bits bits. dst holds n_pad * (lo_bits + hi_bits) / 32 words
+// and is zeroed here, so slots past the runs' total hold 0. n_pad is a power
+// of two >= 32. ns_out as ptq_repack_pages'. Returns the values written, -1
+// on bad arguments (a run outside the payload or the slots).
+ssize_t ptq_hybrid_frame(const uint8_t* packed, size_t packed_len,
+                         const uint8_t* is_rle, const int64_t* counts,
+                         const uint32_t* rle_values, const int64_t* bit_starts,
+                         int64_t n_runs, int width, int lo_bits, int hi_bits,
+                         int64_t n_pad, uint32_t* dst, int64_t* ns_out) {
+  const int64_t t0 = ns_out ? StageClock::now() : 0;
+  auto part = [](int b) { return b == 0 || (b <= 32 && !(b & (b - 1))); };
+  if (width < 0 || width > 32 || !part(lo_bits) || !part(hi_bits) ||
+      lo_bits + hi_bits > 32 || lo_bits + hi_bits < width ||
+      (hi_bits && !lo_bits))
+    return -1;
+  if (n_pad < 32 || (n_pad & (n_pad - 1))) return -1;
+  const FramePlane lo(dst, lo_bits, n_pad);
+  const FramePlane hi(dst + n_pad * lo_bits / 32, hi_bits, n_pad);
+  std::memset(dst, 0, static_cast<size_t>(n_pad) * (lo_bits + hi_bits) / 8);
+  const uint64_t payload_bits = static_cast<uint64_t>(packed_len) * 8;
+  const uint64_t wmask = (1ull << width) - 1;  // width <= 32
+  constexpr int64_t kBlock = 1024;  // values unpacked at a time: 4 KiB, in L1
+  uint32_t block[kBlock];
+  int64_t slot = 0;
+  for (int64_t r = 0; r < n_runs; r++) {
+    int64_t n = counts[r];
+    if (n < 0 || slot + n > n_pad) return -1;
+    const bool rle = is_rle[r] != 0;
+    uint64_t bit = 0;
+    if (rle) {
+      for (int64_t k = 0, m = n < kBlock ? n : kBlock; k < m; k++)
+        block[k] = rle_values[r];
+    } else {
+      if (bit_starts[r] < 0) return -1;
+      bit = static_cast<uint64_t>(bit_starts[r]);
+      if (bit + static_cast<uint64_t>(n) * width > payload_bits) return -1;
+    }
+    while (n > 0) {
+      const int64_t take = n < kBlock ? n : kBlock;
+      for (int64_t k = 0; !rle && k < take; k++, bit += width) {
+        const size_t byte = bit >> 3;
+        const int sh = static_cast<int>(bit & 7);
+        uint64_t raw;
+        if (byte + 8 <= packed_len) {
+          raw = xread64(packed + byte) >> sh;  // sh + width <= 39: one load
+        } else {  // the payload's last bytes: no 8-byte load fits
+          uint64_t acc = 0;
+          for (size_t i = byte; i < packed_len; i++)
+            acc |= static_cast<uint64_t>(packed[i]) << (8 * (i - byte));
+          raw = acc >> sh;
+        }
+        block[k] = static_cast<uint32_t>(raw & wmask);
+      }
+      lo.store(slot, block, take, 0);
+      hi.store(slot, block, take, lo_bits);
+      slot += take;
+      n -= take;
+    }
+  }
+  if (ns_out) *ns_out = StageClock::now() - t0;
+  return static_cast<ssize_t>(slot);
 }
 
 // DELTA_BINARY_PACKED encode (mirrors ops/delta.py encode_delta

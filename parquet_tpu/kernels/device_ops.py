@@ -7,17 +7,16 @@ the host into flat tables (ops/rle_hybrid.py prescan, ops/delta.py prescan);
 everything here is gathers, shifts, segment-broadcasts and scans — the shapes
 TPU executes well (SURVEY §7.2 M3).
 
-Key formulation — bit-unpack without byte loops, and without a gather where
-the layout allows it. A bit-packed hybrid payload is one dense stream at a
-static width W, so W words hold exactly 32 values at fixed bit positions: the
-words are first re-packed by constant shifts of strided slices so that no
-value crosses a word (_align_words), and a value is ONE gather (a run's
-values start where the run does, which is data). A DELTA_BINARY_PACKED
-stream's width is data per miniblock, so on the wire a value's bit position
-is data too; the freeze therefore re-frames the deltas position by position
-at one static width a chunk (pack_delta_upload: the delta frame), in the
-layout _align_words makes, and the delta kernel reads them by static shifts
-alone: no index array, no gather over positions.
+Key formulation — bit-unpack without byte loops, without an index array and
+without a gather. On the wire a value's bit position is data: a hybrid
+stream's RLE runs hold no payload and a page's last group overshoots, a
+DELTA_BINARY_PACKED stream's width changes per miniblock. So the freeze
+re-frames both position by position at ONE static width a chunk
+(pack_hybrid_upload: the hybrid frame; pack_delta_upload: the delta frame),
+in planes of power-of-two part widths in which word j holds slots j, j + L,
+j + 2L, ...: the kernels read a plane by static shifts and one concatenation
+(_unpack_plane). No dictionary value and no value of a delta column is
+formed on the host; only index and delta bits change places.
 
 All index arithmetic is int32: TPU v5e has no native 64-bit integer ALU path
 (XLA emulates i64 as i32 pairs, ~10-100x slower for gather/scan-heavy code),
@@ -35,8 +34,9 @@ not lower on the Mosaic TPU backend of its day — its essential dynamic 1-D
 gather (words[bitpos >> 5]) trips Mosaic's gather lowering rule, which only
 supports take_along_axis-shaped indices. What the XLA formulations cost on
 a v5e is measured, not assumed (PERF.md sections 5 and 6; jax 0.9.0, libtpu
-0.0.34): the reader is device-bound, the two decode kernels here are most of
-its window, and each is a count of full-length gather passes — one
+0.0.34): through PR 37 the reader was device-bound, the two decode kernels
+here were most of its window, and each was a count of full-length gather
+passes — one
 table[idx] over 2^20 indices takes 9.3-9.9 ms whatever the table's length
 above a hundred entries (15-16 ms for 64-bit entries), against 0.3 ms for
 prefix_sum and 0.2-0.6 ms for a scatter-add of up to 65,536 32-bit updates.
@@ -44,18 +44,20 @@ That is why nothing below looks a run, miniblock or page up per value: the
 position -> segment index is a scatter and a scan (_segment_of; searchsorted
 was 13-17 dependent gather passes, 65 % of the device's busy time in PR 26's
 trace), and a segment's fields reach its positions the same way (_spread:
-scatter the differences at the starts, scan; four of expand_hybrid_device's
-six passes and five of the seven that delta_packed_decode_device then had
-went with it in PR 29). What is left of the hybrid kernel is its read of the packed words:
-two words a value through PR 30, 16.5 of the 17-18 ms a stream cost per 2^20
-values at any width and run count; since PR 31 it aligns the payload first
-(under 0.2 ms) and reads one: 8.1-8.4 ms a stream, 9.2 with 65,536 runs, 6.2
-from a 1,024-word payload (PERF.md section 6, PR 31; the gather is 7.5 ms
-with the kernel's near-sequential indices). The delta kernel read the wire
-through PR 33, two uint64 words a value at its miniblock's width: four 32-bit
-passes, 48.9 of its 50.0 ms a stream (the fourth word table was read from
-HBM once the wire passed 2^19 words: a 22 ms pass). Since PR 34 it reads the
-delta frame and holds no gather over positions: under 1 ms a stream (PERF.md
+scatter the differences at the starts, scan; four of the six passes
+expand_hybrid_device then had and five of delta_packed_decode_device's seven
+went with it in PR 29). What was left of the hybrid kernel was its read of
+the packed words: two words a value through PR 30, 16.5 of the 17-18 ms a
+stream cost per 2^20 values at any width and run count; one word a value
+after an alignment by fixed shifts through PR 37: 8.1-8.4 ms a stream, 7.5
+of them the gather (PERF.md section 6, PR 31). Since PR 38 it reads the
+hybrid frame and holds no gather, no scatter and no scan: 5-12 us a stream,
+and the dictionary gather is what the device still does (PERF.md section 5,
+PR 38). The delta kernel read the wire through PR 33, two uint64 words a
+value at its miniblock's width: four 32-bit passes, 48.9 of its 50.0 ms a
+stream (the fourth word table was read from HBM once the wire passed 2^19
+words: a 22 ms pass). Since PR 34 it reads the delta frame and holds no
+gather over positions: under 1 ms a stream (PERF.md
 section 6, PR 34).
 """
 
@@ -103,7 +105,6 @@ from typing import NamedTuple
 
 __all__ = [
     "MAX_DEVICE_BATCH_BITS",
-    "bytes_to_words32",
     "expand_hybrid_device",
     "pack_hybrid_upload",
     "delta_packed_decode_device",
@@ -166,9 +167,8 @@ MAX_DEVICE_BATCH_BITS = 1 << 31
 
 
 # Every jitted kernel below traces under one jax.named_scope "pqt.<kernel>"
-# (the two that hold the reader's device time also under inner scopes:
-# pqt.hybrid_expand/{find_run,unpack,select}, pqt.delta_decode/{unpack,
-# prefix_sum,rebase}). The scope path lands in each HLO op's op_name
+# (the two decode kernels also under inner scopes: pqt.hybrid_expand/unpack,
+# pqt.delta_decode/{unpack,prefix_sum,rebase}). The scope path lands in each HLO op's op_name
 # metadata, which the profiler's trace carries per device op: those names
 # are what benchmark/lib/xspans.py reads, so a refactor may rename or fuse
 # the Python functions and must keep them. Scopes act while a program is
@@ -254,191 +254,180 @@ def _bucket(n: int, floor: int = 1024) -> int:
     return b
 
 
-def bytes_to_words32(data: bytes) -> np.ndarray:
-    """Pad bytes to a uint32 LE word array (+1 guard word for the hi gather)."""
-    pad = (-len(data)) % 4
-    buf = data + b"\x00" * (pad + 4)
-    return np.frombuffer(buf, dtype="<u4")
+def _unpack_plane(plane: jnp.ndarray, bits: int, num_values: int) -> jnp.ndarray:
+    """One part of a frame, slot by slot, as uint32: word j of the plane
+    holds slots j, j + L, j + 2L, ... (L its length), so the slots in order
+    are the plane shifted by each multiple of `bits` in turn, end to end —
+    static shifts and one concatenation, no index array."""
+    if bits == 0:
+        return jnp.zeros(num_values, dtype=jnp.uint32)
+    if bits == 32:
+        return plane
+    mask = jnp.uint32((1 << bits) - 1)
+    return jnp.concatenate([(plane >> (k * bits)) & mask for k in range(32 // bits)])
 
 
-def _align_words(packed_words: jnp.ndarray, width: int):
-    """A bit-packed payload (uint32 words, LSB-first values of `width` bits
-    end to end from bit 0) as a table in which no value crosses a word:
-    (table, bits, row_stride, word_stride). Value v sits in word
-    (v >> 5) * row_stride + ((v & 31) * bits >> 5) * word_stride of the
-    table, `bits` bits from bit (v * bits) & 31; `bits` is the next power of
-    two >= width, so 32 // bits values share a word and the table is under
-    twice the payload.
-
-    `width` words hold exactly 32 values, at bit positions that depend on
-    the static width alone: column c of the payload viewed as rows of `width`
-    words is one strided slice, and each of a row's 32 values is a shift of
-    one column or an or of two, by Python constants — no index array, no
-    gather (the reference's unpack8Int32FuncByWidth, SURVEY.md L1). The
-    `bits` words that re-pack a row come out column by column, so the table
-    keeps them that way — word k of every row, then word k + 1: row_stride 1,
-    word_stride rows — and nothing is transposed. At a width that is a power
-    of two the payload is such a table as it stands (row_stride `width`,
-    word_stride 1). The payload's length is a power-of-two bucket: it is
-    padded with zeros to whole rows, never cut — to a multiple of 1,024 rows,
-    so that every column is whole 1,024-word tiles (a width-17 shape then
-    compiles in 1.8 s on a v5e, not 4.4, and runs no slower; PERF.md
-    section 6, PR 31)."""
-    bits = 1 << (width - 1).bit_length()
-    if bits == width:
-        return packed_words, bits, width, 1
-    pad = (-packed_words.shape[0]) % (width * 1024)
-    if pad:
-        packed_words = jnp.concatenate([packed_words, jnp.zeros(pad, jnp.uint32)])
-    n = packed_words.shape[0]
-    columns = [jax.lax.slice(packed_words, (c,), (n,), (width,)) for c in range(width)]
-    mask = jnp.uint32((1 << width) - 1)
-    per_word = 32 // bits
-    words = []
-    for k in range(bits):
-        word = None
-        for t in range(per_word):
-            c, s = divmod((k * per_word + t) * width, 32)
-            value = columns[c] >> s
-            if s + width > 32:
-                value = value | (columns[c + 1] << (32 - s))
-            value = (value & mask) << (t * bits)
-            word = value if word is None else word | value
-        words.append(word)
-    return jnp.concatenate(words), bits, 1, n // width
+def _hybrid_frame_parts(width: int) -> tuple[int, int]:
+    """(a, b): the part widths of the two planes a hybrid frame ships a
+    `width`-bit index stream in — each 0 or a power of two up to 32, a > b
+    (b = 0: one plane), a + b the SHIPPED width: the least number >= width
+    with at most two set bits. 9 = 8 + 1, 12 = 8 + 4, 17 = 16 + 1, 6 = 4 + 2,
+    3 = 2 + 1; 1, 2, 4, 8, 16, 32 are one plane; 7 ships as 8, 11 as 12,
+    13-15 as 16, 19 as 20, 21-23 as 24, 25-31 as 32."""
+    shipped = width
+    while bin(shipped).count("1") > 2:
+        shipped += 1
+    a = 1 << (shipped.bit_length() - 1) if shipped else 0
+    return a, shipped - a
 
 
-@partial(jax.jit, static_argnames=("width", "num_values", "run_pad"))
+@partial(jax.jit, static_argnames=("width", "num_values"))
 @jax.named_scope("pqt.hybrid_expand")
 def expand_hybrid_device(
-    buf: jnp.ndarray,  # uint32: [run_meta (4*run_pad) | packed words]
+    buf: jnp.ndarray,  # uint32: the hybrid frame's planes
     width: int,
     num_values: int,
-    run_pad: int,
 ) -> jnp.ndarray:
-    """Expand a prescanned hybrid RLE/bit-packed stream on device.
+    """A hybrid RLE/bit-packed index stream from its hybrid frame.
 
-    buf is pack_hybrid_upload's (below): the four per-run vectors and the
-    packed payload words in ONE upload; that function and the five slices
-    here are the only statements of its layout.
-
-    No position looks its run up. A run hands its positions two words by
-    _spread (a scatter of differences at out_start and one prefix sum each,
-    0.3-0.9 ms where a table[r] pass over 2^20 positions is 9): its is_rle flag,
-    and one payload — the value to broadcast if it is an RLE run, else
-    value_off = bit_start // width - out_start. The payload holds bit-packed
-    groups only, so a run's bit_start is a multiple of `width` and the payload
-    is ONE dense stream of `width`-bit values: position i of a bit-packed run
-    is value i + value_off of it. Padding entries of out_start hold n_pad + 1
-    and are dropped; a zero-length run repeats the next run's start and loses
-    to it.
-
-    What is left is ONE gather a value: the payload is first aligned so that
-    no value crosses a word (_align_words: fixed shifts of strided slices,
-    under unpack/align), then position i reads the one word that holds its
-    value (unpack/gather) and shifts it out. Per 2^20 values on a v5e
-    (PERF.md section 6, PR 31): the alignment 0.02-0.19 ms, the gather 7.5
-    (5.6 from a 1,024-word table) whatever the width, the whole call 8.1-8.4
-    (9.2 with 65,536 runs); through PR 30 a value was read as the two words
-    it might straddle, 7.5 + 9.0 ms and 17.1-18.2 the call. At positions of
-    RLE runs the value index means nothing, so the word index is clipped
-    into the table and the result discarded. Positions past the table's
-    total belong to the last run and carry garbage: the caller slices them
-    off.
+    buf is pack_hybrid_upload's (below): that function and the one slice
+    here are the only statements of its layout. The host has re-framed the
+    wire position by position at `width` bits, the chunk's one shipped width
+    (an RLE run written out, a bit-packed run's values moved to their
+    places; no dictionary value is formed there), so nothing here is looked
+    up: each plane unpacks by static shifts and one concatenation
+    (_unpack_plane) and the two parts are or-ed together. No run table, no
+    index array, no gather. Positions past the stream's total hold 0: the
+    caller slices them off. Width 0 (a dictionary of one entry) is zeros and
+    reads nothing. Per 2^20 values on a v5e: 5-12 us (PERF.md section 5,
+    PR 38; the kernel it replaced read one word a value through a gather:
+    7.5 of its 8.1-8.4 ms, PR 31).
     """
     if width == 0:
         return jnp.zeros(num_values, dtype=jnp.uint32)
-    run_is_rle = buf[:run_pad]
-    run_out_start = jax.lax.bitcast_convert_type(buf[run_pad : 2 * run_pad], jnp.int32)
-    run_rle_value = buf[2 * run_pad : 3 * run_pad]
-    run_bp_bit_start = jax.lax.bitcast_convert_type(
-        buf[3 * run_pad : 4 * run_pad], jnp.int32
-    )
-    packed_words = buf[4 * run_pad :]
-    i = jnp.arange(num_values, dtype=jnp.int32)
-    with jax.named_scope("find_run"):
-        run_value_off = run_bp_bit_start // width - run_out_start
-        run_payload = jnp.where(
-            run_is_rle != 0,
-            run_rle_value,
-            jax.lax.bitcast_convert_type(run_value_off, jnp.uint32),
-        )
-        payload = _spread(run_out_start, run_payload, num_values)
-        is_rle = _spread(run_out_start, run_is_rle, num_values) != 0
+    a, b = _hybrid_frame_parts(width)
+    assert a + b == width, "expand_hybrid_device takes a shipped width"
     with jax.named_scope("unpack"):
-        with jax.named_scope("align"):
-            table, bits, row_stride, word_stride = _align_words(packed_words, width)
-        with jax.named_scope("gather"):
-            v = jax.lax.bitcast_convert_type(payload, jnp.int32) + i
-            bit = (v & 31) * bits
-            w = (v >> 5) * row_stride + (bit >> 5) * word_stride
-            bp_vals = table[jnp.clip(w, 0, table.shape[0] - 1)]
-            if width < 32:
-                bp_vals = (bp_vals >> (bit & 31).astype(jnp.uint32)) & jnp.uint32(
-                    (1 << width) - 1
-                )
-    with jax.named_scope("select"):
-        return jnp.where(is_rle, payload, bp_vals)
+        at = num_values * a // 32
+        vals = _unpack_plane(buf[:at], a, num_values)
+        if b:
+            vals = vals | (_unpack_plane(buf[at:], b, num_values) << a)
+    return vals
 
 
 class FrozenHybrid(NamedTuple):
     """One upload of expand_hybrid_device (built in prepare, dispatched by
-    transfer) with the kernel's static arguments; `total` values are real."""
+    transfer) with the kernel's static arguments; `total` values are real.
+    `buf` is pack_hybrid_upload's array and `width` the SHIPPED width: the
+    length of buf, and so the compiled shape, is a function of (width,
+    n_pad) and nothing else."""
 
     buf: np.ndarray
     width: int
     n_pad: int
-    run_pad: int
     total: int
+
+    # What the run-table upload held, by its name: read by
+    # benchmark/selftest/shapes_check*.py, which this PR may not edit
+    # (PERF.md section 7 asks the next benchmark issue to let the freeze
+    # state its own compile key).
+    @property
+    def run_pad(self) -> int:
+        return 0
+
+
+def _hybrid_frame_numpy(
+    packed, is_rle, counts, rle_values, bit_starts, width: int, a: int, b: int, n_pad: int
+) -> np.ndarray:
+    """The planes of pack_hybrid_upload in NumPy over ops/bitpack.py: what
+    runs without the native library, and the reference ptq_hybrid_frame is
+    tested against byte for byte."""
+    from ..ops.bitpack import unpack_bits
+
+    shipped = np.zeros(n_pad, dtype=np.uint32)
+    at = 0
+    for rle, n, value, bit in zip(is_rle.tolist(), counts.tolist(), rle_values.tolist(), bit_starts.tolist()):
+        if n < 0 or at + n > n_pad or (not rle and (bit < 0 or bit + n * width > len(packed) * 8)):
+            raise ValueError("hybrid frame: a run lies outside the payload or the slots")
+        shipped[at : at + n] = value if rle else unpack_bits(packed, n, width, dtype=np.uint32, bit_offset=bit)
+        at += n
+    planes = []
+    for part, bits in [(shipped, a)] + ([(shipped >> np.uint32(a), b)] if b else []):
+        if bits:
+            rows = (part & np.uint32((1 << bits) - 1)).reshape(32 // bits, -1)
+            shifts = (np.arange(32 // bits, dtype=np.uint32) * np.uint32(bits))[:, None]
+            planes.append(np.bitwise_or.reduce(rows << shifts, axis=0))
+    return np.concatenate(planes) if planes else np.zeros(0, dtype=np.uint32)
 
 
 def pack_hybrid_upload(
-    is_rle, counts, rle_values, bit_starts, packed, width: int, dense: bool = False
-) -> FrozenHybrid:
-    """The upload expand_hybrid_device reads, from one row per run: `counts`
-    values each (already clamped: the runs produce exactly the values wanted,
-    a zero-length run is fine), the value an RLE run repeats, the bit offset
-    into `packed` (uint8 array or bytes, LSB-first groups at `width` bits) at
-    which a bit-packed run's payload starts. `packed` holds bit-packed groups
-    only (no headers, no RLE values), each run whole groups of 8 values, so
-    every bit-packed run's bit_start % (8 * width) == 0 — both walks, re-packed
-    pages included (tests/test_fused_prepare.py) — and the payload is one dense
-    stream of `width`-bit values: the kernel divides bit_start by `width` and
-    reads value, not bit, positions. Nobody reads a bit-packed run's value or
-    an RLE run's bit offset. ONE uint32 buffer, because the
-    host<->device link pays a fixed latency per transfer that dwarfs these
-    tables; with run_pad = _bucket(runs, 64), n_pad = _bucket(values):
-      buf[0*run_pad:1*run_pad]  is_rle      0/1
-      buf[1*run_pad:2*run_pad]  out_start   exclusive cumsum of counts (int32);
-                                            padding entries hold n_pad + 1
-      buf[2*run_pad:3*run_pad]  rle_value
-      buf[3*run_pad:4*run_pad]  bit_start   (int32)
-      buf[4*run_pad:]           payload words + 1 guard word, padded to
-                                _bucket(words, 1024)
-    `dense` (the padded delivery, kernels/pipeline.py: a chunk whose counts
-    are data and must not reach a compiled shape) floors both buckets at
-    what n_pad values can need, so that the shape is a function of (width,
-    n_pad) for every stream that is mostly bit-packed: run_pad at n_pad / 256
-    (pyarrow writes bit-packed runs of 504 values; only a stream whose runs
-    average under 256 values leaves the floor) and the payload at n_pad
-    values of `width` bits plus 1,024 words (the guard word and the up to
-    7 values by which each page's last group overshoots)."""
-    k = len(counts)
-    total = int(np.sum(counts))
+    is_rle, counts, rle_values, bit_starts, packed, width: int
+) -> tuple[FrozenHybrid, float]:
+    """The upload expand_hybrid_device reads: the HYBRID FRAME, built from
+    the wire's run tables without a dictionary lookup. The arguments are the
+    wire's, one row per run: `counts` values each (already clamped: the runs
+    produce exactly the values wanted, a zero-length run is fine), the value
+    an RLE run repeats, the bit offset into `packed` (uint8 array or bytes:
+    the chunk's bit-packed groups end to end at `width` bits, LSB first, no
+    headers and no RLE values) at which a bit-packed run's values start.
+    Nobody reads a bit-packed run's value or an RLE run's bit offset. What
+    ships knows none of that:
+
+    - position-indexed: the value of output position i sits at slot i. A
+      bit-packed run's values are copied to their positions, an RLE run's
+      value is written `count` times; run headers and the up to 7 values by
+      which a page's last group overshoots are gone; the slots past the
+      total hold 0.
+    - one static width: the least number >= `width` with at most two set
+      bits (_hybrid_frame_parts: 7 ships as 8, 11 as 12, 13 as 16; at most
+      + 28 %, at 25 -> 32), as one or two planes of power-of-two part widths
+      a > b. A value's low a bits are in the first plane, the b bits above
+      them in the second. A plane of p-bit parts is n_pad * p / 32 uint32
+      words, and word j holds slots j, j + L, j + 2L, ... (L its length),
+      slot s in bits [(s // L) * p, + p) of word s % L — pack_delta_upload's
+      plane layout, read by static shifts with no index array
+      (_unpack_plane).
+
+    ONE uint32 array, the first plane then the second, n_pad * (a + b) / 32
+    words with n_pad = _bucket(total): its length is a function of (shipped
+    width, n_pad) and nothing else — no count of runs, pages or payload
+    words reaches a compiled shape, which is the padded delivery's contract
+    (kernels/pipeline.py) for every chunk. Width 0 (every index 0) is an
+    empty array: zeros, no upload. The worst case is a stream of long RLE
+    runs, which uploads n * width / 8 bytes where the wire held a few bytes
+    a run: at most the indices at their width (2 MB per 2^20 values at 16
+    bits, ~1 ms of link on a v5e host) against the 8 ms a stream the
+    run-table kernel spent on its gather; there is no switch.
+
+    The bytes move in one native call (ptq_hybrid_frame, GIL-free), or
+    through _hybrid_frame_numpy without the library. Returns the record and
+    the seconds the frame took, clocked inside the native call (a clock
+    around it would also count the wait for the GIL on return): the caller's
+    prepare.hybrid_frame sub-clock."""
+    import time
+
+    from ..utils.native import get_native
+
+    t0 = time.perf_counter()
+    counts = np.ascontiguousarray(counts, dtype=np.int64)
+    total = int(counts.sum())
     n_pad = _bucket(max(total, 1))
-    words = bytes_to_words32(bytes(packed))
-    run_floor, words_floor = (n_pad >> 8, n_pad * width // 32 + 1024) if dense else (0, 0)
-    run_pad = _bucket(k, max(64, run_floor))
-    buf = np.zeros(4 * run_pad + _bucket(len(words), max(1024, words_floor)), dtype=np.uint32)
-    buf[run_pad : 2 * run_pad] = np.int32(n_pad + 1).view(np.uint32)
-    out_start = np.zeros(k, dtype=np.int64)
-    np.cumsum(counts[:-1], out=out_start[1:])
-    # every field modulo 2^32: an int32 (a bit offset may be negative) travels
-    # as its bit pattern
-    for row, field in enumerate((is_rle, out_start, rle_values, bit_starts)):
-        buf[row * run_pad : row * run_pad + k] = np.asarray(field).astype(np.uint32)
-    buf[4 * run_pad : 4 * run_pad + len(words)] = words
-    return FrozenHybrid(buf, width, n_pad, run_pad, total)
+    a, b = _hybrid_frame_parts(width)
+    buf = np.empty(n_pad * (a + b) // 32, dtype=np.uint32)  # the planes' writer zeroes them
+    if width == 0:
+        return FrozenHybrid(buf, 0, n_pad, total), 0.0
+    packed = np.frombuffer(packed, dtype=np.uint8)
+    is_rle = np.ascontiguousarray(np.asarray(is_rle) != 0, dtype=np.uint8)
+    # modulo 2^32, as the value shipped in the run table
+    rle_values = np.ascontiguousarray(np.asarray(rle_values).astype(np.uint32))
+    bit_starts = np.ascontiguousarray(bit_starts, dtype=np.int64)
+    lib = get_native()
+    if lib is not None and lib.has_hybrid_frame:
+        seconds = lib.hybrid_frame(packed, is_rle, counts, rle_values, bit_starts, width, a, b, n_pad, buf)
+    else:
+        buf[:] = _hybrid_frame_numpy(packed, is_rle, counts, rle_values, bit_starts, width, a, b, n_pad)
+        seconds = time.perf_counter() - t0
+    return FrozenHybrid(buf, a + b, n_pad, total), seconds
 
 
 # The widths a delta frame ships at (pack_delta_upload): a low part of 0, 8,
@@ -446,19 +435,6 @@ def pack_hybrid_upload(
 # a compiled shape, so the quantum is coarse on purpose: a column's chunks
 # must not straddle two widths under another seed or month.
 DELTA_FRAME_WIDTHS = (0, 8, 16, 32, 40, 48, 64)
-
-
-def _unpack_plane(plane: jnp.ndarray, bits: int, num_values: int) -> jnp.ndarray:
-    """One part of a delta frame, slot by slot, as uint32: word j of the
-    plane holds slots j, j + L, j + 2L, ... (L its length), so the slots in
-    order are the plane shifted by each multiple of `bits` in turn, end to
-    end — static shifts and one concatenation, no index array."""
-    if bits == 0:
-        return jnp.zeros(num_values, dtype=jnp.uint32)
-    if bits == 32:
-        return plane
-    mask = jnp.uint32((1 << bits) - 1)
-    return jnp.concatenate([(plane >> (k * bits)) & mask for k in range(32 // bits)])
 
 
 @partial(jax.jit, static_argnames=("nbits", "width", "num_values", "p_pad"))
@@ -1043,7 +1019,7 @@ def bitpack_encode_device(values: jnp.ndarray, width: int) -> jnp.ndarray:
     occupy disjoint bits, so add IS or and no carries can occur).
 
     Returns uint32 LE words covering ceil(n*width/32) (+1 guard word of
-    zeros, mirroring bytes_to_words32); the host trims the byte tail.
+    zeros); the host trims the byte tail.
     The caller pads `values` to a multiple of 8 where the hybrid format
     requires whole groups (pack_bits has the same contract)."""
     n = values.shape[0]

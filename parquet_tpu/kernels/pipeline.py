@@ -8,15 +8,17 @@ FileReader(..., backend="tpu") — the WithDecoderBackend(TPU) analogue.
 
 Batching model per chunk:
   RLE_DICTIONARY  all pages' run tables concatenate into one table (bit
-                  offsets rebased into one packed buffer, output starts into
-                  one output index space, run counts clamped to each page's
-                  real value count so no padding enters the output) -> ONE
-                  device expansion for the whole chunk, then one device gather
-                  against the dictionary.
-  DELTA_BP        all pages' delta vectors concatenate; a single wrapping
-                  cumsum decodes every page at once — per-page starts are
-                  restored by injecting a correction delta at each page start
-                  (valid in modular arithmetic).
+                  offsets rebased into one packed buffer, run counts clamped
+                  to each page's real value count so no padding enters the
+                  output), and the freeze re-frames the runs position by
+                  position at the chunk's one static width (the hybrid frame,
+                  device_ops.pack_hybrid_upload: no run table reaches the
+                  link) -> ONE device unpack for the whole chunk, then one
+                  device gather against the dictionary.
+  DELTA_BP        all pages' miniblocks re-framed the same way (the delta
+                  frame, device_ops.pack_delta_upload); a single wrapping
+                  prefix sum decodes every page at once, rebased at each page
+                  start (valid in modular arithmetic).
   PLAIN           raw little-endian bytes upload + device bitcast.
 
 The decode of one chunk is split into two phases so a whole row group's worth
@@ -417,11 +419,11 @@ _NUMERIC_DTYPE = {
 
 def _dispatch_hybrid(frozen: FrozenHybrid, padded: bool = False) -> jnp.ndarray:
     """`padded` keeps the kernel's n_pad output whole (the positions past
-    `total` carry garbage): the exact-length slice is a program a length."""
+    `total` hold 0): the exact-length slice is a program a length."""
     with _trace.stage("dispatch.upload", frozen.buf.nbytes):
         buf = jnp.asarray(frozen.buf)
     with _trace.stage("dispatch.launch"):
-        dev = expand_hybrid_device(buf, frozen.width, frozen.n_pad, frozen.run_pad)
+        dev = expand_hybrid_device(buf, frozen.width, frozen.n_pad)
         return dev if padded else dev[: frozen.total]
 
 
@@ -1414,8 +1416,7 @@ def _plan_from_tables(
         # device run batches, PLAIN pages ride the contiguous raw upload,
         # and device_column merges in page order.
         frozen = _freeze_hybrid_from_tables(
-            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0,
-            plan.padded,
+            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0
         )
         if frozen is not None:
             plan.frozen_hybrid = frozen
@@ -1487,8 +1488,7 @@ def _plan_from_tables(
         # (native byte_array_gather) and device_column's ragged merge joins
         # both in output-index space.
         frozen = _freeze_hybrid_from_tables(
-            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0,
-            plan.padded,
+            data_pages, res, len(plan.dictionary) if plan.dictionary is not None else 0
         )
         if frozen is not None:
             from ..core.page import _decode_values
@@ -1646,17 +1646,18 @@ def _repack_pages_to_width(pages: list, res: dict, width: int):
     return out, is_rle, byteoff, packed
 
 
-def _freeze_hybrid_from_tables(
-    data_pages, res, n_dict: int = 0, dense: bool = False
-) -> list | None:
+def _freeze_hybrid_from_tables(data_pages, res, n_dict: int = 0) -> list | None:
     """THE freeze of a dictionary chunk's index pages, from the whole-chunk
     run tables of the native walk (the staged walk lays its prescans out the
     same way: _hybrid_tables_of). A chunk ships at ONE index width
     (_index_width): pages written narrower are re-packed to it first, so the
     compiled shapes do not follow where the dictionary crossed a power of
-    two. Pages group sequentially under the bit cap, one upload a group
-    (device_ops.pack_hybrid_upload, which says what `dense` floors); returns
-    None when a single page exceeds the cap (the caller demotes the chunk)."""
+    two. Pages group sequentially under the bit cap, one upload a group: the
+    group's runs re-framed position by position at one static width
+    (device_ops.pack_hybrid_upload: the hybrid frame; the one pass in which
+    the host reads an index stream's payload, and no dictionary value is
+    formed); returns None when a single page exceeds the cap (the caller
+    demotes the chunk)."""
     cap = _BATCH_BITS_CAP
     pages = [P for P in data_pages if P[_PC_ROUTE] == 1]
     h_is_rle = res["h_is_rle"]
@@ -1683,12 +1684,28 @@ def _freeze_hybrid_from_tables(
             cur[4] += bits
     # payload bits are addressed from the group's first packed byte
     return [
-        pack_hybrid_upload(
-            h_is_rle[rs:re], res["h_counts"][rs:re], res["h_values"][rs:re],
-            (h_byteoff[rs:re] - ps) * 8, packed_all[ps:pe], width, dense,
+        _count_frame(
+            "hybrid",
+            *pack_hybrid_upload(
+                h_is_rle[rs:re], res["h_counts"][rs:re], res["h_values"][rs:re],
+                (h_byteoff[rs:re] - ps) * 8, packed_all[ps:pe], width,
+            ),
+            _hybrid_wire_bytes(h_is_rle[rs:re], res["h_counts"][rs:re], pe - ps, width),
         )
         for rs, re, ps, pe, _bits in groups
     ]
+
+
+def _hybrid_wire_bytes(is_rle, counts, packed_bytes: int, width: int) -> int:
+    """What the runs a hybrid frame replaced take on the wire at `width`
+    bits: the bit-packed groups, an RLE run's value, and a varint header a
+    run (a bit-packed run's holds its count of groups, an RLE run's its
+    count of values; both shifted by the flag bit)."""
+    rle = np.asarray(is_rle) != 0
+    counts = np.asarray(counts, dtype=np.int64)
+    header = np.where(rle, counts, (counts + 7) // 8) << 1
+    header_bytes = len(header) + sum(int((header >> s != 0).sum()) for s in (7, 14, 21, 28, 35))
+    return int(packed_bytes + header_bytes + rle.sum() * ((width + 7) // 8))
 
 
 def _repack_plain_as_delta(plan: _ChunkPlan, whole: np.ndarray, nbits: int) -> bool:
@@ -1760,27 +1777,28 @@ def _repack_plain_as_delta(plan: _ChunkPlan, whole: np.ndarray, nbits: int) -> b
         # pad: an outlier delta can make it as large as the raw values
         bump("repack_declined", raw_bytes)
         return False
-    plan.frozen_delta = [_count_delta_frame(frozen, seconds, int(consumed))]
+    plan.frozen_delta = [_count_frame("delta", frozen, seconds, int(consumed))]
     bump("repack_engaged", frozen.frame.nbytes)
     return True
 
 
-def _count_delta_frame(frozen: FrozenDelta, seconds: float, wire_bytes: int) -> FrozenDelta:
-    """What device_ops.pack_delta_upload returned, counted on its way into a
-    plan: the slots the frame covers, the wire bytes it read and the plane
-    bytes it wrote (so a trace says how much the frame grew or shrank the
-    upload), and the prepare.delta_frame sub-clock (clocked inside the
-    native call; back-dated like the native walk's prepare.* clocks so that
-    it nests in chunk.prepare)."""
+def _count_frame(kind: str, frozen, seconds: float, wire_bytes: int):
+    """What device_ops.pack_hybrid_upload or pack_delta_upload returned
+    (`kind` "hybrid" or "delta"), counted on its way into a plan: the slots
+    the frame covers, the wire bytes it read and the plane bytes it wrote (so
+    a trace says how much the frame grew or shrank the upload), and the
+    prepare.<kind>_frame sub-clock (clocked inside the native call;
+    back-dated like the native walk's prepare.* clocks so that it nests in
+    chunk.prepare)."""
     frame_bytes = frozen.n_pad * frozen.width // 8
     for name, n in (
-        ("delta_values_framed", frozen.total),
-        ("delta_wire_bytes", wire_bytes),
-        ("delta_frame_bytes", frame_bytes),
+        (f"{kind}_values_framed", frozen.total),
+        (f"{kind}_wire_bytes", wire_bytes),
+        (f"{kind}_frame_bytes", frame_bytes),
     ):
         _metrics.event(name, n)
         _trace.count(name, n)
-    _trace.add_seconds("prepare.delta_frame", seconds, frame_bytes)
+    _trace.add_seconds(f"prepare.{kind}_frame", seconds, frame_bytes)
     return frozen
 
 
@@ -1814,7 +1832,8 @@ def _freeze_delta_from_tables(data_pages, res, nbits: int) -> list:
         bases = np.zeros(len(plist), dtype=np.int64)  # each page's first output position
         np.cumsum(totals[:-1], out=bases[1:])
         minis_per_page = [P[_PC_MINIE] - P[_PC_MINIS] for P in plist]
-        frozen.append(_count_delta_frame(
+        frozen.append(_count_frame(
+            "delta",
             *pack_delta_upload(
                 res["d_widths"][ms:me],
                 # payload bits are addressed from the group's first wire byte
@@ -2152,7 +2171,6 @@ def _commit_routes(plan: _ChunkPlan, pending: list) -> None:
         frozen = _freeze_hybrid_from_tables(
             *_hybrid_tables_of(pending),
             len(plan.dictionary) if plan.dictionary is not None else 0,
-            plan.padded,
         )
         if frozen is not None:
             plan.frozen_hybrid = frozen

@@ -342,6 +342,24 @@ class NativeLib:
                 ctypes.c_void_p,
                 ctypes.c_void_p,
             ]
+        self.has_hybrid_frame = hasattr(lib, "ptq_hybrid_frame")
+        if self.has_hybrid_frame:
+            lib.ptq_hybrid_frame.restype = ctypes.c_ssize_t
+            lib.ptq_hybrid_frame.argtypes = [
+                ctypes.c_void_p,
+                ctypes.c_size_t,
+                ctypes.c_void_p,
+                ctypes.c_void_p,
+                ctypes.c_void_p,
+                ctypes.c_void_p,
+                ctypes.c_int64,
+                ctypes.c_int,
+                ctypes.c_int,
+                ctypes.c_int,
+                ctypes.c_int64,
+                ctypes.c_void_p,
+                ctypes.c_void_p,
+            ]
         self.has_delta_encode = hasattr(lib, "ptq_delta_encode")
         if self.has_delta_encode:
             lib.ptq_delta_encode.restype = ctypes.c_ssize_t
@@ -1109,6 +1127,30 @@ class NativeLib:
         )
         if rc != int(counts.sum()):
             raise ValueError(f"native: delta_frame failed ({rc})")
+        return ns.value / 1e9
+
+    def hybrid_frame(
+        self, packed, is_rle, counts, rle_values, bit_starts,
+        width: int, lo_bits: int, hi_bits: int, n_pad: int, out,
+    ) -> float:
+        """The hybrid frame of one upload (kernels/device_ops.py
+        pack_hybrid_upload says what it is) written into `out`, a contiguous
+        uint32 array of n_pad * (lo_bits + hi_bits) / 32 words, in one
+        GIL-free call: the run tables are contiguous arrays (uint8 is_rle,
+        int64 counts / bit_starts, uint32 rle_values), `packed` a uint8
+        array. Returns the seconds the call clocked inside itself."""
+        assert out.dtype == "uint32" and out.flags.c_contiguous
+        assert len(out) == n_pad * (lo_bits + hi_bits) // 32
+        ns = ctypes.c_int64(0)
+        rc = self._lib.ptq_hybrid_frame(
+            ctypes.c_void_p(packed.ctypes.data), len(packed),
+            ctypes.c_void_p(is_rle.ctypes.data), ctypes.c_void_p(counts.ctypes.data),
+            ctypes.c_void_p(rle_values.ctypes.data), ctypes.c_void_p(bit_starts.ctypes.data),
+            len(counts), width, lo_bits, hi_bits, n_pad,
+            ctypes.c_void_p(out.ctypes.data), ctypes.byref(ns),
+        )
+        if rc != int(counts.sum()):
+            raise ValueError(f"native: hybrid_frame failed ({rc})")
         return ns.value / 1e9
 
     def delta_encode(self, values, nbits: int, block_size: int, mini_count: int) -> bytes:

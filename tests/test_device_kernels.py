@@ -3,7 +3,7 @@ expansion of the same tables (tests/test_tpu_backend.py drives them through
 whole files). The uploads are built by the packers that live beside the
 kernels (pack_hybrid_upload, pack_delta_upload: the one statement of the
 layout), so each case checks kernel after packer against an independent
-answer; the two literal goldens at the end pin the layout itself."""
+answer; the literal goldens at the end pin the layouts themselves."""
 
 import numpy as np
 import pytest
@@ -41,6 +41,12 @@ def _wire(bits: np.ndarray) -> bytes:
 # -- expand_hybrid_device -------------------------------------------------------
 
 
+def _shipped(width: int) -> int:
+    """The rule, written out again: the least number >= width with at most
+    two set bits (one plane a set bit)."""
+    return next(w for w in range(width, 33) if bin(w).count("1") <= 2)
+
+
 def _hybrid_case(counts, is_rle, width, rle_bit_start=0):
     """(frozen upload, expected[:total]) for runs of `counts` values each. An
     RLE run's bit_start is read by nobody; `rle_bit_start` is what the table
@@ -70,9 +76,10 @@ def _hybrid_case(counts, is_rle, width, rle_bit_start=0):
         packed.append(vals)
         bits_so_far += c * width
     flat = np.concatenate(packed) if packed else np.zeros(0, np.uint32)
-    frozen = pack_hybrid_upload(
+    frozen, seconds = pack_hybrid_upload(
         is_rle, counts, rle_value, bit_start, _wire(_pack_lsb(flat, width)), width
     )
+    assert seconds >= 0
     return frozen, expected
 
 
@@ -82,75 +89,113 @@ def _many_runs(k, seed):
 
 
 _HYBRID_CASES = {
-    # name: (counts, is_rle, width, the run_pad and n_pad the packer's buckets give)
-    "single-rle-run": ([1024], [1], 3, 64, 1024),
-    "single-bitpacked-run": ([1024], [0], 5, 64, 1024),
-    "run_pad-64-mixed": ([8, 100, 16, 1, 899], [0, 1, 0, 1, 0], 3, 64, 1024),
-    "run_pad-65536": (*_many_runs(40_000, 1), 2, 65536, 131072),
-    "run_pad-4096-full-table": (*_many_runs(4096, 2), 7, 4096, 8192),
-    "zero-length-run-in-the-middle": ([40, 0, 60, 0, 0, 924], [1, 0, 0, 1, 0, 1], 4, 64, 1024),
-    "zero-length-run-at-the-end": ([500, 524, 0], [0, 1, 0], 3, 64, 1024),
-    "zero-length-run-at-the-end-short": ([300, 200, 0, 0], [0, 1, 1, 0], 3, 64, 1024),
-    "zero-length-run-first": ([0, 0, 1000], [1, 0, 0], 6, 64, 1024),
-    "total-below-n_pad": ([700, 301], [0, 1], 9, 64, 1024),
-    "width-0": ([10, 1014], [1, 0], 0, 64, 1024),
-    "rle-only": ([1, 2, 3, 1018], [1, 1, 1, 1], 1, 64, 1024),
-    "bitpacked-only": ([8, 16, 1000], [0, 0, 0], 12, 64, 1024),
-    "width-32": ([100, 200], [0, 1], 32, 64, 1024),
-    # what bringing base = bit_start - out_start * width to the values could break
-    "long-rle-run-first-negative-base": ([900, 124], [1, 0], 13, 64, 1024),
-    "rle-runs-between-bitpacked-negative-base": ([300, 8, 500, 16, 200], [1, 0, 1, 0, 1], 14, 64, 1024),
-    "rle-run-past-the-payload-end": ([8, 60_000], [0, 1], 13, 64, 65536),
-    "rle-run-past-the-payload-end-width-1": ([8, 100_000, 8], [0, 1, 0], 1, 64, 131072),
-    "width-32-rle-first": ([500, 100, 424], [1, 0, 1], 32, 64, 1024),
-    "width-32-bitpacked-only": ([1024], [0], 32, 64, 1024),
-    "rle-bit_start-near-2^31": ([24, 500, 500], [0, 1, 0], 3, 64, 1024, (1 << 31) - 8),
-    "rle-bit_start-near-2^31-width-32": ([8, 1000, 16], [0, 1, 0], 32, 64, 1024, (1 << 31) - 32),
-    "run_pad-4096-long-rle-runs": (
-        np.tile([3000, 8], 2048), np.tile([1, 0], 2048), 3, 4096, 1 << 23),
+    # name: (counts, is_rle, width, the n_pad the packer's bucket gives[, an RLE run's bit_start])
+    "single-rle-run": ([1024], [1], 3, 1024),
+    "single-bitpacked-run": ([1024], [0], 5, 1024),
+    "5-runs-mixed": ([8, 100, 16, 1, 899], [0, 1, 0, 1, 0], 3, 1024),
+    "40000-runs": (*_many_runs(40_000, 1), 2, 131072),
+    "4096-runs": (*_many_runs(4096, 2), 7, 8192),
+    "zero-length-run-in-the-middle": ([40, 0, 60, 0, 0, 924], [1, 0, 0, 1, 0, 1], 4, 1024),
+    "zero-length-run-at-the-end": ([500, 524, 0], [0, 1, 0], 3, 1024),
+    "zero-length-run-at-the-end-short": ([300, 200, 0, 0], [0, 1, 1, 0], 3, 1024),
+    "zero-length-run-first": ([0, 0, 1000], [1, 0, 0], 6, 1024),
+    "zero-length-runs-only": ([0, 0, 0], [1, 0, 1], 5, 1024),
+    "total-below-n_pad": ([700, 301], [0, 1], 9, 1024),
+    "width-0": ([10, 1014], [1, 0], 0, 1024),
+    "rle-only": ([1, 2, 3, 1018], [1, 1, 1, 1], 1, 1024),
+    "bitpacked-only": ([8, 16, 1000], [0, 0, 0], 12, 1024),
+    "width-32": ([100, 200], [0, 1], 32, 1024),
+    "long-rle-run-first": ([900, 124], [1, 0], 13, 1024),
+    "rle-runs-between-bitpacked": ([300, 8, 500, 16, 200], [1, 0, 1, 0, 1], 14, 1024),
+    "rle-run-past-the-payload-end": ([8, 60_000], [0, 1], 13, 65536),
+    "rle-run-past-the-payload-end-width-1": ([8, 100_000, 8], [0, 1, 0], 1, 131072),
+    "width-32-rle-first": ([500, 100, 424], [1, 0, 1], 32, 1024),
+    "width-32-bitpacked-only": ([1024], [0], 32, 1024),
+    "rle-bit_start-near-2^31": ([24, 500, 500], [0, 1, 0], 3, 1024, (1 << 31) - 8),
+    "rle-bit_start-near-2^31-width-32": ([8, 1000, 16], [0, 1, 0], 32, 1024, (1 << 31) - 32),
+    "rle-bit_start-negative": ([24, 500, 500], [0, 1, 0], 9, 1024, -8),
+    "4096-runs-long-rle-runs": (np.tile([3000, 8], 2048), np.tile([1, 0], 2048), 3, 1 << 23),
+    # the frame's own edges: a run longer than the writer's block of 1,024
+    # values, runs across a plane's wrap (slot L of a plane of L words), every
+    # slot real, and the widths that ship rounded up
+    "runs-across-the-planes-wraps": ([31, 2, 30, 3, 1000, 7, 975], [0, 1, 0, 1, 0, 1, 0], 17, 2048),
+    "every-slot-real": ([1000, 1048], [0, 1], 6, 2048),
+    "bitpacked-run-of-5000": ([5000, 3000, 192], [0, 1, 0], 10, 8192),
+    "width-7-ships-as-8": ([100, 900], [1, 0], 7, 1024),
+    "width-11-ships-as-12": ([400, 300, 324], [0, 1, 0], 11, 1024),
+    "width-15-ships-as-16": ([1000], [0], 15, 1024),
+    "width-21-ships-as-24": ([10, 1000], [1, 0], 21, 1024),
+    "width-25-ships-as-32": ([600, 400], [0, 1], 25, 1024),
+    "width-31-ships-as-32": ([1024], [0], 31, 1024),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_HYBRID_CASES), ids=sorted(_HYBRID_CASES))
 def test_expand_hybrid_equals_numpy_expansion(case):
-    counts, is_rle, width, run_pad, n_pad, *rest = _HYBRID_CASES[case]
+    counts, is_rle, width, n_pad, *rest = _HYBRID_CASES[case]
     f, expected = _hybrid_case(counts, is_rle, width, *rest)
-    # the shape the case is named for is the one the packer's buckets give
-    assert (f.width, f.run_pad, f.n_pad, f.total) == (width, run_pad, n_pad, len(expected))
-    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad, f.run_pad))
+    # the shape the case is named for is the one the packer's bucket gives
+    assert (f.width, f.n_pad, f.total, f.run_pad) == (_shipped(width), n_pad, len(expected), 0)
+    if "-ships-as-" in case:
+        assert f.width == int(case.rsplit("-", 1)[1])
+    # the upload's length: a function of (shipped width, n_pad) and nothing else
+    assert f.buf.dtype == np.uint32 and f.buf.shape == (n_pad * f.width // 32,)
+    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad))
     assert got.shape == (n_pad,) and got.dtype == np.uint32
-    # positions past the table's total belong to no run: callers slice them off
     np.testing.assert_array_equal(got[: len(expected)], expected)
+    assert not got[len(expected) :].any()  # the slots past the total hold 0
 
 
-# -- the aligned read: wire streams through the numpy reference ------------------
+def test_two_streams_of_other_runs_and_wire_sizes_give_one_shape():
+    """The padded delivery's contract, for every chunk: neither the count of
+    runs nor the payload's size reaches the upload's shape."""
+    few, _ = _hybrid_case([3000, 8], [1, 0], 9)  # 2 runs, 9 bytes of payload
+    many, _ = _hybrid_case(*_many_runs(1800, 3), 9)  # 1,800 runs
+    dense, _ = _hybrid_case([4096], [0], 9)  # one run, 4,608 bytes of payload
+    assert few.total != many.total != dense.total
+    assert {(f.width, f.n_pad, f.buf.shape) for f in (few, many, dense)} == {(9, 4096, (4096 * 9 // 32,))}
+
+
+@pytest.mark.parametrize("width", [1, 3, 8, 9, 12, 16, 17, 24, 32])
+def test_hybrid_expand_reads_no_position_through_an_index(width):
+    """The lowered kernel holds no gather, no dynamic slice and no array of
+    n_pad indices (an iota): the frame is position-indexed, so the planes
+    unpack by static shifts and one concatenation each."""
+    import jax
+
+    n_pad = 4096
+    jaxpr = jax.make_jaxpr(lambda x: expand_hybrid_device(x, width=width, num_values=n_pad))(
+        jnp.zeros(n_pad * width // 32, jnp.uint32)
+    )
+    seen = set()
+    for eqn in _eqns(jaxpr.jaxpr):
+        seen.add(str(eqn.source_info.name_stack))
+        assert eqn.primitive.name not in ("gather", "dynamic_slice", "iota", "scatter-add", "while"), (
+            eqn.primitive.name, str(eqn.source_info.name_stack))
+    if width < 32:  # at 32 bits the plane is the answer: no equation at all
+        assert any("pqt.hybrid_expand/unpack" in x for x in seen)  # the walk saw the scope
+
+
+def test_hybrid_frame_refuses_a_run_outside_the_payload_or_the_slots():
+    ok = ([0, 1], [16, 8], [0, 3], [0, 0], bytes(10), 5)
+    pack_hybrid_upload(*ok)
+    for bad in (
+        ([0], [17], [0], [0], bytes(10), 5),  # 85 bits of a payload of 80
+        ([0], [8], [0], [48], bytes(10), 5),  # starts too far in
+        ([0], [8], [0], [-8], bytes(10), 5),  # a bit-packed run's offset is read
+        ([1, 0], [8, -1], [0, 0], [0, 0], bytes(10), 5),
+    ):
+        with pytest.raises(ValueError):
+            pack_hybrid_upload(*(np.asarray(x) if isinstance(x, list) else x for x in bad))
+
+
+# -- the frame of wire streams, through the numpy reference ----------------------
 #
 # Every case is pages of real hybrid wire (ops/rle_hybrid.encode_hybrid, or
 # written by hand where the encoder would not produce the shape), prescanned
 # and clamped the way the walks do it, and frozen by pack_hybrid_upload. The
-# kernel answers to two oracles: the numpy decode of the same wire
-# (ops/rle_hybrid.decode_hybrid), and the formula the kernel had through PR 30
-# (two reads of the packed words a value, a value may straddle them), kept
-# here in numpy on the same upload.
-
-def _two_gather_formula(buf, width, num_values, run_pad):
-    """expand_hybrid_device as PR 30 left it, in numpy: position i of a
-    bit-packed run extracts its bits at bit_start + (i - out_start) * width
-    from the two words that hold them."""
-    is_rle = buf[:run_pad]
-    out_start = buf[run_pad : 2 * run_pad].view(np.int32)
-    rle_value = buf[2 * run_pad : 3 * run_pad]
-    bit_start = buf[3 * run_pad : 4 * run_pad].view(np.int32)
-    words = buf[4 * run_pad :]
-    i = np.arange(num_values, dtype=np.int32)
-    run = np.maximum(np.searchsorted(out_start, i, side="right") - 1, 0)
-    bitpos = bit_start[run] + (i - out_start[run]) * np.int32(width)
-    w0 = np.clip(bitpos >> 5, 0, len(words) - 2)
-    s = (bitpos & 31).astype(np.uint32)
-    lo = words[w0] >> s
-    hi = np.where(s == 0, np.uint32(0), words[w0 + 1] << ((np.uint32(32) - s) & np.uint32(31)))
-    mask = np.uint32((1 << width) - 1 if width < 32 else 0xFFFFFFFF)
-    return np.where(is_rle[run] != 0, rle_value[run], (lo | hi) & mask)
+# kernel answers to the numpy decode of the same wire
+# (ops/rle_hybrid.decode_hybrid).
 
 
 def _wire_runs(runs, width):
@@ -194,21 +239,11 @@ def _freeze_pages(pages, width, zero_length_runs=False):
         counts = np.stack([counts, np.zeros(k, np.int64)], axis=1).reshape(-1)
         values = np.stack([values, np.full(k, (1 << width) - 1, np.uint64)], axis=1).reshape(-1)
         bit_starts = np.stack([bit_starts, np.where(kind == 1, 0, n_bytes * 8)], axis=1).reshape(-1)
-    f = pack_hybrid_upload(is_rle, counts, values, bit_starts, np.concatenate(packed), width)
+    f, _seconds = pack_hybrid_upload(is_rle, counts, values, bit_starts, np.concatenate(packed), width)
     return f, np.concatenate(expected)
 
 
-def _groups_that_fill_a_bucket(width):
-    """Groups of 8 values whose payload words + the guard word are exactly a
-    power-of-two bucket (nothing of the bucket is padding), or None."""
-    for bucket in (1 << b for b in range(10, 18)):
-        for nbytes in range(4 * (bucket - 2) + 1, 4 * (bucket - 1) + 1):
-            if nbytes % width == 0:
-                return nbytes // width
-    return None
-
-
-def _aligned_read_pages(shape, width, rng):
+def _wire_pages(shape, width, rng):
     top = 1 << width
     draw = lambda n: rng.integers(0, top, size=n, dtype=np.uint64)  # noqa: E731
     if shape == "one-bit-packed-run":
@@ -216,15 +251,16 @@ def _aligned_read_pages(shape, width, rng):
         return [(_wire_runs([("bp", v)], width), 2000)]
     if shape == "rle-only":
         return [(_wire_runs([("rle", int(c), int(v)) for c, v in zip(rng.integers(1, 90, 40), draw(40))], width), 1500)]
-    if shape in ("alternating-run_pad-64", "alternating-run_pad-4096"):
-        pairs = 30 if shape.endswith("-64") else 1100
+    if shape in ("alternating-30-pairs", "alternating-1100-pairs"):
+        pairs = int(shape.split("-")[1])
         runs = []
         for v in draw(pairs):
             runs += [("bp", draw(8)), ("rle", 8, int(v))]
         return [(_wire_runs(runs, width), 16 * pairs)]
     if shape == "clamped-mid-group-at-page-ends":
-        # each page ends inside a bit-packed group: the run's count is clamped,
-        # the next page's payload starts at the next group
+        # each page ends inside a bit-packed group: the run's count is clamped
+        # (the frame leaves the group's overshoot out), the next page's
+        # payload starts at the next group
         return [(encode_hybrid(draw(n), width), n) for n in (13, 27, 100, 5, 1, 8, 403)] + [
             (_wire_runs([("rle", 50, int(draw(1)[0])), ("bp", draw(24))], width), 50 + 17)
         ]
@@ -233,51 +269,31 @@ def _aligned_read_pages(shape, width, rng):
         for v in draw(12):
             runs += [("bp", draw(16)), ("rle", 11, int(v))]
         return [(_wire_runs(runs, width), 27 * 12)]
-    if shape == "payload-fills-its-bucket":
-        groups = _groups_that_fill_a_bucket(width)
-        return [(_wire_runs([("rle", 40, int(draw(1)[0])), ("bp", draw(8 * groups))], width), 40 + 8 * groups - 3)]
+    if shape == "last-group-read-to-the-payload's-last-byte":
+        # the last values' bits end the payload: no 8-byte load fits there
+        return [(_wire_runs([("rle", 40, int(draw(1)[0])), ("bp", draw(2000))], width), 2040)]
     raise AssertionError(shape)
 
 
-_ALIGNED_WIDTHS = [1, 2, 3, 5, 7, 8, 9, 12, 14, 16, 17, 24, 31, 32]
-_ALIGNED_SHAPES = [
-    "one-bit-packed-run", "rle-only", "alternating-run_pad-64", "alternating-run_pad-4096",
-    "clamped-mid-group-at-page-ends", "zero-length-runs", "payload-fills-its-bucket",
+_WIRE_WIDTHS = [1, 2, 3, 5, 7, 8, 9, 12, 14, 16, 17, 24, 31, 32]
+_WIRE_SHAPES = [
+    "one-bit-packed-run", "rle-only", "alternating-30-pairs", "alternating-1100-pairs",
+    "clamped-mid-group-at-page-ends", "zero-length-runs", "last-group-read-to-the-payload's-last-byte",
 ]
+_WIRE_CASES = [(shape, width) for shape in _WIRE_SHAPES for width in _WIRE_WIDTHS]
 
 
-# 4 * (bucket - 1) bytes are never whole groups at a width that is a multiple
-# of 8: such a payload always leaves padding, and the other shapes cover it
-_ALIGNED_CASES = [
-    (shape, width)
-    for shape in _ALIGNED_SHAPES
-    for width in _ALIGNED_WIDTHS
-    if shape != "payload-fills-its-bucket" or _groups_that_fill_a_bucket(width)
-]
-
-
-@pytest.mark.parametrize("shape,width", _ALIGNED_CASES, ids=[f"{s}-{w}" for s, w in _ALIGNED_CASES])
-def test_aligned_read_equals_numpy_decode_and_the_two_gather_formula(shape, width):
-    rng = np.random.default_rng(width * 131 + _ALIGNED_SHAPES.index(shape))
+@pytest.mark.parametrize("shape,width", _WIRE_CASES, ids=[f"{s}-{w}" for s, w in _WIRE_CASES])
+def test_frame_of_wire_pages_equals_numpy_decode(shape, width):
+    rng = np.random.default_rng(width * 131 + _WIRE_SHAPES.index(shape))
     f, expected = _freeze_pages(
-        _aligned_read_pages(shape, width, rng), width, zero_length_runs=shape == "zero-length-runs"
+        _wire_pages(shape, width, rng), width, zero_length_runs=shape == "zero-length-runs"
     )
     assert f.total == len(expected)
-    if shape == "payload-fills-its-bucket":
-        # words + guard are the whole bucket: a kernel that truncated the
-        # payload to whole rows of `width` words would lose the last values
-        nbytes = _groups_that_fill_a_bucket(width) * width
-        assert len(f.buf) - 4 * f.run_pad == (nbytes + 3) // 4 + 1
-    if shape.startswith("alternating"):
-        assert f.run_pad == int(shape.rsplit("-", 1)[1])
-    # the property the aligned read rests on (pack_hybrid_upload's docstring)
-    bp = f.buf[: f.run_pad] == 0
-    assert not np.any(f.buf[3 * f.run_pad : 4 * f.run_pad].view(np.int32)[bp] % (8 * width))
-    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad, f.run_pad))
+    assert (f.width, f.buf.shape) == (_shipped(width), (f.n_pad * _shipped(width) // 32,))
+    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad))
     np.testing.assert_array_equal(got[: f.total], expected)
-    np.testing.assert_array_equal(
-        _two_gather_formula(f.buf, f.width, f.n_pad, f.run_pad)[: f.total], expected
-    )
+    assert not got[f.total :].any()
 
 
 # -- delta_packed_decode_device -------------------------------------------------
@@ -498,30 +514,65 @@ def _nonzero(a: np.ndarray) -> dict:
     return {int(i): int(a[i]) for i in np.flatnonzero(a)}
 
 
-def test_hybrid_upload_golden():
-    # width 3: 5 x 6 | 0..7 bit-packed at bit 0 | a zero-length run | 3 of the
-    # group 7..0 at bit 24 | 3 x 2. The RLE runs' bit offsets are what the
-    # native walk leaves there for an upload's second group of pages: garbage,
+def test_hybrid_upload_golden_two_planes():
+    # width 3 = 2 + 1: 5 x 6 | 0..7 bit-packed at bit 0 | a zero-length run | 3
+    # of the group 7..0 at bit 24 (its other 5 values are the page's overshoot:
+    # gone) | 3 x 2 | 50 x 5. The RLE runs' bit offsets are what the native
+    # walk leaves there for an upload's second group of pages: garbage,
     # negative, and read by nobody.
-    f = pack_hybrid_upload(
-        is_rle=np.array([1, 0, 1, 0, 1], dtype=np.uint8),
-        counts=np.array([5, 8, 0, 3, 3], dtype=np.int64),
-        rle_values=np.array([6, 0, 7, 0, 2], dtype=np.uint64),
-        bit_starts=np.array([-8, 0, -8, 24, -8], dtype=np.int64),
+    f, seconds = pack_hybrid_upload(
+        is_rle=np.array([1, 0, 1, 0, 1, 1], dtype=np.uint8),
+        counts=np.array([5, 8, 0, 3, 3, 50], dtype=np.int64),
+        rle_values=np.array([6, 0, 7, 0, 2, 5], dtype=np.uint64),
+        bit_starts=np.array([-8, 0, -8, 24, -8, -8], dtype=np.int64),
         packed=bytes([0x88, 0xC6, 0xFA, 0x77, 0x39, 0x05]),
         width=3,
     )
-    assert (f.width, f.n_pad, f.run_pad, f.total) == (3, 1024, 64, 19)
-    assert f.buf.dtype == np.uint32 and f.buf.shape == (4 * 64 + 1024,)
-    want = {0: 1, 2: 1, 4: 1}  # is_rle
-    want.update({64 + 1: 5, 64 + 2: 13, 64 + 3: 13, 64 + 4: 16})  # out_start
-    want.update({k: 1025 for k in range(64 + 5, 128)})  # its padding: n_pad + 1
-    want.update({128: 6, 128 + 2: 7, 128 + 4: 2})  # rle_value
-    want.update({192: 0xFFFFFFF8, 192 + 2: 0xFFFFFFF8, 192 + 3: 24, 192 + 4: 0xFFFFFFF8})  # bit_start
-    want.update({256: 0x77FAC688, 256 + 1: 0x00000539})  # payload words, then the guard word: 0
+    assert (f.width, f.n_pad, f.run_pad, f.total) == (3, 1024, 0, 69) and seconds >= 0
+    assert f.buf.dtype == np.uint32 and f.buf.shape == (1024 * 3 // 32,)
+    # the first plane, 64 words of sixteen 2-bit parts: the low 2 bits of slot
+    # s in bits (s // 64) * 2 of word s % 64
+    want = {k: 2 for k in range(5)}  # 6 = 0b110
+    want.update({5 + v: v & 3 for v in range(8) if v & 3})  # 0..7
+    want.update({13: 3, 14: 2, 15: 1, 16: 2, 17: 2, 18: 2})  # 7, 6, 5; 3 x 2
+    want.update({k: 1 for k in range(19, 64)})  # 5 = 0b101, slots 19..63
+    for k in range(5):  # slots 64..68, the second part of words 0..4
+        want[k] |= 1 << 2
+    # the second plane, 32 words of thirty-two 1-bit parts: bit 2 of slot s in
+    # bit s // 32 of word 64 + s % 32. Slots 0..4, 9..15 and 19..31 hold a 1
+    # there (bit 0), every slot of 32..63 (bit 1) and of 64..68 (bit 2)
+    want.update({64 + k: 2 for k in (5, 6, 7, 8, 16, 17, 18)})
+    want.update({64 + k: 3 for k in (*range(9, 16), *range(19, 32))})
+    want.update({64 + k: 7 for k in range(5)})
     assert _nonzero(f.buf) == want
-    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad, f.run_pad))
-    assert got[: f.total].tolist() == [6] * 5 + list(range(8)) + [7, 6, 5] + [2] * 3
+    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad))
+    assert got[: f.total].tolist() == [6] * 5 + list(range(8)) + [7, 6, 5] + [2] * 3 + [5] * 50
+    assert not got[f.total :].any()
+
+
+def test_hybrid_upload_golden_one_plane():
+    # width 8: 1..8 bit-packed at bit 0 | 250 x 0xAB | 3 of the group 9, 10, 11,
+    # 0xFF x 5 at bit 64 | 2 x 7: 263 values, past the plane's 256 words.
+    f, _seconds = pack_hybrid_upload(
+        is_rle=np.array([0, 1, 0, 1], dtype=np.uint8),
+        counts=np.array([8, 250, 3, 2], dtype=np.int64),
+        rle_values=np.array([0, 0xAB, 0, 7], dtype=np.uint64),
+        bit_starts=np.array([0, 0, 64, 0], dtype=np.int64),
+        packed=bytes([1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 0xFF, 0xFF, 0xFF, 0xFF, 0xFF]),
+        width=8,
+    )
+    assert (f.width, f.n_pad, f.run_pad, f.total) == (8, 1024, 0, 263)
+    assert f.buf.dtype == np.uint32 and f.buf.shape == (1024 * 8 // 32,)
+    # one plane, 256 words of four 8-bit parts: slot s in bits (s // 256) * 8 of word s % 256
+    want = {k: k + 1 for k in range(8)}  # slots 0..7
+    want.update({k: 0xAB for k in range(8, 256)})  # slots 8..255
+    want[0] |= 0xAB << 8  # slots 256, 257: the run's last two
+    want[1] |= 0xAB << 8
+    want.update({2: 3 | 9 << 8, 3: 4 | 10 << 8, 4: 5 | 11 << 8})  # slots 258..260
+    want.update({5: 6 | 7 << 8, 6: 7 | 7 << 8})  # slots 261, 262
+    assert _nonzero(f.buf) == want
+    got = np.asarray(expand_hybrid_device(jnp.asarray(f.buf), f.width, f.n_pad))
+    assert got[: f.total].tolist() == list(range(1, 9)) + [0xAB] * 250 + [9, 10, 11] + [7, 7]
 
 
 @pytest.mark.parametrize("nbits", [32, 64])
@@ -650,7 +701,7 @@ def _mixed_plan(type_name, layout, doubles=None, batch_pages=None, seed=0, padde
         plan.frozen_hybrid.append(pack_hybrid_upload(
             np.array([False]), np.array([len(idx)]), np.array([0], np.uint32), np.array([0]),
             _wire(_pack_lsb(idx, width)), width,
-        ))
+        )[0])
     plan.plain_host = np.concatenate(plain_pages)
     if doubles is not None:
         _shape_double_dictionary(plan)

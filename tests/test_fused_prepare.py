@@ -264,60 +264,81 @@ def test_staged_freeze_equals_native_freeze_three_widths(tmp_path, version, cap,
         _assert_records_equal(nd, sd, (name, "delta"))
         uploads = len(nh) + len(nd)
         assert (uploads == 1) if cap is None else (uploads > 1), (name, uploads)
-        assert all(f.width == 13 for f in nh), name
+        assert all(f.width == 16 for f in nh), name  # the chunk's 13 bits ship as 16
     # both walks widened the same pages, through the same re-pack
     repacked = tr_native.stages["hybrid_pages_repacked"].calls
     assert repacked > 0 and tr_staged.stages["hybrid_pages_repacked"].calls == repacked
     assert "host_decoded_pages" not in tr_native.stages and "host_decoded_pages" not in tr_staged.stages
 
 
-# -- what the hybrid kernel rests on: bit-packed runs start on group boundaries --
+# -- what the hybrid frame holds: the host decode's indices, slot for slot -------
 
-def _assert_runs_start_on_groups(frozen, ctx):
-    """Every bit-packed run of every upload starts a whole number of 8-value
-    groups into the payload (device_ops.pack_hybrid_upload's docstring): the
-    payload is one dense stream of `width`-bit values, which is what lets
-    expand_hybrid_device align it by fixed shifts and read each value once."""
-    runs = 0
-    for f in frozen:
-        k = int(np.count_nonzero(f.buf[f.run_pad : 2 * f.run_pad] != f.n_pad + 1))
-        bit_packed = f.buf[:k] == 0
-        bit_start = f.buf[3 * f.run_pad : 3 * f.run_pad + k].view(np.int32)[bit_packed]
-        assert f.width == 0 or not np.any(bit_start % (8 * f.width)), ctx
-        assert np.all(bit_start >= 0), ctx
-        runs += int(bit_packed.sum())
-    assert runs, ctx  # the case does hold bit-packed runs
+def _unframe(f):
+    """The slots of a FrozenHybrid in order, in numpy: word j of a plane of
+    b-bit parts holds slots j, j + L, j + 2L, ... (device_ops.pack_hybrid_upload)."""
+    a = 1 << (f.width.bit_length() - 1) if f.width else 0
+    out = np.zeros(f.n_pad, dtype=np.uint32)
+    at = 0
+    for bits, shift in ((a, 0), (f.width - a, a)):
+        if bits:
+            plane = f.buf[at : at + f.n_pad * bits // 32]
+            at += len(plane)
+            k = (np.arange(32 // bits, dtype=np.uint32) * np.uint32(bits))[:, None]
+            out |= ((plane[None, :] >> k) & np.uint32((1 << bits) - 1)).reshape(-1) << np.uint32(shift)
+    assert at == len(f.buf) and not out[f.total :].any()
+    return out[: f.total]
+
+
+def _assert_frames_hold_the_host_indices(path, walk, ctx, columns=None, **kw):
+    """Every dictionary chunk's frames, end to end, are the indices the host
+    path decodes from the same wire (read_chunk keep_dict_indices=True):
+    re-packed pages, RLE runs, clamped last groups, nulls and uploads split
+    under the bit cap included. No dictionary value takes part."""
+    frames = 0
+    with walk(), FileReader(path) as r:
+        for window, cc, col in _chunk_windows(r):
+            if columns is not None and col.path_str not in columns:
+                continue
+            plan = prepare_chunk_plan(window, cc, col, **kw)
+            host = read_chunk(window, cc, col, keep_dict_indices=True)
+            assert plan.frozen_hybrid and host.indices is not None, (ctx, col.path_str)
+            got = np.concatenate([_unframe(f) for f in plan.frozen_hybrid])
+            assert np.array_equal(got, np.asarray(host.indices).astype(np.uint32)), (ctx, col.path_str)
+            frames += len(plan.frozen_hybrid)
+    assert frames, ctx
+    return frames
 
 
 @requires_native
 @pytest.mark.parametrize("walk", ["native", "staged"])
 @pytest.mark.parametrize("codec", ["none", "snappy", "gzip"])
 @pytest.mark.parametrize("version", ["1.0", "2.0"])
-def test_bit_packed_runs_start_on_group_boundaries(tmp_path, version, codec, walk, staged_walk):
+def test_frames_hold_the_host_indices(tmp_path, version, codec, walk, staged_walk):
     path = _build(tmp_path, "dict_str", codec, version)
-    records = _frozen_records(path, staged_walk if walk == "staged" else nullcontext)
-    assert records
-    for hybrid, _delta in records:
-        _assert_runs_start_on_groups(hybrid, (version, codec, walk))
+    _assert_frames_hold_the_host_indices(
+        path, staged_walk if walk == "staged" else nullcontext, (version, codec, walk)
+    )
 
 
 @requires_native
 @pytest.mark.parametrize("walk", ["native", "staged"])
 @pytest.mark.parametrize("cap", [None, 1 << 19], ids=["one-upload", "uploads-split-at-2^19-bits"])
 @pytest.mark.parametrize("version", ["1.0", "2.0"])
-def test_bit_packed_runs_start_on_group_boundaries_three_widths(
+def test_frames_hold_the_host_indices_three_widths(
     tmp_path, version, cap, walk, staged_walk, monkeypatch
 ):
-    """Pages re-packed from 2 and 10 bits to the chunk's 13, RLE runs between
-    the bit-packed ones, nulls, a DOUBLE dictionary, uploads split under the
-    bit cap (a later group's offsets count from its own first byte)."""
-    t, path = _write_three_widths(tmp_path, version)
+    """Pages re-packed from 2 and 10 bits to the chunk's 13 (shipped as 16),
+    RLE runs between the bit-packed ones, nulls, a DOUBLE dictionary, uploads
+    split under the bit cap (a later group's offsets count from its own first
+    byte, and its frame from its own first slot)."""
+    _t, path = _write_three_widths(tmp_path, version)
     if cap is not None:
         monkeypatch.setattr(pipeline, "_BATCH_BITS_CAP", cap)
-    records = _frozen_records(path, staged_walk if walk == "staged" else nullcontext, doubles="float32")
-    for name, (hybrid, _delta) in zip(t.column_names, records):
-        if name in ("x", "xn", "xd"):
-            _assert_runs_start_on_groups(hybrid, (name, version, cap, walk))
+    frames = _assert_frames_hold_the_host_indices(
+        path, staged_walk if walk == "staged" else nullcontext, (version, cap, walk),
+        columns=("x", "xn", "xd"), doubles="float32",
+    )
+    assert (frames == 3) if cap is None else (frames > 3)
 
 
 @requires_native

@@ -628,9 +628,16 @@ class TestServePropagation:
                     )
                     assert status == 200
                     rid = headers["X-Request-Id"]
-                    status, _, body = _request(
-                        server, "GET", f"/v1/debug/requests/{rid}/trace"
-                    )
+                    # the recorder keeps the span tree in _finish, after the
+                    # response's last byte: the client can be here first
+                    deadline = time.monotonic() + 5.0
+                    while True:
+                        status, _, body = _request(
+                            server, "GET", f"/v1/debug/requests/{rid}/trace"
+                        )
+                        if status != 404 or time.monotonic() > deadline:
+                            break
+                        time.sleep(0.02)
                     assert status == 200, body
                     doc = json.loads(body)
                     assert (

@@ -286,10 +286,12 @@ def test_counts_on_both_sides_of_a_bucket_compile_nothing(tmp_path):
     58,000 and of 65,536 tokens hold 116 and 131 runs and 29,001 and 32,769
     payload words — on both sides of a 128-run and of a 2^15-word bucket, as the
     token corpus's groups of up to 2^20 tokens lie on both sides of 2,048 and
-    2^19. The padded delivery floors both buckets by the values' own (n_pad), so
+    2^19. The hybrid frame ships neither: an upload's length is a function of
+    (shipped width, n_pad), for the exact delivery as for the padded one, so
     both groups run on one set of programs."""
     from parquet_tpu.core.chunk import ChunkWindow, chunk_byte_range
     from parquet_tpu.kernels.pipeline import prepare_chunk_plan
+    from parquet_tpu.utils.trace import decode_trace
 
     first = write(tmp_path / "a.parquet", [_group_of(1, 1 << 16), _group_of(4, 1 << 16)])
     second = write(tmp_path / "b.parquet", [_group_of(2, 58000), _group_of(3, 65000)])
@@ -302,17 +304,23 @@ def test_counts_on_both_sides_of_a_bucket_compile_nothing(tmp_path):
                     offset, total = chunk_byte_range(cc)
                     (f,) = prepare_chunk_plan(ChunkWindow(r._fetch_chunk(offset, total), offset), cc, leaf,
                                               **kw).frozen_hybrid
-                    out.append((f.width, f.n_pad, f.run_pad, len(f.buf)))
+                    out.append((f.width, f.n_pad, len(f.buf)))
         return out
 
-    exact = uploads(first) + uploads(second)
-    assert len({u[:2] for u in exact}) == 1, "one index width and one bucket of values"
-    assert len({u[2] for u in exact}) == 2 and len({u[3] for u in exact}) >= 2, "the file does not straddle"
-    assert len(set(uploads(first, list_lengths=True) + uploads(second, list_lengths=True))) == 1
+    with decode_trace() as tr:
+        exact = uploads(first) + uploads(second)
+    assert set(exact) == {(16, 1 << 16, (1 << 16) * 16 // 32)}, "one index width, one bucket of values, one length"
+    # what the frames replaced does straddle: the wire's bytes are another number a group
+    assert tr.counters()["hybrid_frame_bytes"] == 4 * (1 << 16) * 16 // 8
+    assert tr.counters()["hybrid_values_framed"] == 2 * (1 << 16) + 58000 + 65000
+    assert tr.counters()["hybrid_wire_bytes"] < tr.counters()["hybrid_frame_bytes"]
+    assert set(uploads(first, list_lengths=True) + uploads(second, list_lengths=True)) == set(exact)
     seen = Compiles()
     got = read_packed(first, 2, 8192)
     mark = len(seen.names)
-    assert "jit(expand_hybrid_device)" in seen.names
+    # the recorder does see this read's programs (the expansion's own, one a
+    # (width, n_pad), may have been compiled by an earlier test of the process)
+    assert "jit(pack_append_device)" in seen.names
     again = read_packed(second, 2, 8192)
     assert seen.names[mark:] == []
     same_as_reference(first, 2, 8192, got)
