@@ -72,6 +72,7 @@ KERNEL_LEGS = {
     "plain_bytearray_encode_device": "kernels",  # ... PLAIN BYTE_ARRAY pages
     "masked_agg_device": "daemon",  # /v1/query device units
     "expr_agg_device": "daemon",  # ... TPC-H Q6: sum(l_extendedprice*l_discount) over a small lineitem
+    "group_agg_device": "daemon",  # ... TPC-H Q1: grouped by two flag columns' resident dictionary indices
 }
 
 
@@ -680,6 +681,40 @@ def query_q6(run: Run, url: str, corpus: Path, group_rows: int) -> dict:
     return r6
 
 
+def query_q1(run: Run, url: str, corpus: Path, group_rows: int) -> dict:
+    """TPC-H Q1 (validation parameter) over the same small LINEITEM file,
+    grouped on the daemon's device lane: every group equal to the plain
+    reference (benchmark/lib/reference_tpch_q1.py) to the last digit, and the
+    body byte for byte the host lane's answer by its CLI."""
+    import pyarrow.parquet as pq
+
+    from byname import load_by_name
+
+    kind, ref = load_by_name("corpora", "tpch_lineitem_q1"), load_by_name("lib", "reference_tpch_q1")
+    spec = json.loads((ROOT / "benchmark" / "configs" / "tpch-sf10-pricing-summary.json").read_text())["corpus"]
+    spec, _ = kind.rehearsal(spec, group_rows)
+    path = corpus / kind.file_name(0)
+    if not path.exists():  # tpch_lineitem's bytes: query_q6 has written them
+        kind.write_file(spec, run.args.seed, 0, str(corpus), [])
+    params = {"delta": "90"}
+    body = {"paths": path.name, "filters": ref.filters(params), "group_by": list(ref.GROUP_BY),
+            "aggregates": list(ref.AGGREGATES)}
+    raw = http(url + "/v1/query", body, timeout=run.remaining())
+    r1 = json.loads(raw)
+    share = ref.q1(pq.read_table(path, columns=list(ref.COLUMNS)), params)
+    want = ref.merge([[[f, s, sums] for (f, s), sums in sorted(share.items())]])
+    check(r1["groups"] == want and r1["group_count"] == len(want) == 4 and r1["rows_scanned"] == spec["rows_per_file"],
+          f"/v1/query Q1: {r1['groups']} over {r1['rows_scanned']} rows != the reference {want}")
+    host = subprocess.run(
+        [sys.executable, "-m", "parquet_tpu.tools.parquet_tool", "scan", str(path), "--filters",
+         json.dumps(body["filters"]), "--aggregate", json.dumps(body["aggregates"]), "--group-by",
+         ",".join(body["group_by"])],
+        capture_output=True, timeout=run.remaining(), env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    check(host.returncode == 0 and host.stdout == raw,
+          f"Q1: the host lane's CLI said {host.stdout[-300:]!r} {host.stderr[-300:]!r}, the daemon {raw[-300:]!r}")
+    return r1
+
+
 def run_daemon(run: Run, corpus: Path) -> dict:
     """The daemon by its CLI, asked over HTTP; answers are pyarrow's."""
     import re
@@ -767,6 +802,7 @@ def run_daemon(run: Run, corpus: Path) -> dict:
     r4 = json.loads(http(url + "/v1/query", q4, timeout=run.remaining()))
     check(r4["result"] == want4, f"/v1/query 4: {r4['result']} != pyarrow {want4}")
     r6 = query_q6(run, url, corpus, group_rows)
+    rq1 = query_q1(run, url, corpus, group_rows)
     rows = [json.loads(x) for x in http(
         url + "/v1/scan",
         {"paths": "trips-0.parquet", "columns": ["trip_id", "vendor", "passenger_count"], "limit": 1000},
@@ -781,8 +817,14 @@ def run_daemon(run: Run, corpus: Path) -> dict:
         return int(m.group(1)) if m else 0
 
     got = {"device": units("device"), "host_fallback": units("host_fallback")}
-    want = {"device": r1["units"] + r2["units"] + r4["units"] + r6["units"], "host_fallback": r3["units"]}
+    # q3 groups by vendor under sum(passenger_count), an input with nulls: the grouped kernel's typed
+    # decline (input_shape), so its units stay the host's
+    want = {"device": r1["units"] + r2["units"] + r4["units"] + r6["units"] + rq1["units"],
+            "host_fallback": r3["units"]}
     check(got == want, f"query units by engine {got}, expected {want}")
+    grouped = re.search(r"query_group_units (\d+)", metrics)
+    check(grouped and int(grouped.group(1)) == rq1["units"],
+          f"query_group_units {grouped and grouped.group(1)}, expected Q1's {rq1['units']}")
     child.send_signal(signal.SIGTERM)
     tail = child.stdout.read()
     print(tail, end="", flush=True)
@@ -791,10 +833,10 @@ def run_daemon(run: Run, corpus: Path) -> dict:
     except subprocess.TimeoutExpired:
         raise SmokeFailure("the daemon did not drain on SIGTERM") from None
     check(rc == 0 and "serve: drained, bye" in tail, f"the daemon exited {rc} without draining")
-    say(f"daemon: 4 queries + 1 scan equal pyarrow, Q6 equals its reference and the host lane's bytes; "
+    say(f"daemon: 4 queries + 1 scan equal pyarrow, Q6 and Q1 (grouped in HBM) equal their references and the host lane's bytes; "
         f"units by engine {got}; drained")
     return {
-        "device": health["device"], "query_units": [r1["units"], r2["units"], r3["units"], r4["units"], r6["units"]],
+        "device": health["device"], "query_units": [r1["units"], r2["units"], r3["units"], r4["units"], r6["units"], rq1["units"]],
         "query_device_units": got, "unexpected_host_fallback_units": 0, "scan_rows": len(rows),
     }
 

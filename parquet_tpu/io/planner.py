@@ -208,7 +208,10 @@ def fetch_ranges(
             piece = mv[off - run_off : off - run_off + n]
             out[(off, n)] = piece
             if cache is not None:
-                cache.put(sid, off, n, piece)
+                # a range that is its whole run (a chunk fetched alone) is
+                # cached as the buffer read, not as a copy of it: the cache
+                # copies a view so as not to pin the run it is cut from
+                cache.put(sid, off, n, buf if n == len(buf) else piece)
     return out
 
 

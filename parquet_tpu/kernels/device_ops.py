@@ -127,6 +127,8 @@ __all__ = [
     "plain_bytearray_encode_device",
     "masked_agg_device",
     "expr_agg_device",
+    "group_agg_device",
+    "GROUP_SLOTS",
 ]
 
 
@@ -168,7 +170,8 @@ MAX_DEVICE_BATCH_BITS = 1 << 31
 
 # Every jitted kernel below traces under one jax.named_scope "pqt.<kernel>"
 # (the two decode kernels also under inner scopes: pqt.hybrid_expand/unpack,
-# pqt.delta_decode/{unpack,prefix_sum,rebase}). The scope path lands in each HLO op's op_name
+# pqt.delta_decode/{unpack,prefix_sum,rebase}; the grouped reduction under
+# pqt.group_agg/{group_id,reduce}). The scope path lands in each HLO op's op_name
 # metadata, which the profiler's trace carries per device op: those names
 # are what benchmark/lib/xspans.py reads, so a refactor may rename or fuse
 # the Python functions and must keep them. Scopes act while a program is
@@ -1306,17 +1309,93 @@ def expr_agg_device(columns: tuple, mask: jnp.ndarray, program: tuple, op: str):
     wrapping arithmetic never wraps. min/max of zero matching rows is the
     dtype's identity: the caller gates on the matched count."""
 
-    def value(node):
-        if node[0] == "col":
-            return columns[node[1]].astype(jnp.int64)
-        if node[0] == "lit":
-            return jnp.int64(node[1])
-        left, right = value(node[1]), value(node[2])
-        if node[0] == "*":
-            return left * right
-        return left + right if node[0] == "+" else left - right
+    return masked_agg_device(
+        jnp.broadcast_to(_program_value(program, columns), mask.shape), mask, op
+    )
 
-    return masked_agg_device(jnp.broadcast_to(value(program), mask.shape), mask, op)
+
+def _program_value(node, columns: tuple):
+    """An integer expression program (expr_agg_device's form) over resident
+    columns, row by row in wrapping int64."""
+    if node[0] == "col":
+        return columns[node[1]].astype(jnp.int64)
+    if node[0] == "lit":
+        return jnp.int64(node[1])
+    left, right = _program_value(node[1], columns), _program_value(node[2], columns)
+    if node[0] == "*":
+        return left * right
+    return left + right if node[0] == "+" else left - right
+
+
+# The one static bucket of group_agg_device: a grouped unit whose key
+# dictionaries multiply to more slots than this is the host's (typed, counted).
+GROUP_SLOTS = 64
+
+
+@partial(jax.jit, static_argnames=("programs",))
+@jax.named_scope("pqt.group_agg")
+def group_agg_device(
+    keys: tuple,          # int32[n] each: a key chunk's resident dictionary indices
+    layout: jnp.ndarray,  # int32[1 + len(keys)]: live slots, then each key's radix
+    columns: tuple,       # the resident integer columns the programs read
+    mask: jnp.ndarray,    # bool[n]: the row mask
+    programs: tuple,      # static: ((program, ops), ...), one per DISTINCT input
+):
+    """A grouped unit's partial in ONE program: per group slot the matched
+    count and, per distinct reduction input, the reductions asked of it —
+    `programs` pairs an integer expression tree (expr_agg_device's form) with
+    a subset of ("sum", "min", "max"); sum(x) and avg(x) share one entry.
+    Returns (count int64[GROUP_SLOTS], ((reduction int64[GROUP_SLOTS] per op)
+    per program)); integers only, wrapping int64 after the caller's proof.
+
+    The group id is the mixed-radix combination of the key indices,
+    id = sum(keys[k] * layout[1 + k]), with the dictionary sizes as RUNTIME
+    scalars: the program compiles per (programs, rows) and never per
+    dictionary size. Slots at or past layout[0] (the product of the
+    dictionary sizes) are never visited and read 0; a visited slot no row
+    fell in has count 0 and its min/max hold the dtype's identity — the
+    caller takes a slot for a group only where its count is positive.
+
+    Compare/select, slot by slot, in a loop over the LIVE slots: one pass
+    over the inputs a slot, every reduction of the slot fused in it. The
+    micro-run that chose it (one v5e, 2^20 rows, Q1's five sums and a count;
+    PERF.md section 6, PR 39): 0.86 ms a call at 6 live slots, 0.91 at 16,
+    2.15 at 64 — 22 us a further slot, an empty jitted call being 0.25 —
+    where a static unroll over 8 / 64 slots took 0.89 / 2.03 and the
+    scatter-add (jax.ops.segment_sum into 64 bins) 62.6 ms a REDUCTION at
+    int64 (9.2 at int32), 375 ms for the six: colliding rows serialise. So
+    the cost is the live slots', not the bucket's."""
+    n = mask.shape[0]
+    with jax.named_scope("group_id"):
+        gid = jnp.zeros(n, dtype=jnp.int32)
+        for k, idx in enumerate(keys):
+            gid = gid + idx.astype(jnp.int32) * layout[1 + k]
+
+    info = jnp.iinfo(jnp.int64)
+    with jax.named_scope("reduce"):
+        values = [jnp.broadcast_to(_program_value(p, columns), (n,)) for p, _ in programs]
+
+        def slot(g, acc):
+            here = mask & (gid == g)
+            count, reduced = acc
+            count = count.at[g].set(jnp.sum(here, dtype=jnp.int64))
+            out = []
+            for v, (_, ops), per_op in zip(values, programs, reduced):
+                row = []
+                for op, into in zip(ops, per_op):
+                    if op == "sum":
+                        r = jnp.sum(jnp.where(here, v, jnp.int64(0)))
+                    elif op == "min":
+                        r = jnp.min(jnp.where(here, v, jnp.int64(info.max)))
+                    else:
+                        r = jnp.max(jnp.where(here, v, jnp.int64(info.min)))
+                    row.append(into.at[g].set(r))
+                out.append(tuple(row))
+            return count, tuple(out)
+
+        zeros = jnp.zeros(GROUP_SLOTS, dtype=jnp.int64)
+        start = (zeros, tuple(tuple(zeros for _ in ops) for _, ops in programs))
+        return jax.lax.fori_loop(0, jnp.minimum(layout[0], GROUP_SLOTS), slot, start)
 
 
 @partial(jax.jit, static_argnames=("rows_pad",))

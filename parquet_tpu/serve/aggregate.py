@@ -9,12 +9,25 @@ response is kilobytes regardless of how many rows matched.
 
 Semantics are PINNED AGAINST PYARROW by construction, not by reimplementation:
 unit partials are pyarrow.compute kernels (count/sum/min/max and
-TableGroupBy for group-by), and merging two partial values runs the same
-kernel over a two-element array OF THE PARTIAL'S ARROW TYPE — so null
+TableGroupBy for group-by), and merging a key's partial values runs the same
+kernel over the array of them OF THE PARTIAL'S ARROW TYPE (QueryState files
+a unit's values as they arrive, in unit order, and folds a key's _FOLD_AT at
+a time and once more when the body is built: one kernel call an aggregate,
+not one a pair — an Arrow call hands the GIL away, and the thread that
+merges is the one that feeds the unit pool) — so null
 skipping (sum/min/max ignore nulls, all-null yields null), NaN propagation
 (sum) vs NaN skipping (min/max), decimal precision, and int64 wraparound
 all come out identical to a single whole-corpus pyarrow aggregation
 (differential tests assert exactly that).
+
+avg is the one op pyarrow's kernels do not pin, because pyarrow averages in
+float: a unit's partial for avg(x) is the exact pair (sum of x in sum's own
+Arrow domain, count of non-null x), pairs merge by adding both halves, and
+the quotient is taken ONCE per group per query, when the body is rendered
+(render_avg): a decimal of x's scale + 4, rounded half up (Spark's rule for
+avg over DECIMAL(p, s): DECIMAL(p + 4, s + 4); an integer x has scale 0),
+as text, in integer arithmetic. avg over zero non-null inputs is null; avg
+over a float input is a typed 400 — no float on either lane.
 
 Group-by cardinality is BOUNDED: the merged table growing past the
 request's max_groups raises the typed overflow ServeError (413
@@ -29,6 +42,7 @@ jsonl-scan contract protocol.py pins for rows.
 
 from __future__ import annotations
 
+import decimal
 import json
 
 from . import expr as _expr
@@ -40,6 +54,7 @@ __all__ = [
     "unit_partial",
     "unit_count_partial",
     "result_dict",
+    "render_avg",
     "render_query_body",
     "run_local_query",
 ]
@@ -127,18 +142,25 @@ def unit_partial(table, query: QueryRequest):
                 if a.op == "count":
                     vals.append(int(pc.count(col).as_py()))
                     continue
-                s = {"sum": pc.sum, "min": pc.min, "max": pc.max}[a.op](col)
+                fn = {"sum": pc.sum, "min": pc.min, "max": pc.max, "avg": pc.sum}
+                s = fn[a.op](col)
             except (pa.ArrowInvalid, pa.ArrowNotImplementedError) as e:
                 raise ServeError(
                     400, "bad_aggregates",
                     f"cannot {a.op} column {a.column!r}: {e}",
                 ) from None
-            vals.append(s.as_py())
             types[j] = s.type
+            if a.op == "avg":
+                _check_avg_domain(a, s.type)
+                vals.append(avg_pair(s.as_py(), int(pc.count(col).as_py())))
+            else:
+                vals.append(s.as_py())
         return {(): vals}, types
     keys = list(query.group_by)
     spec = []
+    at: list = []  # per aggregate: its first column in the spec
     for j, a in enumerate(aggs):
+        at.append(len(spec))
         if a.column is None:
             spec.append(([], "count_all"))
             continue
@@ -146,32 +168,75 @@ def unit_partial(table, query: QueryRequest):
         if a.expr is not None:  # grouped as a column of its own
             name = f"__agg{j}"
             table = table.append_column(name, _agg_input(table, a))
-        spec.append((name, a.op))
+        if a.op == "avg":  # the exact pair: its sum, then its count
+            spec += [(name, "sum"), (name, "count")]
+        else:
+            spec.append((name, a.op))
     try:
         res = table.group_by(keys).aggregate(spec)
     except (pa.ArrowInvalid, pa.ArrowNotImplementedError, KeyError) as e:
         raise ServeError(
             400, "bad_aggregates", f"cannot group by {keys}: {e}"
         ) from None
-    if res.num_columns != len(keys) + len(aggs):
+    if res.num_columns != len(keys) + len(spec):
         raise ServeError(
             500, "internal", "group-by result shape mismatch"
         )
     # pyarrow's aggregate table leads with the key columns, then the
     # aggregates in spec order — read positionally (names can collide)
     kl = [res.column(i).to_pylist() for i in range(len(keys))]
-    al = [res.column(len(keys) + j).to_pylist() for j in range(len(aggs))]
-    types = [
-        None
-        if a.op == "count"
-        else res.column(len(keys) + j).type
-        for j, a in enumerate(aggs)
-    ]
+    cl = [res.column(len(keys) + i).to_pylist() for i in range(len(spec))]
+    types: list = []
+    al: list = []
+    for j, a in enumerate(aggs):
+        typ = res.column(len(keys) + at[j]).type
+        types.append(None if a.op == "count" else typ)
+        if a.op == "avg":
+            _check_avg_domain(a, typ)
+            al.append([avg_pair(s, n) for s, n in zip(cl[at[j]], cl[at[j] + 1])])
+        else:
+            al.append(cl[at[j]])
     groups = {}
     for g in range(res.num_rows):
         key = tuple(k[g] for k in kl)
         groups[key] = [a[g] for a in al]
     return groups, types
+
+
+def _check_avg_domain(a, typ) -> None:
+    import pyarrow as pa
+
+    if not (pa.types.is_integer(typ) or pa.types.is_decimal(typ)):
+        raise ServeError(
+            400, "bad_aggregates",
+            f"cannot avg column {a.column!r}: its sum is {typ}; avg is exact "
+            "(integer and decimal inputs only)",
+        )
+
+
+def avg_pair(total, count: int):
+    """avg's partial value: (sum, count of non-null inputs), or None where
+    nothing was averaged."""
+    return (total, count) if count else None
+
+
+def render_avg(pair, typ):
+    """The quotient of an avg pair as text, taken once: a decimal of the
+    sum's scale + 4 (an integer sum has scale 0), rounded half up — away
+    from zero at the tie — in integer arithmetic. None stays null."""
+    if pair is None:
+        return None
+    total, count = pair
+    scale = getattr(typ, "scale", 0) + 4
+    if isinstance(total, int):
+        unscaled = total * 10**scale
+    else:  # by its digits: decimal's own arithmetic rounds at 28
+        sign, digits, exponent = total.as_tuple()
+        unscaled = int("".join(map(str, digits))) * 10 ** (exponent + scale)
+        unscaled = -unscaled if sign else unscaled
+    q = (2 * abs(unscaled) + count) // (2 * count)
+    text = decimal.Decimal((int(unscaled < 0), tuple(map(int, str(q))), -scale))
+    return format(text, "f")
 
 
 def unit_count_partial(query: QueryRequest, num_rows: int):
@@ -183,17 +248,28 @@ def unit_count_partial(query: QueryRequest, num_rows: int):
     )
 
 
-def _merge_value(op: str, a, b, typ):
+def _merge_values(op: str, vals: list, typ):
+    """One aggregate's partials folded into one: counts add, sum / min / max
+    go through the Arrow kernel over the partials' own type (so the fold is
+    pyarrow's, exactly), nulls skipped, null where every partial is null; an
+    avg pair adds its two halves, the sum in its Arrow domain. ONE kernel
+    call however many partials: a call a pair would hand the GIL away and
+    wait for it again a thousand times a grouped query, on the one thread
+    that also feeds the unit pool."""
     if op == "count":
-        return int(a) + int(b)
-    if a is None:
-        return b
-    if b is None:
-        return a
+        return sum(int(v) for v in vals)
+    live = [v for v in vals if v is not None]
+    if len(live) < 2:
+        return live[0] if live else None
+    if op == "avg":
+        return (
+            _merge_values("sum", [p[0] for p in live], typ),
+            sum(p[1] for p in live),
+        )
     import pyarrow as pa
     import pyarrow.compute as pc
 
-    arr = pa.array([a, b], type=typ)
+    arr = pa.array(live, type=typ)
     if op == "sum":
         return pc.sum(arr).as_py()
     if op == "min":
@@ -201,25 +277,33 @@ def _merge_value(op: str, a, b, typ):
     return pc.max(arr).as_py()
 
 
-class QueryState:
-    """The merged aggregate state one request accumulates unit by unit."""
+# partials a key may hold of one aggregate before they are folded: bounds
+# the state of a query over many units at groups x aggregates x this
+_FOLD_AT = 64
 
-    __slots__ = ("query", "groups", "types", "rows_scanned", "rows_matched")
+
+class QueryState:
+    """The merged aggregate state one request accumulates unit by unit.
+    `absorb` only files a unit's values under their key (plain list work,
+    no Arrow call); they are folded by `_merge_values` when a key holds
+    _FOLD_AT of them and when `groups` is read."""
+
+    __slots__ = ("query", "_pending", "types", "rows_scanned", "rows_matched")
 
     def __init__(self, query: QueryRequest):
         self.query = query
         self.types: list = [None] * len(query.aggregates)
         self.rows_scanned = 0
         self.rows_matched = 0
-        if query.group_by:
-            self.groups: dict = {}
-        else:
+        # key -> per aggregate, the list of partials not folded yet
+        self._pending: dict = {}
+        if not query.group_by:
             # the global row exists even over zero units: count 0, sum/min/
             # max null — matching pyarrow kernels over an empty column
-            self.groups = {
-                (): [0 if a.column is None or a.op == "count" else None
-                     for a in query.aggregates]
-            }
+            self._pending[()] = [
+                [0 if a.column is None or a.op == "count" else None]
+                for a in query.aggregates
+            ]
 
     def absorb(self, part) -> None:
         """Merge one unit's ((groups, types), scanned, matched) partial."""
@@ -231,20 +315,34 @@ class QueryState:
                 self.types[j] = t
         q = self.query
         for key, vals in groups.items():
-            cur = self.groups.get(key)
+            cur = self._pending.get(key)
             if cur is None:
-                if len(self.groups) >= q.max_groups:
+                if len(self._pending) >= q.max_groups:
                     raise ServeError(
                         413, "group_overflow",
                         f"group-by cardinality exceeded max_groups="
                         f"{q.max_groups}; narrow the filter or raise "
                         "max_groups",
                     )
-                self.groups[key] = list(vals)
+                self._pending[key] = [[v] for v in vals]
                 continue
-            for j, a in enumerate(q.aggregates):
+            for held, v in zip(cur, vals):
+                held.append(v)
+            if len(cur[0]) >= _FOLD_AT:  # aggregates are never empty (protocol.py)
+                self._fold(cur)
+
+    def _fold(self, cur: list) -> None:
+        for j, a in enumerate(self.query.aggregates):
+            if len(cur[j]) > 1:
                 op = "count" if a.column is None else a.op
-                cur[j] = _merge_value(op, cur[j], vals[j], self.types[j])
+                cur[j] = [_merge_values(op, cur[j], self.types[j])]
+
+    @property
+    def groups(self) -> dict:
+        """key -> the merged value of every aggregate."""
+        for cur in self._pending.values():
+            self._fold(cur)
+        return {key: [held[0] for held in cur] for key, cur in self._pending.items()}
 
 
 def _key_order(key: tuple) -> str:
@@ -257,6 +355,13 @@ def result_dict(query: QueryRequest, state: QueryState, *, units: int) -> dict:
     """The response body, deterministically ordered (groups sort by their
     canonical key encoding) so daemon bytes == CLI bytes."""
     names = [agg_name(a) for a in query.aggregates]
+
+    def rendered(vals: list) -> dict:
+        return {
+            name: render_avg(v, typ) if a.op == "avg" else v
+            for name, a, v, typ in zip(names, query.aggregates, vals, state.types)
+        }
+
     body: dict = {
         "group_by": list(query.group_by),
         "aggregates": names,
@@ -264,17 +369,15 @@ def result_dict(query: QueryRequest, state: QueryState, *, units: int) -> dict:
         "rows_scanned": state.rows_scanned,
         "rows_matched": state.rows_matched,
     }
+    groups = state.groups
     if query.group_by:
-        body["group_count"] = len(state.groups)
+        body["group_count"] = len(groups)
         body["groups"] = [
-            {
-                "key": list(key),
-                "aggregates": dict(zip(names, state.groups[key])),
-            }
-            for key in sorted(state.groups, key=_key_order)
+            {"key": list(key), "aggregates": rendered(groups[key])}
+            for key in sorted(groups, key=_key_order)
         ]
     else:
-        body["result"] = dict(zip(names, state.groups[()]))
+        body["result"] = rendered(groups[()])
     return body
 
 

@@ -171,21 +171,52 @@ def _run_arrow_unit(session, planned, unit, max_rows, check):
             _close_unit_reader(session, reader)
 
 
-def _pipelined(units, run_one, window: int, check: "_Check"):
-    """Bounded in-order unit pipeline: submit up to `window` ahead, yield
-    results in plan order. Result waits poll in deadline-bounded slices so
-    an expired request raises its typed 504 even while a unit is stuck."""
-    pending: deque = deque()
-    idx = 0
-    try:
-        while pending or idx < len(units):
-            while idx < len(units) and len(pending) < window:
-                u = units[idx]
+def _pipelined(units, run_one, window: int, check: "_Check", ahead: int = 0):
+    """Bounded in-order unit pipeline: up to `window` units running, results
+    yielded in plan order. `ahead` more results may be held finished while
+    the oldest unit still runs (a query's kilobyte partials; a streamed scan
+    holds payload and passes 0). The next unit is submitted by whoever frees
+    its slot — the worker that finished a unit, or this thread when it takes
+    a result — so a worker never idles behind the oldest unit or behind this
+    thread's waking up, and nothing is parked in the pool's queue, whose wait
+    and depth are what brownout reads. Result waits poll in deadline-bounded
+    slices so an expired request raises its typed 504 even while a unit is
+    stuck."""
+    pending: deque = deque()  # submitted, not yet yielded, in plan order
+    lock = threading.Lock()
+    idx = running = 0
+    closed = False
+
+    def refill() -> None:
+        nonlocal idx, running
+        with lock:
+            while (
+                not closed
+                and idx < len(units)
+                and running < window
+                and len(pending) < window + ahead
+            ):
                 pending.append(
-                    instrumented_submit(serve_pool(), run_one, u, pool="pqt-serve")
+                    instrumented_submit(
+                        serve_pool(), run_and_refill, units[idx], pool="pqt-serve"
+                    )
                 )
                 idx += 1
-            fut = pending.popleft()
+                running += 1
+
+    def run_and_refill(u):
+        nonlocal running
+        try:
+            return run_one(u)
+        finally:
+            with lock:
+                running -= 1
+            refill()
+
+    try:
+        refill()
+        while pending:
+            fut = pending[0]
             while True:
                 check()
                 try:
@@ -193,10 +224,15 @@ def _pipelined(units, run_one, window: int, check: "_Check"):
                     break
                 except _FutTimeout:
                     continue
+            with lock:
+                pending.popleft()
+            refill()
             yield result
     finally:
         # abort first so already-running tasks exit at their next check,
         # then drop anything still queued
+        with lock:
+            closed = True
         check.abort.set()
         for f in pending:
             f.cancel()
@@ -445,8 +481,10 @@ def execute_query(
             _metrics.inc("query_device_unavailable_total")
             device_unit = None
     # a streamed scan's window bounds BUFFERED payload; a query's unit
-    # results are kilobyte partials, so the lookahead widens to the pool —
-    # merge order doesn't matter and idle workers are pure waste
+    # results are kilobyte partials, so the pool's worth of units run at once
+    # — idle workers are pure waste — and as many finished partials again
+    # may wait their turn to be absorbed in plan order (the order is part of
+    # the answer: a float sum's last bit)
     window = max(window, min(pool_size(), len(units) or 1))
 
     def run(u):
@@ -482,7 +520,7 @@ def execute_query(
                 _close_unit_reader(session, reader)
             return (unit_partial(t, query), u.num_rows, t.num_rows)
 
-    gen = _pipelined(units, run, window, check)
+    gen = _pipelined(units, run, window, check, ahead=window)
     try:
         for part in _wrap_decode_errors(gen):
             with stage("serve.merge"):

@@ -12,6 +12,22 @@ The device lane (serve/query_device.py) types its integer program from the
 same evaluation over EMPTY arrays, so both lanes agree on every type without
 a second rule book.
 
+ONE rule is this module's own, for a product Arrow cannot type. Arrow gives
+decimal(p1, s1) * decimal(p2, s2) the type decimal(p1 + p2 + 1, s1 + s2) and
+refuses it past decimal128's 38 digits — TPC-H Q1's charge,
+l_extendedprice*(1-l_discount)*(1+l_tax), asks for 49 with decimal literals
+and 61 with integer ones. Such a `*` node is typed decimal128(38, s1 + s2),
+the cap Spark and DuckDB apply, and computed EXACTLY OR NOT AT ALL: the
+operand of the greater precision (the left one on a tie; an integer operand
+counts as the decimal Arrow would make of it, int64 as decimal(19, 0)) is
+first cast, CHECKED, down to the precision that makes the product's declared
+precision 38 (`capped_product`: decimal(32, 4) * decimal(16, 2) multiplies as
+decimal(21, 4) * decimal(16, 2) = decimal(38, 6)). A value that does not fit
+the narrower precision raises (the request's typed 400): no node is ever
+rounded, truncated or computed in float. The device lane proves the same
+bound from the chunks' statistics before it runs (query_device._bind) and
+leaves the unit to the host where it cannot.
+
     expr   := term (("+" | "-") term)*
     term   := factor ("*" factor)*
     factor := NUMBER | NAME | "`" any name "`" | "(" expr ")"
@@ -28,7 +44,9 @@ from __future__ import annotations
 import decimal
 import re
 
-__all__ = ["parse", "render", "columns", "evaluate", "literal"]
+__all__ = ["parse", "render", "columns", "evaluate", "literal", "capped_product"]
+
+MAX_PRECISION = 38  # decimal128's
 
 MAX_NODES = 64  # a tree is query text, not a program: bounded like max_groups
 
@@ -136,4 +154,55 @@ def evaluate(tree, column):
     if tree[0] == "lit":
         return pa.scalar(literal(tree[1]))
     fn = {"*": pc.multiply, "+": pc.add, "-": pc.subtract}[tree[0]]
-    return fn(evaluate(tree[1], column), evaluate(tree[2], column))
+    left, right = evaluate(tree[1], column), evaluate(tree[2], column)
+    if tree[0] == "*":
+        cap = capped_product(left.type, right.type)
+        if cap is not None:  # checked: a value past the narrower precision raises
+            side, narrower = cap
+            if side == 0:
+                left = left.cast(narrower)
+            else:
+                right = right.cast(narrower)
+    return fn(left, right)
+
+
+def _as_decimal(typ):
+    """The decimal128 type Arrow's arithmetic makes of an operand's type
+    (itself; an integer's by Arrow's own implicit cast, read off a product
+    with decimal(1, 0), whose precision is the operand's + 2); None for any
+    other type."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    if pa.types.is_decimal128(typ):
+        return typ
+    if not pa.types.is_integer(typ):
+        return None
+    probe = pc.multiply(pa.array([], typ), pa.array([], pa.decimal128(1, 0))).type
+    return pa.decimal128(probe.precision - 2, 0)
+
+
+def capped_product(left_type, right_type):
+    """The module's one typing rule (docstring): None where Arrow types the
+    product itself, else (side, type) — the operand to narrow (0 left, 1
+    right) and the decimal type to cast it to, checked, so that the product
+    is decimal128(38, s_left + s_right). Raises ArrowInvalid where no
+    precision of that operand gives a product of 38 digits."""
+    import pyarrow as pa
+
+    lt, rt = _as_decimal(left_type), _as_decimal(right_type)
+    if lt is None or rt is None or not (
+        pa.types.is_decimal(left_type) or pa.types.is_decimal(right_type)
+    ):
+        return None  # no decimal product: integers wrap, floats are Arrow's
+    if lt.precision + rt.precision + 1 <= MAX_PRECISION:
+        return None
+    side = 0 if lt.precision >= rt.precision else 1
+    wide, other = (lt, rt) if side == 0 else (rt, lt)
+    precision = MAX_PRECISION - 1 - other.precision
+    if precision < max(wide.scale, 1):
+        raise pa.ArrowInvalid(
+            f"Decimal precision out of range [1, {MAX_PRECISION}]: a product of "
+            f"{lt} and {rt} has no exact decimal128 type"
+        )
+    return side, pa.decimal128(precision, wide.scale)

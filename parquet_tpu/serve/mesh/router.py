@@ -22,8 +22,8 @@ re-assemble in plan order:
   same framing, the same bytes.
 - /v1/query: each unit's response is the canonical body of a one-unit
   query; the router absorbs them IN UNIT ORDER into the same QueryState
-  the daemon merges with — the identical pairwise pyarrow merge
-  sequence, so sums of floats agree to the last bit. 413 group_overflow
+  the daemon merges with — the identical values folded in the
+  identical order, so sums of floats agree to the last bit. 413 group_overflow
   fires at the same unit it would on the daemon.
 
 Requests that pin their own `shard` or `limit` (and 0/1-unit plans) pass
@@ -180,11 +180,37 @@ def _query_obj(req: QueryRequest) -> dict:
     return obj
 
 
+def _require_mergeable(query: QueryRequest) -> None:
+    """Scatter merges per-unit DOCUMENTS, and a rendered avg cannot be
+    merged: averaging the units' averages is another number. Declined typed,
+    as routed append is; merging (sum, count) pairs across replicas needs a
+    partial wire form the daemons do not have."""
+    for a in query.aggregates:
+        if a.op == "avg":
+            raise ServeError(
+                501, "not_routable",
+                f"{agg_name(a)} is not routable across units: a replica "
+                "renders the quotient, and averages do not merge (POST to one "
+                "replica, or route sum and count and divide)",
+            )
+
+
 def _doc_partial(doc: dict, query: QueryRequest):
     """A replica's /v1/query body as a QueryState partial. Types are
     inferred by the merge kernels from the JSON-round-tripped values —
-    exact for the int64/float64/string domains JSON round-trips exactly."""
+    exact for the int64/float64/string domains JSON round-trips exactly. A
+    sum that came back as TEXT is a decimal (an expression under the
+    38-digit cap among them: serve/expr.py): the router holds no schema to
+    type it with, so it declines typed instead of adding strings."""
     names = [agg_name(a) for a in query.aggregates]
+    rows = doc.get("groups", []) if query.group_by else [{"aggregates": doc.get("result") or {}}]
+    for a, n in zip(query.aggregates, names):
+        if a.op == "sum" and any(isinstance(g["aggregates"].get(n), str) for g in rows):
+            raise ServeError(
+                501, "not_routable",
+                f"{n} is a decimal sum, rendered as text: not mergeable "
+                "across units without its type (POST to one replica)",
+            )
     if query.group_by:
         groups = {
             tuple(g["key"]): [g["aggregates"].get(n) for n in names]
@@ -498,6 +524,7 @@ class MeshService:
                 return ticket, self._passthrough_query(
                     request, sig, hdrs, deadline
                 )
+            _require_mergeable(request)
             _metrics.inc(
                 "mesh_requests_total", endpoint="/v1/query", mode="scatter"
             )

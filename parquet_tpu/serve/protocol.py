@@ -50,8 +50,10 @@ _OPS = (
     "contains",
 )
 
-# aggregate ops accepted by /v1/query and `parquet-tool scan --aggregate`
-AGG_OPS = ("count", "sum", "min", "max")
+# aggregate ops accepted by /v1/query and `parquet-tool scan --aggregate`.
+# avg is the exact pair (sum, count of non-null inputs) until the body is
+# rendered (serve/aggregate.py: render_avg)
+AGG_OPS = ("count", "sum", "min", "max", "avg")
 
 # group-by cardinality is BOUNDED: past max_groups the query fails with a
 # typed overflow error instead of buffering an unbounded result (the whole

@@ -236,7 +236,8 @@ Key families (all under the `parquet_tpu_` prefix in exposition):
                                     partial aggregate reduced in HBM
                                     (serve/query_device), "host_fallback"
                                     = shape outside the device envelope
-                                    (float sums, group_by, binary-backed
+                                    (float sums, a group_by the grouped
+                                    kernel declines, binary-backed
                                     decimals), answered by the exact
                                     pyarrow host path — rendered bytes
                                     identical
@@ -251,6 +252,23 @@ Key families (all under the `parquet_tpu_` prefix in exposition):
                                     inside int64 (or that had none): the
                                     host's, where Arrow computes in 128
                                     bits — same answer
+  query_group_units                 device units that grouped in HBM
+                                    (group_agg_device: group ids from the
+                                    key chunks' resident dictionary
+                                    indices, every group and aggregate of
+                                    the unit in one program);
+                                    query_group_rows is the rows those
+                                    kernels reduced (a unit's rows, once)
+  query_group_declined              grouped units outside the kernel's
+                                    envelope, the host's — same answer;
+                                    query_group_decline_reasons_total{reason=}
+                                    says why: "key_not_dictionary" (a
+                                    PLAIN, mixed or numeric key chunk),
+                                    "key_nulls", "too_many_groups" (the key
+                                    dictionaries span more than
+                                    GROUP_SLOTS = 64 slots), "key_shape",
+                                    "input_shape" (nulls or an unsigned
+                                    domain in a reduction input)
   query_mixed_chunks                device units whose aggregate input
                                     was a mixed dictionary + PLAIN chunk,
                                     merged in HBM by

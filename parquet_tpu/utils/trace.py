@@ -38,7 +38,9 @@ holds them (the catalogue; PERF.md section 3 says which metric reads which):
   pqt-serve_*                  serve.aggregate / serve.execute > serve.open_reader
                                (the close is a span of the same name),
                                query.decode > [the read above], query.mask,
-                               query.aggregate, query.sync
+                               query.aggregate > query.group_keys (a grouped
+                               unit's dictionaries to key values and its
+                               slot -> key table), query.sync
 
 The waits are time WAITED beside the producers' time busy: where an idle gap
 of the device falls under a wait and under no producer on any thread, it is

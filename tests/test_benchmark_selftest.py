@@ -7,8 +7,10 @@ ran them: benchmark/selftest/test_corpora.py (a corpus is found by name),
 benchmark/selftest/test_faults_packed.py (`correct` is false exactly when
 something is planted in the packed cell), and the TPC-H table's
 test_corpora_tpch.py (dbgen's laws hold) and test_faults_tpch.py (the Q6
-cell's faults), and test_xsweep.py (the one-pass reduction of the device's
-idle gaps, PR 37). They are loaded by path and their tests, with the fixtures
+cell's faults), test_xsweep.py (the one-pass reduction of the device's
+idle gaps, PR 37), and the Q1 deployment's test_corpora_tpch_q1.py (the same
+bytes as tpch_lineitem; reference = second witness) and test_faults_tpch_q1.py
+(the grouped cell's faults; PR 39). They are loaded by path and their tests, with the fixtures
 they use, collected here under their own names.
 """
 
@@ -32,7 +34,8 @@ def _load(path: Path):
 
 
 FIXTURES = ("tree", "columns")  # test_corpora.py's scratch copy of the benchmark; the TPC-H file's arrays
-for _name in ("test_corpora", "test_faults_packed", "test_corpora_tpch", "test_faults_tpch", "test_xsweep"):
+for _name in ("test_corpora", "test_faults_packed", "test_corpora_tpch", "test_faults_tpch", "test_xsweep",
+              "test_corpora_tpch_q1", "test_faults_tpch_q1"):
     _module = _load(BENCH / "selftest" / f"{_name}.py")
     globals().update({k: v for k, v in vars(_module).items() if k.startswith("test_") or k in FIXTURES})
 

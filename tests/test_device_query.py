@@ -296,7 +296,9 @@ AGG_BODIES = [
     # OUTSIDE the envelope: typed per-unit fallback to the host path
     {"aggregates": [{"op": "sum", "column": "score"}]},  # float domain
     {"aggregates": [{"op": "sum", "column": "dec"}]},  # decimal domain
-    {"aggregates": ["count"], "group_by": ["name"]},  # hash groupby
+    # grouped: the device's since PR 39 where the key chunk is a dictionary
+    # (tests/test_query_group.py), the host's hash groupby otherwise
+    {"aggregates": ["count"], "group_by": ["name"]},
 ]
 
 
@@ -337,7 +339,7 @@ class TestDeviceAggregates:
         snap = metrics.snapshot()
         for body in (
             {"aggregates": [{"op": "sum", "column": "id"}]},  # device
-            {"aggregates": ["count"], "group_by": ["name"]},  # fallback
+            {"aggregates": [{"op": "sum", "column": "score"}]},  # fallback: a float sum
         ):
             ticket, _ = svc.query(self._body(path, body), "test")
             ticket.release()

@@ -320,6 +320,21 @@ class TestCoalesce:
             assert bytes(out[(0, 8)]) == data[:8]
             assert "io_read_calls_total" not in metrics.delta(s1)  # all cached
 
+    def test_a_range_that_is_its_whole_run_is_cached_without_a_copy(self, blob):
+        """A chunk fetched alone is one run: the cache keeps the buffer the
+        source read. Members cut from a longer run are copied, so that a
+        cached block never pins the run it came from."""
+        p, data = blob
+        cache = BlockCache(1 << 20)
+        with LocalFileSource(p) as src:
+            alone = fetch_ranges(src, [(32, 16)], cache=cache, gap=0)[(32, 16)]
+            assert cache.get(src.source_id, 32, 16) is alone.obj
+            run = fetch_ranges(src, [(0, 8), (16, 8)], cache=cache, gap=64)
+            for key in ((0, 8), (16, 8)):
+                held = cache.get(src.source_id, *key)
+                assert held == data[key[0] : key[0] + 8] and len(held) == 8
+                assert held is not run[key].obj
+
 
 class TestPlanRanges:
     def test_full_vs_projected(self, eight_col):

@@ -822,6 +822,45 @@ class TestRoutedTraceMerge:
 # -- lane audit ----------------------------------------------------------------
 
 
+class TestRoutedAvg:
+    def test_a_routed_avg_or_decimal_sum_is_declined_typed_not_merged(self, tmp_path):
+        """Scatter merges per-unit documents: a rendered avg cannot be merged
+        (the average of the units' averages is another number) and a decimal
+        sum comes back as text the router has no type for. Both are typed
+        501 not_routable, as routed append is; a plain sum still scatters,
+        and one replica answers avg itself (PR 39)."""
+        from decimal import Decimal
+
+        from parquet_tpu.serve.mesh import MeshConfig, MeshRouter
+
+        v = [1, 1, 1, 1, 1, 2, 2, 6]
+        pq.write_table(
+            pa.table({"v": pa.array(v, pa.int64()),
+                      "m": pa.array([Decimal(x) / 100 for x in v], pa.decimal128(9, 2))}),
+            str(tmp_path / "t.parquet"), row_group_size=5, store_decimal_as_integer=True,
+        )
+        backends = [ScanServer(ServeConfig(port=0, root=str(tmp_path))).start_background() for _ in range(2)]
+        router = MeshRouter(MeshConfig(port=0, replicas=tuple(b.url for b in backends))).start_background()
+        try:
+            def ask(server, aggregates):
+                status, _, body = _request(server, "POST", "/v1/query", {"paths": "t.parquet", "aggregates": aggregates})
+                return status, json.loads(body)
+
+            status, doc = ask(router, ["count", "sum(v)"])
+            assert status == 200 and doc["result"] == {"count": 8, "sum(v)": 15} and doc["units"] == 2
+            for aggregates, named in ((["avg(v)"], "avg(v)"), (["count", "avg(m)"], "avg(m)"),
+                                      (["sum(m*m)"], "sum(m*m)"), (["sum(m)"], "sum(m)")):
+                status, doc = ask(router, aggregates)
+                assert (status, doc["error"]["code"]) == (501, "not_routable"), doc
+                assert named in doc["error"]["message"]
+            status, doc = ask(backends[0], ["avg(v)", "avg(m)"])
+            assert status == 200 and doc["result"] == {"avg(v)": "1.8750", "avg(m)": "0.018750"}
+        finally:
+            router.close()
+            for b in backends:
+                b.close()
+
+
 class TestLaneCoverage:
     def test_every_pool_prefix_attributes_to_a_named_lane(self):
         """Grep the package for every pqt-* thread/pool name and pin that

@@ -200,6 +200,12 @@ class TestTenantAccounting:
         per-tenant CPU/byte attributions sum to the process totals
         within tolerance, and equal work bills equally."""
         snap0 = metrics.snapshot()
+        # the registry is the process's: a suite that ran in this worker
+        # before may have billed a tenant of the same name
+        billed0 = {
+            name: metrics.get("serve_tenant_decoded_bytes_total", tenant=name)
+            for name in ("alice", "bob", "carol")
+        }
         cpu0 = time.process_time()
         per_tenant = 3
         errors = []
@@ -278,6 +284,7 @@ class TestTenantAccounting:
             )
             assert (
                 metrics.get("serve_tenant_decoded_bytes_total", tenant=name)
+                - billed0[name]
                 == rows[name]["decoded_bytes"]
             )
 

@@ -433,3 +433,144 @@ class TestExecutor:
             )
         assert ei.value.status == 504
         assert time.monotonic() - t0 < WATCHDOG_S
+
+    def test_query_holds_a_pool_of_finished_partials_ahead(self, corpus, monkeypatch):
+        """Results come back in plan order: a query may hold as many finished
+        partials again as the pool has workers, so a worker that finished
+        before the oldest unit is handed its next one. The answer is the same."""
+        from parquet_tpu.serve import executor
+        from parquet_tpu.serve.protocol import ScanRequest
+        from parquet_tpu.serve.session import ScanSession
+
+        q = _query([str(corpus / "*.parquet")], aggregates=[["sum", "id"], "count"])
+        session = ScanSession()
+        planned = session.plan(
+            ScanRequest(
+                paths=q.paths, columns=["id"], filters=None, limit=None,
+                format="jsonl", shard=None, timeout_ms=None,
+            )
+        )
+        assert len(planned.units) == 8
+        seen = []
+        real = executor._pipelined
+
+        def spy(units, run_one, window, check, ahead=0):
+            seen.append((window, ahead))
+            return real(units, run_one, window, check, ahead)
+
+        monkeypatch.setattr(executor, "_pipelined", spy)
+        monkeypatch.setattr(executor, "pool_size", lambda: 3)
+        body = executor.execute_query(planned, q, session)
+        assert seen == [(3, 3)]
+        assert body == run_local_query([str(corpus / "*.parquet")], q)
+
+    @pytest.mark.parametrize("ahead,reached", [(0, 2), (2, 4)])
+    def test_pipeline_refills_behind_a_slow_oldest_unit(self, ahead, reached):
+        """While the oldest unit runs, `ahead` finished results may wait for
+        it: the units behind it go on running, never more than `window` at
+        once (nothing is parked in the pool's queue), and results still come
+        back in plan order."""
+        from parquet_tpu.serve.executor import _Check, _pipelined
+
+        release, lock = threading.Event(), threading.Lock()
+        started, running, most = [], [0], [0]
+
+        def run_one(u):
+            with lock:
+                started.append(u)
+                running[0] += 1
+                most[0] = max(most[0], running[0])
+            if u == 0:
+                release.wait(WATCHDOG_S)
+            with lock:
+                running[0] -= 1
+            return u
+
+        gen = _pipelined(list(range(8)), run_one, 2, _Check(None), ahead)
+        got = []
+        t = threading.Thread(target=lambda: got.extend(gen))
+        t.start()
+        deadline = time.monotonic() + WATCHDOG_S
+        while len(started) < reached and time.monotonic() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.1)  # anything past the bound would have started by now
+        with lock:
+            assert sorted(started) == list(range(reached)) and got == []
+        release.set()
+        t.join(WATCHDOG_S)
+        assert got == list(range(8)) and most[0] <= 2
+
+
+# -- the merge: filed a unit, folded once ----------------------------------------
+
+_D = __import__("decimal").Decimal
+_DEC = pa.decimal128(38, 2)
+MERGE_CASES = [
+    ("count", [3, 0, 5, 7], None),
+    ("sum", [_D("1.25"), None, _D("-7.50"), _D("99999999999999999999.99")], _DEC),
+    ("sum", [None, None], _DEC),
+    ("sum", [None, 4, None], pa.int64()),
+    ("sum", [2**62, 2**62 - 1, -5], pa.int64()),
+    ("min", [_D("3.00"), _D("-1.00"), None, _D("2.00")], _DEC),
+    ("max", [7, None, 9, -3], pa.int64()),
+    ("max", [1.5, float("nan"), None, 2.5], pa.float64()),
+    ("avg", [(_D("10.00"), 4), None, (_D("0.50"), 1), (_D("-3.25"), 2)], _DEC),
+    ("avg", [None, None, None], _DEC),
+    ("avg", [(7, 2), (9, 3)], pa.int64()),
+]
+
+
+def _pairwise(op, vals, typ):
+    """The fold a pair at a time, through the Arrow kernel each time."""
+    acc = vals[0]
+    for v in vals[1:]:
+        if op == "count":
+            acc = acc + v
+        elif acc is None or v is None:
+            acc = v if acc is None else acc
+        elif op == "avg":
+            acc = (pc.sum(pa.array([acc[0], v[0]], type=typ)).as_py(), acc[1] + v[1])
+        else:
+            acc = getattr(pc, op)(pa.array([acc, v], type=typ)).as_py()
+    return acc
+
+
+@pytest.mark.parametrize("op,vals,typ", MERGE_CASES, ids=[f"{c[0]}-{i}" for i, c in enumerate(MERGE_CASES)])
+def test_one_fold_of_all_partials_is_the_pairwise_fold(op, vals, typ):
+    from parquet_tpu.serve.aggregate import _merge_values
+
+    got, want = _merge_values(op, list(vals), typ), _pairwise(op, list(vals), typ)
+    assert repr(got) == repr(want)  # repr: nan equals nan, 1 is not 1.0
+
+
+def test_absorb_files_partials_and_folds_them_once(monkeypatch):
+    """absorb makes no Arrow call (each hands the GIL away, on the thread
+    that feeds the unit pool); a key's partials fold when it holds _FOLD_AT
+    of them and when the state is read, so the state stays bounded."""
+    from parquet_tpu.serve import aggregate
+
+    q = _query(["x.parquet"], aggregates=[["sum", "amount"], ["avg", "amount"], ["min", "id"], "count"],
+               group_by=["name"])
+    calls = []
+    real = aggregate._merge_values
+    monkeypatch.setattr(aggregate, "_merge_values", lambda op, vals, typ: calls.append((op, len(vals))) or real(op, vals, typ))
+    state = aggregate.QueryState(q)
+    types = [_DEC, _DEC, pa.int64(), None]
+    units = aggregate._FOLD_AT + 10
+    for u in range(units):
+        groups = {("a",): [_D(u), (_D(u), 2), u, 3], ("b",): [None, None, -u, 1]}
+        if u == 5:
+            del groups[("b",)]  # a group absent from one unit
+        state.absorb(((groups, types), 10, 4))
+        assert all(len(held) < aggregate._FOLD_AT for cur in state._pending.values() for held in cur)
+    # each key folded once so far, all its partials in one call an aggregate
+    assert {n for _, n in calls} == {aggregate._FOLD_AT} and calls.count(("count", aggregate._FOLD_AT)) == 2
+    total = sum(range(units))
+    assert state.groups == {
+        ("a",): [_D(total), (_D(total), 2 * units), 0, 3 * units],
+        ("b",): [None, None, -(units - 1), units - 1],
+    }
+    assert (state.rows_scanned, state.rows_matched) == (10 * units, 4 * units)
+    body = aggregate.result_dict(q, state, units=units)
+    assert body["group_count"] == 2 and [g["key"] for g in body["groups"]] == [["a"], ["b"]]
+    assert body["groups"][0]["aggregates"]["avg(amount)"] == aggregate.render_avg((_D(total), 2 * units), _DEC)

@@ -670,6 +670,10 @@ def _kernel_cases(pad=64):
             d.expr_agg_device, (i32(4096).astype(jnp.int64), i32(4096)), mask,
             ("*", ("col", 0), ("-", ("lit", 100), ("col", 1))), "sum")),
         ("masked_agg", (), lambda: _hlo(d.masked_agg_device, i32(4096).astype(jnp.int64), mask, "sum")),
+        ("group_agg", ("group_id", "reduce"), lambda: _hlo(
+            d.group_agg_device, (i32(4096) % 3, i32(4096) % 2), jnp.asarray([6, 2, 1], jnp.int32),
+            (i32(4096).astype(jnp.int64), i32(4096)), mask,
+            ((("col", 0), ("sum", "min")), (("*", ("col", 0), ("-", ("lit", 100), ("col", 1))), ("sum",))))),
         ("mask_take", (), lambda: _hlo(d.mask_take_device, i32(4096), mask, out_pad=2048)),
         ("merge_mixed_numeric", (), lambda: _hlo(
             d.merge_mixed_numeric_device, i32(2048), i32(16).astype(jnp.int64),
@@ -695,7 +699,7 @@ def _kernel_cases(pad=64):
 
 _KERNEL_IDS = [
     "hybrid_expand", "delta_decode-64", "delta_decode-32", "dict_gather", "prefix_sum",
-    "query_mask-predicate", "query_mask-lift", "expr_agg", "masked_agg", "mask_take", "merge_mixed_numeric", "merge_mixed_bytes",
+    "query_mask-predicate", "query_mask-lift", "expr_agg", "masked_agg", "group_agg", "mask_take", "merge_mixed_numeric", "merge_mixed_bytes",
     "bss_transpose", "record_starts", "list_layout", "list_contains_mask", "bitpack_encode",
     "rle_hybrid_encode", "dict_indices", "delta_block_encode", "plain_bytearray_encode",
 ]
