@@ -54,7 +54,7 @@ FLAT = ("trip_id", "vendor", "ts", "passenger_count")
 KERNEL_LEGS = {
     "expand_hybrid_device": "decode",  # vendor / passenger_count / stops
     "delta_packed_decode_device": "decode",  # ts, and trip_id's repack
-    "dict_gather_device": "decode",  # passenger_count's numeric dictionary
+    "dict_gather_device": "decode",  # passenger_count's numeric dictionary; every tier in the dict_lookup check
     "double_narrow_device": "decode",  # doubles="float32": fare, and the mixed file
     "predicate_mask_device": "decode",  # filter_rows=True
     "dict_verdict_device": "daemon",  # /v1/query: a predicate on vendor, a byte-array dictionary
@@ -409,6 +409,54 @@ def leg_decode(args) -> dict:
     out["doubles"] = {"rows": int(len(want["fare"]) + 2 * n_d), "forms": forms}
     say(f"doubles: fare (PLAIN), wild (PLAIN, adversarial bit patterns) and mixed (dictionary -> PLAIN) "
         f"equal pyarrow + numpy bit for bit as uint64 bits and as float32; counters {forms}")
+
+    # -- the dictionary lookup, every tier at 2^20 indices ----------------------
+    # dict_gather_device against numpy, bit for bit: tables below, inside and
+    # above the dense band at both entry widths (indices past the table and
+    # below it included), then a TLC-shaped file whose chunks say through the
+    # two counters which formulation each took
+    n_l = 1 << 20
+    top = dops.DICT_DENSE_MAX
+    cases = [(np.int32, 6), (np.int64, 64), (np.int64, 265), (np.uint64, 265), (np.int32, 2526),
+             (np.uint32, 4096), (np.int64, 4096), (np.int32, 1 << 16), (np.uint32, top), (np.uint64, top),
+             (np.int32, top + 1), (np.int64, top + 1)]
+    tiers = {}
+    for dt, size in cases:
+        width = np.dtype(dt).itemsize
+        table = rng.integers(0, 1 << (8 * width), size, dtype=f"u{width}").view(dt)
+        idx = rng.integers(0, size, n_l).astype(np.int64)
+        idx[:4] = [size, 2**31 - 1, -1, -(2**31)]
+        idx = idx.astype(np.int32)
+        got = np.asarray(dops.dict_gather_device(jnp.asarray(table), jnp.asarray(idx)))
+        tier = dops.dict_lookup_tier(size, np.dtype(dt))
+        check(got.dtype == table.dtype and np.array_equal(got, table[np.clip(idx, 0, size - 1)]),
+              f"dict_gather_device ({tier}): {np.dtype(dt).name}[{size}] differs from numpy")
+        tiers[f"{np.dtype(dt).name}[{size}]"] = tier
+    check(set(tiers.values()) == {"dense", "gather"}, f"dictionary lookup tiers {tiers}")
+    n_t = min(group_rows, 1 << 18)
+    lpath = Path(args.corpus) / "lookup.parquet"
+    zone = rng.integers(1, 266, n_t).astype(np.int64) * 1_000_003
+    zone[:265] = np.arange(1, 266) * 1_000_003
+    tip = rng.integers(0, 3000, n_t) / 100.0  # ~3,000 distinct amounts: a 12-bit index stream
+    pq.write_table(
+        pa.table({"zone": pa.array(zone), "rate": pa.array(rng.integers(1, 9, n_t).astype(np.int64)),
+                  "tip": pa.array(tip, mask=rng.random(n_t) < 0.04)}),
+        lpath, compression="snappy", row_group_size=n_t, use_dictionary=True,
+    )
+    with decode_trace() as tr:
+        with FileReader(str(lpath)) as r:
+            (lg,) = r.read_row_groups_device(doubles="float32")
+    lookups = counters_of(tr, "dict_lookup_")
+    with np.errstate(over="ignore"):
+        tip32 = tip[np.asarray(lg[("tip",)].def_levels) == 1].astype(np.float32)
+    check(np.array_equal(np.asarray(lg[("zone",)].values), zone)
+          and np.array_equal(np.asarray(lg[("tip",)].values).view(np.uint32), tip32.view(np.uint32)),
+          "lookup.parquet differs from pyarrow + numpy")
+    check(lookups == {"dict_lookup_dense_chunks": 2, "dict_lookup_gather_chunks": 1}
+          and not counters_of(tr, "host_decoded_pages"),
+          f"dictionary lookup counters {lookups}")
+    out["dict_lookup"] = {"indices": n_l, "tiers": tiers, **lookups}
+    say(f"dict_lookup: every tier equals numpy bit for bit at {n_l} indices ({tiers}); counters {lookups}")
 
     # -- batches into a jitted step --------------------------------------------
     @jax.jit

@@ -51,14 +51,24 @@ the packed words: two words a value through PR 30, 16.5 of the 17-18 ms a
 stream cost per 2^20 values at any width and run count; one word a value
 after an alignment by fixed shifts through PR 37: 8.1-8.4 ms a stream, 7.5
 of them the gather (PERF.md section 6, PR 31). Since PR 38 it reads the
-hybrid frame and holds no gather, no scatter and no scan: 5-12 us a stream,
-and the dictionary gather is what the device still does (PERF.md section 5,
-PR 38). The delta kernel read the wire through PR 33, two uint64 words a
+hybrid frame and holds no gather, no scatter and no scan: 5-12 us a stream.
+The delta kernel read the wire through PR 33, two uint64 words a
 value at its miniblock's width: four 32-bit passes, 48.9 of its 50.0 ms a
 stream (the fourth word table was read from HBM once the wire passed 2^19
 words: a 22 ms pass). Since PR 34 it reads the delta frame and holds no
 gather over positions: under 1 ms a stream (PERF.md
-section 6, PR 34).
+section 6, PR 34). What the device still did after PR 38 was the dictionary
+gather itself, 67-97 % of its busy time in every cell: since PR 40 a table
+of 65 to 131,072 entries is not indexed either but compared with — a one-hot
+of the index's low seven bits contracted on the MXU with the table's byte
+planes, a select over the high bits, exact because a byte and a 0/1 are
+exact in bfloat16 (dict_gather_device, _dense_lookup: 0.33 ms per 2^20
+indices from 265 int64 entries, dispatch included, where the gather took
+17.0; PERF.md section 6, PR 40). The old objection to Pallas above was
+Mosaic's gather rule and does not touch that kernel; it stays an XLA program
+because XLA fuses its one-hot, product and select into one kernel by itself,
+and because a CPU run of the tests then executes the very program the chip
+does.
 """
 
 from __future__ import annotations
@@ -674,11 +684,119 @@ def bss_transpose_device(streams: jnp.ndarray, num_values: int) -> jnp.ndarray:
     return _bss_transpose_padded(streams)[:num_values]
 
 
+# -- the dictionary lookup -----------------------------------------------------
+#
+# XLA's table[idx] on a v5e walks its indices one by one: 9.05-10.3 ms per
+# 2^20 from 128 or more 32-bit entries, 15.1-17.0 from 64-bit entries,
+# whatever the table's length — and 0.22 / 0.25 from 64 entries or fewer,
+# which it keeps in registers. A table short enough to be COMPARED WITH is not
+# indexed at all: dict_gather_device below splits it into byte planes and
+# contracts them with a one-hot of the index on the MXU. The band is where
+# that won PR 40's micro-run (examples/micro_dict_lookup.py; the table is in
+# PERF.md section 6) by 1.5 x or more: x 25-50 up to 4,096 entries, x 9-10 at
+# 16,384, x 2.5-2.9 at 65,536, x 1.5-1.85 at 131,072; past that the gather.
+
+DICT_DENSE_MIN = 65
+DICT_DENSE_MAX = 131072
+_DENSE_LO = 128  # entries a one-hot spans: one MXU tile of contraction
+_DENSE_BLOCK = 32768  # rows a loop step: the one-hot never exists at full length
+
+
+def dict_lookup_tier(length: int, dtype) -> str:
+    """"dense" or "gather": which formulation dict_gather_device runs for a
+    table of `length` entries of `dtype` — a pure function of the two, both
+    static under jit, and the one rule: no argument, environment variable or
+    backend test picks a tier, and pipeline's dict_lookup_*_chunks counters
+    ask here. Integer and float32 entries of 4 or 8 bytes inside the band
+    above are dense; a float64 table stays a gather (a TPU has no f64 <-> u64
+    bitcast: pipeline.DeviceDoubleError; the device road ships DOUBLE
+    dictionaries as uint patterns, which are dense)."""
+    dt = np.dtype(dtype)
+    splits = (dt.kind in "iu" and dt.itemsize in (4, 8)) or dt == np.float32
+    return "dense" if splits and DICT_DENSE_MIN <= length <= DICT_DENSE_MAX else "gather"
+
+
+def _dense_lookup(dictionary: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """dictionary[idx] for in-range idx without a gather: index = hi * 128 +
+    lo; a one-hot of lo [128, block] is contracted with the table's byte
+    planes W[hi * P + p, lo] on the MXU — every hi's candidate byte p for
+    every row at once — and a compare/select over hi keeps one. A byte and a
+    0/1 are exact in bfloat16 and one product a sum is non-zero, so the f32
+    accumulator holds the byte exactly; shifts and ORs re-assemble the entry
+    bit for bit (a 64-bit entry as two uint32 halves, as _spread does). Rows
+    run along the lanes throughout (the table's few planes along the
+    sublanes), in blocks of _DENSE_BLOCK under one lax.map: XLA fuses a
+    block's one-hot, product and select into one kernel whose output is
+    [8, block], so nothing of length n x table exists anywhere (the compiled
+    program's temp size is 0 at every table length). The plane rows go in
+    groups of 8, the f32 sublane tile — two hi's a group at 32-bit entries —
+    because [H * 4, block] -> [H, 4, block] is a physical relayout on a TPU
+    that keeps XLA from fusing the select (x 2.8-6 slower in the micro-run).
+    The MXU does n * ceil(H * P / 128) row passes; per 2^20 indices on a v5e,
+    the ~0.2 ms of a jitted call's dispatch included (PERF.md section 6,
+    PR 40): 0.26 / 0.33 ms from 265 32- / 64-bit entries, 0.35 / 0.60 from
+    4,096, 0.89 / 1.71 from 16,384, 3.11 / 6.08 from 65,536."""
+    dtype = dictionary.dtype
+    length, n = dictionary.shape[0], idx.shape[0]
+    P = dtype.itemsize
+    with jax.named_scope("planes"):
+        if P == 8:
+            u = jax.lax.bitcast_convert_type(dictionary, jnp.uint64)
+            words = [u.astype(jnp.uint32), (u >> 32).astype(jnp.uint32)]
+        else:  # int32, uint32, or float32 as its pattern
+            words = [jax.lax.bitcast_convert_type(dictionary, jnp.uint32)]
+        H = -(-length // _DENSE_LO)
+        H += -H % (8 // P)  # whole groups of 8 plane rows (the f32 sublane tile)
+        planes = jnp.stack([(w >> (8 * k)) & jnp.uint32(0xFF) for w in words for k in range(4)])
+        planes = jnp.pad(planes, ((0, 0), (0, H * _DENSE_LO - length)))  # [P, H * LO]
+        W = planes.reshape(P, H, _DENSE_LO).transpose(1, 0, 2).reshape(H * P, _DENSE_LO).astype(jnp.bfloat16)
+        owner = (jnp.arange(H * P, dtype=jnp.int32) // P).reshape(-1, 8, 1)  # row -> its hi
+
+    def block(i):
+        with jax.named_scope("onehot"):
+            lo, hi = i & (_DENSE_LO - 1), i >> (_DENSE_LO.bit_length() - 1)
+            onehot = (jnp.arange(_DENSE_LO, dtype=jnp.int32)[:, None] == lo[None, :]).astype(jnp.bfloat16)
+        with jax.named_scope("contract"):
+            cand = jnp.dot(W, onehot, preferred_element_type=jnp.float32)  # [H * P, block]
+        with jax.named_scope("select"):
+            cand = cand.reshape(-1, 8, cand.shape[1])
+            picked = jnp.sum(jnp.where(owner == hi[None, None, :], cand, jnp.float32(0)), axis=0)  # [8, block]
+            if P == 4:  # a group of 8 rows holds two hi's planes: one of the two is zero
+                picked = picked[:4] + picked[4:]
+        with jax.named_scope("assemble"):
+            b = picked.astype(jnp.uint32)
+            out = [b[4 * w] | (b[4 * w + 1] << 8) | (b[4 * w + 2] << 16) | (b[4 * w + 3] << 24) for w in range(P // 4)]
+            if P == 8:
+                return (out[1].astype(jnp.uint64) << 32) | out[0].astype(jnp.uint64)
+            return out[0]
+
+    size = min(_DENSE_BLOCK, -(-n // 128) * 128)
+    blocks = -(-n // size)
+    idx = jnp.pad(idx, (0, blocks * size - n))  # a chunk's count is data: the last block's tail reads entry 0
+    out = jax.lax.map(block, idx.reshape(blocks, size)).reshape(-1)[:n]
+    with jax.named_scope("assemble"):
+        return jax.lax.bitcast_convert_type(out, dtype)
+
+
 @jax.jit
 @jax.named_scope("pqt.dict_gather")
 def dict_gather_device(dictionary: jnp.ndarray, indices: jnp.ndarray) -> jnp.ndarray:
-    """Dictionary expansion: one gather (reference: type_dict.go lookup loop)."""
-    return dictionary[indices]
+    """Dictionary expansion (reference: type_dict.go lookup loop):
+    np.asarray(dictionary)[np.clip(indices, 0, len(dictionary) - 1)], bit for
+    bit, for every table length. How it is looked up is decided from what the
+    function can see — dict_lookup_tier(dictionary.shape[0], dictionary.dtype),
+    static under jit, one program a (length, dtype, n) as before: a table of
+    65 to 131,072 entries is compared with and contracted (_dense_lookup:
+    inner scopes planes, onehot, contract, select, assemble; no gather); 64
+    entries or fewer, which XLA already serves in 0.22-0.25 ms per 2^20
+    indices, anything longer and a float64 table stay XLA's gather (inner
+    scope gather). An index is clipped first, so one below 0 reads entry 0
+    (XLA's gather alone would wrap it) and one past the table the last."""
+    idx = jnp.clip(indices, 0, dictionary.shape[0] - 1).astype(jnp.int32)
+    if dict_lookup_tier(dictionary.shape[0], dictionary.dtype) == "dense":
+        return _dense_lookup(dictionary, idx)
+    with jax.named_scope("gather"):
+        return dictionary[idx]
 
 
 @jax.jit

@@ -70,6 +70,7 @@ from .device_ops import (
     delta_packed_decode_device,
     dict_gather_device,
     dict_indices_device,
+    dict_lookup_tier,
     double_narrow_device,
     expand_hybrid_device,
     pack_delta_upload,
@@ -844,8 +845,7 @@ class _ChunkPlan:
                 )
                 out.dict_offsets = jnp.asarray(self.dictionary.offsets)
             else:
-                vals = dict_gather_device(self.dict_dev, idx)
-                out.values = self._typed(vals)
+                out.values = self._typed(self._lookup(idx))
             return out
 
         if kinds <= {"delta", "empty"} and self.dev_delta:
@@ -951,7 +951,7 @@ class _ChunkPlan:
         kinds = {k for _, _, _, k, _ in self.page_infos if k != "empty"}
         count = self.list_elements
         if kinds == {"dict"} and len(self.dev_hybrid) == 1 and self.dict_dev is not None:
-            return self._typed(dict_gather_device(self.dict_dev, self._dev_indices())), count
+            return self._typed(self._lookup(self._dev_indices())), count
         if kinds == {"delta"} and len(self.dev_delta) == 1:
             return self.dev_delta[0], count
         if kinds == {"values"} and self.dev_plain is not None:
@@ -1001,6 +1001,16 @@ class _ChunkPlan:
         if vals.dtype == jnp.uint64:
             vals = self._narrow(vals)
         return jax.lax.bitcast_convert_type(vals, jnp.float32)
+
+    def _lookup(self, idx: jnp.ndarray) -> jnp.ndarray:
+        """The chunk's numeric dictionary looked up at `idx`, counted by the
+        formulation dict_gather_device takes for this table (its own static
+        rule, dict_lookup_tier): dict_lookup_dense_chunks — compared with and
+        contracted, no gather — or dict_lookup_gather_chunks."""
+        name = f"dict_lookup_{dict_lookup_tier(self.dict_dev.shape[0], self.dict_dev.dtype)}_chunks"
+        _metrics.event(name)
+        _trace.count(name)
+        return dict_gather_device(self.dict_dev, idx)
 
     def _dev_indices(self) -> jnp.ndarray:
         """All dispatched dict-index batches as one int32 device array."""

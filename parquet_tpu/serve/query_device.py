@@ -176,7 +176,8 @@ def _reduced_type(op: str, typ):
 
 def _dense_values(dc, leaf):
     """The chunk's dense values as a resident jax array (dictionary-encoded
-    numeric chunks expand with one small upload + gather)."""
+    numeric chunks expand with one small upload + dict_gather_device's
+    lookup: dense or XLA's gather by the table's length and dtype)."""
     import jax.numpy as jnp
 
     if dc.values is not None:

@@ -281,6 +281,18 @@ Key families (all under the `parquet_tpu_` prefix in exposition):
                                     compact dictionary gather, each run
                                     of adjacent pages of one kind placed
                                     as a contiguous range)
+  events_total{event="dict_lookup_dense_chunks"},
+  events_total{event="dict_lookup_gather_chunks"}
+                                    one per numeric dictionary chunk
+                                    expanded in HBM on any device read, by
+                                    the formulation dict_gather_device took
+                                    for its table (device_ops.
+                                    dict_lookup_tier: length and dtype
+                                    alone): "dense" = compared with and
+                                    contracted over byte planes, no gather
+                                    (65 to 131,072 entries); "gather" =
+                                    XLA's table[idx] (64 entries or fewer,
+                                    anything longer, a float64 table)
   query_device_unavailable_total    units that wanted the device path but
                                     jax was not importable (device=
                                     misconfiguration made visible)

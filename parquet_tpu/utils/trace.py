@@ -42,6 +42,13 @@ holds them (the catalogue; PERF.md section 3 says which metric reads which):
                                unit's dictionaries to key values and its
                                slot -> key table), query.sync
 
+Counters of a device read that no stage carries (count() / bump(); PERF.md
+section 3's audit says what reads each): dict_lookup_dense_chunks /
+dict_lookup_gather_chunks — one a numeric dictionary chunk expanded in HBM,
+by the formulation its table's length and dtype pick
+(device_ops.dict_lookup_tier) — beside hybrid_values_framed,
+delta_values_framed, mixed_chunks_by_segments, padded_delivery_exact_chunks.
+
 The waits are time WAITED beside the producers' time busy: where an idle gap
 of the device falls under a wait and under no producer on any thread, it is
 the hop between two threads (benchmark/lib/xsweep.py).

@@ -639,6 +639,10 @@ def _hlo(fn, *args, **kw) -> str:
     return fn.lower(*args, **kw).compile().as_text()
 
 
+_LOOKUP_N = 1 << 17  # indices of the three dict_gather cases below: four blocks of the dense tier's loop
+_DENSE_SCOPES = ("planes", "onehot", "contract", "select", "assemble")
+
+
 def _kernel_cases(pad=64):
     """(kernel, scopes its compiled HLO must carry, lowering thunk): every
     jitted kernel of device_ops at a small shape; `pad` is the length of the
@@ -662,7 +666,15 @@ def _kernel_cases(pad=64):
         ("delta_decode", inner_delta, lambda: _hlo(
             d.delta_packed_decode_device, u32(3 * pad + 4096 * 16 // 32),
             nbits=32, width=16, num_values=4096, p_pad=pad)),
-        ("dict_gather", (), lambda: _hlo(d.dict_gather_device, i32(16).astype(jnp.int64), i32(4096) % 16)),
+        # one case a tier of the lookup (device_ops.dict_lookup_tier): XLA's
+        # gather, and the dense formulation at a table of one-level size (265
+        # int64 entries: 3 groups of 128) and of two-level size (4,096 int32)
+        ("dict_gather", ("gather",), lambda: _hlo(
+            d.dict_gather_device, i32(16).astype(jnp.int64), i32(_LOOKUP_N) % 16)),
+        ("dict_gather", _DENSE_SCOPES, lambda: _hlo(
+            d.dict_gather_device, i32(265).astype(jnp.int64), i32(_LOOKUP_N) % 265)),
+        ("dict_gather", _DENSE_SCOPES, lambda: _hlo(
+            d.dict_gather_device, i32(4096), i32(_LOOKUP_N) % 4096)),
         ("prefix_sum", (), lambda: _hlo(d.prefix_sum, i32(4096))),
         ("query_mask", ("predicate",), lambda: _hlo(d.predicate_mask_device, i32(4096), "<", 5, 5, True)),
         ("query_mask", ("lift",), lambda: _hlo(d.dict_verdict_device, mask[:16], i32(4096) % 16)),
@@ -698,7 +710,8 @@ def _kernel_cases(pad=64):
 
 
 _KERNEL_IDS = [
-    "hybrid_expand", "delta_decode-64", "delta_decode-32", "dict_gather", "prefix_sum",
+    "hybrid_expand", "delta_decode-64", "delta_decode-32",
+    "dict_gather-gather", "dict_gather-dense1", "dict_gather-dense2", "prefix_sum",
     "query_mask-predicate", "query_mask-lift", "expr_agg", "masked_agg", "group_agg", "mask_take", "merge_mixed_numeric", "merge_mixed_bytes",
     "bss_transpose", "record_starts", "list_layout", "list_contains_mask", "bitpack_encode",
     "rle_hybrid_encode", "dict_indices", "delta_block_encode", "plain_bytearray_encode",
@@ -718,8 +731,33 @@ class TestKernelScopes:
         op_names = set(re.findall(r'op_name="([^"]*)"', thunk()))
         scoped = {n for n in op_names if f"/pqt.{name}/" in f"{n}/"}
         assert scoped, (name, sorted(op_names)[:8])
-        for part in inner:
-            assert any(f"/pqt.{name}/{part}/" in f"{n}/" for n in scoped), (name, part)
+        for part in inner:  # under the kernel's scope, a loop's own path components allowed between
+            assert any(re.search(rf"/pqt\.{name}/(.+/)?{part}/", f"{n}/") for n in scoped), (name, part)
+
+    @pytest.mark.parametrize("k", range(3, 6), ids=_KERNEL_IDS[3:6])
+    def test_a_dictionary_is_gathered_or_compared_with_never_both(self, k):
+        """The dense tiers compare and contract: their compiled programs hold
+        no gather, and nothing as large as indices x table (the one-hot spans
+        128 entries, a block of rows at a time); the path that stays is
+        exactly one gather. A table[idx] that comes back into a dense tier is
+        a 9-17 ms pass per 2^20 indices on a v5e (PERF.md section 6, PR 40)."""
+        import math
+        import re
+
+        _, inner, thunk = _kernel_cases()[k]
+        hlo = thunk()
+        gathers = len(re.findall(r" gather\(", hlo))
+        if inner == ("gather",):
+            assert gathers == 1 and not re.search(r" convolution\(| dot\(", hlo)
+            return
+        table = {"dict_gather-dense1": 265, "dict_gather-dense2": 4096}[_KERNEL_IDS[k]]
+        assert gathers == 0, _KERNEL_IDS[k]
+        largest = max(
+            math.prod(int(x) for x in shape.split(","))
+            for shape in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]", hlo)
+        )
+        assert largest < _LOOKUP_N * table, (largest, _LOOKUP_N * table)
+        assert len(re.findall(r"\bwhile\(", hlo)) == 1  # the blocks are a loop, not unrolled
 
     @pytest.mark.parametrize("k", range(3), ids=_KERNEL_IDS[:3])
     def test_the_segment_lookups_hold_no_loop(self, k):
