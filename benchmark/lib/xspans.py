@@ -4,10 +4,11 @@ stage()/span() calls hold open (parquet_tpu/utils/trace.py). One trace plane:
 device ops and host spans sit on the profiler's clock, so nothing is shifted.
 
 `load()` opens the newest trace run.py wrote, once per run; `extract` pulls
-plain lists out of the serialized XSpace; `scope_seconds` and `gap_seconds`
-are arithmetic on those lists, checked on a hand-built trace
-(selftest/xspans_check.py). The XSpace is decoded here, from the protobuf
-wire format (tsl/profiler/protobuf/xplane.proto): the scope path is a stat
+plain lists out of the serialized XSpace; `scope_seconds` is arithmetic on
+those lists, checked on a hand-built trace (selftest/xspans_check.py), and
+lib/xsweep.py puts the device's idle gaps down to the spans. The XSpace is
+decoded here, from the protobuf wire format
+(tsl/profiler/protobuf/xplane.proto): the scope path is a stat
 of an op's event METADATA, which jax's ProfileData does not hand out (its
 `event.stats` are the event's own), and no xplane_pb2 is installed.
 Definitions:
@@ -21,12 +22,7 @@ Definitions:
           seconds are those of the fusions named after it. The line nests: a
           while op's event spans the events of its body;
   spans   every "pqt:" event of the host planes (name cut at '#', where the
-          annotation's arguments start), whatever thread line it is on;
-  gaps    the complement of the union of ops inside the window. A gap goes
-          to the first label of GAP_ORDER that is open ON ANY THREAD at the
-          gap's midpoint, else to "none": the layer furthest down the
-          reader's pipeline that was at work while the device had nothing
-          to run.
+          annotation's arguments start), whatever thread line it is on.
 """
 
 from __future__ import annotations
@@ -42,8 +38,6 @@ SCOPE_MARK = "pqt."
 # where the TPU runtime puts an op's op_name metadata: "tf_op" on the v5e
 # (libtpu 0.0.34, PERF.md section 5); the first that holds a pqt. path wins
 SCOPE_STATS = ("tf_op", "hlo_op", "name", "long_name")
-GAP_ORDER = ("dispatch.upload", "dispatch.launch", "chunk.prepare", "io.read", "deliver")
-NONE = "none"
 
 
 def _varint(buf, i: int) -> tuple:
@@ -197,23 +191,3 @@ def scope_seconds(trace: dict, scope: str):
     hits = [(s, e) for path, s, e in trace["ops"] if want in f"/{path}/"]
     return sum(e - s for s, e in union(clip(hits, lo, hi))) / 1e9
 
-
-def gap_seconds(trace: dict) -> dict | None:
-    """{label: idle seconds} of the device's gaps inside the window, labels
-    being GAP_ORDER's and "none"; they sum to the idle time. None where the
-    trace holds no pqt: span at all (a program without annotations)."""
-    if trace["window"] is None or not trace["spans"] or not trace["ops"]:
-        return None
-    lo, hi = trace["window"]
-    busy = union(clip([(s, e) for _, s, e in trace["ops"]], lo, hi))
-    by_label = {label: union([(s, e) for name, s, e in trace["spans"] if name == label])
-                for label in GAP_ORDER}
-    out = dict.fromkeys((*GAP_ORDER, NONE), 0)
-    edge = lo
-    for s, e in busy + [[hi, hi]]:
-        if s > edge:
-            mid = (edge + s) // 2
-            label = next((lb for lb in GAP_ORDER if any(a <= mid < b for a, b in by_label[lb])), NONE)
-            out[label] += s - edge
-        edge = max(edge, e)
-    return {k: v / 1e9 for k, v in out.items()}

@@ -1,9 +1,8 @@
 """Every idle gap of the device under the name of ONE host span, in one pass.
 
-lib/xspans.py's `gap_seconds` knows five labels and tests every interval of
-every label for every gap: minutes on a trace of the packed cell's size. This
-module takes the same lists (`xspans.load()`: `window`, `ops`, `spans`) and
-the same rule, over every span the program holds open, and costs a sort.
+This module takes lib/xspans.py's lists (`xspans.load()`: `window`, `ops`,
+`spans`) and costs a sort: a reduction that tests every interval of every
+label for every gap takes minutes on a trace of the packed cell's size.
 
 The rule. Every gap of the first device inside "bench:window" (the complement
 of the union of its ops there) goes to exactly ONE label: the first of ORDER
@@ -23,10 +22,10 @@ ORDER, by rank:
   5. OUTSIDE: no such span open. The caller's time: the benchmark's verify,
      the client between two answers, the daemon's _finish.
 
-Restricted to xspans.GAP_ORDER the sweep gives xspans.gap_seconds' numbers
-exactly (selftest/test_xsweep.py). Cost: each label's intervals are merged
-once and every gap's midpoint is looked up in them by binary search, all
-gaps of a label at a time: O((gaps + spans) log spans) a label. `gaps()`
+selftest/test_xsweep.py pins its numbers on a hand-built trace. Cost: each
+label's intervals are merged once and every gap's midpoint is looked up in
+them by binary search, all gaps of a label at a time:
+O((gaps + spans) log spans) a label. `gaps()`
 does it once a run for every metric of the family (readers/xplane_sweep.py).
 """
 
@@ -50,7 +49,7 @@ OUTSIDE = "outside"
 
 def device_gaps(trace: dict) -> np.ndarray:
     """[[start_ns, end_ns]] of the first device's gaps inside the window, in
-    time order (xspans.gap_seconds' walk, kept as a list)."""
+    time order."""
     lo, hi = trace["window"]
     busy = union(clip([(s, e) for _, s, e in trace["ops"]], lo, hi))
     out, edge = [], lo
@@ -61,17 +60,17 @@ def device_gaps(trace: dict) -> np.ndarray:
     return np.asarray(out, dtype=np.int64).reshape(-1, 2)
 
 
-def label_gaps(trace: dict, order: tuple = ORDER) -> tuple:
-    """(gaps, winner): the device's gaps and, for each, the index in `order`
-    of the label it goes to (len(order) = no label open)."""
+def label_gaps(trace: dict) -> tuple:
+    """(gaps, winner): the device's gaps and, for each, the index in ORDER
+    of the label it goes to (len(ORDER) = no label open)."""
     gaps = device_gaps(trace)
     mids = (gaps[:, 0] + gaps[:, 1]) // 2
     by_label: dict = {}
     for name, s, e in trace["spans"]:
         by_label.setdefault(name, []).append((s, e))
-    winner = np.full(len(gaps), len(order), dtype=np.int64)
-    for rank in range(len(order) - 1, -1, -1):  # last to first: the first of the order wins
-        merged = np.asarray(union(by_label.get(order[rank], [])), dtype=np.int64).reshape(-1, 2)
+    winner = np.full(len(gaps), len(ORDER), dtype=np.int64)
+    for rank in range(len(ORDER) - 1, -1, -1):  # last to first: the first of the order wins
+        merged = np.asarray(union(by_label.get(ORDER[rank], [])), dtype=np.int64).reshape(-1, 2)
         if not len(merged):
             continue
         at = np.searchsorted(merged[:, 0], mids, side="right") - 1
@@ -79,15 +78,15 @@ def label_gaps(trace: dict, order: tuple = ORDER) -> tuple:
     return gaps, winner
 
 
-def gap_seconds(trace: dict, order: tuple = ORDER, none: str = OUTSIDE) -> dict | None:
-    """{label: idle seconds} over `order`'s labels and `none`; they sum to the
+def gap_seconds(trace: dict) -> dict | None:
+    """{label: idle seconds} over ORDER's labels and OUTSIDE; they sum to the
     window's idle time. A label that never opens reads 0. None where the
     trace holds no window, no device op or no pqt: span at all."""
     if trace["window"] is None or not trace["spans"] or not trace["ops"]:
         return None
-    gaps, winner = label_gaps(trace, order)
-    sums = np.bincount(winner, weights=gaps[:, 1] - gaps[:, 0], minlength=len(order) + 1)
-    return {label: int(ns) / 1e9 for label, ns in zip((*order, none), sums)}
+    gaps, winner = label_gaps(trace)
+    sums = np.bincount(winner, weights=gaps[:, 1] - gaps[:, 0], minlength=len(ORDER) + 1)
+    return {label: int(ns) / 1e9 for label, ns in zip((*ORDER, OUTSIDE), sums)}
 
 
 @functools.lru_cache(maxsize=1)

@@ -3,8 +3,7 @@
     JAX_PLATFORMS=cpu python -m pytest benchmark/selftest/test_xsweep.py -q     (CPU, half a minute)
 
 1. On fixture.xspans.txt (selftest/xspans_check.py's hand-built trace) the
-   sweep restricted to xspans.GAP_ORDER gives xspans.gap_seconds' numbers
-   label by label, and the full order the same sums under the new names.
+   sweep gives the fixture header's literal numbers.
 2. On a hand-built daemon trace every label of the order wins the gap it
    should while every later one is open too, and the labels sum to window -
    busy.
@@ -16,6 +15,10 @@
    spans open at the longest gaps' midpoints.
 5. A CPU rehearsal with --trace 1 of each cell reports the program-span
    metrics of the family and none of the eight gap_* (obs.xplane is None).
+6. The harness's files: every layer_metrics/*.json names a reader that
+   exists, every per-layer metric of BENCHMARK.json has its file, and no
+   module under benchmark/ brings back the quadratic reduction (xspans'
+   gap_seconds, retired for lib/xsweep.py).
 tests/test_benchmark_selftest.py is tier-1's door to this file.
 """
 
@@ -23,6 +26,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -40,6 +44,7 @@ import xsweep  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
+QUERY_CELLS = ["tpch-sf10.q6", "tpch-sf10.q1"]  # the cells that go through the daemon
 GAP_METRICS = ["gap_upload_ms_per_mrow", "gap_launch_ms_per_mrow", "gap_prepare_ms_per_mrow",
                "gap_deliver_ms_per_mrow", "gap_consumer_wait_ms_per_mrow", "gap_query_unit_ms_per_mrow",
                "gap_request_ms_per_mrow", "gap_outside_ms_per_mrow"]
@@ -53,13 +58,9 @@ def fixture_xspace() -> bytes:
 
 def test_restricted_to_the_old_order_the_sweep_is_the_old_reduction():
     trace = xspans.extract(fixture_xspace())
-    old = xspans.gap_seconds(trace)
-    assert xsweep.gap_seconds(trace, xspans.GAP_ORDER, xspans.NONE) == old
-    assert {k: round(v * 1e9) for k, v in old.items() if v} == {
-        "dispatch.upload": 2500, "chunk.prepare": 1000, "deliver": 3000, "none": 3500}
     full = xsweep.gap_seconds(trace)
     assert set(full) == {*xsweep.ORDER, xsweep.OUTSIDE}
-    assert {k: round(v * 1e9) for k, v in full.items() if v} == {
+    assert {k: round(v * 1e9) for k, v in full.items() if v} == {  # the fixture header's numbers
         "dispatch.upload": 2500, "chunk.prepare": 1000, "deliver": 3000, "outside": 3500}
     # a program without annotations: nothing to read, nothing raised
     assert xsweep.gap_seconds(dict(trace, spans=[])) is None
@@ -123,7 +124,7 @@ def test_the_readers_share_one_sweep_and_read_nothing_on_a_rehearsal(monkeypatch
     assert by_file["gap_request_ms_per_mrow"] == pytest.approx(sum(want[k] for k in xsweep.REQUEST) * per_mrow)
     listed = {m["name"]: m for m in BENCH["per_layer"]}
     for name in GAP_METRICS:
-        cells = CELLS if "query_unit" not in name and "request" not in name else ["tpch-sf10.q6"]
+        cells = CELLS if "query_unit" not in name and "request" not in name else QUERY_CELLS
         assert listed[name]["workloads"] == cells and listed[name]["source"] == "device_trace"
 
 
@@ -180,10 +181,31 @@ def test_a_traced_rehearsal_reports_the_span_metrics_and_no_gap_metric(cell):
     metrics = line["metrics"]
     assert metrics["consumer_wait_ms_per_mrow"]["value"] > 0
     assert not set(GAP_METRICS) & set(metrics), "a device-trace metric on a CPU rehearsal"
-    if cell == "tpch-sf10.q6":
+    if cell in QUERY_CELLS:
         assert metrics["query_decode_ms_per_mrow"]["value"] > 0
         assert metrics["request_host_ms_per_query"] == {"value": metrics["request_host_ms_per_query"]["value"],
                                                          "unit": "ms/query"}
         assert metrics["request_host_ms_per_query"]["value"] > 0
     else:
         assert "query_decode_ms_per_mrow" not in metrics and "request_host_ms_per_query" not in metrics
+
+
+def test_every_metric_file_names_a_reader_that_exists():
+    readers = {p.stem for p in (HERE.parent / "readers").glob("*.py")}
+    files = {p.stem: json.loads(p.read_text()) for p in (HERE.parent / "layer_metrics").glob("*.json")}
+    assert {name: spec["reader"] for name, spec in files.items() if spec["reader"] not in readers} == {}
+    assert all(spec["name"] == name for name, spec in files.items())
+    assert {m["name"] for m in BENCH["per_layer"]} <= set(files), "a per-layer metric without its file"
+
+
+def test_no_module_brings_back_the_quadratic_gap_reduction():
+    assert not hasattr(xspans, "gap_seconds")
+    found = {}
+    for path in (ROOT / "benchmark").rglob("*.py"):
+        text = path.read_text()
+        hits = re.findall(r"xspans\.gap_seconds|from xspans import[^\n]*\bgap_seconds\b", text)
+        if path != HERE.parent / "lib" / "xsweep.py":  # the sweep's own gap_seconds
+            hits += re.findall(r"^def gap_seconds\b", text, flags=re.M)
+        if hits:
+            found[str(path.relative_to(ROOT))] = hits
+    assert found == {}

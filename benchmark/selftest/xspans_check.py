@@ -1,4 +1,4 @@
-"""lib/xspans.py and the two readers over it, checked on a hand-built trace
+"""lib/xspans.py and the scope reader over it, checked on a hand-built trace
 whose numbers are known.
 
     python benchmark/selftest/xspans_check.py
@@ -38,33 +38,19 @@ def main() -> int:
     assert ns("pqt.hybrid") == 0  # whole path components only
     assert ns("pqt.dict_gather") == 0  # scopes are there, this one ran nothing
 
-    gaps = {k: round(v * 1e9) for k, v in xspans.gap_seconds(trace).items()}
-    assert gaps == {"dispatch.upload": 2500, "dispatch.launch": 0, "chunk.prepare": 1000, "io.read": 0,
-                    "deliver": 3000, "none": 3500}, gaps
-    busy = 10000
-    assert sum(gaps.values()) == (21000 - 1000) - busy  # the gap metrics add up to the idle time
-
     # a program without scopes or annotations (the parent of PR 26): nothing to read, nothing raised
     bare = dict(trace, ops=[("", s, e) for _, s, e in trace["ops"]], spans=[])
-    assert xspans.scope_seconds(bare, "pqt.hybrid_expand") is None and xspans.gap_seconds(bare) is None
+    assert xspans.scope_seconds(bare, "pqt.hybrid_expand") is None
 
-    # the readers: None on a rehearsal (obs.xplane is None), numbers over the denominator otherwise
-    import xplane_gap
+    # the reader: None on a rehearsal (obs.xplane is None), numbers over the denominator otherwise
     import xplane_scope
 
-    xspans.load = lambda: trace
-    xplane_gap.load = xplane_scope.load = xspans.load
-    obs = SimpleNamespace(xplane={"busy_s": busy / 1e9}, rows=2_000_000, window_s=20000 / 1e9)
+    xplane_scope.load = lambda: trace
+    obs = SimpleNamespace(xplane={"busy_s": 10000 / 1e9}, rows=2_000_000, window_s=20000 / 1e9)
     assert xplane_scope.read(SimpleNamespace(xplane=None), "pqt.hybrid_expand", "mrow") is None
-    assert xplane_gap.read(SimpleNamespace(xplane=None), ["none"], "mrow") is None
     ms_per_mrow = lambda ns_: ns_ / 1e9 * 1e3 / 2.0  # noqa: E731
     assert abs(xplane_scope.read(obs, "pqt.hybrid_expand/find_run", "mrow") - ms_per_mrow(4000)) < 1e-12
-    parts = [xplane_gap.read(obs, labels, "mrow") for labels in
-             (["dispatch.upload", "dispatch.launch"], ["chunk.prepare", "io.read"], ["deliver", "none"])]
-    assert [round(p / ms_per_mrow(1)) for p in parts] == [2500, 1000, 6500], parts
-    idle = ms_per_mrow((21000 - 1000) - busy)
-    assert abs(sum(parts) - idle) < 1e-9 * idle
-    print("xspans_check: ok (scopes 5000/4000/4500 ns; gaps 2500 + 1000 + 6500 = 10000 ns idle of a 20000 ns window)")
+    print("xspans_check: ok (scopes 5000/4000/4500 ns of a 20000 ns window)")
     return 0
 
 
