@@ -18,6 +18,7 @@ import gc
 import itertools
 import os
 import threading
+from collections import deque
 from contextlib import contextmanager, nullcontext
 from functools import partial
 from concurrent.futures import ThreadPoolExecutor
@@ -85,6 +86,13 @@ class _GroupQuarantined(Exception):
 
 _pool: ThreadPoolExecutor | None = None
 _pool_lock = threading.Lock()
+
+# How many row groups lists="pack" stages ahead of the one it delivers. A
+# group is one chunk, which one pool thread prepares in about twice the time
+# the consumer takes to deliver and pack a group (tok-8k.packed): with one
+# group ahead the prepare would set the pace, with two it stays under the
+# consumer's.
+_PACKED_LOOKAHEAD = 2
 
 
 def _host_pool() -> ThreadPoolExecutor | None:
@@ -1251,15 +1259,17 @@ class FileReader:
         sharding=None, device=None,
     ):
         """lists="pack": the row groups' decoded ids, delivered at their
-        padded lengths, go through one SequencePacker (core/packing.py) with
-        the same one-group lookahead as _iter_device_batches. The packer's
-        launches are the deliver.pack stage, inside deliver."""
+        padded lengths, go through one SequencePacker (core/packing.py),
+        _PACKED_LOOKAHEAD groups staged ahead (none under a memory ceiling,
+        as in _iter_device_batches). The packer's launches are the
+        deliver.pack stage, inside deliver."""
         import jax
 
         from .packing import PackedBatch, SequencePacker
 
         columns = [leaf.path]
-        lookahead = self.alloc is None  # see _iter_device_batches
+        # a memory ceiling forbids the lookahead (see _iter_device_batches)
+        depth = _PACKED_LOOKAHEAD if self.alloc is None else 0
 
         def plan_of(i, staged):
             if staged is not None:
@@ -1278,22 +1288,30 @@ class FileReader:
 
         packer = SequencePacker(batch_size, seq_len)
         groups = range(self.num_row_groups)
-        staged_next = stage_group(0) if lookahead and groups else None
-        for i in groups:
-            # device work scoped so the pin never leaks across a yield
-            with self._devctx(device):
-                staged = staged_next
-                if lookahead:
-                    staged_next = stage_group(i + 1) if i + 1 < len(groups) else None
-                plan = plan_of(i, staged)
-                with stage("deliver", args=_chunk_args(i, leaf.path)):
-                    values, count = plan.device_values_padded()
-                    with stage("deliver.pack"):
-                        packer.append(values, plan.dev_lengths, plan.list_lengths, count)
-            while packer.ready():
-                with self._devctx(device), stage("deliver"), stage("deliver.pack"):
-                    batch = placed(packer.emit())
-                yield batch
+        ahead = deque(map(stage_group, groups[:depth]))
+        try:
+            for i in groups:
+                # device work scoped so the pin never leaks across a yield
+                with self._devctx(device):
+                    staged = ahead.popleft() if ahead else None
+                    if depth and i + depth < len(groups):
+                        ahead.append(stage_group(i + depth))
+                    plan = plan_of(i, staged)
+                    with stage("deliver", args=_chunk_args(i, leaf.path)):
+                        values, count = plan.device_values_padded()
+                        with stage("deliver.pack"):
+                            packer.append(values, plan.dev_lengths, plan.list_lengths, count)
+                while packer.ready():
+                    with self._devctx(device), stage("deliver"), stage("deliver.pack"):
+                        batch = placed(packer.emit())
+                    yield batch
+        finally:
+            # a consumer that stops early, or a group that raised, leaves
+            # staged groups in flight: they finish (their errors read and
+            # dropped) before the reader's source may close under them
+            for futs in ahead:
+                for _, fut in futs:
+                    fut.exception()
         sequences = packer.sequences_left()
         if not sequences or (drop_remainder and sequences < batch_size):
             return
@@ -1325,8 +1343,10 @@ class FileReader:
         Every chunk's prepare is submitted to the worker pool up front (no
         per-group barrier — the pool never drains between groups); device
         dispatch is enqueued per chunk in deterministic (group, column) order
-        as its prepare resolves. Returns [[(path, future-of-dispatched-plan)]]
-        per group, unresolved."""
+        as its prepare resolves. A stage of ONE chunk is one pool task,
+        prepare then dispatch, and returns without waiting on either
+        (counter pooled_single_chunk_stages). Returns [[(path,
+        future-of-dispatched-plan)]] per group, unresolved."""
         from ..kernels.pipeline import check_doubles_form, prepare_chunk_plan
         from ..utils.native import get_native
         from .chunk import ChunkWindow, chunk_byte_range
@@ -1373,7 +1393,7 @@ class FileReader:
         # way to attribute worker time to the right trace). It also records
         # how long each task waited for its pool (pool.wait): for the single
         # pqt-dispatch thread, the wait of a prepared chunk behind the queue.
-        if pool is None or sum(len(chunks) for _, chunks in groups) <= 1:
+        if pool is None:
             # Single-core host: prepare serially; device dispatch (transfer
             # RPCs, which release the GIL) still overlaps the next prepare.
             return [
@@ -1384,6 +1404,24 @@ class FileReader:
                 for i, chunks in groups
             ]
         get_native()  # thread-safe lazy init before fan-out
+        if sum(len(chunks) for _, chunks in groups) == 1:
+            # One chunk (a one-column group: lists="pack", a single-column
+            # batch stream): ONE pool task prepares it and enqueues its
+            # dispatch, so the caller's thread stages without preparing and
+            # a prepare error waits in the future for the group's own
+            # delivery. The task may wait on the dispatch thread, which
+            # never waits on the pool.
+            def prep_and_dispatch(i, path, cc, column):
+                return dispatch(i, path, prep(i, path, cc, column)).result()
+
+            bump("pooled_single_chunk_stages")
+            return [
+                [
+                    (path, instrumented_submit(pool, prep_and_dispatch, i, path, cc, column))
+                    for path, cc, column in chunks
+                ]
+                for i, chunks in groups
+            ]
         prep_futs = [
             (
                 i,
